@@ -41,7 +41,6 @@ Layout:
 
 __version__ = "0.1.0"
 
-from lux_tpu import _compat  # noqa: F401  (jax version shims)
 from lux_tpu.format import LuxFileHeader, read_lux, write_lux, peek_lux
 from lux_tpu.graph import Graph, ShardedGraph
 from lux_tpu.partition import edge_balanced_bounds

@@ -91,13 +91,16 @@ __all__ = [
 ]
 
 # collective primitive names as they appear in traced jaxprs; the
-# psum_scatter API lowers to a "reduce_scatter" eqn, normalized below
+# psum_scatter API lowers to a "reduce_scatter" eqn, and a psum of a
+# device-varying value inside a VMA-checked shard_map traces as
+# "psum_invariant" (same all-reduce on the wire) — normalized below
 COLLECTIVE_PRIMS = frozenset({
     "ppermute", "all_to_all", "psum_scatter", "reduce_scatter",
-    "all_gather", "psum", "pmin", "pmax",
+    "all_gather", "psum", "psum_invariant", "pmin", "pmax",
 })
 
-_NORMALIZE = {"psum_scatter": "reduce_scatter"}
+_NORMALIZE = {"psum_scatter": "reduce_scatter",
+              "psum_invariant": "psum"}
 
 
 class CommLedgerError(Exception):

@@ -105,22 +105,24 @@ def rmat_edges(scale: int, edge_factor: int = 16, seed: int = 0,
 
 
 def rmat_graph(scale: int, edge_factor: int = 16, seed: int = 0,
-               prefer_native: bool = True):
-    """Build an R-MAT Graph, using the native C++ generate+sort+CSC
-    path when available (~10x faster host setup at benchmark scales);
-    falls back to rmat_edges + edges_to_csc.  The two paths use
-    different RNG streams: same distribution, different instances."""
+               use_native: bool = True):
+    """Build an R-MAT Graph with the native C++ generate+sort+CSC
+    path (~10x faster host setup at benchmark scales).  The NumPy
+    generator (``use_native=False``: rmat_edges + edges_to_csc) draws
+    from a different RNG stream — same distribution, a DIFFERENT
+    graph for the same seed — so it is never substituted silently:
+    a missing native library raises (native._load_lib) instead of
+    handing a benchmark another instance."""
     from lux_tpu.graph import Graph
 
-    if prefer_native:
+    if use_native:
         from lux_tpu import native
-        if native.available():
-            row_ptrs, col_idx, degrees = native.rmat_csc(
-                scale, edge_factor, seed)
-            nv = 1 << scale
-            return Graph(nv=nv, ne=int(col_idx.shape[0]),
-                         row_ptrs=row_ptrs, col_idx=col_idx,
-                         weights=None, out_degrees=degrees)
+        row_ptrs, col_idx, degrees = native.rmat_csc(
+            scale, edge_factor, seed)
+        nv = 1 << scale
+        return Graph(nv=nv, ne=int(col_idx.shape[0]),
+                     row_ptrs=row_ptrs, col_idx=col_idx,
+                     weights=None, out_degrees=degrees)
     src, dst, nv = rmat_edges(scale, edge_factor, seed)
     return Graph.from_edges(src, dst, nv)
 

@@ -31,6 +31,8 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from lux_tpu.parallel.mesh import vary_like
+
 # Block length for the MXU cumsum-as-matmul in expand_frontier: one
 # int8 lower-triangular [B, B] matrix (64 KB) contracted per block,
 # same sizing rationale as ops/tiled.MXU_SCAN_BLOCK.
@@ -59,8 +61,9 @@ def _cumsum_matmul(x, block: int = FRONTIER_MXU_BLOCK):
         out = inner + carry
         return out[-1], out
 
-    _, blocks = jax.lax.scan(step, jnp.zeros((), x.dtype),
-                             x.reshape(nB, block))
+    _, blocks = jax.lax.scan(
+        step, vary_like(jnp.zeros((), x.dtype), x),
+        x.reshape(nB, block))
     return blocks.reshape(Np)[:N]
 
 

@@ -24,9 +24,11 @@ _lib = None
 
 
 def ensure_built(quiet: bool = True) -> bool:
-    """Build the native tools if missing.  Returns availability."""
-    if os.path.exists(_LIB) and os.path.exists(CONVERTER):
-        return True
+    """Run ``make`` for the native tools and return availability.
+    ALWAYS runs it (a no-op when the build is up to date): an existing
+    ``build/`` is not trusted, because a tree copied as it stands on
+    disk carries the ignored build directory along — what runs must
+    be what the sources in THIS tree build."""
     try:
         subprocess.run(["make", "-C", _DIR],
                        check=True,
@@ -36,37 +38,15 @@ def ensure_built(quiet: bool = True) -> bool:
     return os.path.exists(_LIB) and os.path.exists(CONVERTER)
 
 
-def _rebuild() -> bool:
-    """Force a rebuild (stale .so from before a source was added)."""
-    try:
-        subprocess.run(["make", "-C", _DIR, "clean"], check=True,
-                       capture_output=True)
-        subprocess.run(["make", "-C", _DIR], check=True,
-                       capture_output=True)
-    except (OSError, subprocess.CalledProcessError):
-        return False
-    return os.path.exists(_LIB)
-
-
 def _load_lib():
     global _lib
     if _lib is not None:
         return _lib
-    if not os.path.exists(_LIB) and not ensure_built():
-        raise OSError("native library unavailable (no toolchain?)")
+    if not ensure_built():
+        raise OSError("native library unavailable: `make -C "
+                      f"{_DIR}` failed (no toolchain?)")
     lib = ctypes.CDLL(_LIB)
-    try:
-        _bind(lib)
-    except AttributeError:
-        # stale build missing a newer symbol: rebuild once.  dlopen
-        # caches by path, so the old handle must be closed before the
-        # rebuilt library can be mapped.
-        import _ctypes
-        _ctypes.dlclose(lib._handle)
-        if not _rebuild():
-            raise OSError("native library stale and rebuild failed")
-        lib = ctypes.CDLL(_LIB)
-        _bind(lib)
+    _bind(lib)
     _lib = lib
     return lib
 

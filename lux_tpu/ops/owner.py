@@ -385,8 +385,7 @@ OWNER_SCAN_KEYS = ("own_src", "own_rel", "own_cs", "own_lc", "own_w",
 
 def owner_contribs(lay: OwnerLayout, state_rows, g: dict,
                    kind: str, msg_fn, msg_dtype, num_parts: int,
-                   reduce_method: str, varying_axis=None,
-                   use_mxu: bool = False):
+                   reduce_method: str, use_mxu: bool = False):
     """lax.scan over the locally-held SOURCE parts: each step gathers
     from ONE [vpad, ...] state shard (the scan is what makes the XLA
     emitter see the small table — a vmapped batched gather still pays
@@ -395,14 +394,13 @@ def owner_contribs(lay: OwnerLayout, state_rows, g: dict,
     ``[num_parts, n_tiles*W, ...]`` to every destination part.
 
     g: graph-array dict; the OWNER_SCAN_KEYS present in it ride the
-    scan with the local-row leading dim.  varying_axis: mesh axis name
-    when called under shard_map (marks the identity carry
-    device-varying)."""
+    scan with the local-row leading dim."""
     import jax
     import jax.numpy as jnp
 
     from lux_tpu.ops.segment import identity_for
     from lux_tpu.ops.tiled import combine_op
+    from lux_tpu.parallel.mesh import vary_like
 
     ntw = lay.n_tiles * lay.W
     comb = combine_op(kind)
@@ -415,18 +413,14 @@ def owner_contribs(lay: OwnerLayout, state_rows, g: dict,
             d.get("own_rel"), d.get("own_w"),
             d["own_cs"], d["own_lc"], kind, msg_fn, reduce_method,
             use_mxu=use_mxu, extr_pos=d.get("own_ep"),
-            extr_tile=d.get("own_et"), varying_axis=varying_axis,
-            nvalid=d.get("own_nv"))
+            extr_tile=d.get("own_et"), nvalid=d.get("own_nv"))
         contrib = tiles.reshape((num_parts, ntw) + tiles.shape[2:])
         return comb(acc, contrib), None
 
     acc0 = jnp.full((num_parts, ntw) + state_rows.shape[2:],
                     identity_for(kind, msg_dtype), msg_dtype)
-    if varying_axis is not None:
-        # the scan folds in device-varying contributions; the constant
-        # initial carry must be marked varying too (VMA)
-        acc0 = jax.lax.pcast(acc0, (varying_axis,), to="varying")
-    acc, _ = jax.lax.scan(step, acc0, (state_rows, xs))
+    acc, _ = jax.lax.scan(step, vary_like(acc0, state_rows, xs),
+                          (state_rows, xs))
     return acc
 
 
@@ -497,7 +491,7 @@ def ring_reduce_scatter(acc, kind: str, axis, ndev: int):
 def owner_part_tiles(lay: OwnerLayout, state_s, src, rel, weight, cs,
                      lc, kind: str, msg_fn, reduce_method: str,
                      use_mxu: bool = False, extr_pos=None,
-                     extr_tile=None, varying_axis=None, nvalid=None):
+                     extr_tile=None, nvalid=None):
     """One source part's contribution: gather from its OWN shard
     ``state_s [vpad, ...]``, message, chunk-reduce, and combine into
     per-global-tile results ``[G, W, ...]`` (identity where the part
@@ -517,8 +511,7 @@ def owner_part_tiles(lay: OwnerLayout, state_s, src, rel, weight, cs,
         return streamed_chunk_combined(
             state_s, src, rel, weight, lay, kind, msg_fn,
             reduce_method, cs, extr_pos, extr_tile, lc,
-            use_mxu=use_mxu,
-            varying_axis=varying_axis, nvalid=nvalid)  # [G, W, ...]
+            use_mxu=use_mxu, nvalid=nvalid)           # [G, W, ...]
     if lay.streams():
         partials = streamed_chunk_partials(
             state_s, src, rel, weight, lay, kind, msg_fn, reduce_method,

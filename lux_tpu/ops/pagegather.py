@@ -1047,6 +1047,8 @@ def _lane_shuffle_pallas(rows, slot_lane, block_r: int = 512,
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
+    from lux_tpu.parallel.mesh import vma_of
+
     R, Wd = rows.shape
     bm = block_r if R % block_r == 0 else 8
     return pl.pallas_call(
@@ -1060,7 +1062,10 @@ def _lane_shuffle_pallas(rows, slot_lane, block_r: int = 512,
         ],
         out_specs=pl.BlockSpec((bm, Wd), lambda b: (b, 0),
                                memory_space=pltpu.VMEM),
-        out_shape=jax.ShapeDtypeStruct((R, Wd), rows.dtype),
+        # under shard_map the result varies over the inputs' mesh
+        # axes; the VMA check needs it stated (see pallas_reduce.py)
+        out_shape=jax.ShapeDtypeStruct((R, Wd), rows.dtype,
+                                       vma=vma_of(rows, slot_lane)),
         interpret=interpret,
     )(rows, slot_lane)
 
@@ -1263,7 +1268,7 @@ def plan_graph_arrays(pp: PagedPlan, dev, owner: bool, dot: bool,
 
 def paged_owner_contribs(pp: PagedPlan, state_rows, g: dict, kind: str,
                          msg_fn, msg_dtype, num_parts: int,
-                         reduce_method: str, varying_axis=None):
+                         reduce_method: str):
     """lax.scan over the locally-held SOURCE parts, each step running
     the paged delivery against ONE [vpad, ...] state shard (the shard
     reshapes to its own [vpad/128, 128, ...] page table — the scan
@@ -1276,6 +1281,7 @@ def paged_owner_contribs(pp: PagedPlan, state_rows, g: dict, kind: str,
 
     from lux_tpu.ops.segment import identity_for
     from lux_tpu.ops.tiled import combine_op
+    from lux_tpu.parallel.mesh import vary_like
 
     ntw = pp.n_tiles * W // num_parts
     comb = combine_op(kind)
@@ -1292,16 +1298,15 @@ def paged_owner_contribs(pp: PagedPlan, state_rows, g: dict, kind: str,
 
     acc0 = jnp.full((num_parts, ntw) + state_rows.shape[2:],
                     identity_for(kind, msg_dtype), msg_dtype)
-    if varying_axis is not None:
-        acc0 = jax.lax.pcast(acc0, (varying_axis,), to="varying")
-    acc, _ = jax.lax.scan(step, acc0, (state_rows, xs))
+    acc, _ = jax.lax.scan(step, vary_like(acc0, state_rows, xs),
+                          (state_rows, xs))
     return acc
 
 
 def pagemajor_owner_deliver(pp: PagedPlan, state_rows, g: dict,
                             kind: str, msg_fn, msg_dtype,
                             num_parts: int, reduce_method: str,
-                            axis=None, varying_axis=None):
+                            axis=None):
     """The PAGE-MAJOR owner delivery, routing included: a lax.scan
     over the locally-held SOURCE parts runs the full-fill gather-row
     pipeline against each shard's own page table and emits COMPLETE
@@ -1322,9 +1327,6 @@ def pagemajor_owner_deliver(pp: PagedPlan, state_rows, g: dict,
 
     Mg = pp.route
     xs = {k: g[k] for k in PAGEMAJOR_OWNER_SEND_KEYS if k in g}
-    carry0 = jnp.zeros((), jnp.int32)
-    if varying_axis is not None:
-        carry0 = jax.lax.pcast(carry0, (varying_axis,), to="varying")
 
     def step(c, x):
         st_s, d = x
@@ -1333,7 +1335,7 @@ def pagemajor_owner_deliver(pp: PagedPlan, state_rows, g: dict,
         msgs = msg_fn(vals, d.get("own_pm_w")).astype(msg_dtype)
         return c, msgs
 
-    _, msgs = jax.lax.scan(step, carry0, (state_rows, xs))
+    _, msgs = jax.lax.scan(step, None, (state_rows, xs))
     # msgs [L_src, P_dst * Mg, 128, ...] -> route whole rows
     L = msgs.shape[0]
     m = msgs.reshape((L, num_parts, Mg) + msgs.shape[2:])

@@ -33,6 +33,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from lux_tpu.ops.segment import identity_for
+from lux_tpu.parallel.mesh import vma_of
 
 
 def _partial_kernel(vals_ref, rel_ref, out_ref, *, W: int, kind: str):
@@ -84,6 +85,10 @@ def chunk_partials_pallas(vals, rel_dst, W: int, kind: str,
         ],
         out_specs=pl.BlockSpec((block_c, W), lambda b: (b, 0),
                                memory_space=pltpu.VMEM),
-        out_shape=jax.ShapeDtypeStruct((C, W), vals.dtype),
+        # inside shard_map the kernel's result varies over whatever
+        # mesh axes its inputs vary over; the VMA check needs that
+        # stated on the out_shape (empty outside shard_map)
+        out_shape=jax.ShapeDtypeStruct((C, W), vals.dtype,
+                                       vma=vma_of(vals, rel_dst)),
         interpret=interpret,
     )(vals, rel_dst)

@@ -37,6 +37,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from lux_tpu.ops.segment import identity_for
+from lux_tpu.parallel.mesh import vary_like
 
 
 def _ceil_div(a: int, b: int) -> int:
@@ -314,7 +315,8 @@ def _mxu_compare_reduce(vals, rel_dst, W: int, kind: str):
         cand = cand & (back == bit)
         return cand, res
 
-    _, res = jax.lax.fori_loop(0, bits, bitplane, (cand0, res0))
+    _, res = jax.lax.fori_loop(
+        0, bits, bitplane, vary_like((cand0, res0), m, onehot))
     if kind == "min":
         res = (~res) & (jnp.uint32(0xFFFFFFFF) >> (32 - bits))
     out = _order_decode(res, vals.dtype)
@@ -463,7 +465,7 @@ def _segscan_blocked(partials, chunk_start, kind,
 
     carry0 = jnp.full(trail, ident, partials.dtype)
     _, blocks = jax.lax.scan(
-        step, carry0,
+        step, vary_like(carry0, partials, chunk_start),
         (partials.reshape((nB, block) + trail),
          chunk_start.reshape(nB, block)))
     return blocks.reshape((Cp,) + trail)[:C]
@@ -483,7 +485,7 @@ def _segscan_matmul(partials, chunk_start, block: int | None = None):
     products (TCU scan-as-matmul, PAPERS.md): per block the lower-
     triangular same-segment matrix T[i, j] = (i >= j) & (seg i == seg
     j) is built ON DEVICE from cumsum(flags) (no baked constant — the
-    413 const-bytes audit stays green) and one int8 contraction
+    const-bytes audit stays green) and one int8 contraction
     produces every prefix in the block; the carry folds into rows
     before the block's first flag exactly as _segscan_blocked.
     Sum-only: min/max have no matmul recurrence (see combine_chunks).
@@ -520,7 +522,7 @@ def _segscan_matmul(partials, chunk_start, block: int | None = None):
 
     carry0 = jnp.full(trail, ident, partials.dtype)
     _, blocks = jax.lax.scan(
-        step, carry0,
+        step, vary_like(carry0, partials, chunk_start),
         (partials.reshape((nB, block) + trail),
          chunk_start.reshape(nB, block)))
     return blocks.reshape((Cp,) + trail)[:C]
@@ -704,7 +706,7 @@ def streamed_chunk_combined(flat_state, src_slot, rel_dst, weight,
                             extr_pos, extr_tile, last_chunk,
                             use_mxu: bool = False,
                             block_chunks: int | None = None,
-                            varying_axis=None, nvalid=None):
+                            nvalid=None):
     """Fused streamed gather + message + per-chunk partials +
     BLOCKED segmented combine + last-chunk extraction for ONE part:
     returns per-tile results [n_tiles, W, ...] WITHOUT ever
@@ -785,12 +787,8 @@ def streamed_chunk_combined(flat_state, src_slot, rel_dst, weight,
     n_tiles = last_chunk.shape[0]
     run0 = jnp.full((W,) + trail, ident, msg_aval.dtype)
     acc0 = jnp.full((n_tiles + 1, W) + trail, ident, msg_aval.dtype)
-    if varying_axis is not None:
-        # under shard_map the constant initial carry must be marked
-        # device-varying (the scan folds in sharded contributions)
-        run0 = jax.lax.pcast(run0, (varying_axis,), to="varying")
-        acc0 = jax.lax.pcast(acc0, (varying_axis,), to="varying")
-    (_, acc), _ = jax.lax.scan(step, (run0, acc0), xs)
+    (_, acc), _ = jax.lax.scan(
+        step, vary_like((run0, acc0), flat_state, xs), xs)
     out = acc[:n_tiles]                               # [n_tiles, W, ..]
     empty = (last_chunk < 0).reshape(
         last_chunk.shape + (1,) * (out.ndim - 1))

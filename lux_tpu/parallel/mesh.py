@@ -30,6 +30,31 @@ def make_mesh(num_devices: int | None = None, devices=None) -> Mesh:
     return Mesh(np.asarray(devices), (PARTS_AXIS,))
 
 
+def vma_of(*refs) -> frozenset:
+    """The manual mesh axes any leaf of ``refs`` varies over — the
+    varying-manual-axes (VMA) type of whatever is computed from them.
+    Empty outside ``shard_map``."""
+    return frozenset().union(
+        *(jax.typeof(r).vma for r in jax.tree.leaves(refs)))
+
+
+def vary_like(init, *refs):
+    """Type a constant loop carry for a loop whose body folds ``refs``
+    into it.  Inside ``shard_map`` a scan/while carry must have the
+    same VMA type going in as coming out; a fresh
+    ``jnp.zeros``/``jnp.full`` is device-invariant while a body that
+    mixes in sharded ``refs`` yields a device-varying value.  Casts
+    every leaf of ``init`` to vary over ``vma_of(refs)``; the identity
+    outside shard_map."""
+    want = vma_of(refs)
+
+    def cast(x):
+        axes = tuple(want - jax.typeof(x).vma)
+        return jax.lax.pcast(x, axes, to="varying") if axes else x
+
+    return jax.tree.map(cast, init)
+
+
 def parts_spec(mesh: Mesh) -> NamedSharding:
     return NamedSharding(mesh, PartitionSpec(PARTS_AXIS))
 

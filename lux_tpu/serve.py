@@ -1431,6 +1431,8 @@ def _check_answers(g, responses) -> int:
 def main(argv=None) -> int:
     import argparse
 
+    from lux_tpu import runtime
+    runtime.use_compile_cache()
     ap = argparse.ArgumentParser(
         prog="python -m lux_tpu.serve",
         description="continuous-batching serve smoke: 2B mixed "

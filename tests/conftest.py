@@ -15,3 +15,10 @@ import jax
 
 # Keep default 32-bit types: that is what runs on TPU.
 jax.config.update("jax_platforms", "cpu")
+
+
+def pytest_configure(config):
+    config.addinivalue_line(
+        "markers",
+        "slow: minutes-long builds/sweeps, deselected by tier-1 "
+        "(-m 'not slow')")

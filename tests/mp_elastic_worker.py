@@ -70,7 +70,12 @@ def run_distributed(pid: int, nproc: int, port: str, workdir: str):
         topo = hb.propose_shrink(survivors, generation=1)
         print(f"SHRINK pid={pid} lost={list(e.lost)} "
               f"survivors={topo['survivors']}", flush=True)
-        sys.exit(3)
+        # os._exit, not sys.exit: interpreter teardown would run
+        # jax.distributed's shutdown barrier, which cannot complete
+        # with a dead peer and aborts the survivor (exit 1) instead
+        # of letting it report the relaunch code.  The checkpoint and
+        # the topology record are already durable.
+        os._exit(3)
     print(f"MP_ELASTIC_OK pid={pid}", flush=True)
 
 

@@ -1,0 +1,207 @@
+"""Compile-only TPU v5e checks, runnable without a chip.
+
+``jax.experimental.topologies.get_topology_desc(platform="tpu", ...)``
+hands back a compile-only v5e client from the installed libtpu, so the
+Mosaic lowering of the Pallas kernels and the TPU compile of whole
+engine programs are checked in tier-1 on the CPU box: a kernel that
+stops lowering, or a block size that no longer fits scoped VMEM, fails
+here instead of on the first chip run.  Compiling is not running —
+``chip_smoke.py`` is what runs them.
+
+Skips, with the reason printed, where the topology client cannot be
+created (no libtpu in the environment).
+"""
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+
+@pytest.fixture(scope="module")
+def topo():
+    """The compile-only v5e 2x2 topology (four devices)."""
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — any init failure = no client
+        pytest.skip(f"no compile-only TPU topology client: "
+                    f"{type(e).__name__}: {str(e)[:200]}")
+
+
+@pytest.fixture(scope="module")
+def v5e(topo):
+    """SingleDeviceSharding on one compile-only v5e device."""
+    return jax.sharding.SingleDeviceSharding(topo.devices[0])
+
+
+def _on(sharding, tree):
+    """Abstract stand-ins of ``tree``'s arrays, placed on ``sharding``
+    (lowering against them targets that device's platform)."""
+    return jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype,
+                                       sharding=sharding), tree)
+
+
+def _compiled_text(jitted, *args, **static):
+    return jitted.lower(*args, **static).compile().as_text()
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.int32])
+@pytest.mark.parametrize("kind", ["sum", "min"])
+@pytest.mark.parametrize("block_c,E", [(8, 128), (64, 128), (8, 512)])
+def test_chunk_partials_kernel_compiles(v5e, block_c, E, kind, dtype):
+    """The (block_c, E) shapes the engines use: pair-residual tiles
+    (E=128, bc=64 via _block_partials / fixed in ops/pairs.py) and the
+    default E=512 chunks at bc=8."""
+    from lux_tpu.ops.pallas_reduce import chunk_partials_pallas
+    C = 2 * block_c
+    text = _compiled_text(
+        chunk_partials_pallas,
+        jax.ShapeDtypeStruct((C, E), dtype, sharding=v5e),
+        jax.ShapeDtypeStruct((C, E), jnp.int8, sharding=v5e),
+        W=128, kind=kind, block_c=block_c)
+    assert "tpu_custom_call" in text
+
+
+def test_block_64x512_is_refused_and_block_partials_avoids_it(v5e):
+    """(64, 512) overflows scoped VMEM — the case _block_partials'
+    sizing rule guards: handed a 64-chunk E=512 block it must pick
+    block_c=8 (and so compile)."""
+    from lux_tpu.ops.pallas_reduce import chunk_partials_pallas
+    from lux_tpu.ops.tiled import _block_partials
+    vals = jax.ShapeDtypeStruct((64, 512), jnp.float32, sharding=v5e)
+    rel = jax.ShapeDtypeStruct((64, 512), jnp.int8, sharding=v5e)
+    with pytest.raises(Exception, match="(?i)vmem|scoped|exhausted"):
+        _compiled_text(chunk_partials_pallas, vals, rel, W=128,
+                       kind="sum", block_c=64)
+
+    def block(flat_state, src_b, rel_b):
+        return _block_partials(flat_state, src_b, rel_b, None,
+                               lambda v, w: v, "sum", 512, 128,
+                               "pallas", False)
+
+    text = _compiled_text(
+        jax.jit(block),
+        jax.ShapeDtypeStruct((4096,), jnp.float32, sharding=v5e),
+        jax.ShapeDtypeStruct((64, 512), jnp.int32, sharding=v5e), rel)
+    assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.int32])
+def test_lane_shuffle_kernel_compiles(v5e, dtype):
+    from lux_tpu.ops.pagegather import _lane_shuffle_pallas
+    text = _compiled_text(
+        jax.jit(_lane_shuffle_pallas),
+        jax.ShapeDtypeStruct((64, 128), dtype, sharding=v5e),
+        jax.ShapeDtypeStruct((64, 128), jnp.int32, sharding=v5e))
+    assert "tpu_custom_call" in text
+
+
+def test_kernels_compile_inside_shard_map(topo):
+    """Both Pallas kernels under a VMA-checked ``shard_map`` over the
+    four-device parts mesh — the owner exchange's position on a real
+    mesh.  ``pallas_call`` refuses an ``out_shape`` without ``vma``
+    there; the CPU mesh tests never reach it (off-TPU ``auto``
+    resolves to XLA), so the first four-chip run found it (PR 21)."""
+    import functools
+
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from lux_tpu.ops.pagegather import _lane_shuffle_pallas
+    from lux_tpu.ops.pallas_reduce import chunk_partials_pallas
+    from lux_tpu.parallel.mesh import PARTS_AXIS
+
+    mesh = Mesh(np.asarray(topo.devices), (PARTS_AXIS,))
+    parts = NamedSharding(mesh, P(PARTS_AXIS))
+    on_mesh = functools.partial(jax.shard_map, mesh=mesh,
+                                in_specs=P(PARTS_AXIS),
+                                out_specs=P(PARTS_AXIS))
+
+    def sds(dtype):
+        return jax.ShapeDtypeStruct((64, 128), dtype, sharding=parts)
+
+    reduce_ = jax.jit(on_mesh(lambda v, r: chunk_partials_pallas(
+        v, r, W=128, kind="min", block_c=8)))
+    shuffle = jax.jit(on_mesh(_lane_shuffle_pallas))
+    assert "tpu_custom_call" in _compiled_text(
+        reduce_, sds(jnp.float32), sds(jnp.int8))
+    assert "tpu_custom_call" in _compiled_text(
+        shuffle, sds(jnp.float32), sds(jnp.int32))
+
+
+def _rmat12():
+    from lux_tpu.convert import rmat_graph
+    return rmat_graph(scale=12, edge_factor=8, seed=0)
+
+
+def _step_text(eng, v5e):
+    jitted, args = eng.audit_variant("step")
+    return _compiled_text(jitted, *_on(v5e, args()))
+
+
+def test_pull_step_compiles_with_pallas(v5e):
+    """One pull engine's per-iteration program (PageRank, RMAT12),
+    reduce_method='pallas', compiled for v5e."""
+    from lux_tpu.apps import pagerank
+    from lux_tpu.engine.pull import PullEngine
+    from lux_tpu.graph import ShardedGraph
+    sg = ShardedGraph.build(_rmat12(), 1)
+    eng = PullEngine(sg, pagerank.make_program(),
+                     reduce_method="pallas")
+    assert "tpu_custom_call" in _step_text(eng, v5e)
+
+
+@pytest.mark.parametrize("family", ["pull", "push"])
+def test_mesh_owner_step_compiles_with_pallas(topo, monkeypatch,
+                                              family):
+    """The WHOLE per-iteration program of a four-part owner-exchange
+    engine on the four-device mesh (shard_map + Pallas reduce + the
+    routing collective), compiled for the v5e 2x2 topology — what
+    `-np 4 -mesh 4 -exchange owner` runs on a four-chip host.  The
+    topology's devices cannot hold data, so placement is replaced by
+    abstract parts-sharded stand-ins; nothing executes."""
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from lux_tpu.apps import pagerank, sssp
+    from lux_tpu.engine import pull, push
+    from lux_tpu.graph import ShardedGraph
+    from lux_tpu.parallel.mesh import PARTS_AXIS
+
+    mesh = Mesh(np.asarray(topo.devices), (PARTS_AXIS,))
+    parts = NamedSharding(mesh, P(PARTS_AXIS))
+
+    def abstract_shard(_mesh, tree, num_parts=None):
+        return _on(parts, jax.tree.map(np.asarray, tree))
+
+    sg = ShardedGraph.build(_rmat12(), 4)
+    if family == "pull":
+        monkeypatch.setattr(pull, "shard_over_parts", abstract_shard)
+        eng = pull.PullEngine(sg, pagerank.make_program(), mesh=mesh,
+                              exchange="owner",
+                              reduce_method="pallas")
+    else:
+        monkeypatch.setattr(push, "shard_over_parts", abstract_shard)
+        eng = push.PushEngine(sg, sssp.make_program(0), mesh=mesh,
+                              exchange="owner",
+                              reduce_method="pallas")
+    jitted, args = eng.audit_variant("step")
+    replicated = NamedSharding(mesh, P())
+    args = [a if getattr(a, "sharding", None) is not None
+            else _on(parts if a.ndim else replicated, a)
+            for a in args()]
+    assert "tpu_custom_call" in _compiled_text(jitted, *args)
+
+
+def test_push_step_compiles_with_pallas(v5e):
+    """One push engine's per-iteration program (SSSP, RMAT12: dense
+    iteration + sparse-frontier branch), reduce_method='pallas'."""
+    from lux_tpu.apps import sssp
+    from lux_tpu.engine.push import PushEngine
+    from lux_tpu.graph import ShardedGraph
+    sg = ShardedGraph.build(_rmat12(), 1)
+    eng = PushEngine(sg, sssp.make_program(0),
+                     reduce_method="pallas")
+    assert "tpu_custom_call" in _step_text(eng, v5e)
