@@ -32,6 +32,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec
 
+from lux_tpu import telemetry
 from lux_tpu.engine.auditable import AuditableEngine
 from lux_tpu.engine.program import PartCtx, PullProgram, vmask_of
 from lux_tpu.graph import ShardedGraph
@@ -201,6 +202,11 @@ class PullEngine(AuditableEngine):
     the leading axis, vmapped).  With a mesh, all part-major arrays are
     sharded over the ``parts`` axis and the same per-part computation
     runs under shard_map with an all-gather for remote state.
+
+    Construction leaves the spans ``build.pair_plan`` and
+    ``build.dense_layout`` (telemetry.span); on a single device the
+    latter also hands each array to the device as it is built (to
+    dispatch, not to arrival).
     """
 
     def __init__(self, sg: ShardedGraph, program: PullProgram, mesh=None,
@@ -295,36 +301,9 @@ class PullEngine(AuditableEngine):
         self.stats_cap = int(stats_cap or DEFAULT_STATS_CAP)
         self.reduce_method = resolve_reduce_method(reduce_method)
         dev = jnp.asarray if mesh is None else np.asarray
-        if self.page_plan is not None:
-            # the paged plan IS the edge layout: neither the tiled
-            # chunk arrays nor the owner chunk layout is built
-            self.owner = None
-            self.tiles = None
-            arrays = dict(common_graph_arrays(sg, dev),
-                          **self._paged_arrays(dev, program))
-        elif exchange == "owner":
-            from lux_tpu.ops.owner import OwnerLayout
-            self.owner = OwnerLayout.build(sg, E=owner_tile_e or 256)
-            self.tiles = None
-            arrays = dict(
-                **common_graph_arrays(sg, dev),
-                **_owner_edge_arrays(self.owner, dev),
-                own_cs=dev(self.owner.chunk_start),
-                own_lc=dev(self.owner.last_chunk))
-            if self.owner.weight is not None:
-                arrays["own_w"] = dev(self.owner.weight)
-            if self.owner.streams():
-                # fused streamed combine: never materializes [C, W]
-                ep, et = self.owner.extract_plan()
-                arrays["own_ep"] = dev(ep)
-                arrays["own_et"] = dev(et)
-        else:
-            self.owner = None
-            arrays, self.tiles = build_graph_arrays(
-                sg, layout,
-                program.needs_dst
-                or program.edge_value_from_dot is not None,
-                tile_w, tile_e, device=mesh is None)
+        with telemetry.span("build.dense_layout"):
+            arrays = self._dense_layout(dev, layout, tile_w, tile_e,
+                                        owner_tile_e)
         if program.extra_arrays is not None:
             # program-contributed per-part constants (e.g. per-query
             # reset vectors): jit ARGUMENTS like every graph array —
@@ -355,6 +334,44 @@ class PullEngine(AuditableEngine):
             # on anything but 'warn'/'error')
             from lux_tpu import audit as _audit
             _audit.audit_engine(self, mode=audit)
+
+    def _dense_layout(self, dev, layout, tile_w, tile_e,
+                      owner_tile_e) -> dict:
+        """Arrays of the edge layout (paged plan, owner chunks or
+        tiled chunks), each through ``dev``; sets ``self.owner`` /
+        ``self.tiles``."""
+        sg, program = self.sg, self.program
+        if self.page_plan is not None:
+            # the paged plan IS the edge layout: neither the tiled
+            # chunk arrays nor the owner chunk layout is built
+            self.owner = None
+            self.tiles = None
+            return dict(common_graph_arrays(sg, dev),
+                        **self._paged_arrays(dev, program))
+        if self.exchange == "owner":
+            from lux_tpu.ops.owner import OwnerLayout
+            self.owner = OwnerLayout.build(sg, E=owner_tile_e or 256)
+            self.tiles = None
+            arrays = dict(
+                **common_graph_arrays(sg, dev),
+                **_owner_edge_arrays(self.owner, dev),
+                own_cs=dev(self.owner.chunk_start),
+                own_lc=dev(self.owner.last_chunk))
+            if self.owner.weight is not None:
+                arrays["own_w"] = dev(self.owner.weight)
+            if self.owner.streams():
+                # fused streamed combine: never materializes [C, W]
+                ep, et = self.owner.extract_plan()
+                arrays["own_ep"] = dev(ep)
+                arrays["own_et"] = dev(et)
+            return arrays
+        self.owner = None
+        arrays, self.tiles = build_graph_arrays(
+            sg, layout,
+            program.needs_dst
+            or program.edge_value_from_dot is not None,
+            tile_w, tile_e, device=self.mesh is None)
+        return arrays
 
     # -- pair-lane fast path (ops/pairs.py) ----------------------------
 
@@ -470,13 +487,19 @@ class PullEngine(AuditableEngine):
     # -- state placement ----------------------------------------------
 
     def init_state(self):
-        state = self._consume_pending_init()
-        if state is None:
-            state = self.program.init(self.sg)
-        if self.mesh is not None:
-            return shard_over_parts(self.mesh, [np.asarray(state)],
-                                    self.sg.num_parts)[0]
-        return jnp.asarray(state)
+        """Fresh state on the engine's devices, under a ``state.init``
+        span (``bytes``; the transfer is asynchronous, so the span
+        ends at dispatch)."""
+        with telemetry.span("state.init") as sp:
+            state = self._consume_pending_init()
+            if state is None:
+                state = self.program.init(self.sg)
+            state = np.asarray(state)
+            sp.count(bytes=state.nbytes)
+            if self.mesh is not None:
+                return shard_over_parts(self.mesh, [state],
+                                        self.sg.num_parts)[0]
+            return jnp.asarray(state)
 
     def place(self, state):
         """Put a host state pytree on the engine's devices with the
@@ -485,15 +508,19 @@ class PullEngine(AuditableEngine):
         RE-PLACEMENT entry point (round 11): the input is the global
         ``[P, vpad, ...]`` view, so the same call re-shards a
         checkpoint written on an 8-device mesh onto this engine's
-        4-device one — parts fixed, device mapping changed."""
+        4-device one — parts fixed, device mapping changed.
+        Leaves a ``state.place`` span (``bytes``); asynchronous, so
+        the span ends at dispatch, not at arrival."""
         self._drop_pending_init()     # resume never needs the probe
         leaves, treedef = jax.tree.flatten(state)
-        if self.mesh is not None:
-            leaves = shard_over_parts(
-                self.mesh, [np.asarray(x) for x in leaves],
-                self.sg.num_parts)
-        else:
-            leaves = [jnp.asarray(x) for x in leaves]
+        with telemetry.span("state.place",
+                            bytes=sum(x.nbytes for x in leaves)):
+            if self.mesh is not None:
+                leaves = shard_over_parts(
+                    self.mesh, [np.asarray(x) for x in leaves],
+                    self.sg.num_parts)
+            else:
+                leaves = [jnp.asarray(x) for x in leaves]
         return jax.tree.unflatten(treedef, leaves)
 
     def update_program_arrays(self, **host_arrays):
@@ -1230,9 +1257,14 @@ class PullEngine(AuditableEngine):
 
     def unpad(self, state) -> np.ndarray:
         """Padded device state -> [nv, ...] user order (host).
-        Multi-host runs gather remote shards over the process group."""
+        Multi-host runs gather remote shards over the process group.
+        Leaves a ``state.fetch`` span (``bytes``: what came to the
+        host)."""
         from lux_tpu.parallel.multihost import fetch_global
-        return self.sg.from_padded(fetch_global(state))
+        with telemetry.span("state.fetch") as sp:
+            host = fetch_global(state)
+            sp.count(bytes=host.nbytes)
+            return self.sg.from_padded(host)
 
     # -- per-iteration phase observability ----------------------------
 

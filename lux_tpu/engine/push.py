@@ -49,6 +49,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec
 
+from lux_tpu import telemetry
 from lux_tpu.engine import frontier as fr
 from lux_tpu.engine.auditable import AuditableEngine
 from lux_tpu.engine.program import vmask_of
@@ -97,7 +98,13 @@ class PushProgram:
 
 
 class PushEngine(AuditableEngine):
-    """Compiled frontier iterations for one ShardedGraph + PushProgram."""
+    """Compiled frontier iterations for one ShardedGraph + PushProgram.
+
+    Construction leaves the spans ``build.pair_plan`` (counts
+    pair_edges, residual_edges), ``build.dense_layout`` and
+    ``build.sparse_view`` (telemetry.span); on a single device
+    ``build.dense_layout`` also hands each array to the device as it
+    is built (to dispatch, not to arrival)."""
 
     def __init__(self, sg: ShardedGraph, program: PushProgram, mesh=None,
                  layout: str = "tiled", tile_w: int = 128,
@@ -123,7 +130,6 @@ class PushEngine(AuditableEngine):
                 f"num_parts={sg.num_parts} not divisible by mesh size "
                 f"{mesh.devices.size}")
         from lux_tpu.engine.pull import (_check_local_parts,
-                                         build_graph_arrays,
                                          resolve_exchange,
                                          resolve_reduce_method,
                                          resolve_use_mxu)
@@ -225,46 +231,9 @@ class PushEngine(AuditableEngine):
                               if stream_msgs is None
                               else bool(stream_msgs))
         dev = jnp.asarray if mesh is None else np.asarray
-        if self.page_plan is not None:
-            # the paged plan IS the dense edge layout (sparse
-            # iterations keep the src-sorted view added below)
-            from lux_tpu.engine.pull import common_graph_arrays
-            from lux_tpu.ops.pagegather import plan_graph_arrays
-            self.owner = None
-            self.tiles = None
-            arrays = dict(
-                common_graph_arrays(dense_sg, dev),
-                **plan_graph_arrays(
-                    self.page_plan, dev,
-                    owner=exchange == "owner", dot=False,
-                    num_parts=sg.num_parts, vpad=sg.vpad))
-        elif exchange == "owner":
-            # dense iterations run owner-side (ops/owner.py): per-
-            # source-part small-shard gathers + reduce_scatter replace
-            # the label all_gather + big-table gather; the sparse path
-            # below is unchanged (queue exchange is already O(queue))
-            from lux_tpu.engine.pull import (_owner_edge_arrays,
-                                             common_graph_arrays)
-            from lux_tpu.ops.owner import OwnerLayout
-            self.owner = OwnerLayout.build(dense_sg, E=owner_tile_e or 256)
-            self.tiles = None
-            arrays = dict(
-                **common_graph_arrays(dense_sg, dev),
-                **_owner_edge_arrays(self.owner, dev),
-                own_cs=dev(self.owner.chunk_start),
-                own_lc=dev(self.owner.last_chunk))
-            if self.owner.weight is not None:
-                arrays["own_w"] = dev(self.owner.weight)
-            if self.owner.streams():
-                # fused streamed combine: never materializes [C, W]
-                ep, et = self.owner.extract_plan()
-                arrays["own_ep"] = dev(ep)
-                arrays["own_et"] = dev(et)
-        else:
-            self.owner = None
-            arrays, self.tiles = build_graph_arrays(
-                dense_sg, layout, needs_dst=False, tile_w=tile_w,
-                tile_e=tile_e, device=mesh is None)
+        with telemetry.span("build.dense_layout"):
+            arrays = self._dense_layout(dev, dense_sg, layout, tile_w,
+                                        tile_e, owner_tile_e)
         if self.pairs is not None:
             arrays["pair_rowbind"] = dev(self.pairs.rowbind)
             arrays["pair_rel"] = dev(self.pairs.rel_dst)
@@ -276,12 +245,14 @@ class PushEngine(AuditableEngine):
             # The compressed source index's pad size is a compiled
             # SHAPE: on multi-host runs agree on the max across every
             # process's parts.
-            s_pad = sg.src_unique_max()
-            if jax.process_count() > 1:
-                from jax.experimental import multihost_utils
-                s_pad = int(np.max(multihost_utils.process_allgather(
-                    np.asarray([s_pad]))))
-            ss = sg.src_sorted(s_pad=s_pad)
+            with telemetry.span("build.sparse_view"):
+                s_pad = sg.src_unique_max()
+                if jax.process_count() > 1:
+                    from jax.experimental import multihost_utils
+                    s_pad = int(np.max(
+                        multihost_utils.process_allgather(
+                            np.asarray([s_pad]))))
+                ss = sg.src_sorted(s_pad=s_pad)
             # Reference queue sizing rule (push_model.inl:393-397).
             self.queue_cap = frontier_capacity(sg.vpad, sparse_threshold)
             # The edge budget must cover any single vertex's out-edges
@@ -320,22 +291,82 @@ class PushEngine(AuditableEngine):
             from lux_tpu import audit as _audit
             _audit.audit_engine(self, mode=audit)
 
+    def _dense_layout(self, dev, dense_sg, layout, tile_w, tile_e,
+                      owner_tile_e) -> dict:
+        """Arrays of the DENSE iterations' edge layout (paged plan,
+        owner chunks or tiled chunks), each through ``dev``; sets
+        ``self.owner`` / ``self.tiles``."""
+        sg = self.sg
+        if self.page_plan is not None:
+            # the paged plan IS the dense edge layout (sparse
+            # iterations keep the src-sorted view added below)
+            from lux_tpu.engine.pull import common_graph_arrays
+            from lux_tpu.ops.pagegather import plan_graph_arrays
+            self.owner = None
+            self.tiles = None
+            arrays = dict(
+                common_graph_arrays(dense_sg, dev),
+                **plan_graph_arrays(
+                    self.page_plan, dev,
+                    owner=self.exchange == "owner", dot=False,
+                    num_parts=sg.num_parts, vpad=sg.vpad))
+        elif self.exchange == "owner":
+            # dense iterations run owner-side (ops/owner.py): per-
+            # source-part small-shard gathers + reduce_scatter replace
+            # the label all_gather + big-table gather; the sparse path
+            # below is unchanged (queue exchange is already O(queue))
+            from lux_tpu.engine.pull import (_owner_edge_arrays,
+                                             common_graph_arrays)
+            from lux_tpu.ops.owner import OwnerLayout
+            self.owner = OwnerLayout.build(dense_sg, E=owner_tile_e or 256)
+            self.tiles = None
+            arrays = dict(
+                **common_graph_arrays(dense_sg, dev),
+                **_owner_edge_arrays(self.owner, dev),
+                own_cs=dev(self.owner.chunk_start),
+                own_lc=dev(self.owner.last_chunk))
+            if self.owner.weight is not None:
+                arrays["own_w"] = dev(self.owner.weight)
+            if self.owner.streams():
+                # fused streamed combine: never materializes [C, W]
+                ep, et = self.owner.extract_plan()
+                arrays["own_ep"] = dev(ep)
+                arrays["own_et"] = dev(et)
+        else:
+            from lux_tpu.engine.pull import build_graph_arrays
+            self.owner = None
+            arrays, self.tiles = build_graph_arrays(
+                dense_sg, layout, needs_dst=False, tile_w=tile_w,
+                tile_e=tile_e, device=self.mesh is None)
+        return arrays
+
     # ------------------------------------------------------------------
 
     def init_state(self):
-        pending = self._consume_pending_init()
-        if pending is not None:
-            label0, active0 = pending
-        else:
-            label0, active0 = self.program.init(self.sg)
-        return self.place(label0, active0)
+        """Fresh (label, active) on the engine's devices, under a
+        ``state.init`` span (``bytes``; ends at dispatch)."""
+        with telemetry.span("state.init") as sp:
+            pending = self._consume_pending_init()
+            if pending is not None:
+                label0, active0 = pending
+            else:
+                label0, active0 = self.program.init(self.sg)
+            sp.count(bytes=label0.nbytes + active0.nbytes)
+            return self._place(label0, active0)
 
     def place(self, label, active):
         """Put host (or replicated) state arrays on the engine's
         devices with the parts sharding (used by checkpoint resume).
         Like PullEngine.place, this is the elastic re-placement entry
         point: the global ``[P, vpad]`` label/active views re-shard
-        onto whatever mesh THIS engine was built over (round 11)."""
+        onto whatever mesh THIS engine was built over (round 11).
+        Leaves a ``state.place`` span (``bytes``); the transfer is
+        asynchronous, so the span ends at dispatch, not at arrival."""
+        with telemetry.span("state.place",
+                            bytes=label.nbytes + active.nbytes):
+            return self._place(label, active)
+
+    def _place(self, label, active):
         self._drop_pending_init()     # resume never needs the probe
         if self.mesh is not None:
             return tuple(shard_over_parts(
@@ -751,8 +782,11 @@ class PushEngine(AuditableEngine):
             return self._dense_parts(label, active, full_l, full_a, g)
 
         def body(label, active, count, g):
+            """-> (label, active, 1 if the SPARSE branch ran else 0):
+            the int32 the loops sum into their ``sparse_iters``
+            carry (the one device-side counter telemetry reads)."""
             if not use_sparse:
-                return dense_body(label, active, g)
+                return (*dense_body(label, active, g), jnp.int32(0))
 
             # Reference heuristic: frontier > nv/16 -> dense/pull mode
             # (sssp_gpu.cu:414), and the queue must fit (_sparse_mode).
@@ -766,7 +800,8 @@ class PushEngine(AuditableEngine):
                     return dense_body(label, active, g)
 
             q_fits = count <= jnp.int32(sparse_limit)
-            return jax.lax.cond(q_fits, sparse_branch, dense_branch)
+            return (*jax.lax.cond(q_fits, sparse_branch, dense_branch),
+                    q_fits.astype(jnp.int32))
 
         use_delta = converge and self.delta is not None
 
@@ -809,7 +844,7 @@ class PushEngine(AuditableEngine):
 
             if not converge:
                 cnt0 = global_sum(active)
-                new_label, new_active = body(label, active, cnt0, g)
+                new_label, new_active, _ = body(label, active, cnt0, g)
                 return new_label, new_active, global_sum(new_active)
 
             if use_delta:
@@ -833,6 +868,9 @@ class PushEngine(AuditableEngine):
                 # stretches terminate on their own: while any vertex is
                 # active, raising B eventually makes the frontier
                 # non-empty.
+                # carry: (it, lbl, act, B, cnt, [4 stats buffers],
+                # [health word, stall], sparse_iters) — the counter
+                # rides LAST so every index before it stands
                 def cond(c):
                     it, lbl, act, B, cnt = c[:5]
                     ok = (cnt > 0) & (it < max_iters)
@@ -863,7 +901,7 @@ class PushEngine(AuditableEngine):
                                                    mode="drop"),
                                    fedp.at[it].set(ep, mode="drop")) \
                                 + buf[4:]
-                        nl, na = body(lbl, front, nf, g)
+                        nl, na, took = body(lbl, front, nf, g)
                         merged = (act & ~front) | na
                         if health:
                             # the watchdog watches relax steps only:
@@ -872,8 +910,9 @@ class PushEngine(AuditableEngine):
                             h, stall = health_step(
                                 buf[4], buf[5], lbl, nl, cnt,
                                 global_sum(merged))
-                            buf = buf[:4] + (h, stall)
-                        return (it + 1, nl, merged, B, *buf)
+                            buf = buf[:4] + (h, stall) + buf[6:]
+                        return (it + 1, nl, merged, B, *buf[:-1],
+                                buf[-1] + took)
 
                     def advance(it, lbl, act, B, *buf):
                         # Strict progress: with float labels a delta
@@ -907,16 +946,13 @@ class PushEngine(AuditableEngine):
                         jnp.zeros((cap_n, sg.num_parts), jnp.uint32))
                 if health:
                     init = init + (h0, stall0)
-                out = jax.lax.while_loop(cond, wbody, init)
-                it, lbl, act = out[0], out[1], out[2]
-                if health:
-                    return lbl, act, it, out[5], out[6], out[7], \
-                        out[8], out[9], out[10]
-                if stats:
-                    return lbl, act, it, out[5], out[6], out[7], \
-                        out[8]
-                return lbl, act, it
+                out = jax.lax.while_loop(cond, wbody,
+                                         init + (jnp.int32(0),))
+                # (lbl, act, it, [stats], [health], sparse_iters)
+                return (out[1], out[2], out[0], *out[5:])
 
+            # carry: (it, lbl, act, cnt, [4 stats buffers], [health
+            # word, stall], sparse_iters) — the counter rides LAST
             def cond(c):
                 it, lbl, act, cnt = c[:4]
                 ok = (cnt > 0) & (it < max_iters)
@@ -934,8 +970,9 @@ class PushEngine(AuditableEngine):
                     ep = esum_parts(act)
                     fed = fed.at[it].set(jnp.sum(ep), mode="drop")
                     fedp = fedp.at[it].set(ep, mode="drop")
-                nl, na = body(lbl, act, cnt, g)
+                nl, na, took = body(lbl, act, cnt, g)
                 ncnt = global_sum(na)
+                ns = c[-1] + took
                 if stats:
                     # frontier AFTER the iteration — exactly the
                     # series the stepwise -verbose path printed
@@ -946,9 +983,10 @@ class PushEngine(AuditableEngine):
                         h, stall = health_step(c[8], c[9], lbl,
                                                nl, cnt, ncnt)
                         return (it + 1, nl, na, ncnt, fsz, fed, fszp,
-                                fedp, h, stall)
-                    return it + 1, nl, na, ncnt, fsz, fed, fszp, fedp
-                return it + 1, nl, na, ncnt
+                                fedp, h, stall, ns)
+                    return (it + 1, nl, na, ncnt, fsz, fed, fszp,
+                            fedp, ns)
+                return it + 1, nl, na, ncnt, ns
 
             it0 = jnp.int32(0)
             cnt0 = global_sum(active)
@@ -961,14 +999,10 @@ class PushEngine(AuditableEngine):
                     jnp.zeros((cap_n, sg.num_parts), jnp.uint32))
             if health:
                 init = init + (h0, stall0)
-            out = jax.lax.while_loop(cond, wbody, init)
-            it, lbl, act = out[0], out[1], out[2]
-            if health:
-                return lbl, act, it, out[4], out[5], out[6], out[7], \
-                    out[8], out[9]
-            if stats:
-                return lbl, act, it, out[4], out[5], out[6], out[7]
-            return lbl, act, it
+            out = jax.lax.while_loop(cond, wbody,
+                                     init + (jnp.int32(0),))
+            # (lbl, act, it, [stats], [health], sparse_iters)
+            return (out[1], out[2], out[0], *out[4:])
 
         if prog.name:
             inner = jax.named_scope(f"lux_{prog.name}")(inner)
@@ -984,6 +1018,9 @@ class PushEngine(AuditableEngine):
                 # the health word + stall counter are built from
                 # psum/pmin'd scalars, identical on every device
                 out_specs = out_specs + (P(), P())
+            if converge:
+                # sparse_iters sums a predicate of the psum'd count
+                out_specs = out_specs + (P(),)
             in_specs = (P(PARTS_AXIS), P(PARTS_AXIS), P())
             if health:
                 in_specs = in_specs + (P(), P())    # h0, stall0
@@ -1027,16 +1064,28 @@ class PushEngine(AuditableEngine):
                      watch=None):
                 if watch is None:
                     watch = (_hw.init_word(), jnp.int32(0))
-                l, a, it, fsz, fed, fszp, fedp, h, stall = jitted(
+                l, a, it, fsz, fed, fszp, fedp, h, stall, ns = jitted(
                     label, active, jnp.int32(max_iters), *watch,
                     *extra, *graph_args)
+                telemetry.mark("push.converge", iters=it,
+                               sparse_iters=ns)
                 return l, a, it, fsz, fed, fszp, fedp, (h, stall)
 
             return call
 
         def call(label, active, max_iters=np.iinfo(np.int32).max):
-            return jitted(label, active, jnp.int32(max_iters), *extra,
-                          *graph_args)
+            """One dispatch; stays asynchronous.  A converge variant
+            leaves a ``push.converge`` mark whose ``iters`` /
+            ``sparse_iters`` are the un-fetched device scalars
+            (fetched at ``telemetry.spans()``, never here)."""
+            out = jitted(label, active, jnp.int32(max_iters), *extra,
+                         *graph_args)
+            if not converge:
+                return out
+            *out, ns = out
+            telemetry.mark("push.converge", iters=out[2],
+                           sparse_iters=ns)
+            return tuple(out)
 
         return call
 
@@ -1178,8 +1227,13 @@ class PushEngine(AuditableEngine):
         return self.unpad(label), it
 
     def unpad(self, state) -> np.ndarray:
+        """Device state -> host array in vertex order, under a
+        ``state.fetch`` span (``bytes``: what came to the host)."""
         from lux_tpu.parallel.multihost import fetch_global
-        return self.sg.from_padded(fetch_global(state))
+        with telemetry.span("state.fetch") as sp:
+            host = fetch_global(state)
+            sp.count(bytes=host.nbytes)
+            return self.sg.from_padded(host)
 
     # -- per-iteration phase observability ----------------------------
 

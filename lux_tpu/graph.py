@@ -33,6 +33,7 @@ import dataclasses
 import numpy as np
 
 from lux_tpu import format as luxfmt
+from lux_tpu import telemetry
 from lux_tpu.partition import edge_balanced_bounds, part_edge_counts
 
 
@@ -246,31 +247,41 @@ def pair_relabel(g: Graph, num_parts: int = 1,
     Returns (relabeled graph, perm, starts) with perm[new] = old and
     ``starts`` the partition cut points to pass to ShardedGraph.build
     (tile-aligned; a partial trailing tile is placed last).
+
+    Leaves a ``relabel`` span with one child per stage
+    (``relabel.degree_sort``, ``.pair_histogram`` when num_parts > 1,
+    ``.deal``, ``.rebuild_csc``); ``verbose`` prints one line per
+    stage from those records.
     """
-    import time as _time
-
-    def _tick(t0, stage):
-        if verbose:
-            print(f"# pair_relabel/{stage}: {_time.time() - t0:.1f}s",
-                  flush=True)
-        return _time.time()
-
     if vpad_cap < 1:
         # cap * P must cover every full tile, or the LPT's all-capped
         # argmin would dump the remainder on part 0 uncapped AND
         # unbalanced
         raise ValueError(f"vpad_cap={vpad_cap} must be >= 1")
-    t0 = _time.time()
-    src, dst = g.edge_arrays()
-    # uint32 endpoint arrays: the whole pipeline below is billion-edge
-    # host prep, and every avoided int64 temporary is 8 GB at RMAT26
-    src = src.astype(np.uint32)
-    dst = dst.astype(np.uint32)
-    deg = (np.bincount(src, minlength=g.nv)
-           + np.bincount(dst, minlength=g.nv))
-    by_deg = np.argsort(-deg, kind="stable")      # degree position -> old
-    del deg
-    t0 = _tick(t0, "edges+degree_sort")
+    with telemetry.span("relabel") as sp:
+        out = _pair_relabel(g, num_parts, pair_threshold, gather_cost,
+                            pair_cost, vpad_cap)
+    if verbose:
+        for rec in telemetry.spans():
+            if rec["parent"] == sp.id:
+                print(f"# pair_relabel/{rec['name'][len('relabel.'):]}: "
+                      f"{rec['t1'] - rec['t0']:.1f}s", flush=True)
+    return out
+
+
+def _pair_relabel(g, num_parts, pair_threshold, gather_cost, pair_cost,
+                  vpad_cap):
+    with telemetry.span("relabel.degree_sort"):
+        src, dst = g.edge_arrays()
+        # uint32 endpoint arrays: the whole pipeline below is
+        # billion-edge host prep, and every avoided int64 temporary is
+        # 8 GB at RMAT26
+        src = src.astype(np.uint32)
+        dst = dst.astype(np.uint32)
+        deg = (np.bincount(src, minlength=g.nv)
+               + np.bincount(dst, minlength=g.nv))
+        by_deg = np.argsort(-deg, kind="stable")  # degree position -> old
+        del deg
     Wt = 128
     n_tiles = -(-g.nv // Wt)
     full = n_tiles - 1 if g.nv % Wt else n_tiles
@@ -278,82 +289,94 @@ def pair_relabel(g: Graph, num_parts: int = 1,
     if P > 1 and full < P:
         # graph too small for whole-tile dealing; plain degree sort,
         # default (cost-balanced) cuts
-        rank = np.empty(g.nv, np.int64)
-        rank[by_deg] = np.arange(g.nv)
-        g2 = Graph.from_edges(rank[src], rank[dst], g.nv,
-                              weights=g.weights)
+        with telemetry.span("relabel.rebuild_csc"):
+            rank = np.empty(g.nv, np.int64)
+            rank[by_deg] = np.arange(g.nv)
+            g2 = Graph.from_edges(rank[src], rank[dst], g.nv,
+                                  weights=g.weights)
         return g2, by_deg, None
 
+    tile_cost = None
     if P > 1 and full:
-        # estimated per-tile in-edge cost in the DEGREE-SORTED tiling
-        rank0 = np.empty(g.nv, np.uint32)
-        rank0[by_deg] = np.arange(g.nv, dtype=np.uint32)
-        s2t = (rank0[src] // Wt).astype(np.int64)     # src tile
-        d2t = (rank0[dst] // Wt).astype(np.int32)     # dst tile
-        key = s2t * np.int64(n_tiles)
-        key += d2t
-        del s2t
-        # per-edge pair multiplicity without np.unique's inverse
-        # machinery: one FUSED radix sort carrying the edge index as
-        # payload (sequential passes, no argsort random reads and no
-        # key/index gathers — native.sort_kv, PERF_NOTES round 4),
-        # then group boundaries on the sorted keys
-        from lux_tpu import native
-        idx = np.arange(len(key),
-                        dtype=np.uint32 if len(key) < 2**32
-                        else np.int64)
-        native.sort_kv(key, (idx,))
-        newg = np.ones(len(key), bool)
-        newg[1:] = key[1:] != key[:-1]
-        del key
-        gid = (np.cumsum(newg) - 1).astype(np.int32)
-        cnt = np.bincount(gid)
-        is_pair = np.empty(len(gid), bool)            # per-edge dense?
-        is_pair[idx] = cnt[gid] >= pair_threshold
-        del idx, newg, gid, cnt
-        # per-tile cost without a float64 per-edge array: count the
-        # pair-served edges per dst tile, price the two classes
-        pair_by_tile = np.bincount(d2t[is_pair], minlength=n_tiles)
-        all_by_tile = np.bincount(d2t, minlength=n_tiles)
-        del d2t, is_pair
-        tile_cost = (pair_cost * pair_by_tile
-                     + gather_cost * (all_by_tile - pair_by_tile))
-        t0 = _tick(t0, "pair_histogram")
-        cap = max(1, int(np.ceil(vpad_cap * full / P)))
-        load = np.zeros(P)
-        tiles_held = np.zeros(P, np.int64)
-        owner = np.empty(full, np.int64)
-        for t in range(full):                     # capped LPT greedy
-            masked = np.where(tiles_held < cap, load, np.inf)
-            p = int(np.argmin(masked))
-            owner[t] = p
-            load[p] += tile_cost[t]
-            tiles_held[p] += 1
-        part_tiles = [np.nonzero(owner == p)[0] for p in range(P)]
-    else:
-        part_tiles = [np.arange(p, full, P) for p in range(P)]
+        with telemetry.span("relabel.pair_histogram"):
+            tile_cost = _tile_costs(g.nv, src, dst, by_deg, n_tiles,
+                                    pair_threshold, gather_cost,
+                                    pair_cost)
+    with telemetry.span("relabel.deal"):
+        if tile_cost is not None:
+            cap = max(1, int(np.ceil(vpad_cap * full / P)))
+            load = np.zeros(P)
+            tiles_held = np.zeros(P, np.int64)
+            owner = np.empty(full, np.int64)
+            for t in range(full):                 # capped LPT greedy
+                masked = np.where(tiles_held < cap, load, np.inf)
+                p = int(np.argmin(masked))
+                owner[t] = p
+                load[p] += tile_cost[t]
+                tiles_held[p] += 1
+            part_tiles = [np.nonzero(owner == p)[0] for p in range(P)]
+        else:
+            part_tiles = [np.arange(p, full, P) for p in range(P)]
 
-    counts_v = [len(t) * Wt for t in part_tiles]
-    if g.nv % Wt:
-        part_tiles[-1] = np.concatenate(
-            [part_tiles[-1], [full]]).astype(np.int64)
-        counts_v[-1] += g.nv % Wt
-    starts = np.concatenate(([0], np.cumsum(counts_v))).astype(np.int64)
-    tile_seq = np.concatenate(part_tiles)
-    vert_order = (tile_seq[:, None] * Wt +
-                  np.arange(Wt)[None, :]).reshape(-1)
-    vert_order = vert_order[vert_order < g.nv]    # clip partial tile
-    perm = by_deg[vert_order]                     # new -> old
-    rank = np.empty(g.nv, np.uint32)
-    rank[perm] = np.arange(g.nv, dtype=np.uint32)
-    t0 = _tick(t0, "lpt_dealing")
-    ns = rank[src]
-    del src
-    nd = rank[dst]
-    del dst, rank
-    g2 = Graph.from_edges(ns, nd, g.nv, weights=g.weights)
-    _tick(t0, "rebuild_csc")
+        counts_v = [len(t) * Wt for t in part_tiles]
+        if g.nv % Wt:
+            part_tiles[-1] = np.concatenate(
+                [part_tiles[-1], [full]]).astype(np.int64)
+            counts_v[-1] += g.nv % Wt
+        starts = np.concatenate(
+            ([0], np.cumsum(counts_v))).astype(np.int64)
+        tile_seq = np.concatenate(part_tiles)
+        vert_order = (tile_seq[:, None] * Wt +
+                      np.arange(Wt)[None, :]).reshape(-1)
+        vert_order = vert_order[vert_order < g.nv]  # clip partial tile
+        perm = by_deg[vert_order]                   # new -> old
+        rank = np.empty(g.nv, np.uint32)
+        rank[perm] = np.arange(g.nv, dtype=np.uint32)
+    with telemetry.span("relabel.rebuild_csc"):
+        ns = rank[src]
+        del src
+        nd = rank[dst]
+        del dst, rank
+        g2 = Graph.from_edges(ns, nd, g.nv, weights=g.weights)
     return g2, perm, starts
+
+
+def _tile_costs(nv, src, dst, by_deg, n_tiles, pair_threshold,
+                gather_cost, pair_cost):
+    """Estimated per-tile in-edge cost in the DEGREE-SORTED tiling
+    (pair_relabel's cost model)."""
+    Wt = 128
+    rank0 = np.empty(nv, np.uint32)
+    rank0[by_deg] = np.arange(nv, dtype=np.uint32)
+    s2t = (rank0[src] // Wt).astype(np.int64)     # src tile
+    d2t = (rank0[dst] // Wt).astype(np.int32)     # dst tile
+    key = s2t * np.int64(n_tiles)
+    key += d2t
+    del s2t
+    # per-edge pair multiplicity without np.unique's inverse
+    # machinery: one FUSED radix sort carrying the edge index as
+    # payload (sequential passes, no argsort random reads and no
+    # key/index gathers — native.sort_kv, PERF_NOTES round 4),
+    # then group boundaries on the sorted keys
+    from lux_tpu import native
+    idx = np.arange(len(key),
+                    dtype=np.uint32 if len(key) < 2**32
+                    else np.int64)
+    native.sort_kv(key, (idx,))
+    newg = np.ones(len(key), bool)
+    newg[1:] = key[1:] != key[:-1]
+    del key
+    gid = (np.cumsum(newg) - 1).astype(np.int32)
+    cnt = np.bincount(gid)
+    is_pair = np.empty(len(gid), bool)            # per-edge dense?
+    is_pair[idx] = cnt[gid] >= pair_threshold
+    del idx, newg, gid, cnt
+    # per-tile cost without a float64 per-edge array: count the
+    # pair-served edges per dst tile, price the two classes
+    pair_by_tile = np.bincount(d2t[is_pair], minlength=n_tiles)
+    all_by_tile = np.bincount(d2t, minlength=n_tiles)
+    return (pair_cost * pair_by_tile
+            + gather_cost * (all_by_tile - pair_by_tile))
 
 
 @dataclasses.dataclass
@@ -422,7 +445,16 @@ class ShardedGraph:
 
         parts: materialize only these parts' array rows (multi-host:
         each process builds its own parts, engines assemble the global
-        sharded arrays with jax.make_array_from_process_local_data)."""
+        sharded arrays with jax.make_array_from_process_local_data).
+
+        Leaves one ``layout.shard`` span."""
+        with telemetry.span("layout.shard"):
+            return cls._build(g, num_parts, vpad_align, epad_align,
+                              starts, pair_threshold, parts)
+
+    @classmethod
+    def _build(cls, g, num_parts, vpad_align, epad_align, starts,
+               pair_threshold, parts) -> "ShardedGraph":
         if pair_threshold is not None:
             vpad_align = max(vpad_align, 128)
             if starts is None and num_parts > 1:

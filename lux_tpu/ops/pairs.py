@@ -560,6 +560,22 @@ def cost_balanced_starts(g, num_parts: int, threshold: int,
 def plan_sharded_pairs(sg, threshold: int,
                        min_fill: int | str | None = None,
                        kdim: int = 1):
+    """``_plan_sharded_pairs`` under one ``build.pair_plan`` span
+    (telemetry.span) whose counts say what the plan covers:
+    ``pair_edges`` (edges served by pair rows) and ``residual_edges``
+    (left to the gather path)."""
+    from lux_tpu import telemetry
+
+    with telemetry.span("build.pair_plan") as span:
+        sp, residual = _plan_sharded_pairs(sg, threshold, min_fill,
+                                           kdim)
+        covered = 0 if sp is None else int(sp.stats["covered"])
+        span.count(pair_edges=covered,
+                   residual_edges=int(np.sum(residual.ne_part)))
+    return sp, residual
+
+
+def _plan_sharded_pairs(sg, threshold: int, min_fill, kdim: int):
     """Build per-part pair plans for a ShardedGraph and the RESIDUAL
     ShardedGraph (uncovered edges, re-padded) the regular gather path
     should run on.  Returns (StackedPairPlan | None, residual_sg);

@@ -6,16 +6,14 @@ pagerank.cc:108-118).  The TPU-native equivalents:
 
 - ``trace(dir)``: captures an XLA/TPU profiler trace viewable in
   TensorBoard / Perfetto (the analogue of Legion's prof logs).
-- ``PhaseTimer``: host-side phase timing with completion fences
-  (load / build / compile / iterate), printed like the reference's
-  loadTime/compTime/updateTime breakdown; ``report()`` returns the
-  phases list so callers (event logs, tables) consume it directly
-  instead of re-parsing stdout.
 - ``annotation``/``step_annotation``: host-side
-  ``jax.profiler.TraceAnnotation`` wrappers the run paths (timing.py,
-  segmented.py, checkpoint.py, engine/phased.py) put around their
-  iterate / segment / checkpoint regions, so a captured trace shows
-  named regions instead of anonymous XLA ops; the engines' traced
+  ``jax.profiler.TraceAnnotation`` wrappers — the only place in the
+  package that touches that class.  ``telemetry.span`` opens
+  ``annotation("lux:" + name)`` round every host region the program
+  times; the run paths (timing.py, segmented.py, checkpoint.py,
+  engine/phased.py) put them around their iterate / segment /
+  checkpoint regions, so a captured trace shows named regions instead
+  of anonymous XLA ops; the engines' traced
   code additionally carries ``jax.named_scope`` labels (lux_exchange /
   lux_gather / lux_reduce / lux_apply, push: lux_relax / lux_update /
   lux_sparse) that name the device-side ops themselves.
@@ -24,7 +22,6 @@ pagerank.cc:108-118).  The TPU-native equivalents:
 from __future__ import annotations
 
 import contextlib
-import time
 
 
 @contextlib.contextmanager
@@ -62,55 +59,3 @@ def step_annotation(name: str, step: int):
         return jax.profiler.StepTraceAnnotation(name, step_num=step)
     except Exception:       # noqa: BLE001
         return contextlib.nullcontext()
-
-
-class _Phase:
-    """Set ``.fence`` to a device value produced INSIDE the block to
-    include its async execution in the phase time."""
-
-    def __init__(self):
-        self.fence = None
-
-
-class PhaseTimer:
-    """Named phase wall-clocks with reliable fences.
-
-    Device work dispatches asynchronously, so a phase that produces
-    device values must fence them — assign the result to the phase
-    handle (or pass ``fence=`` a zero-arg callable evaluated at exit):
-
-    >>> pt = PhaseTimer()
-    >>> with pt.phase("load"):
-    ...     g = Graph.from_file(...)
-    >>> with pt.phase("iterate") as ph:
-    ...     state = eng.run(state, 10)
-    ...     ph.fence = state
-    >>> pt.report()
-    """
-
-    def __init__(self):
-        self.phases: list[tuple[str, float]] = []
-
-    @contextlib.contextmanager
-    def phase(self, name: str, fence=None):
-        h = _Phase()
-        with annotation(f"lux_phase_{name}"):
-            t0 = time.perf_counter()
-            yield h
-            f = fence() if callable(fence) else fence
-            for val in (f, h.fence):
-                if val is not None:
-                    from lux_tpu.timing import fetch
-                    fetch(val)
-            self.phases.append((name, time.perf_counter() - t0))
-
-    def report(self, file=None) -> list[tuple[str, float]]:
-        """Print the phase table and RETURN the (name, seconds) phases
-        list, so callers (CLI tables, event logs) consume the data
-        directly instead of re-parsing stdout."""
-        total = sum(t for _, t in self.phases)
-        for name, t in self.phases:
-            print(f"  {name:<12s} {t:8.3f} s "
-                  f"({100 * t / max(total, 1e-12):5.1f}%)", file=file)
-        print(f"  {'total':<12s} {total:8.3f} s", file=file)
-        return list(self.phases)

@@ -282,7 +282,12 @@ def converge_segments(eng, label, active, segment,
             res = on_segment(label, active, total, cnt)
             if res is not None:
                 label, active = res
-                cnt = int(np.asarray(jax.device_get(jnp.sum(active))))
+                # the hook's replacement may still be on its way to
+                # the device (``place`` ends at dispatch): the count
+                # is where the host waits for it to arrive
+                with telemetry.span("segment.recount"):
+                    cnt = int(np.asarray(jax.device_get(
+                        jnp.sum(active))))
         # counters land only after the segment hook (checkpoint save)
         # survives: a crash in the save window makes the retry re-run
         # this slice, so appending earlier would double-count it

@@ -75,6 +75,8 @@ from typing import Callable
 
 import numpy as np
 
+from lux_tpu import telemetry
+
 DEFAULT_SEG_ITERS = 4
 KINDS = ("sssp", "components", "pagerank")
 
@@ -703,11 +705,50 @@ class _RunnerBase:
               cached=True, **ep, **slo, **self._rep())
         return True
 
-    def _boundary_metrics(self, retired: int, filled: int,
-                          queued: int) -> None:
+    # -- the host boundary's two transfers (telemetry spans) ------------
+    #
+    # Every segment boundary is one ``serve.boundary`` span (counts:
+    # retired, filled, occupied, queued, worked 0/1) with children
+    # ``.counts`` (push: the device-to-host fetch of the [B] active
+    # counts), ``.delta`` (live graphs only), ``.fetch``, ``.unpad``,
+    # ``.residual`` (pull: per-column residuals of the fetched state,
+    # host arithmetic), ``.retire``, ``.fill``, ``.pad``, ``.place``.
+    # A push boundary that neither retires nor refills has ``worked``
+    # 0 and only the ``.counts`` child.
+
+    def _fetch_unpad(self, *state) -> list:
+        """Device state arrays -> host ``[nv, B]`` arrays:
+        ``serve.boundary.fetch`` (device_get; ``bytes``) then
+        ``serve.boundary.unpad``."""
+        import jax
+
+        sg = self.eng.sg
+        with telemetry.span("serve.boundary.fetch") as sp:
+            padded = [np.asarray(jax.device_get(x)) for x in state]
+            sp.count(bytes=sum(x.nbytes for x in padded))
+        with telemetry.span("serve.boundary.unpad"):
+            return [sg.from_padded(x) for x in padded]
+
+    def _pad_place(self, *host):
+        """Host ``[nv, B]`` arrays -> device state:
+        ``serve.boundary.pad`` then ``serve.boundary.place``
+        (``bytes``; the transfer is asynchronous, so the span ends at
+        dispatch, not at arrival)."""
+        sg = self.eng.sg
+        with telemetry.span("serve.boundary.pad"):
+            padded = [sg.to_padded(x) for x in host]
+        with telemetry.span("serve.boundary.place",
+                            bytes=sum(x.nbytes for x in padded)):
+            return self.eng.place(*padded)
+
+    def _boundary_metrics(self, bsp, worked: bool, retired: int,
+                          filled: int, queued: int) -> None:
         """Per-segment-boundary series (host-side by construction —
-        the drivers' on_segment hooks are the only callers): batch
-        occupancy, segment count, retire/refill rates."""
+        the drivers' on_segment hooks are the only callers): the
+        ``serve.boundary`` span's counts, then batch occupancy,
+        segment count, retire/refill rates in the metrics registry."""
+        bsp.count(worked=int(worked), retired=retired, filled=filled,
+                  occupied=len(self._occupied()), queued=queued)
         if self.metrics is None:
             return
         m = self.metrics
@@ -808,6 +849,10 @@ class PushBatchRunner(_RunnerBase):
                                   sg.to_padded(act_h))
 
         def hook(label, active, total, cnt):
+            with telemetry.span("serve.boundary") as bsp:
+                return boundary(bsp, label, active, total)
+
+        def boundary(bsp, label, active, total):
             if self.on_boundary is not None:
                 self.on_boundary(self)
             if self.mem is not None:
@@ -822,36 +867,40 @@ class PushBatchRunner(_RunnerBase):
                 # column retires only when its frontier is empty AND
                 # the delta offered no improvement — i.e. at the
                 # fixed point of base + delta@its-epoch.
-                label, active = self._apply_delta(label, active)
-            counts = np.asarray(jax.device_get(
-                jnp.sum(active, axis=tuple(range(active.ndim - 1)))))
+                with telemetry.span("serve.boundary.delta"):
+                    label, active = self._apply_delta(label, active)
+            with telemetry.span("serve.boundary.counts"):
+                counts = np.asarray(jax.device_get(jnp.sum(
+                    active, axis=tuple(range(active.ndim - 1)))))
             done = [c for c in self._occupied()
                     if counts[c] == 0
                     or self.slots[c].segments >= self.max_segments]
             want_fill = len(collector) > 0 and (
                 done or self._free_cols())
             if not done and not want_fill:
-                self._boundary_metrics(0, 0, len(collector))
+                self._boundary_metrics(bsp, False, 0, 0,
+                                       len(collector))
                 # the delta step may have changed the device state —
                 # hand the updated arrays back to the driver
                 return (label, active) if self.live is not None \
                     else None
-            lab_h = sg.from_padded(np.asarray(jax.device_get(label)))
-            act_h = sg.from_padded(np.asarray(jax.device_get(active)))
-            for c in done:
-                self._retire(c, lab_h[:, c].copy(), total,
-                             converged=bool(counts[c] == 0))
-                lab_h[:, c] = self._inf
-                act_h[:, c] = False
-            n_filled = self._fill(lab_h, act_h, collector, total,
-                                  deadline_s)
+            lab_h, act_h = self._fetch_unpad(label, active)
+            with telemetry.span("serve.boundary.retire"):
+                for c in done:
+                    self._retire(c, lab_h[:, c].copy(), total,
+                                 converged=bool(counts[c] == 0))
+                    lab_h[:, c] = self._inf
+                    act_h[:, c] = False
+            with telemetry.span("serve.boundary.fill"):
+                n_filled = self._fill(lab_h, act_h, collector, total,
+                                      deadline_s)
             _emit("serve_refill", query_kind=self.kind,
                   retired=len(done),
                   filled=n_filled, occupied=len(self._occupied()),
                   queued=len(collector))
-            self._boundary_metrics(len(done), n_filled,
+            self._boundary_metrics(bsp, True, len(done), n_filled,
                                    len(collector))
-            return eng.place(sg.to_padded(lab_h), sg.to_padded(act_h))
+            return self._pad_place(lab_h, act_h)
 
         converge_segments(eng, label, active, self.seg_iters,
                           on_segment=hook)
@@ -996,6 +1045,10 @@ class PullBatchRunner(_RunnerBase):
         state = eng.place(sg.to_padded(state_h))
 
         def hook(state, done_iters):
+            with telemetry.span("serve.boundary") as bsp:
+                return boundary(bsp, state, done_iters)
+
+        def boundary(bsp, state, done_iters):
             nonlocal prev
             if self.on_boundary is not None:
                 self.on_boundary(self)
@@ -1004,7 +1057,7 @@ class PullBatchRunner(_RunnerBase):
             for s in self.slots:
                 if s is not None:
                     s.segments += 1
-            new = sg.from_padded(np.asarray(jax.device_get(state)))
+            new, = self._fetch_unpad(state)
             corrected = False
             if self.live is not None:
                 # the host half of the live pull iteration: add the
@@ -1012,37 +1065,40 @@ class PullBatchRunner(_RunnerBase):
                 # normalized by the effective degree) — new is now
                 # one exact PPR iteration of prev over each column's
                 # graph_at(col_epoch)
-                new, corrected = self._correct(prev, new)
+                with telemetry.span("serve.boundary.delta"):
+                    new, corrected = self._correct(prev, new)
             # per-query convergence: max-abs state change over the
             # WHOLE segment <= tol (an upper bound on any single
             # iteration's residual — strictly conservative)
-            res = np.max(np.abs(new - prev), axis=0)
+            with telemetry.span("serve.boundary.residual"):
+                res = np.max(np.abs(new - prev), axis=0)
             done = [c for c in self._occupied()
                     if res[c] <= self.tol
                     or self.slots[c].segments >= self.max_segments]
-            for c in done:
-                self._retire(c, new[:, c].copy(), done_iters,
-                             converged=bool(res[c] <= self.tol))
-            n_filled = self._fill(new, collector, done_iters,
-                                  deadline_s)
+            with telemetry.span("serve.boundary.retire"):
+                for c in done:
+                    self._retire(c, new[:, c].copy(), done_iters,
+                                 converged=bool(res[c] <= self.tol))
+            with telemetry.span("serve.boundary.fill"):
+                n_filled = self._fill(new, collector, done_iters,
+                                      deadline_s)
             if done or n_filled:
                 _emit("serve_refill", query_kind=self.kind,
                       retired=len(done), filled=n_filled,
                       occupied=len(self._occupied()),
                       queued=len(collector))
-            self._boundary_metrics(len(done), n_filled,
-                                   len(collector))
+            self._boundary_metrics(bsp, bool(done or n_filled),
+                                   len(done), n_filled, len(collector))
             if not self._occupied() and not len(collector):
                 raise _Drained()
             prev = new
             if n_filled:
                 self._push_resets()
-                return eng.place(sg.to_padded(new))
-            if corrected:
-                # the host correction changed the state the next
-                # iteration must start from — hand it back even when
-                # no column turned over
-                return eng.place(sg.to_padded(new))
+            if n_filled or corrected:
+                # a refill, or the host correction, changed the state
+                # the next iteration must start from — hand it back
+                # (the correction also when no column turned over)
+                return self._pad_place(new)
             return None
 
         try:

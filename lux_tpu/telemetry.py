@@ -48,6 +48,26 @@ supervisor (resilience.py), the CLI and bench.py all emit into:
   through every signature.  The default is a null handle: emitting is
   a no-op and engines build their counter-free programs.
 
+- ``span(name, **counts)`` / ``mark(name, **counts)`` / ``spans()``:
+  the ONE way the program times a host region.  A span is a context
+  manager that opens ``profiling.annotation("lux:" + name)`` (so in
+  any profiler capture it lies on the ``/host:CPU`` plane, on the
+  device trace's clock, beside the idle gap it explains), reads
+  ``time.perf_counter()`` on entry and exit, and appends ONE record
+  ``{id, parent, name, t0, t1, counts}`` to a process-wide ring of
+  ``SPAN_RING`` records (oldest dropped).  ``parent`` is the enclosing
+  span on this thread (0 = none).  Counts live ON the records —
+  there is no counter registry: ``sp.count(bytes=n)`` fills them
+  inside the block, ``mark`` leaves a zero-length record for a count
+  with no duration of its own.  A count may be an un-fetched device
+  scalar; it is fetched when ``spans()`` takes a snapshot (or, once
+  it is ready, when a later record brings device counts of its own),
+  never by waiting at the call, so no site gains a host sync.  The ring is always on: no
+  flag, no sampling.  A span never fences — where its work is
+  asynchronous the site's doc line says so.  With an event sink or an
+  observer installed the record also goes out once as a ``span``
+  event (``-events`` log, flight recorder, ``tracing.trace_export``).
+
 Counter semantics (what the buffers mean, engine by engine):
 
 - push classic (``PushEngine.converge_stats``): ``frontier[i]`` is the
@@ -69,13 +89,17 @@ Counter semantics (what the buffers mean, engine by engine):
 from __future__ import annotations
 
 import binascii
+import collections
 import contextlib
 import contextvars
 import dataclasses
+import itertools
 import json
 import os
 import threading
 import time
+
+from lux_tpu.profiling import annotation
 
 SCHEMA = 1
 
@@ -187,6 +211,12 @@ class EventLog:
         self.rotations = 0
         self.events: list[dict] = []
         self._closed = False
+        # one event is built, kept and written under this lock: two
+        # threads emitting into one log (fleet replicas, span events)
+        # must not write their ``tm`` out of order.  Re-entrant: a
+        # rotation notifies observers while it is held, and an
+        # observer may emit
+        self._lock = threading.RLock()
         self._fd = self._open() if path else None
 
     def _open(self) -> int:
@@ -281,28 +311,30 @@ class EventLog:
             pass
 
     def emit(self, kind: str, **fields) -> dict:
-        if self.path is not None and self.rotate_bytes is not None:
-            self._maybe_rotate()
-        ev = make_event(kind, fields)
-        self.events.append(ev)
-        if self._fd is not None:
-            # ONE buffer, ONE write: the line-atomicity contract
-            os.write(self._fd, (json.dumps(ev) + "\n").encode())
+        with self._lock:
+            if self.path is not None and self.rotate_bytes is not None:
+                self._maybe_rotate()
+            ev = make_event(kind, fields)
+            self.events.append(ev)
+            if self._fd is not None:
+                # ONE buffer, ONE write: the line-atomicity contract
+                os.write(self._fd, (json.dumps(ev) + "\n").encode())
         _notify(ev)
         return ev
 
     def counts(self) -> dict:
         """{kind: occurrences} over everything emitted so far."""
         out: dict[str, int] = {}
-        for ev in self.events:
+        for ev in list(self.events):
             out[ev["kind"]] = out.get(ev["kind"], 0) + 1
         return out
 
     def close(self) -> None:
-        self._closed = True
-        if self._fd is not None:
-            os.close(self._fd)
-            self._fd = None
+        with self._lock:
+            self._closed = True
+            if self._fd is not None:
+                os.close(self._fd)
+                self._fd = None
 
     def __enter__(self):
         return self
@@ -575,3 +607,129 @@ def use(events: EventLog | None = None,
         yield tel
     finally:
         _current.reset(token)
+
+
+# ---- spans: the one way the program times a host region ---------------
+
+SPAN_RING = 16384
+
+_RING: collections.deque = collections.deque(maxlen=SPAN_RING)
+# records whose counts still hold device scalars, oldest first: a
+# later such record settles the ones that have become ready, so a
+# long-lived server pins a handful of device buffers, not a ring full
+_UNSETTLED: collections.deque = collections.deque()
+_SETTLE_LOCK = threading.Lock()
+_SPAN_IDS = itertools.count(1)
+# id of the enclosing span on this thread (0 = none): a new thread
+# starts with a fresh context, so its spans are roots
+_enclosing: contextvars.ContextVar[int] = contextvars.ContextVar(
+    "lux_tpu_span", default=0)
+_HOST_SCALARS = (int, float, bool, str, type(None))
+
+
+def _record(sid: int, parent: int, name: str, t0: float, t1: float,
+            counts: dict) -> None:
+    rec = {"id": sid, "parent": parent, "name": name,
+           "t0": t0, "t1": t1, "counts": counts}
+    _RING.append(rec)
+    if not all(isinstance(v, _HOST_SCALARS) for v in counts.values()):
+        _settle(wait=False)
+        _UNSETTLED.append(rec)
+    tel = _current.get()
+    if tel.events is not None or _OBSERVERS:
+        # a device scalar still in flight goes out as null: emitting
+        # must not become the host sync the ring avoids
+        ready = {k: (v if isinstance(v, _HOST_SCALARS)
+                     else v.item() if _is_ready(v) else None)
+                 for k, v in counts.items()}
+        tel.emit("span", name=name, id=sid, parent=parent,
+                 t0=round(t0, 6), t1=round(t1, 6),
+                 seconds=round(t1 - t0, 6), counts=ready)
+
+
+def _is_ready(v) -> bool:
+    """True where reading ``v`` needs no waiting (a jax.Array says
+    so itself; anything that cannot say counts as in flight)."""
+    ready = getattr(v, "is_ready", None)
+    return ready is not None and ready()
+
+
+def _settle(wait: bool) -> None:
+    """Replace device-scalar counts of earlier records by their host
+    values, oldest first: all of them (``wait``: a snapshot), or
+    those that need no waiting (a later record with device counts).
+    A scalar that cannot be read — its device was lost — becomes
+    None instead of failing every reader of the ring."""
+    with _SETTLE_LOCK:
+        while _UNSETTLED:
+            counts = _UNSETTLED[0]["counts"]
+            late = {k: v for k, v in counts.items()
+                    if not isinstance(v, _HOST_SCALARS)}
+            if not wait and not all(map(_is_ready, late.values())):
+                return
+            for k, v in late.items():
+                try:
+                    counts[k] = v.item()
+                except Exception:       # noqa: BLE001
+                    counts[k] = None
+            _UNSETTLED.popleft()
+
+
+class Span:
+    """One open host region (see the module docstring); use through
+    ``span()``.  ``id`` is set on entry."""
+
+    __slots__ = ("name", "counts", "id", "parent", "t0", "_ann",
+                 "_token")
+
+    def __init__(self, name: str, counts: dict):
+        self.name = name
+        self.counts = counts
+        self.id = 0
+
+    def count(self, **counts) -> None:
+        """Add counts to the record this span will leave."""
+        self.counts.update(counts)
+
+    def __enter__(self):
+        self.id = next(_SPAN_IDS)
+        self.parent = _enclosing.get()
+        self._token = _enclosing.set(self.id)
+        self._ann = annotation("lux:" + self.name)
+        self._ann.__enter__()
+        self.t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc):
+        t1 = time.perf_counter()
+        self._ann.__exit__(*exc)
+        _enclosing.reset(self._token)
+        _record(self.id, self.parent, self.name, self.t0, t1,
+                self.counts)
+        return False
+
+
+def span(name: str, **counts) -> Span:
+    """``with telemetry.span("build.pair_plan") as sp: ...;
+    sp.count(rows=n)``.  Never fences: a span round asynchronous work
+    ends at dispatch."""
+    return Span(name, counts)
+
+
+def mark(name: str, seconds: float = 0.0, **counts) -> None:
+    """A record that ends now.  Zero-length for a count with no
+    duration of its own (a cache hit, a loop's iteration counts);
+    ``seconds`` is for a duration someone else measured (JAX's own
+    compile timers, runtime.watch_compiles)."""
+    t = time.perf_counter()
+    _record(next(_SPAN_IDS), _enclosing.get(), name, t - seconds, t,
+            counts)
+
+
+def spans() -> list[dict]:
+    """Snapshot of the ring, oldest first.  Device-scalar counts that
+    are still un-fetched are fetched HERE (and written back, so each
+    is fetched once); one whose device is gone reads None."""
+    _settle(wait=True)
+    return [{**rec, "counts": dict(rec["counts"])}
+            for rec in list(_RING)]

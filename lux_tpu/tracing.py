@@ -105,6 +105,10 @@ COMM_PHASE_NAMES = ("exchange", "gen_exchange")
 # transition).
 QUERY_TID_BASE = 100
 QUERY_REPLICA_STRIDE = 40
+# program spans (telemetry.span ``span`` events): lanes from here, one
+# per set of non-overlapping ROOT spans; a child rides its parent's
+# lane, so the nesting the records carry is the nesting drawn
+SPAN_TID_BASE = 50
 
 
 def _num(x) -> bool:
@@ -155,10 +159,14 @@ def split_streams(events):
 
 def split_runs(events):
     """Group one stream into runs at run_start/config_start
-    boundaries; a log without boundary events is one anonymous run."""
+    boundaries; a log without boundary events is one anonymous run.
+    Program spans that precede a boundary and nothing else (a run's
+    load / relabel / build, done before its ``run_start``) belong to
+    the run they prepare."""
     runs, cur = [], []
     for ev in events:
-        if ev["kind"] in RUN_BOUNDARIES and cur:
+        if ev["kind"] in RUN_BOUNDARIES and cur \
+                and not all(e["kind"] == "span" for e in cur):
             runs.append(cur)
             cur = []
         cur.append(ev)
@@ -334,6 +342,8 @@ def _run_spans(run, us, trk: _Track, te: list):
                                 int(ev.get("peak_bytes", 0))}})
         elif kind in RUN_BOUNDARIES:
             pass                       # represented by the run span
+        elif kind == "span":
+            pass                       # drawn by _program_spans
         else:
             scope = "p" if kind in PROCESS_INSTANTS else "t"
             iargs = {k: v for k, v in ev.items()
@@ -347,7 +357,48 @@ def _run_spans(run, us, trk: _Track, te: list):
             trk.shrink_labels[trk.epoch] = (
                 f"exec (after shrink #{trk.epoch}"
                 + (f", ndev={to}" if _num(to) else "") + ")")
+    _program_spans(run, times, trk, te, rstart, rend)
     _query_spans(run, times, trk, te, rstart, rend)
+
+
+def _program_spans(run, times, trk: _Track, te: list, rstart, rend):
+    """``span`` events (telemetry.span records: id, parent, t0, t1 on
+    ``perf_counter``) as complete events, each NESTED under its
+    parent: the stream's perf_counter-to-trace offset is the median
+    of (emit time - t1), roots pack greedily onto ``spans.N`` lanes
+    and a child takes its parent's lane.  Zero-length records
+    (``telemetry.mark``) become instants on that lane."""
+    recs = [(ev, ts) for ev, ts in zip(run, times)
+            if ev["kind"] == "span" and _num(ev.get("t0"))
+            and _num(ev.get("t1")) and ev["t1"] >= ev["t0"]]
+    if not recs:
+        return
+    off = median(ts - ev["t1"] * 1e6 for ev, ts in recs)
+    lane_of, lane_ends = {}, []
+    for ev, _ts in sorted(recs, key=lambda r: (r[0]["t0"],
+                                                -r[0]["t1"])):
+        s, d = _clamp(ev["t0"] * 1e6 + off,
+                      (ev["t1"] - ev["t0"]) * 1e6, rstart, rend)
+        lane = lane_of.get(ev.get("parent"))
+        if lane is None:
+            lane = next((i for i, end in enumerate(lane_ends)
+                         if end <= s), len(lane_ends))
+            if lane == len(lane_ends):
+                lane_ends.append(s + d)
+                te.append(_meta("thread_name", trk.pid,
+                                f"spans.{lane}",
+                                tid=SPAN_TID_BASE + lane))
+            lane_ends[lane] = max(lane_ends[lane], s + d)
+        lane_of[ev.get("id")] = lane
+        args = dict(ev.get("counts") or {}, id=ev.get("id"),
+                    parent=ev.get("parent"))
+        tid = SPAN_TID_BASE + lane
+        if ev["t1"] > ev["t0"]:
+            te.append(_span(ev.get("name", "?"), "span", s, d,
+                            trk.pid, tid, args=args))
+        else:
+            te.append(_instant(ev.get("name", "?"), s, trk.pid, tid,
+                               args=args))
 
 
 def _collective_spans(comm, i, ph, s, d, pid, tid) -> list:

@@ -1497,6 +1497,13 @@ def check_event_lines(path: str, events):
         if "seconds" in ev and not _is_num(ev["seconds"]):
             errs.append(f"{path} ({where}): non-finite seconds "
                         f"{ev['seconds']!r}")
+        if ev.get("kind") == "span" and not (
+                isinstance(ev.get("name"), str)
+                and _is_num(ev.get("t0")) and _is_num(ev.get("t1"))
+                and ev["t1"] >= ev["t0"]
+                and isinstance(ev.get("counts"), dict)):
+            errs.append(f"{path} ({where}): span event needs a string "
+                        f"name, numeric t0 <= t1 and a counts object")
     return errs
 
 

@@ -100,6 +100,11 @@ human shape — and audits it while doing so:
   recovered re-dispatch (``query_enqueue`` with ``recovered``) with
   no preceding ``journal_replay`` naming the journal it came from.
 
+- PR 24 (one span primitive, lux_tpu/telemetry.py ``span``/``mark``):
+  ``span`` events render as host seconds and summed counts (bytes,
+  retired, pair_edges, ...) by span name; one whose
+  ``t1`` precedes its ``t0`` (or without a name) FAILS.
+
 Usage:
     python scripts/events_summary.py FILE [FILE...]
     python scripts/events_summary.py -flight FLIGHT.json
@@ -130,7 +135,7 @@ KNOWN = {"run_start", "config_start", "header", "timed_run",
          "wal_truncate", "wal_replay", "reseed", "compact_scheduled",
          "mem_sample", "mem_watermark", "mem_pressure",
          "replica_respawn", "replica_quarantine", "canary",
-         "journal_truncate", "journal_replay"}
+         "journal_truncate", "journal_replay", "span"}
 
 # round 19 (communication observatory, lux_tpu/comms.py): the
 # collective primitives a comm_ledger breakdown may name — matching
@@ -1131,6 +1136,32 @@ def render_run(run, out=sys.stdout) -> list[str]:
               f"{lr.get('path')} -> .1 at {lr.get('rotate_bytes')} "
               f"bytes, {lr.get('generations')} generation(s) kept",
               file=out)
+
+    # program spans (telemetry.span): host seconds by span name; a
+    # record whose t1 precedes its t0 cannot be a span and FAILS
+    span_s = {}
+    for sp in by.get("span", []):
+        t0, t1 = sp.get("t0"), sp.get("t1")
+        if not (_is_num(t0) and _is_num(t1) and t1 >= t0
+                and isinstance(sp.get("name"), str)):
+            errs.append(f"span event without a name and ordered "
+                        f"numeric t0/t1: {sp!r}"[:160])
+            continue
+        n, tot, sums = span_s.get(sp["name"], (0, 0.0, {}))
+        for k, v in (sp.get("counts") or {}).items():
+            if _is_num(v) and not isinstance(v, bool):
+                sums[k] = sums.get(k, 0) + v
+        span_s[sp["name"]] = (n + 1, tot + (t1 - t0), sums)
+    if span_s:
+        print(f"  program spans: "
+              f"{sum(n for n, _, _ in span_s.values())} "
+              f"record(s), {len(span_s)} name(s); counts summed",
+              file=out)
+        for name, (n, tot, sums) in sorted(
+                span_s.items(), key=lambda kv: -kv[1][1])[:12]:
+            counts = " ".join(f"{k}={v:g}" for k, v in sums.items())
+            print(f"    {name:<28s} x{n:<5d} {tot:10.6f} s  {counts}",
+                  file=out)
 
     done = by.get("run_done", [])
     if done:

@@ -315,6 +315,33 @@ def test_audit_never_alters_push_outputs():
     np.testing.assert_array_equal(lab_a, lab_b)   # bitwise
 
 
+@pytest.mark.parametrize("np_parts,mesh_n", [(2, 0), (8, 8)])
+def test_audited_converge_programs_carry_the_sparse_iters_counter(
+        np_parts, mesh_n):
+    """PR 24: every converge variant the auditor walks (plain, stats,
+    health) returns ONE more output than its public signature, a
+    replicated int32 scalar LAST (the ``sparse_iters`` carry); the
+    single-step program does not.  The audit stays clean with it."""
+    import jax
+
+    from lux_tpu.apps import sssp
+    from lux_tpu.parallel.mesh import make_mesh
+    eng = sssp.build_engine(_graph(), 0, num_parts=np_parts,
+                            mesh=make_mesh(mesh_n) if mesh_n else None,
+                            audit="error")
+    outs = {}
+    for name, (jitted, thunk) in eng.audit_programs().items():
+        outs[name] = jax.eval_shape(jitted, *thunk())
+    assert len(outs["step"]) == 3
+    assert {n: len(o) for n, o in outs.items() if n != "step"} == {
+        "converge": 3 + 1, "converge_stats": 7 + 1,
+        "converge_health": 9 + 1}
+    for name, out in outs.items():
+        if name != "step":
+            assert out[-1].shape == () and out[-1].dtype == np.int32
+    assert audit.audit_engine(eng, mode="error") == []
+
+
 def test_audit_warn_mode_warns_not_raises(monkeypatch):
     """mode='warn' surfaces findings as AuditWarnings and returns
     them; mode='error' raises."""

@@ -11,7 +11,6 @@ from lux_tpu import checkpoint as ckpt
 from lux_tpu.apps import pagerank, sssp
 from lux_tpu.convert import uniform_random_edges
 from lux_tpu.graph import Graph
-from lux_tpu.profiling import PhaseTimer
 
 
 def test_save_load_roundtrip(tmp_path):
@@ -176,17 +175,3 @@ def test_resume_falls_back_and_replays_lost_segment(tmp_path):
     np.testing.assert_array_equal(eng.unpad(got), want)
     assert ckpt.load(p)[1]["iter"] == 10   # re-saved clean
 
-
-def test_phase_timer(capsys):
-    pt = PhaseTimer()
-    with pt.phase("a"):
-        pass
-    with pt.phase("b", fence=np.zeros(3)):
-        pass
-    phases = pt.report()
-    out = capsys.readouterr().out
-    assert "a" in out and "total" in out
-    # round 7: report() RETURNS the phases list so callers consume
-    # the data instead of re-parsing stdout
-    assert [name for name, _t in phases] == ["a", "b"]
-    assert all(t >= 0 for _n, t in phases)

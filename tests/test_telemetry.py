@@ -506,3 +506,475 @@ def test_event_log_rotation_single_process(tmp_path):
     # a plain (never-rotated) path is its own one-element set
     lone = str(tmp_path / "lone.jsonl")
     assert telemetry.rotated_paths(lone) == [lone]
+
+
+# ---- PR 24: the span primitive (telemetry.span / mark / spans) --------
+
+def _since(mark_id):
+    """Ring records made after the record with id ``mark_id``."""
+    return [r for r in telemetry.spans() if r["id"] > mark_id]
+
+
+def _ring_tip():
+    telemetry.mark("test.tip")
+    return telemetry.spans()[-1]["id"]
+
+
+def test_span_nesting_ids_and_parents():
+    tip = _ring_tip()
+    with telemetry.span("outer", k=1) as a:
+        with telemetry.span("inner") as b:
+            b.count(bytes=12)
+        with telemetry.span("inner") as c:
+            telemetry.mark("note", hits=3)
+    recs = {r["id"]: r for r in _since(tip)}
+    assert a.id < b.id < c.id and set(recs) >= {a.id, b.id, c.id}
+    assert recs[a.id]["parent"] == 0
+    assert recs[b.id]["parent"] == recs[c.id]["parent"] == a.id
+    assert recs[b.id]["counts"] == {"bytes": 12}
+    assert recs[a.id]["counts"] == {"k": 1}
+    note = next(r for r in recs.values() if r["name"] == "note")
+    assert note["parent"] == c.id and note["t0"] == note["t1"]
+    assert note["counts"] == {"hits": 3}
+    # children lie inside the parent on the one clock
+    for kid in (b.id, c.id):
+        assert recs[a.id]["t0"] <= recs[kid]["t0"] <= recs[kid]["t1"] \
+            <= recs[a.id]["t1"]
+    # records land in exit order: children before their parent
+    order = [r["id"] for r in _since(tip)]
+    assert order.index(b.id) < order.index(c.id) < order.index(a.id)
+
+
+def test_span_on_another_thread_is_a_root():
+    import threading
+    tip = _ring_tip()
+    seen = {}
+
+    def work():
+        with telemetry.span("thread.root") as s:
+            with telemetry.span("thread.child") as k:
+                seen["ids"] = (s.id, k.id)
+
+    with telemetry.span("main.root") as m:
+        t = threading.Thread(target=work)
+        t.start()
+        t.join()
+    recs = {r["id"]: r for r in _since(tip)}
+    root, child = seen["ids"]
+    assert recs[root]["parent"] == 0          # NOT under main.root
+    assert recs[child]["parent"] == root
+    assert recs[m.id]["parent"] == 0
+    assert len({m.id, root, child}) == 3
+
+
+def test_span_ring_is_bounded_and_drops_the_oldest():
+    for _ in range(telemetry.SPAN_RING + 10):
+        telemetry.mark("fill")
+    recs = telemetry.spans()
+    assert len(recs) == telemetry.SPAN_RING
+    ids = [r["id"] for r in recs]
+    assert ids == sorted(ids) and ids[-1] - ids[0] == len(ids) - 1
+    telemetry.mark("one.more")
+    again = telemetry.spans()
+    assert len(again) == telemetry.SPAN_RING
+    assert again[0]["id"] == ids[1] and again[-1]["name"] == "one.more"
+
+
+def test_span_event_reaches_an_observer_exactly_once():
+    got = []
+    telemetry.add_observer(got.append)
+    try:
+        with telemetry.span("obs.outer", n=2) as s:
+            pass
+    finally:
+        telemetry.remove_observer(got.append)
+    evs = [e for e in got if e["kind"] == "span"]
+    assert len(evs) == 1
+    ev = evs[0]
+    assert (ev["name"], ev["id"], ev["parent"]) == ("obs.outer", s.id, 0)
+    assert ev["counts"] == {"n": 2} and ev["t1"] >= ev["t0"]
+    assert ev["seconds"] == pytest.approx(ev["t1"] - ev["t0"], abs=2e-6)
+    # ... and through an event sink scoped with use()
+    log = telemetry.EventLog()
+    with telemetry.use(events=log):
+        with telemetry.span("sink.span"):
+            pass
+    assert [e["name"] for e in log.events if e["kind"] == "span"] \
+        == ["sink.span"]
+
+
+def test_span_builds_no_event_without_sink_or_observer(monkeypatch):
+    assert not telemetry._OBSERVERS
+    assert telemetry.current().events is None
+
+    def boom(kind, fields):
+        raise AssertionError(f"built a {kind} event nobody asked for")
+
+    monkeypatch.setattr(telemetry, "make_event", boom)
+    tip = telemetry.spans()[-1]["id"] if telemetry.spans() else 0
+    with telemetry.span("quiet"):
+        telemetry.mark("quiet.mark")
+    assert [r["name"] for r in _since(tip)] == ["quiet.mark", "quiet"]
+
+
+def test_device_scalar_count_is_fetched_at_snapshot_not_at_the_call():
+    class Lazy:
+        fetched = 0
+
+        def item(self):
+            Lazy.fetched += 1
+            return 7
+
+    telemetry.mark("lazy.mark", iters=Lazy())
+    with telemetry.span("lazy.span") as s:
+        s.count(ns=Lazy())
+    assert Lazy.fetched == 0
+    recs = telemetry.spans()
+    assert Lazy.fetched == 2
+    assert recs[-2]["counts"] == {"iters": 7}
+    assert recs[-1]["counts"] == {"ns": 7}
+    telemetry.spans()                   # written back: fetched once
+    assert Lazy.fetched == 2
+    # a real device scalar: un-fetched in the ring, an int in the
+    # snapshot; an event built meanwhile carries it only if ready
+    x = jax.numpy.int32(41) + 1
+    telemetry.mark("dev.mark", iters=x)
+    assert telemetry._RING[-1]["counts"]["iters"] is x
+    assert telemetry.spans()[-1]["counts"] == {"iters": 42}
+
+
+def test_later_device_counts_settle_the_ready_ones_before_them():
+    """A long-lived server must not pin a ring full of device
+    scalars: a record that brings device counts settles every earlier
+    one that needs no waiting, and never waits for one in flight."""
+    class Dev:
+        fetched = 0
+
+        def __init__(self, ready):
+            self.ready = ready
+
+        def is_ready(self):
+            return self.ready
+
+        def item(self):
+            Dev.fetched += 1
+            return 5
+
+    telemetry.spans()                       # nothing left unsettled
+    first, second = Dev(True), Dev(False)
+    telemetry.mark("dev.a", iters=first)
+    assert Dev.fetched == 0                 # never at its own call
+    telemetry.mark("dev.b", iters=second)
+    assert Dev.fetched == 1                 # dev.a was ready
+    telemetry.mark("dev.c", iters=Dev(True))
+    assert Dev.fetched == 1                 # dev.b in flight: no wait
+    assert len(telemetry._UNSETTLED) == 2
+    assert [r["counts"] for r in telemetry.spans()[-3:]] \
+        == [{"iters": 5}] * 3
+    assert not telemetry._UNSETTLED
+
+
+def test_a_count_on_a_lost_device_reads_none_and_breaks_no_reader():
+    class Lost:
+        def item(self):
+            raise RuntimeError("device went away")
+
+    telemetry.mark("lost.mark", iters=Lost(), kept=3)
+    telemetry.mark("after.lost")
+    recs = telemetry.spans()
+    assert recs[-2]["counts"] == {"iters": None, "kept": 3}
+    assert recs[-1]["name"] == "after.lost"
+
+
+def test_span_body_that_raises_still_records_and_reraises():
+    tip = _ring_tip()
+    with pytest.raises(KeyError):
+        with telemetry.span("raises.outer"):
+            with telemetry.span("raises.inner"):
+                raise KeyError("x")
+    assert [r["name"] for r in _since(tip)] \
+        == ["raises.inner", "raises.outer"]
+    # the enclosing-span context is restored: the next span is a root
+    with telemetry.span("after") as s:
+        pass
+    assert telemetry.spans()[-1]["parent"] == 0 and s.parent == 0
+
+
+def test_empty_span_costs_under_a_generous_ceiling():
+    import time
+    n = 5000
+    for _ in range(200):                # warm the paths
+        with telemetry.span("cost"):
+            pass
+    best = float("inf")
+    for _ in range(5):
+        t0 = time.perf_counter()
+        for _ in range(n):
+            with telemetry.span("cost"):
+                pass
+        best = min(best, (time.perf_counter() - t0) / n)
+    assert best < 50e-6, f"{best * 1e6:.1f} us per empty span"
+
+
+def test_mark_with_seconds_is_a_record_that_ends_now():
+    telemetry.mark("timed.mark", seconds=0.25, fun="f")
+    r = telemetry.spans()[-1]
+    assert r["t1"] - r["t0"] == pytest.approx(0.25)
+    assert r["counts"] == {"fun": "f"}
+
+
+def test_trace_export_nests_span_events_and_validates():
+    import time
+
+    from lux_tpu import tracing
+    log = telemetry.EventLog()
+    with telemetry.use(events=log):
+        log.emit("run_start", app="spans")
+        with telemetry.span("relabel") as b:
+            with telemetry.span("relabel.deal", tiles=3):
+                time.sleep(0.001)
+            with telemetry.span("relabel.rebuild_csc"):
+                telemetry.mark("jit.compile")
+                time.sleep(0.001)
+        with telemetry.span("state.init"):
+            time.sleep(0.001)   # under a microsecond draws as a mark
+        log.emit("run_done", seconds=0.0)
+    trace = tracing.trace_export(log.events)
+    assert tracing.validate_trace(trace) == []
+    drawn = [e for e in trace["traceEvents"] if e.get("cat") == "span"]
+    by = {e["name"]: e for e in drawn}
+    assert set(by) == {"relabel", "relabel.deal",
+                       "relabel.rebuild_csc", "state.init"}
+    assert by["relabel.deal"]["args"]["parent"] == b.id
+    assert by["relabel.deal"]["args"]["tiles"] == 3
+    assert len({e["tid"] for e in drawn}) == 1      # one lane, nested
+    for kid in ("relabel.deal", "relabel.rebuild_csc"):
+        assert by["relabel"]["ts"] <= by[kid]["ts"] + 2
+        assert by[kid]["ts"] + by[kid]["dur"] \
+            <= by["relabel"]["ts"] + by["relabel"]["dur"] + 2
+    marks = [e for e in trace["traceEvents"] if e.get("ph") == "i"
+             and e["name"] == "jit.compile"]
+    assert len(marks) == 1 and marks[0]["tid"] == by["relabel"]["tid"]
+
+
+def test_scripts_accept_the_span_kind(tmp_path):
+    import io
+    import sys
+    sys.path.insert(0, "scripts")
+    import check_bench
+    import events_summary
+    path = str(tmp_path / "ev.jsonl")
+    with telemetry.EventLog(path) as log, telemetry.use(events=log):
+        for _ in range(2):
+            with telemetry.span("state.fetch", bytes=4):
+                pass
+    events = list(check_bench.iter_event_lines(path))
+    assert [ev["kind"] for _w, ev in events] == ["span", "span"]
+    assert check_bench.check_event_lines(path, events) == []
+    bad = [("line 1", dict(events[0][1], t1=events[0][1]["t0"] - 1))]
+    assert check_bench.check_event_lines(path, bad)
+    out = io.StringIO()
+    assert "span" in events_summary.KNOWN
+    assert events_summary.render_run([ev for _w, ev in events],
+                                     out=out) == []
+    assert "program spans: 2 record(s)" in out.getvalue()
+    assert "bytes=8" in out.getvalue()      # counts summed by name
+    assert events_summary.render_run([bad[0][1]], out=io.StringIO())
+
+
+# ---- PR 24: the spans at their sites ---------------------------------
+
+def _children(recs, parent_id):
+    return [r for r in recs if r["parent"] == parent_id]
+
+
+def test_graph_prep_leaves_relabel_and_layout_spans(capsys):
+    from lux_tpu.graph import ShardedGraph, pair_relabel
+    g = small_graph(nv=700, ne=9000)
+    tip = _ring_tip()
+    g2, _perm, starts = pair_relabel(g, 2, pair_threshold=4,
+                                     verbose=True)
+    ShardedGraph.build(g2, 2, starts=starts, pair_threshold=4)
+    recs = _since(tip)
+    names = [r["name"] for r in recs]
+    assert names.count("layout.shard") == 1
+    rel = next(r for r in recs if r["name"] == "relabel")
+    kids = [r["name"] for r in _children(recs, rel["id"])]
+    assert kids == ["relabel.degree_sort", "relabel.pair_histogram",
+                    "relabel.deal", "relabel.rebuild_csc"]
+    # -verbose prints one line per stage, from those records
+    lines = [ln for ln in capsys.readouterr().out.splitlines()
+             if ln.startswith("# pair_relabel/")]
+    assert [ln.split("/")[1].split(":")[0] for ln in lines] \
+        == ["degree_sort", "pair_histogram", "deal", "rebuild_csc"]
+
+
+@pytest.mark.parametrize("engine", ["push", "pull"])
+def test_engine_build_leaves_every_child_once(engine):
+    from lux_tpu.graph import ShardedGraph, pair_relabel
+    g = small_graph(nv=700, ne=9000)
+    g2, _perm, starts = pair_relabel(g, 1, pair_threshold=4)
+    sg = ShardedGraph.build(g2, 1, starts=starts, pair_threshold=4)
+    tip = _ring_tip()
+    if engine == "push":
+        eng = sssp.build_engine(g2, start_vertex=1, num_parts=1, sg=sg,
+                                pair_threshold=4)
+        want = ["build.pair_plan", "build.dense_layout",
+                "build.sparse_view"]
+    else:
+        eng = pagerank.build_engine(g2, 1, None, sg=sg,
+                                    pair_threshold=4)
+        want = ["build.pair_plan", "build.dense_layout"]
+    kids = [r for r in _since(tip) if r["name"].startswith("build.")]
+    assert [r["name"] for r in kids] == want
+    assert all(r["parent"] == 0 for r in kids)
+    plan = kids[0]["counts"]
+    assert plan["pair_edges"] + plan["residual_edges"] == g2.ne
+    assert plan["pair_edges"] == eng.pairs.stats["covered"] > 0
+
+
+def test_state_spans_carry_the_bytes_they_move():
+    g = small_graph()
+    eng = sssp.build_engine(g, start_vertex=1, num_parts=2)
+    tip = _ring_tip()
+    label, active = eng.init_state()
+    nbytes = label.nbytes + active.nbytes
+    label, active = eng.place(np.asarray(label), np.asarray(active))
+    dist = eng.unpad(label)
+    recs = _since(tip)
+    assert [(r["name"], r["counts"]["bytes"]) for r in recs] == [
+        ("state.init", nbytes), ("state.place", nbytes),
+        ("state.fetch", np.asarray(label).nbytes)]
+    assert dist.shape == (g.nv,)
+    peng = pagerank.build_engine(g, 2, None)
+    tip = _ring_tip()
+    state = peng.init_state()
+    peng.unpad(peng.place(np.asarray(state)))
+    assert [(r["name"], r["counts"]["bytes"]) for r in _since(tip)] == [
+        ("state.init", state.nbytes), ("state.place", state.nbytes),
+        ("state.fetch", state.nbytes)]
+
+
+@pytest.mark.parametrize("variant", ["plain", "stats", "health",
+                                     "delta"])
+def test_sparse_iters_counts_the_branch_the_loop_took(variant):
+    """``push.converge``'s ``sparse_iters`` against the count derived
+    from ``converge_stats``' frontier series and the engine's own
+    ``sparse_limit``, on a graph whose search takes BOTH branches; the
+    answers are bitwise the all-dense engine's."""
+    g = small_graph(nv=600, ne=5000, seed=3,
+                    weighted=variant == "delta")
+    kw = dict(start_vertex=1, num_parts=2, weighted=variant == "delta")
+    if variant == "delta":
+        kw["delta"] = 40
+    eng = sssp.build_engine(g, health=variant == "health", **kw)
+    usable, limit = eng._sparse_mode()
+    assert usable
+    _l, _a, it, fsz, *_ = eng.converge_stats(*eng.init_state())
+    it = int(it)
+    series = np.asarray(fsz)[:it].tolist()
+    if variant == "delta":
+        entering = series           # the bucket front entering a relax
+    else:
+        entering = [1] + series[:-1]
+    want = sum(1 for c in entering if c <= limit)
+    assert 0 < want < it, "the search must take both branches"
+    tip = _ring_tip()
+    if variant == "health":
+        label, _a, it2, *_ = eng.converge_health(*eng.init_state())
+    elif variant == "stats":
+        label, _a, it2, *_ = eng.converge_stats(*eng.init_state())
+    else:
+        label, _a, it2 = eng.converge(*eng.init_state())
+    # the mark is there before anything is fetched, scalars un-fetched
+    raw = telemetry._RING[-1]
+    assert raw["name"] == "push.converge"
+    assert isinstance(raw["counts"]["sparse_iters"], jax.Array)
+    marks = [r for r in _since(tip) if r["name"] == "push.converge"]
+    assert len(marks) == 1
+    assert marks[0]["counts"] == {"iters": it, "sparse_iters": want}
+    assert int(it2) == it
+    dense = sssp.build_engine(g, enable_sparse=False, **kw)
+    want_label, _a, _it = dense.converge(*dense.init_state())
+    np.testing.assert_array_equal(np.asarray(label),
+                                  np.asarray(want_label))
+    dmark = telemetry.spans()[-1]
+    assert dmark["counts"]["sparse_iters"] == 0
+
+
+def test_jit_compile_record_on_a_first_call_and_none_on_a_second():
+    from lux_tpu import runtime
+    runtime.watch_compiles()
+    runtime.watch_compiles()            # idempotent: one listener
+
+    @jax.jit
+    def fresh_program(x):
+        return x * 3 + 1
+
+    x = jax.numpy.ones(5)               # its own helper programs
+    tip = _ring_tip()
+    fresh_program(x)
+    first = [r for r in _since(tip) if r["name"].startswith("jit.")]
+    compiles = [r for r in first if r["name"] == "jit.compile"]
+    assert len(compiles) == 1
+    assert "fresh_program" in compiles[0]["counts"]["fun"]
+    assert compiles[0]["t1"] > compiles[0]["t0"]
+    assert {"jit.trace", "jit.lower"} <= {r["name"] for r in first}
+    tip = _ring_tip()
+    fresh_program(x)
+    assert not [r for r in _since(tip) if r["name"].startswith("jit.")]
+
+
+def test_threads_share_the_ring_and_one_log_without_losing_order(
+        tmp_path):
+    """More threads than cores span into one ring and one on-disk
+    EventLog: no record is lost, ids are unique, each thread's nesting
+    holds, and the file's monotonic ``tm`` never runs backwards (the
+    log builds and writes an event under one lock; without it the
+    write, which releases the interpreter lock, reorders lines)."""
+    import os
+    import sys
+    import threading
+    workers, each = 2 * (os.cpu_count() or 4), 150
+    assert workers * each * 2 < telemetry.SPAN_RING
+    tip = _ring_tip()
+    path = str(tmp_path / "stress.jsonl")
+    log = telemetry.EventLog(path)
+    errors = []
+
+    def work(w):
+        try:
+            with telemetry.use(events=log):
+                for i in range(each):
+                    with telemetry.span("stress.outer", w=w, i=i) as o:
+                        with telemetry.span("stress.inner", w=w) as k:
+                            pass
+                        assert k.parent == o.id and o.parent == 0
+        except Exception as e:      # noqa: BLE001 - reported below
+            errors.append(repr(e))
+
+    old = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        threads = [threading.Thread(target=work, args=(w,))
+                   for w in range(workers)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=60)
+    finally:
+        sys.setswitchinterval(old)
+    assert not errors and not any(t.is_alive() for t in threads)
+    recs = [r for r in _since(tip) if r["name"].startswith("stress.")]
+    assert len(recs) == workers * each * 2
+    assert len({r["id"] for r in recs}) == len(recs)
+    outer = {r["id"]: r for r in recs if r["name"] == "stress.outer"}
+    for r in recs:
+        if r["name"] == "stress.inner":
+            assert outer[r["parent"]]["counts"]["w"] == r["counts"]["w"]
+    evs = [e for e in log.events if e["kind"] == "span"]
+    assert len(evs) == len(recs)
+    log.close()
+    with open(path) as f:
+        tms = [json.loads(line)["tm"] for line in f]
+    assert len(tms) == len(recs) and tms == sorted(tms)
