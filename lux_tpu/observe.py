@@ -957,8 +957,9 @@ DEBTS = (
          "+ personalized PageRank) on the chip: the modeled "
          "~9/B per-query amortization (scalemodel.per_query_edge_ns, "
          "BATCH_LANE_NS wide-row lane rate) is CPU-A/B'd only; the "
-         "serve refill path's host column scatter also wants a "
-         "device-side scatter once measured",
+         "pull runner's boundary still moves the whole [nv, B] "
+         "state through the host (the push runner's turns columns "
+         "over on the device, PR 25)",
          "PERF_NOTES round 14 (query batching)"),
     Debt("live-mutation-on-device",
          "bench.py -config serve-live (live-graph serving: mutation "

@@ -52,10 +52,13 @@ PRs 1-8 built:
   reads the snapshots back and scripts/events_summary.py cross-audits
   them against the raw ``query_done`` stream.
 
-Costs and debts: the refill path fetches the [nv, B] state at
-boundaries that retire or fill columns (host scatter + re-place) —
-O(state) per boundary, fine for the CPU mesh and small B; the
-device-side column scatter and the on-device batch sweep are carried
+Costs and debts: a PUSH boundary moves one padded ``[P, vpad]`` column
+per retired query to the host and a few ``[B]`` vectors back — the
+retire (``_take_column``) and the refill (``_start_columns``) are
+device programs of fixed shape, so the ``[nv, B]`` state never
+crosses (PR 25).  The PULL boundary still fetches and re-places the
+whole state (its per-column residual is host arithmetic) — O(state)
+per boundary; moving it, and the on-device batch sweep, are carried
 debts (lux_tpu/observe.py DEBTS "batch-sweep-on-device").
 
 Smoke: ``python -m lux_tpu.serve`` builds a small random graph,
@@ -705,41 +708,22 @@ class _RunnerBase:
               cached=True, **ep, **slo, **self._rep())
         return True
 
-    # -- the host boundary's two transfers (telemetry spans) ------------
+    # -- the segment boundary's spans (lux_tpu/telemetry.py) -----------
     #
     # Every segment boundary is one ``serve.boundary`` span (counts:
-    # retired, filled, occupied, queued, worked 0/1) with children
-    # ``.counts`` (push: the device-to-host fetch of the [B] active
-    # counts), ``.delta`` (live graphs only), ``.fetch``, ``.unpad``,
-    # ``.residual`` (pull: per-column residuals of the fetched state,
-    # host arithmetic), ``.retire``, ``.fill``, ``.pad``, ``.place``.
-    # A push boundary that neither retires nor refills has ``worked``
-    # 0 and only the ``.counts`` child.
-
-    def _fetch_unpad(self, *state) -> list:
-        """Device state arrays -> host ``[nv, B]`` arrays:
-        ``serve.boundary.fetch`` (device_get; ``bytes``) then
-        ``serve.boundary.unpad``."""
-        import jax
-
-        sg = self.eng.sg
-        with telemetry.span("serve.boundary.fetch") as sp:
-            padded = [np.asarray(jax.device_get(x)) for x in state]
-            sp.count(bytes=sum(x.nbytes for x in padded))
-        with telemetry.span("serve.boundary.unpad"):
-            return [sg.from_padded(x) for x in padded]
-
-    def _pad_place(self, *host):
-        """Host ``[nv, B]`` arrays -> device state:
-        ``serve.boundary.pad`` then ``serve.boundary.place``
-        (``bytes``; the transfer is asynchronous, so the span ends at
-        dispatch, not at arrival)."""
-        sg = self.eng.sg
-        with telemetry.span("serve.boundary.pad"):
-            padded = [sg.to_padded(x) for x in host]
-        with telemetry.span("serve.boundary.place",
-                            bytes=sum(x.nbytes for x in padded)):
-            return self.eng.place(*padded)
+    # retired, filled, occupied, queued, worked 0/1).  A push boundary
+    # that neither retires nor refills has ``worked`` 0 and only the
+    # ``.counts`` child (the device-to-host fetch of the [B] active
+    # counts; after ``.delta`` on live graphs).  One that works has
+    # ``.counts``, ``.fetch`` (one padded label column per retired
+    # query: ``bytes``), ``.unpad``, ``.retire``, ``.fill`` (host
+    # bookkeeping only) and ``.place`` (the device reset of the
+    # retired and refilled columns; ``bytes`` = the [B] vectors that
+    # steer it; ends at dispatch).  The pull boundary moves the WHOLE
+    # state: ``.fetch``, ``.unpad``, ``.residual`` (per-column
+    # residuals, host arithmetic), ``.retire``, ``.fill``, and after a
+    # refill ``.pad`` and ``.place`` (with the engine's
+    # ``state.place`` under it).
 
     def _boundary_metrics(self, bsp, worked: bool, retired: int,
                           filled: int, queued: int) -> None:
@@ -766,11 +750,48 @@ class _RunnerBase:
                       kind=self.kind).inc(filled)
 
 
+def _take_column(label, col):
+    """Column ``col`` of the ``[P, vpad, B]`` labels as a contiguous
+    ``[P, vpad]`` array: what one retirement brings to the host.
+    ``col`` is traced, so every column is the same executable."""
+    import jax
+
+    return jax.lax.dynamic_index_in_dim(label, col, axis=2,
+                                        keepdims=False)
+
+
+def _start_columns(label, active, rows, cols, pos, init, inf):
+    """The refill, on the device: every column in the ``[B]`` mask
+    ``cols`` becomes a fresh query whose source sits at padded
+    position ``pos[c]`` (``part * vpad + offset``) with label
+    ``init[c]``, or an idle column (``pos[c]`` = -1: all ``inf``, no
+    frontier).  Column for column this is ``sg.to_padded`` of "label
+    ``inf`` everywhere but the source, frontier = the source alone":
+    ``rows[p]`` is part p's count of real vertices, and the padding
+    rows past it get 0 / False as ``to_padded`` gives them.
+    Elementwise against an iota, no scatter: it shards over parts as
+    it stands, and its shape does not depend on how many columns
+    turn over."""
+    import jax.numpy as jnp
+
+    P, vpad, _B = label.shape
+    row = jnp.arange(vpad, dtype=jnp.int32)[None, :, None]
+    part = jnp.arange(P, dtype=jnp.int32)[:, None, None]
+    source = part * vpad + row == pos
+    fresh = jnp.where(row < rows[:, None, None],
+                      jnp.where(source, init, inf),
+                      jnp.zeros((), label.dtype))
+    return jnp.where(cols, fresh, label), jnp.where(cols, source, active)
+
+
 class PushBatchRunner(_RunnerBase):
     """Continuous-batching runner for push kinds (sssp /
     components): one batched PushEngine, columns retire when their
     per-query frontier empties, refill rides
-    ``converge_segments``'s ``on_segment`` hook."""
+    ``converge_segments``'s ``on_segment`` hook.  The state stays on
+    the device for the whole drain: a retirement fetches its own
+    label column (``_take_column``), columns start and go idle
+    through ``_start_columns`` — the drain's first fill included."""
 
     def __init__(self, kind: str, g, B: int, *, num_parts: int = 1,
                  mesh=None, exchange: str = "auto",
@@ -811,19 +832,69 @@ class PushBatchRunner(_RunnerBase):
             self._dtype = np.int32
         else:
             raise ValueError(f"unknown push kind {kind!r}")
+        import jax
+        import jax.numpy as jnp
+
+        sg = self.eng.sg
+        shape, dtype = (sg.num_parts, sg.vpad, self.B), self._dtype
+        # the state keeps the engine's parts sharding through every
+        # program here, so ``converge`` never sees a second layout
+        sharded = None
+        if mesh is not None:
+            from lux_tpu.parallel.mesh import parts_spec
+            sharded = (parts_spec(mesh),) * 2
+        self._blank = jax.jit(
+            lambda: (jnp.zeros(shape, dtype), jnp.zeros(shape, bool)),
+            out_shardings=sharded)
+        self._take = jax.jit(_take_column)
+        self._reset = jax.jit(_start_columns, donate_argnums=(0, 1),
+                              out_shardings=sharded)
+        self._rows = np.diff(sg.starts).astype(np.int32)
 
     def _col_init(self, req: Request):
-        """(label [nv], active [nv]) for a fresh query column."""
-        nv = self.g.nv
+        """(padded position of the source, its label) for a fresh
+        query column — ``_start_columns``' ``pos`` and ``init``."""
+        sg = self.eng.sg
         s = int(req.source)
-        if not 0 <= s < nv:
+        if not 0 <= s < self.g.nv:
             raise ValueError(f"query {req.qid}: source {s} out of "
-                             f"range [0, {nv})")
-        lab = np.full(nv, self._inf, dtype=self._dtype)
-        act = np.zeros(nv, dtype=bool)
-        lab[s] = s if self.kind == "components" else 0
-        act[s] = True
-        return lab, act
+                             f"range [0, {self.g.nv})")
+        part = int(np.searchsorted(sg.starts, s, side="right")) - 1
+        return (part * sg.vpad + s - int(sg.starts[part]),
+                s if self.kind == "components" else 0)
+
+    def _turnover(self, cols=()):
+        """The ``[B]`` host vectors that steer ``_start_columns``,
+        with the columns ``cols`` marked to go idle; ``_fill`` marks
+        the ones it starts."""
+        mask = np.zeros(self.B, bool)
+        mask[list(cols)] = True
+        return (mask, np.full(self.B, -1, np.int32),
+                np.full(self.B, self._inf, self._dtype))
+
+    def _fetch_columns(self, label, cols) -> list:
+        """The ``[nv]`` answers of the columns ``cols``:
+        ``serve.boundary.fetch`` (one ``_take_column`` dispatch per
+        column, then one device_get of them all; ``bytes``) and
+        ``serve.boundary.unpad``."""
+        import jax
+
+        sg = self.eng.sg
+        with telemetry.span("serve.boundary.fetch") as sp:
+            padded = jax.device_get(
+                [self._take(label, np.int32(c)) for c in cols])
+            sp.count(bytes=sum(x.nbytes for x in padded))
+        with telemetry.span("serve.boundary.unpad"):
+            return [sg.from_padded(x) for x in padded]
+
+    def _place_columns(self, label, active, turnover):
+        """``serve.boundary.place``: one ``_start_columns`` dispatch
+        on the (donated) device state; ``bytes`` is what goes to the
+        device for it."""
+        args = (self._rows, *turnover, self._inf)
+        with telemetry.span("serve.boundary.place",
+                            bytes=sum(a.nbytes for a in args)):
+            return self._reset(label, active, *args)
 
     def drain(self, collector: BatchCollector,
               deadline_s: float = 0.0) -> list[Response]:
@@ -834,19 +905,13 @@ class PushBatchRunner(_RunnerBase):
 
         from lux_tpu.segmented import converge_segments
 
-        eng, sg = self.eng, self.eng.sg
-        nv, B = self.g.nv, self.B
         n0 = len(self.responses)
-
-        lab_h = np.full((nv, B), self._inf, dtype=self._dtype)
-        act_h = np.zeros((nv, B), dtype=bool)
-        filled = self._fill(lab_h, act_h, collector, 0, deadline_s)
-        if not filled:
+        turnover = self._turnover(range(self.B))
+        if not self._fill(turnover, collector, 0, deadline_s):
             # cache hits may have retired queries without taking a
             # column — they are this drain's responses
             return self.responses[n0:]
-        label, active = eng.place(sg.to_padded(lab_h),
-                                  sg.to_padded(act_h))
+        label, active = self._place_columns(*self._blank(), turnover)
 
         def hook(label, active, total, cnt):
             with telemetry.span("serve.boundary") as bsp:
@@ -884,15 +949,17 @@ class PushBatchRunner(_RunnerBase):
                 # hand the updated arrays back to the driver
                 return (label, active) if self.live is not None \
                     else None
-            lab_h, act_h = self._fetch_unpad(label, active)
+            # only the labels of the retiring columns come to the
+            # host: a column leaves with an empty frontier or is cut
+            # at max_segments, and its mask is discarded either way
+            answers = self._fetch_columns(label, done)
             with telemetry.span("serve.boundary.retire"):
-                for c in done:
-                    self._retire(c, lab_h[:, c].copy(), total,
+                for c, answer in zip(done, answers):
+                    self._retire(c, answer, total,
                                  converged=bool(counts[c] == 0))
-                    lab_h[:, c] = self._inf
-                    act_h[:, c] = False
+            turnover = self._turnover(done)
             with telemetry.span("serve.boundary.fill"):
-                n_filled = self._fill(lab_h, act_h, collector, total,
+                n_filled = self._fill(turnover, collector, total,
                                       deadline_s)
             _emit("serve_refill", query_kind=self.kind,
                   retired=len(done),
@@ -900,9 +967,9 @@ class PushBatchRunner(_RunnerBase):
                   queued=len(collector))
             self._boundary_metrics(bsp, True, len(done), n_filled,
                                    len(collector))
-            return self._pad_place(lab_h, act_h)
+            return self._place_columns(label, active, turnover)
 
-        converge_segments(eng, label, active, self.seg_iters,
+        converge_segments(self.eng, label, active, self.seg_iters,
                           on_segment=hook)
         return self.responses[n0:]
 
@@ -938,8 +1005,12 @@ class PushBatchRunner(_RunnerBase):
             return None
         return int(self._col_epoch[col])
 
-    def _fill(self, lab_h, act_h, collector, total_iters,
+    def _fill(self, turnover, collector, total_iters,
               deadline_s) -> int:
+        """Give free columns to queued requests — host bookkeeping
+        only; what each started column must look like goes into
+        ``turnover`` for the boundary's ``_start_columns``."""
+        mask, pos, init = turnover
         free = self._free_cols()
         filled = 0
         first = True
@@ -953,7 +1024,8 @@ class PushBatchRunner(_RunnerBase):
                 if self._serve_cached(req):
                     continue     # answered without a column
                 col = free.pop(0)
-                lab_h[:, col], act_h[:, col] = self._col_init(req)
+                mask[col] = True
+                pos[col], init[col] = self._col_init(req)
                 self._col_epoch[col] = req.epoch or 0
                 self._start(col, req, total_iters)
                 filled += 1
@@ -1025,6 +1097,31 @@ class PullBatchRunner(_RunnerBase):
             + self.deg_corr[:, col]
         return np.where(deg > 0, reset / np.maximum(deg, 1),
                         reset).astype(np.float32)
+
+    def _fetch_unpad(self, *state) -> list:
+        """Device state arrays -> host ``[nv, B]`` arrays:
+        ``serve.boundary.fetch`` (device_get; ``bytes``) then
+        ``serve.boundary.unpad``."""
+        import jax
+
+        sg = self.eng.sg
+        with telemetry.span("serve.boundary.fetch") as sp:
+            padded = [np.asarray(jax.device_get(x)) for x in state]
+            sp.count(bytes=sum(x.nbytes for x in padded))
+        with telemetry.span("serve.boundary.unpad"):
+            return [sg.from_padded(x) for x in padded]
+
+    def _pad_place(self, *host):
+        """Host ``[nv, B]`` arrays -> device state:
+        ``serve.boundary.pad`` then ``serve.boundary.place``
+        (``bytes``; the transfer is asynchronous, so the span ends at
+        dispatch, not at arrival)."""
+        sg = self.eng.sg
+        with telemetry.span("serve.boundary.pad"):
+            padded = [sg.to_padded(x) for x in host]
+        with telemetry.span("serve.boundary.place",
+                            bytes=sum(x.nbytes for x in padded)):
+            return self.eng.place(*padded)
 
     def drain(self, collector: BatchCollector,
               deadline_s: float = 0.0) -> list[Response]:

@@ -139,27 +139,30 @@ class TestBoundarySpans:
         assert sum(b["counts"]["retired"] for b in bounds) == 5
         assert sum(b["counts"]["filled"] for b in bounds) == 3
         sg = srv._runner("sssp").eng.sg
-        padded = (sg.to_padded(np.zeros((NV, 2), np.int32)).nbytes
-                  + sg.to_padded(np.zeros((NV, 2), bool)).nbytes)
+        column = sg.to_padded(np.zeros(NV, np.int32)).nbytes
         pre = "serve.boundary."
         for b in worked:
             kids = [r for r in recs if r["parent"] == b["id"]]
             assert [k["name"][len(pre):] for k in kids] == [
-                "counts", "fetch", "unpad", "retire", "fill", "pad",
-                "place"]
+                "counts", "fetch", "unpad", "retire", "fill", "place"]
             by = {k["name"][len(pre):]: k for k in kids}
-            assert by["fetch"]["counts"]["bytes"] == padded
-            assert by["place"]["counts"]["bytes"] == padded
+            # one padded label column per retired query comes to the
+            # host; a few [B] vectors go back
+            assert by["fetch"]["counts"]["bytes"] \
+                == b["counts"]["retired"] * column
+            assert 0 < by["place"]["counts"]["bytes"] < 64
             assert sum(k["t1"] - k["t0"] for k in kids) \
                 <= b["t1"] - b["t0"]
             assert all(b["t0"] <= k["t0"] <= k["t1"] <= b["t1"]
                        for k in kids)
-            # the engine's own placement span is the leaf under .place
-            assert [r["name"] for r in recs
-                    if r["parent"] == by["place"]["id"]] \
-                == ["state.place"]
+            # the state is reset where it lies: no placement from the
+            # host under .place
+            assert not [r for r in recs
+                        if r["parent"] == by["place"]["id"]]
             assert set(b["counts"]) == {"worked", "retired", "filled",
                                         "occupied", "queued"}
+        assert not [r for r in recs
+                    if r["name"] in ("state.place", pre + "pad")]
         for b in idle:      # neither retired nor refilled
             assert [r["name"] for r in recs if r["parent"] == b["id"]] \
                 == [pre + "counts"]
@@ -187,6 +190,244 @@ class TestBoundarySpans:
                                  if b["counts"]["filled"] else [])
             assert b["counts"]["worked"] == int(bool(
                 b["counts"]["retired"] or b["counts"]["filled"]))
+
+
+def _dense_column(runner, source):
+    """Host statement of a fresh query column, ``[nv]`` label and
+    frontier: the unit everywhere but at the source."""
+    lab = np.full(runner.g.nv, runner._inf, runner._dtype)
+    act = np.zeros(runner.g.nv, bool)
+    lab[source] = source if runner.kind == "components" else 0
+    act[source] = True
+    return lab, act
+
+
+def _schedule(ks, B, seg_iters, max_segments):
+    """``(query, iters, segments, converged)`` in retirement order, as
+    a drain's bookkeeping has to come out when query q's frontier
+    empties after ``ks[q]`` iterations of its own: columns are given
+    lowest first to queries in order, a segment runs ``seg_iters``
+    iterations or until every frontier is empty."""
+    queue, cols, total, out = list(range(len(ks))), [None] * B, 0, []
+
+    def fill():
+        for c in range(B):
+            if cols[c] is None and queue:
+                q = queue.pop(0)
+                cols[c] = {"q": q, "left": ks[q], "t0": total, "seg": 0}
+
+    fill()
+    while any(cols):
+        n = min(seg_iters, max(s["left"] for s in cols if s))
+        total += n
+        for c, s in enumerate(cols):
+            if s is None:
+                continue
+            s["left"] = max(0, s["left"] - n)
+            s["seg"] += 1
+            if not s["left"] or s["seg"] >= max_segments:
+                out.append((s["q"], total - s["t0"], s["seg"],
+                            not s["left"]))
+                cols[c] = None
+        fill()
+    return out
+
+
+def _tailed(weighted: bool):
+    """A random graph on the first 192 vertices and a path 192 -> ...
+    -> 255 -> 0 into it: a search from the path takes as many more
+    iterations as its source lies from the path's end, so the queries
+    of one drain do not all retire in step."""
+    src, dst = uniform_random_edges(192, 1536, seed=5)
+    src = np.concatenate([src, np.arange(192, NV)])
+    dst = np.concatenate([dst, np.arange(193, NV), [0]])
+    w = np.random.default_rng(5).uniform(
+        0.5, 4.0, size=len(src)).astype(np.float32)
+    return Graph.from_edges(src, dst, NV,
+                            weights=w if weighted else None)
+
+
+@pytest.fixture(scope="module")
+def gt():
+    return _tailed(False)
+
+
+@pytest.fixture(scope="module")
+def gtw():
+    return _tailed(True)
+
+
+@pytest.fixture(scope="module")
+def chain():
+    """0 -> 1 -> ... -> 63: a search from s takes 64 - s iterations,
+    so the sources choose which boundary retires how many columns."""
+    n = 64
+    return Graph.from_edges(np.arange(n - 1), np.arange(1, n), n)
+
+
+class TestDeviceBoundary:
+    """PR 25: the push boundary retires and refills columns of the
+    DEVICE state; nothing a caller sees may differ from one
+    single-source engine run per query."""
+
+    SOURCES = (3, 250, 17, 240, 99, 255, 180, 245, 120)
+
+    @staticmethod
+    def _single(app, graph, num_parts, **kw):
+        """One B=1 engine; ``run(runner, source, iters)`` starts it
+        from the host statement of a fresh column and returns (answer
+        after ``iters`` iterations or at convergence, iterations)."""
+        eng = app.build_engine(graph, sources=[0],
+                               num_parts=num_parts, **kw)
+
+        def run(runner, source, iters=None):
+            lab, act = _dense_column(runner, source)
+            label, _act, it = eng.converge(
+                *eng.place(eng.sg.to_padded(lab[:, None]),
+                           eng.sg.to_padded(act[:, None])), iters)
+            return eng.sg.from_padded(np.asarray(label))[:, 0], int(it)
+
+        return run
+
+    @pytest.mark.parametrize("kind,weighted,num_parts,max_segments", [
+        ("sssp", False, 1, None), ("sssp", False, 2, None),
+        ("sssp", True, 1, None), ("sssp", True, 2, None),
+        ("components", False, 1, None), ("components", False, 2, None),
+        ("sssp", False, 2, 8), ("components", False, 1, 8)])
+    def test_drain_is_one_engine_run_per_query(
+            self, gt, gtw, kind, weighted, num_parts, max_segments):
+        """More queries than columns: answers bitwise, retirement
+        order, ``iters`` and ``segments`` are what single-source runs
+        and the schedule give — also where ``max_segments`` cuts a
+        column short and the next query takes it over."""
+        graph = gtw if weighted else gt
+        seg_iters = 1 if max_segments else 2
+        srv = serve.Server(graph, batch=3, num_parts=num_parts,
+                           seg_iters=seg_iters, weighted=weighted)
+        runner = srv._runner(kind)
+        if max_segments:
+            runner.max_segments = max_segments
+        submit_all(srv, [(kind, s) for s in self.SOURCES])
+        responses = srv.run()
+        app = sssp if kind == "sssp" else components
+        kw = {"weighted": True} if weighted else {}
+        single = self._single(app, graph, num_parts, **kw)
+        ks = [single(runner, s)[1] for s in self.SOURCES]
+        want = _schedule(ks, 3, seg_iters,
+                         max_segments or runner.max_segments)
+        assert [(r.qid, r.iters, r.segments, r.converged)
+                for r in responses] == want
+        if max_segments:    # the case has both outcomes in it
+            assert {c for *_q, c in want} == {True, False}
+        for r in responses:
+            answer, _it = single(runner, r.source,
+                                 None if r.converged else r.iters)
+            assert r.answer.dtype == answer.dtype
+            np.testing.assert_array_equal(r.answer, answer)
+
+    def test_live_batch_with_two_admission_epochs(self, gt):
+        """Columns admitted at different epochs turn over on the
+        device like any other; each answer is bitwise a single-source
+        run over the graph AS OF its admission epoch."""
+        from lux_tpu.livegraph import LiveGraph
+        lg = LiveGraph(gt, capacity=32)
+        srv = serve.Server(gt, batch=3, num_parts=2, seg_iters=2,
+                           live=lg)
+        first, later = self.SOURCES[:2], self.SOURCES[2:]
+        submit_all(srv, [("sssp", s) for s in first])
+        rng = np.random.default_rng(61)
+        srv.mutate(rng.integers(0, NV, 10), rng.integers(0, NV, 10))
+        submit_all(srv, [("sssp", s) for s in later])
+        responses = srv.run()
+        assert sorted(r.qid for r in responses) \
+            == list(range(len(self.SOURCES)))
+        assert {r.qid: r.epoch for r in responses} == {
+            q: int(q >= len(first)) for q in range(len(self.SOURCES))}
+        runner = srv._runner("sssp")
+        singles = {e: self._single(sssp, lg.graph_at(e), 2)
+                   for e in (0, 1)}
+        for r in responses:
+            assert r.converged
+            np.testing.assert_array_equal(
+                r.answer, singles[r.epoch](runner, r.source)[0])
+
+    def test_warm_boundary_compiles_nothing(self, chain):
+        """One column, then several, then all turn over at different
+        boundaries of ONE drain: after a warm drain that only retired,
+        no boundary compiles (every program has one shape, whatever
+        the number of columns)."""
+        from lux_tpu import runtime
+        runtime.watch_compiles()
+        n, ks = chain.nv, (1, 3, 3, 6, 2, 3, 3, 3, 1, 1, 2, 2)
+        want = _schedule(ks, 4, 1, 10_000)
+        srv = serve.Server(chain, batch=4, num_parts=2, seg_iters=1)
+        submit_all(srv, [("sssp", n - k) for k in (2, 1, 3, 1)])
+        assert len(srv.run()) == 4              # the warm-up
+        telemetry.mark("test.tip")
+        tip = telemetry.spans()[-1]["id"]
+        submit_all(srv, [("sssp", n - k) for k in ks])
+        responses = srv.run()
+        assert [(r.qid - 4, r.iters, r.segments, r.converged)
+                for r in responses] == want
+        recs = [r for r in telemetry.spans() if r["id"] > tip]
+        turned = [(b["counts"]["retired"], b["counts"]["filled"])
+                  for b in recs if b["name"] == "serve.boundary"
+                  and b["counts"]["worked"]]
+        assert turned[:3] == [(1, 1), (3, 3), (4, 4)]
+        assert not [r for r in recs if r["name"] == "jit.compile"]
+
+    @pytest.mark.parametrize("kind,weighted,num_parts", [
+        ("sssp", False, 1), ("sssp", True, 2), ("components", False, 2)])
+    def test_device_reset_is_the_padded_host_column(
+            self, gt, gtw, kind, weighted, num_parts):
+        """``_start_columns`` leaves, column for column and padding
+        rows included, ``sg.to_padded`` of the host statement of a
+        fresh (or idle) column, and leaves the other columns alone."""
+        graph = gtw if weighted else gt
+        runner = serve.PushBatchRunner(kind, graph, 4,
+                                       num_parts=num_parts,
+                                       weighted=weighted)
+        sg = runner.eng.sg
+
+        def turnover(starting, idle=()):
+            t = runner._turnover(idle)
+            for col, s in starting.items():
+                t[0][col] = True
+                t[1][col], t[2][col] = runner._col_init(
+                    serve.Request(qid=0, kind=kind, source=s))
+            return t
+
+        def host(starting):
+            lab = np.full((NV, 4), runner._inf, runner._dtype)
+            act = np.zeros((NV, 4), bool)
+            for col, s in starting.items():
+                lab[:, col], act[:, col] = _dense_column(runner, s)
+            return sg.to_padded(lab), sg.to_padded(act)
+
+        def both(state):
+            return tuple(np.asarray(x) for x in state)
+
+        first = {0: 0, 2: NV - 1, 3: 131}
+        state = runner._place_columns(
+            *runner._blank(), turnover(first, idle=range(4)))
+        for got, want in zip(both(state), host(first)):
+            assert got.dtype == want.dtype
+            np.testing.assert_array_equal(got, want)
+        # run on, so that the columns hold more than their start
+        label, active, _it = runner.eng.converge(*state, 2)
+        before = both((label, active))
+        state = runner._place_columns(
+            label, active, turnover({1: 77}, idle=[0]))
+        want = host({1: 77})
+        for got, old, new in zip(both(state), before, want):
+            np.testing.assert_array_equal(got[..., :2], new[..., :2])
+            np.testing.assert_array_equal(got[..., 2:], old[..., 2:])
+
+    def test_source_out_of_range_is_refused(self, g):
+        srv = serve.Server(g, batch=2, num_parts=2)
+        srv.submit("sssp", source=NV)
+        with pytest.raises(ValueError, match="out of range"):
+            srv.run()
 
 
 class TestDeterminism:

@@ -205,3 +205,52 @@ def test_push_step_compiles_with_pallas(v5e):
     eng = PushEngine(sg, sssp.make_program(0),
                      reduce_method="pallas")
     assert "tpu_custom_call" in _step_text(eng, v5e)
+
+
+@pytest.mark.parametrize("chips", [1, 4])
+def test_serving_column_programs_compile_at_cell_size(topo, v5e, chips):
+    """The push serving boundary's two device programs (serve.py
+    ``_take_column``, ``_start_columns``) at ``ksssp.kron20.closed``'s
+    state, ``[P, 2**20 / P, 16]`` int32 + bool on one chip and sharded
+    over the four-device parts mesh: the reset rewrites the donated
+    state in place (no second copy of it on a device) and neither
+    program needs a collective."""
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from lux_tpu import serve
+    from lux_tpu.parallel.mesh import PARTS_AXIS
+
+    if chips == 1:
+        parts = small = v5e
+    else:
+        mesh = Mesh(np.asarray(topo.devices), (PARTS_AXIS,))
+        parts = NamedSharding(mesh, P(PARTS_AXIS))
+        small = NamedSharding(mesh, P())
+    shape, B = (chips, (1 << 20) // chips, 16), 16
+
+    def sds(shape, dtype, sharding=small):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+    state = (4 + 1) * (1 << 20) * B // chips        # bytes a device
+    active = sds(shape, jnp.bool_, parts)
+    for dtype in (jnp.int32, jnp.float32):      # hops, weighted sssp
+        label = sds(shape, dtype, parts)
+        take = jax.jit(serve._take_column).lower(
+            label, sds((), jnp.int32)).compile()
+        reset = jax.jit(
+            serve._start_columns, donate_argnums=(0, 1),
+            out_shardings=(parts, parts)).lower(
+                label, active, sds((chips,), jnp.int32),
+                sds((B,), jnp.bool_), sds((B,), jnp.int32),
+                sds((B,), dtype), sds((), dtype)).compile()
+        assert take.memory_analysis().output_size_in_bytes \
+            == 4 * (1 << 20) // chips
+        mem = reset.memory_analysis()
+        assert mem.alias_size_in_bytes == state
+        assert mem.temp_size_in_bytes < state // 16
+        for compiled in (take, reset):
+            text = compiled.as_text()
+            assert not any(op in text for op in (
+                "all-reduce", "all-gather", "all-to-all",
+                "collective-permute"))
