@@ -188,6 +188,18 @@ class MXUUnsupportedError(ValueError):
             f"dtype {self.dtype}: {why}")
 
 
+def _exact_sum_precision(dtype):
+    """The contraction precision of an MXU SUM against an exact 0/1
+    int8 operand.  On the TPU a float32 operand of a default-precision
+    contraction is rounded to bfloat16 (one pass: a relative error of
+    2**-9 per term); HIGHEST keeps the float32 operand (the compiler
+    splits it into bfloat16 parts, each exact against the 0/1
+    operand), so the sum is the float32 one.  Integer payloads are
+    exact as they are."""
+    return (jax.lax.Precision.HIGHEST
+            if np.dtype(dtype).kind == "f" else None)
+
+
 def _lane_onehot(rel_dst, W: int):
     """int8 lane-membership matrix [..., E, W]: row e is one-hot at
     rel_dst[..., e] and ALL-ZERO for pad lanes (rel == -1 matches no
@@ -350,6 +362,7 @@ def chunk_partials(vals, rel_dst, W: int, kind: str, use_mxu: bool = False):
             # [C, E, ...] x [C, E, W] -> [C, W, ...]; pad lanes have
             # all-zero one-hot rows = the sum identity
             return jnp.einsum("ce...,cew->cw...", vals, onehot,
+                              precision=_exact_sum_precision(dt),
                               preferred_element_type=vals.dtype)
         if kind in ("min", "max"):
             return _mxu_compare_reduce(vals, rel_dst, W, kind)
@@ -514,6 +527,7 @@ def _segscan_matmul(partials, chunk_start, block: int | None = None):
         T = ((ii >= jj) &
              (sid[:, None] == sid[None, :])).astype(jnp.int8)
         inner = jnp.einsum("ij,j...->i...", T, p_b,
+                           precision=_exact_sum_precision(p_b.dtype),
                            preferred_element_type=p_b.dtype)
         absorb = sid == 0       # no flag at-or-before: continue carry
         ab = absorb.reshape(absorb.shape + (1,) * len(trail))

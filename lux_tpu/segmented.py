@@ -6,7 +6,7 @@ this module is the single copy of that slicing logic so per-segment
 behaviors (save, finite checks, stall detection, fault injection,
 duration budgeting) compose instead of forking.
 
-Two extensions beyond plain fixed-size slicing:
+Three extensions beyond plain fixed-size slicing:
 
 - ``on_segment`` hooks may RETURN a replacement state to continue
   with (the fault-injection harness corrupts state this way;
@@ -18,6 +18,10 @@ Two extensions beyond plain fixed-size slicing:
   the systematic replacement for the ad-hoc ``seg=2`` / small-``ni``
   routing big-scale runs used against a ~55 s per-execution wall
   seen on the earlier installation (PERF_NOTES round 5).
+- each driver is a GENERATOR of segments (``each_run_segment``,
+  ``each_converge_segment``) that a caller may suspend between two
+  segments; ``run_segments`` / ``converge_segments`` are the same
+  generators run to their end.
 
 Both drivers are telemetry emitters (lux_tpu/telemetry.py): with an
 active handle, every slice emits a ``segment`` event (sizes, fenced
@@ -136,12 +140,29 @@ def _next_n(segment, remaining: int) -> int:
 def run_segments(eng, state, num_iters: int, segment,
                  on_segment: Callable | None = None,
                  start_iter: int = 0, mem=None):
-    """Run a pull engine in slices (``segment``: int size or
-    DurationBudget).  ``on_segment(state, done_iters)`` runs after
-    each slice and may return a replacement state.  ``mem`` is a
-    memwatch.MemoryTrail sampled at every segment boundary (the
-    round-22 occupancy trail — O(1) host work, outside the fused
-    loop by construction).
+    """Run a pull engine in slices to ``num_iters``: every segment of
+    ``each_run_segment`` (same arguments), one after another; returns
+    the final state."""
+    for state in each_run_segment(eng, state, num_iters, segment,
+                                  on_segment, start_iter, mem):
+        pass
+    return state
+
+
+def each_run_segment(eng, state, num_iters: int, segment,
+                     on_segment: Callable | None = None,
+                     start_iter: int = 0, mem=None):
+    """The pull driver, one slice at a time: a generator that runs ONE
+    slice (``segment``: int size or DurationBudget) and its
+    ``on_segment(state, done_iters)`` hook — which may return a
+    replacement state — per ``next()``, and yields the state it goes
+    on with.  Between two ``next()`` calls the driver is suspended
+    with its state on the device and its watchdog, budget and counter
+    history intact, so a caller may run something else in between
+    (lux_tpu/serve.py time-shares one chip among runners this way).
+    ``mem`` is a memwatch.MemoryTrail sampled at every segment
+    boundary (the round-22 occupancy trail — O(1) host work, outside
+    the fused loop by construction).
 
     With telemetry active (lux_tpu/telemetry.py): each slice emits a
     ``segment`` event with its fenced seconds, and with iter-stats the
@@ -206,22 +227,40 @@ def run_segments(eng, state, num_iters: int, segment,
         # this slice, so appending earlier would double-count it
         if st is not None:
             st.extend_pull(res_b, chg_b, n, res_p, chg_p)
-    return state
+        yield state
 
 
 def converge_segments(eng, label, active, segment,
                       max_iters: int | None = None,
                       on_segment: Callable | None = None,
                       start_iter: int = 0, mem=None):
-    """Run a push engine to convergence in slices (``segment``: int
-    size or DurationBudget).
+    """Run a push engine to convergence in slices: every segment of
+    ``each_converge_segment`` (same arguments), one after another.
+    Returns (label, active, total_iters)."""
+    out = label, active, start_iter
+    for out in each_converge_segment(eng, label, active, segment,
+                                     max_iters, on_segment,
+                                     start_iter, mem):
+        pass
+    return out
+
+
+def each_converge_segment(eng, label, active, segment,
+                          max_iters: int | None = None,
+                          on_segment: Callable | None = None,
+                          start_iter: int = 0, mem=None):
+    """The push driver, one slice at a time: a generator that runs ONE
+    slice (``segment``: int size or DurationBudget) and its hook per
+    ``next()``, yields ``(label, active, total_iters)``, and ends when
+    the active mask is empty or ``max_iters`` is reached (suspension
+    as in ``each_run_segment``).
 
     ``on_segment(label, active, total_iters, active_count)`` runs after
     each slice (may raise to abort, or return a replacement
     ``(label, active)``).  Convergence is detected from the active
     mask, never from iteration counts (delta-stepping counts relax
-    steps only).  Returns (label, active, total_iters).  ``mem`` is
-    a memwatch.MemoryTrail sampled at every boundary (round 22).
+    steps only).  ``mem`` is a memwatch.MemoryTrail sampled at every
+    boundary (round 22).
 
     With telemetry active: each slice emits a ``segment`` event, and
     with iter-stats the slice runs ``eng.converge_stats`` — frontier/
@@ -293,6 +332,6 @@ def converge_segments(eng, label, active, segment,
         # this slice, so appending earlier would double-count it
         if st is not None:
             st.extend_push(fsz, fed, it, fszp, fedp)
+        yield label, active, total
         if cnt == 0:
             break
-    return label, active, total

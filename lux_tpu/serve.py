@@ -21,11 +21,23 @@ PRs 1-8 built:
   updates are no-ops) — the retired-column identity rule
   (ARCHITECTURE.md "Query batching & serving").
 - segments run on the EXISTING drivers: push kinds converge through
-  ``segmented.converge_segments`` and pull kinds through
-  ``segmented.run_segments``, with the continuous-batching refill
-  implemented as the drivers' documented ``on_segment`` hook — so
-  duration budgeting, telemetry segment events, iter-stats counters
-  and the health watchdog all compose unchanged.
+  ``segmented.each_converge_segment`` and pull kinds through
+  ``segmented.each_run_segment`` (the generators behind
+  ``converge_segments`` / ``run_segments``), with the
+  continuous-batching refill implemented as the drivers' documented
+  ``on_segment`` hook — so duration budgeting, telemetry segment
+  events, iter-stats counters and the health watchdog all compose
+  unchanged.
+- the **scheduling rule** across kinds (``Server.run``): one TURN — one
+  segment and its boundary — for each kind that has work (a queued
+  query or an occupied column), round-robin, until none has.  A
+  runner is suspended between its turns with its state left on the
+  device (``_RunnerBase.turn``; nothing is fetched or re-placed
+  because another kind ran), so under sustained arrivals of several
+  kinds none waits for another's queue to empty.  With one kind in
+  the ring the turns are that runner's ``drain``: ``python -m
+  lux_tpu.serve``, scripts/loadgen.py and the fleet's replicas all
+  go through the same path.
 - at each segment boundary the hook RETIRES converged columns (push:
   the column's frontier is empty; pull: the column's residual fell
   under ``tol``), scatters their answers into per-query
@@ -142,12 +154,6 @@ class Response:
     converged: bool = True      # False: retired on the segment cap
     epoch: int | None = None    # admission epoch (live graphs)
     cached: bool = False        # served from the epoch-keyed cache
-
-
-class _Drained(Exception):
-    """Raised by the pull hook when the queue is empty and every
-    column is idle — the documented ``on_segment`` abort path of
-    ``segmented.run_segments``."""
 
 
 class BatchCollector:
@@ -540,7 +546,12 @@ def _emit(event: str, **fields):
 
 
 class _RunnerBase:
-    """Shared slot bookkeeping for one batched engine of width B."""
+    """Shared slot bookkeeping for one batched engine of width B, and
+    the unit the chip is shared in: ``turn`` runs ONE segment and its
+    boundary and leaves the runner suspended with its state on the
+    device; ``drain`` is turns until no work is left."""
+
+    family = ""                 # "push" / "pull": the turn span's name
 
     def __init__(self, kind: str, B: int, seg_iters: int,
                  max_segments: int, metrics=None,
@@ -551,6 +562,10 @@ class _RunnerBase:
         self.max_segments = int(max_segments)
         self.slots: list[_Slot | None] = [None] * self.B
         self.responses: list[Response] = []
+        # the suspended segment driver (a generator of
+        # lux_tpu/segmented.py) while any column is occupied; it holds
+        # the device state between turns.  None: idle, nothing resident
+        self._segments = None
         self.metrics = metrics
         self.slo_ms = None if slo_ms is None else float(slo_ms)
         # live-graph serving (round 20, lux_tpu/livegraph.py): the
@@ -577,6 +592,55 @@ class _RunnerBase:
 
     def _rep(self) -> dict:
         return {} if self.replica is None else {"replica": self.replica}
+
+    @property
+    def resident(self) -> bool:
+        """Suspended between two turns with queries in its columns."""
+        return self._segments is not None
+
+    def turn(self, collector: BatchCollector, deadline_s: float = 0.0,
+             switch: bool = False) -> list[Response]:
+        """One turn at the chip: start from ``collector`` if nothing
+        is resident, then ONE segment and its boundary (retire,
+        refill from ``collector``); returns the responses retired in
+        it.  Afterwards the runner is suspended with its state on the
+        device (``resident``), or idle: every column retired and the
+        collector empty.  The span ``serve.turn.<family>`` holds the
+        turn (counts: ``kind``; ``switch`` 1 where the scheduler ran
+        another runner's turn before this one), with the segment's
+        ``serve.boundary`` as its child."""
+        n0 = len(self.responses)
+        with telemetry.span("serve.turn." + self.family,
+                            kind=self.kind, switch=int(switch)):
+            if self._segments is None:
+                self._segments = self._begin(collector, deadline_s)
+            if self._segments is not None:
+                try:
+                    next(self._segments)
+                except StopIteration:
+                    self._segments = None
+                except BaseException:
+                    # a mid-drain death (fleet kill plans, a tripped
+                    # watchdog): the columns' state is gone with it
+                    self._segments = None
+                    raise
+                else:
+                    # every column idle after a boundary that found
+                    # the collector empty: nothing is resident
+                    if not self._occupied():
+                        self._segments = None
+        return self.responses[n0:]
+
+    def drain(self, collector: BatchCollector,
+              deadline_s: float = 0.0) -> list[Response]:
+        """Serve until the collector is empty and every column is
+        idle — turns until no work is left; returns the responses
+        retired during this drain."""
+        n0 = len(self.responses)
+        self.turn(collector, deadline_s)
+        while self.resident:
+            self.turn(collector, deadline_s)
+        return self.responses[n0:]
 
     def _free_cols(self):
         return [c for c, s in enumerate(self.slots) if s is None]
@@ -711,7 +775,9 @@ class _RunnerBase:
     # -- the segment boundary's spans (lux_tpu/telemetry.py) -----------
     #
     # Every segment boundary is one ``serve.boundary`` span (counts:
-    # retired, filled, occupied, queued, worked 0/1).  A push boundary
+    # retired, filled, occupied, queued, worked 0/1, family "push" /
+    # "pull": what tells the two boundaries below apart), the child
+    # of its turn's ``serve.turn.<family>``.  A push boundary
     # that neither retires nor refills has ``worked`` 0 and only the
     # ``.counts`` child (the device-to-host fetch of the [B] active
     # counts; after ``.delta`` on live graphs).  One that works has
@@ -722,8 +788,8 @@ class _RunnerBase:
     # steer it; ends at dispatch).  The pull boundary moves the WHOLE
     # state: ``.fetch``, ``.unpad``, ``.residual`` (per-column
     # residuals, host arithmetic), ``.retire``, ``.fill``, and after a
-    # refill ``.pad`` and ``.place`` (with the engine's
-    # ``state.place`` under it).
+    # refill ``.pad`` and ``.place`` (state and reset tables; with
+    # the engine's ``state.place`` under it).
 
     def _boundary_metrics(self, bsp, worked: bool, retired: int,
                           filled: int, queued: int) -> None:
@@ -732,7 +798,8 @@ class _RunnerBase:
         ``serve.boundary`` span's counts, then batch occupancy,
         segment count, retire/refill rates in the metrics registry."""
         bsp.count(worked=int(worked), retired=retired, filled=filled,
-                  occupied=len(self._occupied()), queued=queued)
+                  occupied=len(self._occupied()), queued=queued,
+                  family=self.family)
         if self.metrics is None:
             return
         m = self.metrics
@@ -787,11 +854,14 @@ def _start_columns(label, active, rows, cols, pos, init, inf):
 class PushBatchRunner(_RunnerBase):
     """Continuous-batching runner for push kinds (sssp /
     components): one batched PushEngine, columns retire when their
-    per-query frontier empties, refill rides
-    ``converge_segments``'s ``on_segment`` hook.  The state stays on
-    the device for the whole drain: a retirement fetches its own
-    label column (``_take_column``), columns start and go idle
-    through ``_start_columns`` — the drain's first fill included."""
+    per-query frontier empties, refill rides the segment driver's
+    ``on_segment`` hook.  The state stays on the device while any
+    column is occupied, other runners' turns included: a retirement
+    fetches its own label column (``_take_column``), columns start
+    and go idle through ``_start_columns`` — the first fill
+    included."""
+
+    family = "push"
 
     def __init__(self, kind: str, g, B: int, *, num_parts: int = 1,
                  mesh=None, exchange: str = "auto",
@@ -896,21 +966,20 @@ class PushBatchRunner(_RunnerBase):
                             bytes=sum(a.nbytes for a in args)):
             return self._reset(label, active, *args)
 
-    def drain(self, collector: BatchCollector,
-              deadline_s: float = 0.0) -> list[Response]:
-        """Serve until the collector is empty and every column is
-        idle; returns the responses retired during this drain."""
+    def _begin(self, collector: BatchCollector, deadline_s: float):
+        """Fill the blank columns from ``collector`` and return the
+        segment driver that serves them, suspended before its first
+        segment (None where no query took a column)."""
         import jax
         import jax.numpy as jnp
 
-        from lux_tpu.segmented import converge_segments
+        from lux_tpu.segmented import each_converge_segment
 
-        n0 = len(self.responses)
         turnover = self._turnover(range(self.B))
         if not self._fill(turnover, collector, 0, deadline_s):
             # cache hits may have retired queries without taking a
-            # column — they are this drain's responses
-            return self.responses[n0:]
+            # column — they are this turn's responses
+            return None
         label, active = self._place_columns(*self._blank(), turnover)
 
         def hook(label, active, total, cnt):
@@ -969,9 +1038,8 @@ class PushBatchRunner(_RunnerBase):
                                    len(collector))
             return self._place_columns(label, active, turnover)
 
-        converge_segments(self.eng, label, active, self.seg_iters,
-                          on_segment=hook)
-        return self.responses[n0:]
+        return each_converge_segment(self.eng, label, active,
+                                     self.seg_iters, on_segment=hook)
 
     def _apply_delta(self, label, active):
         """One live delta-relax application (livegraph.delta_step —
@@ -1039,6 +1107,8 @@ class PullBatchRunner(_RunnerBase):
     at the boundary) falls under ``tol``; refill swaps the column's
     reset vector in place (``PullEngine.update_program_arrays``)."""
 
+    family = "pull"
+
     def __init__(self, kind: str, g, B: int, *, num_parts: int = 1,
                  mesh=None, exchange: str = "auto",
                  health: bool = False,
@@ -1098,50 +1168,55 @@ class PullBatchRunner(_RunnerBase):
         return np.where(deg > 0, reset / np.maximum(deg, 1),
                         reset).astype(np.float32)
 
-    def _fetch_unpad(self, *state) -> list:
-        """Device state arrays -> host ``[nv, B]`` arrays:
+    def _fetch_unpad(self, state) -> np.ndarray:
+        """Device state -> host ``[nv, B]`` array:
         ``serve.boundary.fetch`` (device_get; ``bytes``) then
         ``serve.boundary.unpad``."""
         import jax
 
-        sg = self.eng.sg
         with telemetry.span("serve.boundary.fetch") as sp:
-            padded = [np.asarray(jax.device_get(x)) for x in state]
-            sp.count(bytes=sum(x.nbytes for x in padded))
+            padded = np.asarray(jax.device_get(state))
+            sp.count(bytes=padded.nbytes)
         with telemetry.span("serve.boundary.unpad"):
-            return [sg.from_padded(x) for x in padded]
+            return self.eng.sg.from_padded(padded)
 
-    def _pad_place(self, *host):
-        """Host ``[nv, B]`` arrays -> device state:
+    def _pad_place(self, state_h, resets: bool):
+        """Host ``[nv, B]`` state -> device state, and with ``resets``
+        (a refill) the columns' reset tables too:
         ``serve.boundary.pad`` then ``serve.boundary.place``
-        (``bytes``; the transfer is asynchronous, so the span ends at
-        dispatch, not at arrival)."""
+        (``bytes``; the transfers are asynchronous, so the span ends
+        at dispatch, not at arrival)."""
         sg = self.eng.sg
         with telemetry.span("serve.boundary.pad"):
-            padded = [sg.to_padded(x) for x in host]
-        with telemetry.span("serve.boundary.place",
-                            bytes=sum(x.nbytes for x in padded)):
-            return self.eng.place(*padded)
+            padded = sg.to_padded(state_h)
+            tables = self._padded_resets() if resets else {}
+        with telemetry.span(
+                "serve.boundary.place",
+                bytes=padded.nbytes + sum(t.nbytes
+                                          for t in tables.values())):
+            self.eng.update_program_arrays(**tables)
+            return self.eng.place(padded)
 
-    def drain(self, collector: BatchCollector,
-              deadline_s: float = 0.0) -> list[Response]:
-        import jax
-
-        from lux_tpu.segmented import run_segments
+    def _begin(self, collector: BatchCollector, deadline_s: float):
+        """As ``PushBatchRunner._begin``."""
+        from lux_tpu.segmented import each_run_segment
+        from lux_tpu.timing import fence
 
         eng, sg = self.eng, self.eng.sg
-        B = self.B
-        n0 = len(self.responses)
 
         state_h = sg.from_padded(np.asarray(
             self.eng.program.init(sg)))          # [nv, B]
         if not self._fill(state_h, collector, 0, deadline_s):
-            return self.responses[n0:]   # cache hits take no column
-        self._push_resets()
+            return None                  # cache hits take no column
+        eng.update_program_arrays(**self._padded_resets())
         prev = state_h.copy()
         state = eng.place(sg.to_padded(state_h))
 
         def hook(state, done_iters):
+            # the pull driver dispatches a segment and waits for it
+            # only where it times one: wait here, so that the
+            # boundary's spans hold the boundary and not the segment
+            fence(state)
             with telemetry.span("serve.boundary") as bsp:
                 return boundary(bsp, state, done_iters)
 
@@ -1154,7 +1229,7 @@ class PullBatchRunner(_RunnerBase):
             for s in self.slots:
                 if s is not None:
                     s.segments += 1
-            new, = self._fetch_unpad(state)
+            new = self._fetch_unpad(state)
             corrected = False
             if self.live is not None:
                 # the host half of the live pull iteration: add the
@@ -1186,24 +1261,16 @@ class PullBatchRunner(_RunnerBase):
                       queued=len(collector))
             self._boundary_metrics(bsp, bool(done or n_filled),
                                    len(done), n_filled, len(collector))
-            if not self._occupied() and not len(collector):
-                raise _Drained()
             prev = new
-            if n_filled:
-                self._push_resets()
             if n_filled or corrected:
                 # a refill, or the host correction, changed the state
                 # the next iteration must start from — hand it back
                 # (the correction also when no column turned over)
-                return self._pad_place(new)
+                return self._pad_place(new, resets=bool(n_filled))
             return None
 
-        try:
-            run_segments(eng, state, np.iinfo(np.int32).max,
-                         self.seg_iters, on_segment=hook)
-        except _Drained:
-            pass
-        return self.responses[n0:]
+        return each_run_segment(eng, state, np.iinfo(np.int32).max,
+                                self.seg_iters, on_segment=hook)
 
     def _correct(self, prev, new):
         """Host half of the live pull iteration (round 21): the
@@ -1233,11 +1300,13 @@ class PullBatchRunner(_RunnerBase):
             return None
         return int(self._col_epoch[col])
 
-    def _push_resets(self):
+    def _padded_resets(self) -> dict:
+        """The program arrays a refill swaps
+        (``PullEngine.update_program_arrays``)."""
         kw = {"reset": self.eng.sg.to_padded(self.resets)}
         if self.live is not None:
             kw["deg_corr"] = self.eng.sg.to_padded(self.deg_corr)
-        self.eng.update_program_arrays(**kw)
+        return kw
 
     def _fill(self, state_h, collector, total_iters,
               deadline_s) -> int:
@@ -1354,6 +1423,7 @@ class Server:
         self._last_snapshot = 0.0
         self._collectors: dict[str, BatchCollector] = {}
         self._runners: dict[str, _RunnerBase] = {}
+        self._last_turn: _RunnerBase | None = None   # run()'s ring
         self._next_qid = 0
 
     def _collector(self, kind: str) -> BatchCollector:
@@ -1500,11 +1570,17 @@ class Server:
                     f"drain first")
         self.g = self.live.base
         self._runners.clear()
+        self._last_turn = None      # or it keeps an old engine alive
 
     def run(self) -> list[Response]:
         """Drain every kind's queue; returns responses in retirement
         order (continuous batching: later queries refill columns
-        freed by earlier retirements).  Publishes a periodic
+        freed by earlier retirements).  The kinds share the chip by
+        turns — one segment and its boundary each, round-robin over
+        the kinds that have work — so under sustained arrivals of
+        several kinds none waits for another's queue to empty; every
+        runner's state stays on the device between its turns.
+        Publishes a periodic
         ``metrics_snapshot`` event (at most one per
         ``snapshot_every_s`` of non-empty drains — the cadence a
         long-lived serving loop rides; ``emit_metrics_snapshot()``
@@ -1525,11 +1601,26 @@ class Server:
             self.cache.sweep({k: self._admission_epoch(k)
                               for k in KINDS})
         out: list[Response] = []
-        # list(): submit() may add a NEW kind's collector from a
-        # submitter thread while an open-loop drain iterates
-        for kind, coll in list(self._collectors.items()):
-            while len(coll):
-                out += self._runner(kind).drain(coll, self.deadline_s)
+        # the scheduling rule: one turn (a segment and its boundary)
+        # for each kind that has work — a queued query or an occupied
+        # column — in the ring's order, round and round until none
+        # has.  With one kind in the ring this is that runner's drain.
+        served = True
+        while served:
+            served = False
+            # list(): submit() may add a NEW kind's collector from a
+            # submitter thread while an open-loop drain iterates
+            for kind, coll in list(self._collectors.items()):
+                runner = self._runners.get(kind)
+                if not (len(coll) or (runner is not None
+                                      and runner.resident)):
+                    continue
+                runner = self._runner(kind)
+                out += runner.turn(
+                    coll, self.deadline_s,
+                    switch=self._last_turn not in (None, runner))
+                self._last_turn = runner
+                served = True
         if self.live is not None:
             # one release per retired response: the admit() taken at
             # submit ends exactly when the answer leaves the server

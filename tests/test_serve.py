@@ -15,7 +15,9 @@ Round 17 (serving observability) acceptance bars:
   bench.py serve-slo line is accepted by scripts/check_bench.py.
 """
 
+import contextlib
 import json
+import signal
 import subprocess
 import sys
 import time
@@ -120,8 +122,7 @@ class TestBoundarySpans:
 
     @staticmethod
     def _drain(g, kind, sources, **kw):
-        telemetry.mark("test.tip")
-        tip = telemetry.spans()[-1]["id"]
+        tip = _tip()
         srv = serve.Server(g, batch=2, num_parts=2, seg_iters=2, **kw)
         submit_all(srv, [(kind, s) for s in sources])
         responses = srv.run()
@@ -160,7 +161,9 @@ class TestBoundarySpans:
             assert not [r for r in recs
                         if r["parent"] == by["place"]["id"]]
             assert set(b["counts"]) == {"worked", "retired", "filled",
-                                        "occupied", "queued"}
+                                        "occupied", "queued",
+                                        "family"}
+            assert b["counts"]["family"] == "push"
         assert not [r for r in recs
                     if r["name"] in ("state.place", pre + "pad")]
         for b in idle:      # neither retired nor refilled
@@ -428,6 +431,194 @@ class TestDeviceBoundary:
         srv.submit("sssp", source=NV)
         with pytest.raises(ValueError, match="out of range"):
             srv.run()
+
+
+@contextlib.contextmanager
+def _limit(seconds: float):
+    """A time limit of the test's own: a starved kind fails the test
+    instead of hanging it."""
+    def late(signum, frame):
+        raise TimeoutError(f"not done after {seconds} s")
+    old = signal.signal(signal.SIGALRM, late)
+    signal.setitimer(signal.ITIMER_REAL, seconds)
+    try:
+        yield
+    finally:
+        signal.setitimer(signal.ITIMER_REAL, 0)
+        signal.signal(signal.SIGALRM, old)
+
+
+def _turns_since(tip):
+    """``(family, kind, switch)`` of the ``serve.turn.*`` records
+    after record ``tip``, in order."""
+    return [(r["name"].rsplit(".", 1)[1], r["counts"]["kind"],
+             r["counts"]["switch"])
+            for r in telemetry.spans()
+            if r["id"] > tip and r["name"].startswith("serve.turn.")]
+
+
+def _tip():
+    telemetry.mark("test.tip")
+    return telemetry.spans()[-1]["id"]
+
+
+# what the commit before the turns (dc58603) emitted for the two
+# single-kind drains of ``test_one_kind_is_the_parents_sequence``
+PARENT_TRAIL = {
+    "sssp": [
+        ("segment", "push", 2, 2, 115), ("segment", "push", 2, 4, 31),
+        ("segment", "push", 2, 6, 1),
+        ("serve_refill", "sssp", 2, 2, 3, 4),
+        ("segment", "push", 2, 8, 134), ("segment", "push", 2, 10, 22),
+        ("segment", "push", 2, 12, 1),
+        ("serve_refill", "sssp", 2, 2, 3, 2),
+        ("segment", "push", 2, 14, 66), ("segment", "push", 2, 16, 110),
+        ("segment", "push", 2, 18, 1),
+        ("serve_refill", "sssp", 2, 2, 3, 0),
+        ("segment", "push", 2, 20, 30), ("segment", "push", 2, 22, 45),
+        ("segment", "push", 2, 24, 83),
+        ("serve_refill", "sssp", 1, 0, 2, 0),
+        ("segment", "push", 2, 26, 4), ("segment", "push", 2, 28, 1),
+        ("serve_refill", "sssp", 1, 0, 1, 0),
+        ("segment", "push", 2, 30, 12), ("segment", "push", 2, 32, 94),
+        ("segment", "push", 2, 34, 0),
+        ("serve_refill", "sssp", 1, 0, 0, 0)],
+    "pagerank": [
+        ("segment", "pull", 2, 2), ("segment", "pull", 2, 4),
+        ("segment", "pull", 2, 6), ("segment", "pull", 2, 8),
+        ("serve_refill", "pagerank", 2, 1, 1, 0),
+        ("segment", "pull", 2, 10), ("segment", "pull", 2, 12),
+        ("segment", "pull", 2, 14), ("segment", "pull", 2, 16),
+        ("serve_refill", "pagerank", 1, 0, 0, 0)],
+}
+
+
+class TestTurns:
+    """PR 26: the kinds share the chip by turns (one segment and its
+    boundary each, round-robin over the kinds that have work); a
+    runner is suspended between its turns with its state on the
+    device."""
+
+    MIX = ([("sssp", s) for s in (3, 250, 17, 240, 99)]
+           + [("components", s) for s in (255, 180, 7, 120)]
+           + [("pagerank", s) for s in (3, 17, 40)])
+
+    def test_no_kind_waits_for_a_fed_queue_to_empty(self, gt):
+        """A feeder keeps the sssp queue non-empty (a new query at
+        every retirement, 40 in all): the components and the pagerank
+        query still get the chip every third turn and retire within
+        the turns their own segments need."""
+        srv = serve.Server(gt, batch=2, num_parts=1, seg_iters=2)
+        fed = []
+
+        def feeder(ev):
+            if ev.get("kind") == "query_done" \
+                    and ev.get("query_kind") == "sssp" and len(fed) < 40:
+                fed.append(srv.submit("sssp", source=3 + len(fed)))
+
+        for s in (3, 250, 17):
+            srv.submit("sssp", source=s)
+        others = {srv.submit("components", source=240): "components",
+                  srv.submit("pagerank", source=99): "pagerank"}
+        tip = _tip()
+        telemetry.add_observer(feeder)
+        try:
+            with _limit(120):
+                responses = srv.run()
+        finally:
+            telemetry.remove_observer(feeder)
+        assert len(fed) == 40 and len(responses) == 45
+        turns = _turns_since(tip)
+        kinds = [k for _f, k, _s in turns]
+        for kind in others.values():
+            at = [i for i, k in enumerate(kinds) if k == kind]
+            r = next(r for r in responses if r.kind == kind)
+            # a turn a segment, and never more than two turns of the
+            # other kinds between two of its own
+            assert len(at) == r.segments
+            assert at[0] <= 2 and max(np.diff(at), default=0) <= 3
+            assert at[-1] <= 3 * r.segments - 1
+        # while all three had work every turn was another runner's
+        busy = kinds[:3 * min(
+            r.segments for r in responses if r.qid in others)]
+        assert busy == ["sssp", "components", "pagerank"] * (
+            len(busy) // 3)
+        assert all(s == 1 for _f, _k, s in turns[1:len(busy)])
+        assert serve._check_answers(gt, responses) == 0
+
+    def test_three_kinds_answer_as_each_kind_alone(self, gt):
+        """Per query the mixed drain gives the single-kind drain's
+        answer: bitwise for sssp and components, and for pagerank the
+        oracle's at the reported iterations."""
+        with _limit(120):
+            mixed = run_specs(gt, self.MIX, batch=2)
+            alone = [r for kind in serve.KINDS for r in run_specs(
+                gt, [x for x in self.MIX if x[0] == kind], batch=2)]
+        assert len(mixed) == len(alone) == len(self.MIX)
+        want = {(r.kind, r.source): r for r in alone}
+        for r in mixed:
+            w = want[r.kind, r.source]
+            assert (r.iters, r.segments, r.converged) == (
+                w.iters, w.segments, w.converged)
+            if r.kind == "pagerank":
+                ref = pagerank.reference_pagerank_batched(
+                    gt, pagerank.one_hot_resets(gt.nv, [r.source]),
+                    r.iters)[:, 0]
+                np.testing.assert_allclose(r.answer, ref, atol=5e-5)
+            else:
+                assert r.answer.dtype == w.answer.dtype
+                np.testing.assert_array_equal(r.answer, w.answer)
+
+    @pytest.mark.parametrize("kind", serve.KINDS)
+    def test_suspended_and_resumed_equals_the_drain(self, gt, kind):
+        """Turns of one runner with another runner's whole drain
+        between each two equal its uninterrupted drain: the state
+        waits on the device."""
+        sources = (3, 250, 17, 240, 99)
+        other = "pagerank" if kind != "pagerank" else "sssp"
+        with _limit(120):
+            want = run_specs(gt, [(kind, s) for s in sources], batch=2)
+            srv = serve.Server(gt, batch=2, num_parts=2, seg_iters=2)
+            submit_all(srv, [(kind, s) for s in sources])
+            runner, coll = srv._runner(kind), srv._collector(kind)
+            got = list(runner.turn(coll))
+            while runner.resident:
+                srv.submit(other, source=7)
+                srv._runner(other).drain(srv._collector(other))
+                got += runner.turn(coll)
+        assert [(r.source, r.iters, r.segments) for r in got] == \
+            [(r.source, r.iters, r.segments) for r in want]
+        for r, w in zip(got, want):
+            np.testing.assert_array_equal(r.answer, w.answer)
+
+    @pytest.mark.parametrize("kind,graph,sources,batch,kw", [
+        ("sssp", "gt", TestDeviceBoundary.SOURCES, 3, {}),
+        ("pagerank", "g", (3, 17, 40), 2, {"tol": 1e-9})])
+    def test_one_kind_is_the_parents_sequence(self, request, kind,
+                                              graph, sources, batch, kw):
+        """With one kind in the ring the ``segment`` and
+        ``serve_refill`` events are, field for field, what the drain
+        loop before the turns emitted; no turn is a switch."""
+        fields = {"segment": ("engine", "iters", "total", "active",
+                              "n", "done"),
+                  "serve_refill": ("query_kind", "retired", "filled",
+                                   "occupied", "queued")}
+        ev = telemetry.EventLog()
+        tip = _tip()
+        with _limit(120), telemetry.use(events=ev):
+            srv = serve.Server(request.getfixturevalue(graph),
+                               batch=batch, num_parts=2, seg_iters=2,
+                               **kw)
+            submit_all(srv, [(kind, s) for s in sources])
+            srv.run()
+        trail = [(e["kind"],) + tuple(e[k] for k in fields[e["kind"]]
+                                      if k in e)
+                 for e in ev.events if e["kind"] in fields]
+        assert trail == PARENT_TRAIL[kind]
+        turns = _turns_since(tip)
+        assert len(turns) == sum(t[0] == "segment" for t in trail)
+        assert {t for t in turns} == {
+            (serve._engine_family(kind), kind, 0)}
 
 
 class TestDeterminism:

@@ -254,3 +254,26 @@ def test_serving_column_programs_compile_at_cell_size(topo, v5e, chips):
             assert not any(op in text for op in (
                 "all-reduce", "all-gather", "all-to-all",
                 "collective-permute"))
+
+
+@pytest.mark.parametrize("dtype,exact", [(jnp.float32, True),
+                                         (jnp.int32, False)])
+def test_mxu_sum_of_floats_is_not_rounded_to_bfloat16(v5e, dtype, exact):
+    """The MXU one-hot sum at the serving cell's payload (16 query
+    lanes) and the matmul scan that joins its chunks: a float32
+    payload asks the chip's compiler for HIGHEST (three exact
+    bfloat16 parts against the 0/1 operand), where the default would
+    round it to bfloat16 — what `mixed.kron20.closed` first read from
+    personalized PageRank on the chip (PERF.md, PR 26); an integer
+    payload is exact at the default."""
+    from lux_tpu.ops.tiled import _segscan_matmul, chunk_partials
+    C, E, W, B = 256, 512, 128, 16
+    vals = jax.ShapeDtypeStruct((C, E, B), dtype, sharding=v5e)
+    rel = jax.ShapeDtypeStruct((C, E), jnp.int32, sharding=v5e)
+    parts = jax.ShapeDtypeStruct((C, W, B), dtype, sharding=v5e)
+    start = jax.ShapeDtypeStruct((C,), jnp.bool_, sharding=v5e)
+    for text in (
+            _compiled_text(jax.jit(lambda v, r: chunk_partials(
+                v, r, W, "sum", use_mxu=True)), vals, rel),
+            _compiled_text(jax.jit(_segscan_matmul), parts, start)):
+        assert ("operand_precision={highest,highest}" in text) == exact
