@@ -72,7 +72,9 @@ def make_batched_program(resets, dtype=jnp.float32) -> PullProgram:
     ARGUMENT the engine ships like any graph array (``ctx.extra
     ['reset']``), so the no-closure convention holds and the serving
     front-end can swap retired columns' resets in place
-    (PullEngine.update_program_arrays).  ONE state-table gather per
+    (PullEngine.update_program_arrays; the serving tier rewrites the
+    column in the table where it lies on the device and hands the
+    table back).  ONE state-table gather per
     dense iteration serves all B queries (audit gather-budget);
     ``state_bytes = 4B`` keeps the auto-exchange and ledger
     estimates honest at B > 1.
@@ -83,8 +85,8 @@ def make_batched_program(resets, dtype=jnp.float32) -> PullProgram:
     tier sets column q to the delta-append out-degree at q's
     admission epoch, so the engine normalizes by the EFFECTIVE
     degree of ``graph_at(epoch_q)`` while iterating the base edges;
-    the host-side correction step adds the delta edges' rank mass at
-    each boundary (serve.PullBatchRunner — together one exact PPR
+    the correction step, a device program, adds the delta edges' rank
+    mass at each boundary (serve._delta_mass — together one exact PPR
     iteration over the epoch's graph, which is how pull admissions
     advance with published epochs without waiting for a fold)."""
     resets = np.asarray(resets, dtype=np.dtype(dtype))

@@ -523,17 +523,20 @@ class PullEngine(AuditableEngine):
                 leaves = [jnp.asarray(x) for x in leaves]
         return jax.tree.unflatten(treedef, leaves)
 
-    def update_program_arrays(self, **host_arrays):
+    def update_program_arrays(self, **arrays):
         """Swap program-contributed per-part arrays
         (``PullProgram.extra_arrays``; key ``<name>`` here maps to
-        graph-array key ``prog_<name>``) with SAME-shape/dtype host
+        graph-array key ``prog_<name>``) with SAME-shape/dtype
         replacements — no recompile: every compiled variant reads
         ``self.graph_args`` at call time, so the next step/run sees
-        the new arrays.  This is the serving front-end's
+        the new arrays.  A host array is placed as the engine places
+        its graph arrays; a device array (``jax.Array``) that already
+        has the current one's sharding is taken as it is, with no
+        transfer.  This is the serving front-end's
         continuous-batching refill path (lux_tpu/serve.py): a retired
-        query column's reset vector is replaced without rebuilding
-        the engine."""
-        for k, v in host_arrays.items():
+        query column's reset vector is replaced in the table where it
+        lies on the device, and the updated table handed back here."""
+        for k, v in arrays.items():
             key = f"prog_{k}"
             if key not in self.arrays:
                 raise KeyError(
@@ -541,7 +544,8 @@ class PullEngine(AuditableEngine):
                     f"(program.extra_arrays supplies "
                     f"{[x[5:] for x in self.arrays if x.startswith('prog_')]})")
             cur = self.arrays[key]
-            arr = np.asarray(v)
+            placed = isinstance(v, jax.Array)
+            arr = v if placed else np.asarray(v)
             if (arr.shape != tuple(cur.shape)
                     or np.dtype(arr.dtype) != np.dtype(cur.dtype)):
                 raise ValueError(
@@ -549,7 +553,14 @@ class PullEngine(AuditableEngine):
                     f"{tuple(cur.shape)}/{np.dtype(cur.dtype)} "
                     f"(got {arr.shape}/{arr.dtype}) — shapes are "
                     f"compiled; rebuild the engine to change B")
-            if self.mesh is not None:
+            if placed:
+                if not arr.sharding.is_equivalent_to(cur.sharding,
+                                                     arr.ndim):
+                    raise ValueError(
+                        f"program array {k!r} arrives on the device "
+                        f"with sharding {arr.sharding}, the engine "
+                        f"holds it as {cur.sharding}")
+            elif self.mesh is not None:
                 arr = shard_over_parts(self.mesh, [arr],
                                        self.sg.num_parts)[0]
             else:

@@ -97,8 +97,9 @@ Epoch visibility per engine family: the PUSH kinds (sssp /
 components) see base + published delta at the latest epoch — their
 monotone min/max programs absorb delta APPENDS exactly through the
 delta-relax step.  The PULL kinds (pagerank) absorb appends through
-the host-side base-generation + degree-correction step (serve.py
-PullBatchRunner, round 21), so both families' admissions advance
+the base-generation + degree-correction step (serve.py
+PullBatchRunner, round 21; device programs since PR 27), so both
+families' admissions advance
 with published epochs WITHOUT waiting for a fold.  The one cap is
 anti-monotone: while a deletion/reweight is pending (not yet folded),
 ``view_epoch`` holds BOTH families at (earliest pending anti epoch -
@@ -900,7 +901,7 @@ class LiveGraph:
         """The epoch a newly admitted query of this engine family
         pins.  Both families now advance with published epochs — push
         kinds absorb appends through the delta-relax step, pull kinds
-        through the host-side degree/delta correction (serve.py
+        through the degree/delta correction (serve.py
         PullBatchRunner, round 21) — EXCEPT past a pending
         anti-monotone op: a deletion/reweight cannot be expressed by
         either mechanism, so admission is capped at (earliest pending
@@ -1006,8 +1007,10 @@ class LiveGraph:
     def append_deltas(self):
         """Host view of the published APPEND slots — (src i64, dst
         i64, w f32, epoch i32) with tombstone/overwrite slots
-        filtered out.  The pull runners' host-side correction surface
-        (serve.PullBatchRunner, round 21): published slots are
+        filtered out.  The pull runner counts a fresh column's
+        effective out-degrees from it (serve.PullBatchRunner; the
+        correction itself reads ``delta_arrays`` on the device):
+        published slots are
         immutable and ``count`` is advanced after the slot's epoch
         lands, so a lock-free snapshot here is consistent by the same
         construction the device delta arrays rely on."""
@@ -1061,7 +1064,7 @@ class LiveGraph:
             raise ValueError(
                 f"live delta relax requires a monotone min/max "
                 f"program, got reduce={reduce!r} (pull kinds use the "
-                f"host-side degree correction instead — serve.py)")
+                f"degree correction instead — serve.py)")
 
         def step(label, active, src_slot, dst_slot, w, d_kind,
                  d_epoch, col_epoch):

@@ -956,10 +956,9 @@ DEBTS = (
          "bench.py -config batch-sweep (B in {1,8,64} k-source SSSP "
          "+ personalized PageRank) on the chip: the modeled "
          "~9/B per-query amortization (scalemodel.per_query_edge_ns, "
-         "BATCH_LANE_NS wide-row lane rate) is CPU-A/B'd only; the "
-         "pull runner's boundary still moves the whole [nv, B] "
-         "state through the host (the push runner's turns columns "
-         "over on the device, PR 25)",
+         "BATCH_LANE_NS wide-row lane rate) is CPU-A/B'd only (both "
+         "runners turn columns over on the device: push PR 25, "
+         "pull PR 27)",
          "PERF_NOTES round 14 (query batching)"),
     Debt("live-mutation-on-device",
          "bench.py -config serve-live (live-graph serving: mutation "

@@ -42,8 +42,9 @@ PRs 1-8 built:
   the column's frontier is empty; pull: the column's residual fell
   under ``tol``), scatters their answers into per-query
   :class:`Response` objects, and REFILLS the freed columns from the
-  queue (pull refills also swap the column's reset vector in place
-  via ``PullEngine.update_program_arrays`` — no recompile).
+  queue (pull refills also rewrite the column of the reset table on
+  the device and hand the table back through
+  ``PullEngine.update_program_arrays`` — no recompile).
 - per-query telemetry: ``query_enqueue`` / ``query_start`` /
   ``query_done`` events (latency, wait, iterations, segments) plus a
   ``serve_refill`` event per boundary — rendered and validated by
@@ -64,14 +65,15 @@ PRs 1-8 built:
   reads the snapshots back and scripts/events_summary.py cross-audits
   them against the raw ``query_done`` stream.
 
-Costs and debts: a PUSH boundary moves one padded ``[P, vpad]`` column
-per retired query to the host and a few ``[B]`` vectors back — the
-retire (``_take_column``) and the refill (``_start_columns``) are
-device programs of fixed shape, so the ``[nv, B]`` state never
-crosses (PR 25).  The PULL boundary still fetches and re-places the
-whole state (its per-column residual is host arithmetic) — O(state)
-per boundary; moving it, and the on-device batch sweep, are carried
-debts (lux_tpu/observe.py DEBTS "batch-sweep-on-device").
+Costs and debts: a boundary moves one padded ``[P, vpad]`` column per
+retired query to the host and a few ``[B]`` vectors back — the retire
+(``_take_column``) and the refill (``_start_columns``) are device
+programs of fixed shape, and so are the pull runner's per-column
+residual (``_column_residuals``; 4 B a column come to the host) and
+its live-graph correction, so the ``[nv, B]`` state never crosses
+(push: PR 25; pull: PR 27).  Only a query that brings its own reset
+vector uploads that one column.  The on-device batch sweep is a
+carried debt (lux_tpu/observe.py DEBTS "batch-sweep-on-device").
 
 Smoke: ``python -m lux_tpu.serve`` builds a small random graph,
 enqueues 2B mixed queries (sssp + components + pagerank), drains them
@@ -561,6 +563,11 @@ class _RunnerBase:
         self.seg_iters = int(seg_iters)
         self.max_segments = int(max_segments)
         self.slots: list[_Slot | None] = [None] * self.B
+        # per-column admission epochs (live graphs): the live steps
+        # mask each column's delta edges to its OWN epoch, so columns
+        # admitted at different epochs share one engine dispatch with
+        # snapshot isolation intact
+        self._col_epoch = np.zeros(self.B, np.int32)
         self.responses: list[Response] = []
         # the suspended segment driver (a generator of
         # lux_tpu/segmented.py) while any column is occupied; it holds
@@ -649,13 +656,16 @@ class _RunnerBase:
         return [c for c, s in enumerate(self.slots) if s is not None]
 
     def _answer_epoch(self, col: int) -> int | None:
-        """The epoch the answer in ``col`` was actually computed at —
-        runner-specific (push: the column's delta-mask epoch; pull:
-        the engine's base-generation epoch).  Audited against the
+        """The epoch the answer in ``col`` was actually computed at:
+        the column's own ``_col_epoch``, which the live mechanism
+        (push: the delta mask; pull: the degree correction and the
+        delta mass) reads on the device.  Audited against the
         admission epoch by scripts/events_summary.py; a divergence is
         a torn read, so this must come from the MECHANISM, never be
         copied from the request."""
-        return None
+        if self.live is None:
+            return None
+        return int(self._col_epoch[col])
 
     def _start(self, col: int, req: Request, total_iters: int):
         now = time.monotonic()
@@ -772,6 +782,102 @@ class _RunnerBase:
               cached=True, **ep, **slo, **self._rep())
         return True
 
+    # -- columns on the device -------------------------------------------
+    #
+    # Both families keep their ``[P, vpad, B]`` state on the device
+    # while any column is occupied; a retirement fetches its own
+    # column (``_take_column``) and columns start through
+    # ``_start_columns``, the first fill included.
+
+    def _column_programs(self, mesh, *dtypes):
+        """The device programs of fixed shape every boundary uses
+        (``self.eng`` is built): ``_blank`` (zero arrays of
+        ``dtypes``, what a drain starts from), ``_take`` and
+        ``_reset``.  The state keeps the engine's parts sharding
+        (``self._parts``; None without a mesh) through every program,
+        so the engine never sees a second layout."""
+        import jax
+        import jax.numpy as jnp
+
+        sg = self.eng.sg
+        shape = (sg.num_parts, sg.vpad, self.B)
+        self._parts = None
+        if mesh is not None:
+            from lux_tpu.parallel.mesh import parts_spec
+            self._parts = parts_spec(mesh)
+
+        def sharded(n):
+            return None if mesh is None else (self._parts,) * n
+
+        self._blank = jax.jit(
+            lambda: tuple(jnp.zeros(shape, d) for d in dtypes),
+            out_shardings=sharded(len(dtypes)))
+        self._take = jax.jit(_take_column)
+        self._reset = jax.jit(_start_columns, donate_argnums=(0, 1),
+                              out_shardings=sharded(2))
+        self._rows = np.diff(sg.starts).astype(np.int32)
+
+    def _source_pos(self, req: Request) -> int:
+        """Padded position (``part * vpad + offset``) of the query's
+        source: ``_start_columns``' ``pos``."""
+        sg = self.eng.sg
+        s = int(req.source)
+        if not 0 <= s < self.g.nv:
+            raise ValueError(f"query {req.qid}: source {s} out of "
+                             f"range [0, {self.g.nv})")
+        part = int(np.searchsorted(sg.starts, s, side="right")) - 1
+        return part * sg.vpad + s - int(sg.starts[part])
+
+    def _turnover(self, cols=()):
+        """The ``[B]`` host vectors that steer ``_start_columns``,
+        with the columns ``cols`` marked to go idle; ``_fill`` marks
+        the ones it starts."""
+        mask = np.zeros(self.B, bool)
+        mask[list(cols)] = True
+        return (mask, np.full(self.B, -1, np.int32),
+                np.full(self.B, self._inf, self._dtype))
+
+    def _fetch_columns(self, label, cols) -> list:
+        """The ``[nv]`` answers of the columns ``cols``:
+        ``serve.boundary.fetch`` (one ``_take_column`` dispatch per
+        column, then one device_get of them all; ``bytes``) and
+        ``serve.boundary.unpad``."""
+        import jax
+
+        sg = self.eng.sg
+        with telemetry.span("serve.boundary.fetch") as sp:
+            padded = jax.device_get(
+                [self._take(label, np.int32(c)) for c in cols])
+            sp.count(bytes=sum(x.nbytes for x in padded))
+        with telemetry.span("serve.boundary.unpad"):
+            return [sg.from_padded(x) for x in padded]
+
+    def _fill(self, turnover, collector, total_iters,
+              deadline_s) -> int:
+        """Give free columns to queued requests — host bookkeeping
+        only; what each started column must look like goes into
+        ``turnover`` for the boundary's ``_start_columns``."""
+        mask, pos, init = turnover
+        free = self._free_cols()
+        filled = 0
+        first = True
+        while free:
+            reqs = collector.collect(len(free),
+                                     deadline_s if first else 0.0)
+            first = False
+            if not reqs:
+                break
+            for req in reqs:
+                if self._serve_cached(req):
+                    continue     # answered without a column
+                col = free.pop(0)
+                mask[col] = True
+                pos[col], init[col] = self._col_init(req)
+                self._col_epoch[col] = req.epoch or 0
+                self._start(col, req, total_iters)
+                filled += 1
+        return filled
+
     # -- the segment boundary's spans (lux_tpu/telemetry.py) -----------
     #
     # Every segment boundary is one ``serve.boundary`` span (counts:
@@ -785,11 +891,24 @@ class _RunnerBase:
     # query: ``bytes``), ``.unpad``, ``.retire``, ``.fill`` (host
     # bookkeeping only) and ``.place`` (the device reset of the
     # retired and refilled columns; ``bytes`` = the [B] vectors that
-    # steer it; ends at dispatch).  The pull boundary moves the WHOLE
-    # state: ``.fetch``, ``.unpad``, ``.residual`` (per-column
-    # residuals, host arithmetic), ``.retire``, ``.fill``, and after a
-    # refill ``.pad`` and ``.place`` (state and reset tables; with
-    # the engine's ``state.place`` under it).
+    # steer it; ends at dispatch).  A pull boundary has ``.residual``
+    # (the per-column residuals: a device program and the fetch of
+    # its [B] result; after ``.delta``, the device correction, on
+    # live graphs), ``.fetch`` and ``.unpad`` where a column retires,
+    # ``.retire`` and ``.fill`` (host bookkeeping) always, and
+    # ``.place`` after a refill, as the push one.
+
+    def _enter_boundary(self) -> None:
+        """The top of every segment boundary: the serving tier's hook
+        (heartbeat, kill plans), the memory sample, and one more
+        segment on every resident query's count."""
+        if self.on_boundary is not None:
+            self.on_boundary(self)
+        if self.mem is not None:
+            self.mem.sample(where=f"{self.kind}:boundary")
+        for s in self.slots:
+            if s is not None:
+                s.segments += 1
 
     def _boundary_metrics(self, bsp, worked: bool, retired: int,
                           filled: int, queued: int) -> None:
@@ -838,7 +957,9 @@ def _start_columns(label, active, rows, cols, pos, init, inf):
     rows past it get 0 / False as ``to_padded`` gives them.
     Elementwise against an iota, no scatter: it shards over parts as
     it stands, and its shape does not depend on how many columns
-    turn over."""
+    turn over.  The pull runner passes its float reset table in the
+    frontier's place: a started column of it becomes the one-hot
+    distribution of the source, an idle one all zero."""
     import jax.numpy as jnp
 
     P, vpad, _B = label.shape
@@ -873,11 +994,6 @@ class PushBatchRunner(_RunnerBase):
                          metrics=metrics, slo_ms=slo_ms, live=live,
                          cache=cache)
         self.g = g
-        # per-column admission epochs (live graphs): the delta-relax
-        # step masks each column's delta edges to its OWN epoch, so
-        # columns admitted at different epochs share one engine
-        # dispatch with snapshot isolation intact
-        self._col_epoch = np.zeros(self.B, np.int32)
         # delta-drag sampling cadence (round 21): every DRAG_SAMPLE_N
         # boundaries one _apply_delta is fenced-timed and fed to the
         # scheduler's economics (LiveGraph.record_drag_sample)
@@ -902,60 +1018,13 @@ class PushBatchRunner(_RunnerBase):
             self._dtype = np.int32
         else:
             raise ValueError(f"unknown push kind {kind!r}")
-        import jax
-        import jax.numpy as jnp
-
-        sg = self.eng.sg
-        shape, dtype = (sg.num_parts, sg.vpad, self.B), self._dtype
-        # the state keeps the engine's parts sharding through every
-        # program here, so ``converge`` never sees a second layout
-        sharded = None
-        if mesh is not None:
-            from lux_tpu.parallel.mesh import parts_spec
-            sharded = (parts_spec(mesh),) * 2
-        self._blank = jax.jit(
-            lambda: (jnp.zeros(shape, dtype), jnp.zeros(shape, bool)),
-            out_shardings=sharded)
-        self._take = jax.jit(_take_column)
-        self._reset = jax.jit(_start_columns, donate_argnums=(0, 1),
-                              out_shardings=sharded)
-        self._rows = np.diff(sg.starts).astype(np.int32)
+        self._column_programs(mesh, self._dtype, bool)
 
     def _col_init(self, req: Request):
         """(padded position of the source, its label) for a fresh
         query column — ``_start_columns``' ``pos`` and ``init``."""
-        sg = self.eng.sg
-        s = int(req.source)
-        if not 0 <= s < self.g.nv:
-            raise ValueError(f"query {req.qid}: source {s} out of "
-                             f"range [0, {self.g.nv})")
-        part = int(np.searchsorted(sg.starts, s, side="right")) - 1
-        return (part * sg.vpad + s - int(sg.starts[part]),
-                s if self.kind == "components" else 0)
-
-    def _turnover(self, cols=()):
-        """The ``[B]`` host vectors that steer ``_start_columns``,
-        with the columns ``cols`` marked to go idle; ``_fill`` marks
-        the ones it starts."""
-        mask = np.zeros(self.B, bool)
-        mask[list(cols)] = True
-        return (mask, np.full(self.B, -1, np.int32),
-                np.full(self.B, self._inf, self._dtype))
-
-    def _fetch_columns(self, label, cols) -> list:
-        """The ``[nv]`` answers of the columns ``cols``:
-        ``serve.boundary.fetch`` (one ``_take_column`` dispatch per
-        column, then one device_get of them all; ``bytes``) and
-        ``serve.boundary.unpad``."""
-        import jax
-
-        sg = self.eng.sg
-        with telemetry.span("serve.boundary.fetch") as sp:
-            padded = jax.device_get(
-                [self._take(label, np.int32(c)) for c in cols])
-            sp.count(bytes=sum(x.nbytes for x in padded))
-        with telemetry.span("serve.boundary.unpad"):
-            return [sg.from_padded(x) for x in padded]
+        return (self._source_pos(req),
+                int(req.source) if self.kind == "components" else 0)
 
     def _place_columns(self, label, active, turnover):
         """``serve.boundary.place``: one ``_start_columns`` dispatch
@@ -987,13 +1056,7 @@ class PushBatchRunner(_RunnerBase):
                 return boundary(bsp, label, active, total)
 
         def boundary(bsp, label, active, total):
-            if self.on_boundary is not None:
-                self.on_boundary(self)
-            if self.mem is not None:
-                self.mem.sample(where=f"{self.kind}:boundary")
-            for s in self.slots:
-                if s is not None:
-                    s.segments += 1
+            self._enter_boundary()
             if self.live is not None:
                 # the live delta-relax step: delta blocks as jit
                 # ARGUMENTS, each column masked to its OWN admission
@@ -1068,44 +1131,102 @@ class PushBatchRunner(_RunnerBase):
                 time.perf_counter() - t0, n_slots)
         return label, active
 
-    def _answer_epoch(self, col: int) -> int | None:
-        if self.live is None:
-            return None
-        return int(self._col_epoch[col])
 
-    def _fill(self, turnover, collector, total_iters,
-              deadline_s) -> int:
-        """Give free columns to queued requests — host bookkeeping
-        only; what each started column must look like goes into
-        ``turnover`` for the boundary's ``_start_columns``."""
-        mask, pos, init = turnover
-        free = self._free_cols()
-        filled = 0
-        first = True
-        while free:
-            reqs = collector.collect(len(free),
-                                     deadline_s if first else 0.0)
-            first = False
-            if not reqs:
-                break
-            for req in reqs:
-                if self._serve_cached(req):
-                    continue     # answered without a column
-                col = free.pop(0)
-                mask[col] = True
-                pos[col], init[col] = self._col_init(req)
-                self._col_epoch[col] = req.epoch or 0
-                self._start(col, req, total_iters)
-                filled += 1
-        return filled
+def _column_residuals(new, prev, rows):
+    """``[B]`` per-column residuals ``max |new - prev|`` over the real
+    vertices of every part: ``rows[p]`` is part p's count of them, and
+    the padding rows past it are left out, as ``sg.from_padded``
+    leaves them out."""
+    import jax.numpy as jnp
+
+    vpad = new.shape[1]
+    real = (jnp.arange(vpad, dtype=jnp.int32)[None, :, None]
+            < rows[:, None, None])
+    return jnp.max(jnp.where(real, jnp.abs(new - prev), 0),
+                   axis=(0, 1))
+
+
+def _put_column(table, column, col):
+    """``table`` ``[P, vpad, B]`` with its column ``col`` replaced by
+    ``column`` ``[P, vpad]``.  ``col`` is traced, so every column is
+    the same executable."""
+    import jax
+
+    return jax.lax.dynamic_update_index_in_dim(table, column, col,
+                                               axis=2)
+
+
+def _delta_seen(kind, epoch, col_epoch):
+    """``[cap, B]``: the delta slots each column's admission epoch
+    sees — published appends up to it (an unused slot carries the
+    epoch sentinel and is seen by none)."""
+    from lux_tpu.livegraph import DK_APPEND
+
+    return (kind == DK_APPEND)[:, None] & (epoch[:, None] <= col_epoch)
+
+
+def _delta_degrees(deg_corr, cols, col_epoch, src_slot, _dst_slot, _w,
+                   kind, epoch):
+    """Live refill (delta block as ``LiveGraph.delta_arrays`` gives
+    it): the degree correction of every column in the ``[B]`` mask
+    ``cols`` becomes the number of delta appends leaving each vertex
+    that the column's admission epoch sees — fixed for the column's
+    residence (later appends carry later epochs, anti ops cap
+    admission below themselves, so nothing admitted can change it)."""
+    import jax.numpy as jnp
+
+    seen = _delta_seen(kind, epoch, col_epoch).astype(deg_corr.dtype)
+    count = jnp.zeros((deg_corr.shape[0] * deg_corr.shape[1],
+                       deg_corr.shape[2]), deg_corr.dtype)
+    count = count.at[src_slot].add(seen).reshape(deg_corr.shape)
+    return jnp.where(cols, count, deg_corr)
+
+
+def _delta_mass(new, prev, deg, deg_corr, col_epoch, src_slot, dst_slot,
+                _w, kind, epoch, *, alpha):
+    """The live half of a pull iteration: the engine produced ``new``
+    = ``apply(acc_base)`` of ``prev`` with effective-degree
+    normalization; one exact PPR iteration over ``graph_at(col_epoch)``
+    additionally accumulates ``alpha * prev[src]`` into each delta
+    append's destination, with the SAME normalization (linearity of
+    the divide).  Each column sees the delta up to its own admission
+    epoch — the snapshot-isolation rule of the push delta step.  One
+    gather of ``prev`` at the slots' sources, one scatter-add into
+    their destinations (an unused slot's lies past the table and is
+    dropped)."""
+    import jax.numpy as jnp
+
+    flat = prev.reshape((-1, prev.shape[2]))
+    mass = jnp.where(_delta_seen(kind, epoch, col_epoch),
+                     jnp.take(flat, src_slot, axis=0), 0)
+    acc = jnp.zeros_like(flat).at[dst_slot].add(mass, mode="drop")
+    deg_eff = deg.astype(new.dtype)[..., None] + deg_corr
+    return new + alpha * acc.reshape(new.shape) / jnp.maximum(deg_eff,
+                                                              1)
 
 
 class PullBatchRunner(_RunnerBase):
     """Continuous-batching runner for personalized PageRank: one
     batched PullEngine; a column retires when its per-query residual
-    (max-abs state change over a segment's last iteration, computed
-    at the boundary) falls under ``tol``; refill swaps the column's
-    reset vector in place (``PullEngine.update_program_arrays``)."""
+    (max-abs state change over the WHOLE segment, an upper bound on
+    any single iteration's) falls under ``tol``.  The state, the
+    snapshot of it the segment began from and the reset table stay on
+    the device while any column is occupied, other runners' turns
+    included: a boundary fetches the ``[B]`` residuals and one column
+    per retired query, and starts columns where they lie
+    (``_place_columns``); the tables go back to the engine as device
+    arrays (``PullEngine.update_program_arrays``).  Columns that
+    retire without a successor keep iterating.
+
+    Live graphs: appends change out-degree normalization, which the
+    engine's base iteration cannot see — so each column runs at its
+    OWN admission epoch via the base-generation + correction split:
+    the engine normalizes by the EFFECTIVE degree (base + the
+    column's delta-append out-degree, the ``deg_corr`` extra array)
+    and the boundary adds the delta edges' rank mass
+    (``_delta_mass``).  The correction is per-ITERATION math, so live
+    forces ``seg_iters`` to 1 (the boundary must run between
+    consecutive iterations, not after a burst)."""
 
     family = "pull"
 
@@ -1121,96 +1242,114 @@ class PullBatchRunner(_RunnerBase):
                          cache=cache)
         if kind != "pagerank":
             raise ValueError(f"unknown pull kind {kind!r}")
+        import jax
+        import jax.numpy as jnp
+
         from lux_tpu.apps import pagerank as app
         self.g = g
-        self.app = app
         self.tol = float(tol)
-        # live pull serving (round 21): appends change out-degree
-        # normalization, which the engine's base iteration cannot
-        # see — so each column runs at its OWN admission epoch via
-        # the base-generation + correction split: the engine
-        # normalizes by the EFFECTIVE degree (base + the column's
-        # delta-append out-degree, the ``deg_corr`` extra array) and
-        # the boundary hook adds the delta edges' rank mass
-        # host-side — together one exact PPR iteration over
-        # graph_at(col_epoch).  The correction is per-ITERATION
-        # math, so live forces seg_iters to 1 (the hook must run
-        # between consecutive iterations, not after a burst).
-        self._col_epoch = np.zeros(B, np.int32)
-        self.deg_corr = np.zeros((g.nv, B), np.float32)
         if live is not None:
             self.seg_iters = 1
-        # idle columns carry the uniform reset's fixed-point-bound
-        # trajectory — cheap, and refilled before they matter
-        self.resets = np.full((g.nv, B), 1.0 / g.nv, dtype=np.float32)
+        # the tables start uniform (a view: no [nv, B] host array);
+        # the first fill zeroes every column it does not start
         self.eng = app.build_engine(
-            g, num_parts=num_parts, mesh=mesh, resets=self.resets,
+            g, num_parts=num_parts, mesh=mesh,
+            resets=np.broadcast_to(np.float32(1.0 / g.nv), (g.nv, B)),
             exchange=exchange, health=health)
+        # an idle column is all zero under a zero reset: a fixed point
+        self._inf, self._dtype = np.float32(0), np.float32
+        self._column_programs(mesh, self._dtype)
+        self._deg = np.asarray(g.out_degrees, np.float32)
+        self._residual = jax.jit(_column_residuals)
+        self._snapshot = jax.jit(jnp.copy, out_shardings=self._parts)
+        self._put = jax.jit(_put_column, donate_argnums=0,
+                            out_shardings=self._parts)
+        if live is not None:
+            import functools
+            self._degrees = jax.jit(_delta_degrees, donate_argnums=0,
+                                    out_shardings=self._parts)
+            self._mass = jax.jit(
+                functools.partial(_delta_mass, alpha=app.ALPHA),
+                donate_argnums=0, out_shardings=self._parts)
 
-    def _col_reset(self, req: Request) -> np.ndarray:
-        if req.reset is not None:
-            r = np.asarray(req.reset, np.float32)
-            if r.shape != (self.g.nv,):
-                raise ValueError(
-                    f"query {req.qid}: reset must be [nv], got "
-                    f"{r.shape}")
-            return r
-        return self.app.one_hot_resets(self.g.nv,
-                                       [int(req.source)])[:, 0]
-
-    def _col_init(self, reset: np.ndarray, col: int) -> np.ndarray:
-        # the column's init state normalizes by the same EFFECTIVE
-        # degree the engine's apply uses (base + deg_corr) — mixing
-        # base-degree init with corrected-degree iteration would
-        # start the column off its own trajectory
-        deg = np.asarray(self.g.out_degrees, np.float32) \
-            + self.deg_corr[:, col]
+    def _col_share(self, req: Request, reset, v):
+        """What a fresh column holds at the vertices ``v`` (an index
+        or a slice) whose reset share is ``reset``: the share over
+        the EFFECTIVE out-degree the engine's apply uses (base + the
+        appends the query's epoch sees) — mixing a base-degree start
+        with corrected-degree iteration would put the column off its
+        own trajectory."""
+        deg = self._deg[v]
+        if self.live is not None:
+            ds, _dd, _dw, de = self.live.append_deltas()
+            deg = deg + np.bincount(
+                ds[de <= int(req.epoch or 0)],
+                minlength=self.g.nv)[v].astype(np.float32)
         return np.where(deg > 0, reset / np.maximum(deg, 1),
                         reset).astype(np.float32)
 
-    def _fetch_unpad(self, state) -> np.ndarray:
-        """Device state -> host ``[nv, B]`` array:
-        ``serve.boundary.fetch`` (device_get; ``bytes``) then
-        ``serve.boundary.unpad``."""
-        import jax
+    def _col_init(self, req: Request):
+        """(padded position of the source, its share there) for a
+        fresh one-hot column — ``_start_columns``' ``pos`` and
+        ``init``; a query with its own ``reset`` vector starts as an
+        idle column, which ``_place_columns`` then writes whole."""
+        if req.reset is None:
+            return (self._source_pos(req),
+                    self._col_share(req, np.float32(1),
+                                    int(req.source)))
+        if np.shape(req.reset) != (self.g.nv,):
+            raise ValueError(f"query {req.qid}: reset must be [nv], "
+                             f"got {np.shape(req.reset)}")
+        return -1, self._inf
 
-        with telemetry.span("serve.boundary.fetch") as sp:
-            padded = np.asarray(jax.device_get(state))
-            sp.count(bytes=padded.nbytes)
-        with telemetry.span("serve.boundary.unpad"):
-            return self.eng.sg.from_padded(padded)
-
-    def _pad_place(self, state_h, resets: bool):
-        """Host ``[nv, B]`` state -> device state, and with ``resets``
-        (a refill) the columns' reset tables too:
-        ``serve.boundary.pad`` then ``serve.boundary.place``
-        (``bytes``; the transfers are asynchronous, so the span ends
-        at dispatch, not at arrival)."""
-        sg = self.eng.sg
-        with telemetry.span("serve.boundary.pad"):
-            padded = sg.to_padded(state_h)
-            tables = self._padded_resets() if resets else {}
-        with telemetry.span(
-                "serve.boundary.place",
-                bytes=padded.nbytes + sum(t.nbytes
-                                          for t in tables.values())):
-            self.eng.update_program_arrays(**tables)
-            return self.eng.place(padded)
+    def _place_columns(self, state, turnover):
+        """``serve.boundary.place``: the columns marked in
+        ``turnover`` start where they lie — ``_start_columns`` on the
+        (donated) state and reset table, the table in the frontier's
+        place (its column becomes 1.0 at the source); a started query
+        that brought its own ``reset`` vector then has that column
+        and its share uploaded and written (``_put_column``); on live
+        graphs ``_delta_degrees`` counts the columns' degree
+        corrections.  The tables go back to the engine as the device
+        arrays they are.  ``bytes`` is what goes to the device for it
+        all; ends at dispatch."""
+        eng, sg = self.eng, self.eng.sg
+        sent = [self._rows, *turnover, self._inf]
+        with telemetry.span("serve.boundary.place") as sp:
+            state, reset = self._reset(state, eng.arrays["prog_reset"],
+                                       *sent)
+            for col in self._occupied():
+                req = self.slots[col].req
+                if not turnover[0][col] or req.reset is None:
+                    continue
+                own = np.asarray(req.reset, np.float32)
+                share = self._col_share(req, own, slice(None))
+                sent += [sg.to_padded(share), sg.to_padded(own)]
+                state = self._put(state, sent[-2], np.int32(col))
+                reset = self._put(reset, sent[-1], np.int32(col))
+            tables = {"reset": reset}
+            if self.live is not None:
+                live = (self._col_epoch, *self.live.delta_arrays(sg))
+                sent += live
+                tables["deg_corr"] = self._degrees(
+                    eng.arrays["prog_deg_corr"], turnover[0], *live)
+            eng.update_program_arrays(**tables)
+            sp.count(bytes=sum(a.nbytes for a in sent))
+            return state
 
     def _begin(self, collector: BatchCollector, deadline_s: float):
         """As ``PushBatchRunner._begin``."""
+        import jax
+
         from lux_tpu.segmented import each_run_segment
         from lux_tpu.timing import fence
 
-        eng, sg = self.eng, self.eng.sg
-
-        state_h = sg.from_padded(np.asarray(
-            self.eng.program.init(sg)))          # [nv, B]
-        if not self._fill(state_h, collector, 0, deadline_s):
+        turnover = self._turnover(range(self.B))
+        if not self._fill(turnover, collector, 0, deadline_s):
             return None                  # cache hits take no column
-        eng.update_program_arrays(**self._padded_resets())
-        prev = state_h.copy()
-        state = eng.place(sg.to_padded(state_h))
+        state = self._place_columns(*self._blank(), turnover)
+        # what the segment begins from: ``eng.run`` donates its input
+        prev = self._snapshot(state)
 
         def hook(state, done_iters):
             # the pull driver dispatches a segment and waits for it
@@ -1222,37 +1361,31 @@ class PullBatchRunner(_RunnerBase):
 
         def boundary(bsp, state, done_iters):
             nonlocal prev
-            if self.on_boundary is not None:
-                self.on_boundary(self)
-            if self.mem is not None:
-                self.mem.sample(where=f"{self.kind}:boundary")
-            for s in self.slots:
-                if s is not None:
-                    s.segments += 1
-            new = self._fetch_unpad(state)
-            corrected = False
-            if self.live is not None:
-                # the host half of the live pull iteration: add the
-                # delta appends' rank mass (the engine already
-                # normalized by the effective degree) — new is now
-                # one exact PPR iteration of prev over each column's
-                # graph_at(col_epoch)
+            self._enter_boundary()
+            if self.live is not None and self.live.count:
+                # after it ``state`` is one exact PPR iteration of
+                # ``prev`` over each column's graph_at(col_epoch)
                 with telemetry.span("serve.boundary.delta"):
-                    new, corrected = self._correct(prev, new)
-            # per-query convergence: max-abs state change over the
-            # WHOLE segment <= tol (an upper bound on any single
-            # iteration's residual — strictly conservative)
+                    state = self._mass(
+                        state, prev, self.eng.arrays["deg"],
+                        self.eng.arrays["prog_deg_corr"],
+                        self._col_epoch,
+                        *self.live.delta_arrays(self.eng.sg))
+            # per-query convergence: 4 B a column come to the host
             with telemetry.span("serve.boundary.residual"):
-                res = np.max(np.abs(new - prev), axis=0)
+                res = np.asarray(jax.device_get(
+                    self._residual(state, prev, self._rows)))
             done = [c for c in self._occupied()
                     if res[c] <= self.tol
                     or self.slots[c].segments >= self.max_segments]
+            answers = self._fetch_columns(state, done) if done else ()
             with telemetry.span("serve.boundary.retire"):
-                for c in done:
-                    self._retire(c, new[:, c].copy(), done_iters,
+                for c, answer in zip(done, answers):
+                    self._retire(c, answer, done_iters,
                                  converged=bool(res[c] <= self.tol))
+            turnover = self._turnover()
             with telemetry.span("serve.boundary.fill"):
-                n_filled = self._fill(new, collector, done_iters,
+                n_filled = self._fill(turnover, collector, done_iters,
                                       deadline_s)
             if done or n_filled:
                 _emit("serve_refill", query_kind=self.kind,
@@ -1261,86 +1394,14 @@ class PullBatchRunner(_RunnerBase):
                       queued=len(collector))
             self._boundary_metrics(bsp, bool(done or n_filled),
                                    len(done), n_filled, len(collector))
-            prev = new
-            if n_filled or corrected:
-                # a refill, or the host correction, changed the state
-                # the next iteration must start from — hand it back
-                # (the correction also when no column turned over)
-                return self._pad_place(new, resets=bool(n_filled))
-            return None
+            if n_filled:
+                state = self._place_columns(state, turnover)
+            prev = self._snapshot(state)
+            return state
 
-        return each_run_segment(eng, state, np.iinfo(np.int32).max,
+        return each_run_segment(self.eng, state,
+                                np.iinfo(np.int32).max,
                                 self.seg_iters, on_segment=hook)
-
-    def _correct(self, prev, new):
-        """Host half of the live pull iteration (round 21): the
-        engine produced ``apply(acc_base)`` of ``prev`` with
-        effective-degree normalization; one exact PPR iteration over
-        ``graph_at(col_epoch)`` additionally accumulates ``ALPHA *
-        prev[src]`` into each delta-append edge's destination, with
-        the SAME normalization (linearity of the divide).  Each
-        column masks the delta to its own admission epoch — the
-        snapshot-isolation rule the push delta step enforces
-        on-device, applied host-side."""
-        ds, dd, _dw, de = self.live.append_deltas()
-        if not len(ds):
-            return new, False
-        mask = de[:, None] <= self._col_epoch[None, :]
-        if not mask.any():
-            return new, False
-        acc = np.zeros_like(new)
-        np.add.at(acc, dd, prev[ds] * mask)
-        deg_eff = np.asarray(self.g.out_degrees,
-                             np.float32)[:, None] + self.deg_corr
-        new = new + self.app.ALPHA * acc / np.maximum(deg_eff, 1.0)
-        return new.astype(np.float32), True
-
-    def _answer_epoch(self, col: int) -> int | None:
-        if self.live is None:
-            return None
-        return int(self._col_epoch[col])
-
-    def _padded_resets(self) -> dict:
-        """The program arrays a refill swaps
-        (``PullEngine.update_program_arrays``)."""
-        kw = {"reset": self.eng.sg.to_padded(self.resets)}
-        if self.live is not None:
-            kw["deg_corr"] = self.eng.sg.to_padded(self.deg_corr)
-        return kw
-
-    def _fill(self, state_h, collector, total_iters,
-              deadline_s) -> int:
-        free = self._free_cols()
-        filled = 0
-        first = True
-        while free:
-            reqs = collector.collect(len(free),
-                                     deadline_s if first else 0.0)
-            first = False
-            if not reqs:
-                break
-            for req in reqs:
-                if self._serve_cached(req):
-                    continue     # answered without a column
-                col = free.pop(0)
-                reset = self._col_reset(req)
-                self.resets[:, col] = reset
-                if self.live is not None:
-                    # pin the column's epoch and materialize its
-                    # delta-append out-degree correction — fixed for
-                    # the column's residence (later appends carry
-                    # later epochs, anti ops cap admission below
-                    # themselves, so nothing admitted can change it)
-                    e = int(req.epoch or 0)
-                    self._col_epoch[col] = e
-                    self.deg_corr[:, col] = 0.0
-                    ds, _dd, _dw, de = self.live.append_deltas()
-                    np.add.at(self.deg_corr[:, col], ds[de <= e],
-                              1.0)
-                state_h[:, col] = self._col_init(reset, col)
-                self._start(col, req, total_iters)
-                filled += 1
-        return filled
 
 
 class Server:

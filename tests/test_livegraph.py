@@ -883,6 +883,60 @@ class TestServeLive:
         assert r3.epoch == 2
         assert check_live_answers(lg, [r3]) == 0
 
+    def test_two_pagerank_epochs_in_one_batch_corrected_on_device(
+            self, g):
+        """PR 27: the pull correction is a device program.  Two
+        pagerank columns admitted at two epochs share one batch and
+        both answer at their own epoch; column for column the device
+        degree correction IS the host's (counts: exact), and the
+        device rank-mass step is the host correction it replaced on
+        the same ``prev`` within float32 summation order: the host
+        added a destination's delta edges in slot order
+        (``np.add.at``), the device scatter adds them in any order;
+        at most four non-negative terms meet in one destination
+        here, so the sums agree within 3 ulp (4e-7): ``rtol`` 1e-6."""
+        from lux_tpu.apps import pagerank
+        lg = LiveGraph(g, capacity=32)
+        srv = self._server(g, lg)
+        srv.submit("pagerank", source=5)
+        srv.mutate([5, 5, 7, 9, 11, 5, 9], [20, 21, 20, 20, 22, 20, 5])
+        srv.submit("pagerank", source=5)
+        responses = srv.run()
+        assert sorted(r.epoch for r in responses) == [0, 1]
+        assert check_live_answers(lg, responses) == 0
+        a, b = (r.answer for r in responses)
+        assert not np.array_equal(a, b)
+
+        runner = srv._runner("pagerank")
+        eng, sg = runner.eng, runner.eng.sg
+        col_epoch = np.array([0, 1], np.int32)
+        rng = np.random.default_rng(27)
+        prev, new = (rng.random((g.nv, 2)).astype(np.float32)
+                     for _ in range(2))
+        # the host correction before PR 27
+        ds, dd, _dw, de = lg.append_deltas()
+        deg_corr = np.zeros((g.nv, 2), np.float32)
+        for col, e in enumerate(col_epoch):
+            np.add.at(deg_corr[:, col], ds[de <= e], 1.0)
+        acc = np.zeros_like(new)
+        np.add.at(acc, dd, prev[ds] * (de[:, None] <= col_epoch[None]))
+        deg_eff = np.asarray(g.out_degrees,
+                             np.float32)[:, None] + deg_corr
+        want = (new + pagerank.ALPHA * acc
+                / np.maximum(deg_eff, 1.0)).astype(np.float32)
+        assert np.count_nonzero(want != new) >= 4
+        # the device programs
+        delta = lg.delta_arrays(sg)
+        table = runner._degrees(
+            sg.to_padded(np.full((g.nv, 2), 7, np.float32)),
+            np.ones(2, bool), col_epoch, *delta)
+        np.testing.assert_array_equal(
+            sg.from_padded(np.asarray(table)), deg_corr)
+        got = runner._mass(sg.to_padded(new), sg.to_padded(prev),
+                           eng.arrays["deg"], table, col_epoch, *delta)
+        np.testing.assert_allclose(sg.from_padded(np.asarray(got)),
+                                   want, rtol=1e-6, atol=0)
+
     def test_refresh_live_guards_and_delta_full(self, g):
         lg = LiveGraph(g, capacity=4)
         srv = self._server(g, lg)

@@ -178,21 +178,37 @@ class TestBoundarySpans:
             == len(worked)
 
     def test_pull_drain_uses_the_same_children(self, g):
-        _srv, responses, recs = self._drain(g, "pagerank", (3, 17, 40),
-                                            tol=1e-9)
+        """Since PR 27 the pull boundary keeps its state on the
+        device too: the residuals first, ``fetch`` / ``unpad`` only
+        where a column retires (one padded column each), ``place``
+        only after a refill (a few [B] vectors), and no ``pad`` nor a
+        placement from the host anywhere."""
+        srv, responses, recs = self._drain(g, "pagerank", (3, 17, 40),
+                                           tol=1e-9)
         assert len(responses) == 3
         bounds = [r for r in recs if r["name"] == "serve.boundary"]
         assert sum(b["counts"]["retired"] for b in bounds) == 3
+        assert sum(b["counts"]["filled"] for b in bounds) == 1
+        sg = srv._runner("pagerank").eng.sg
+        column = sg.to_padded(np.zeros(NV, np.float32)).nbytes
         pre = "serve.boundary."
         for b in bounds:
-            names = [r["name"][len(pre):] for r in recs
-                     if r["parent"] == b["id"]]
-            assert names[:5] == ["fetch", "unpad", "residual", "retire",
-                                 "fill"]
-            assert names[5:] == (["pad", "place"]
-                                 if b["counts"]["filled"] else [])
-            assert b["counts"]["worked"] == int(bool(
-                b["counts"]["retired"] or b["counts"]["filled"]))
+            kids = [r for r in recs if r["parent"] == b["id"]]
+            by = {k["name"][len(pre):]: k for k in kids}
+            retired, filled = b["counts"]["retired"], b["counts"]["filled"]
+            assert list(by) == (
+                ["residual"] + ["fetch", "unpad"] * bool(retired)
+                + ["retire", "fill"] + ["place"] * bool(filled))
+            if retired:
+                assert by["fetch"]["counts"]["bytes"] == retired * column
+            if filled:
+                assert 0 < by["place"]["counts"]["bytes"] < 1024
+                assert not [r for r in recs
+                            if r["parent"] == by["place"]["id"]]
+            assert b["counts"]["worked"] == int(bool(retired or filled))
+            assert b["counts"]["family"] == "pull"
+        assert not [r for r in recs
+                    if r["name"] in ("state.place", pre + "pad")]
 
 
 def _dense_column(runner, source):
@@ -430,6 +446,162 @@ class TestDeviceBoundary:
         srv = serve.Server(g, batch=2, num_parts=2)
         srv.submit("sssp", source=NV)
         with pytest.raises(ValueError, match="out of range"):
+            srv.run()
+
+
+def _parent_pull_drain(graph, queries, B, num_parts, seg_iters, tol,
+                       max_segments=500):
+    """Host statement of the pull protocol before PR 27, which moved
+    the whole ``[nv, B]`` state through the host at every boundary:
+    fetch and unpad it, residual ``max |new - prev|`` by NumPy, retire
+    in column order, give free columns lowest first to the queue in
+    order and write each fresh column (reset share over the
+    out-degree) and its reset vector on the host, then pad and place
+    state and reset table.  ``queries`` are sources or ``[nv]`` reset
+    vectors; returns ``(query, answer, iters, segments, converged)``
+    in retirement order."""
+    resets = np.full((graph.nv, B), 1.0 / graph.nv, np.float32)
+    eng = pagerank.build_engine(graph, num_parts=num_parts,
+                                resets=resets)
+    sg = eng.sg
+    deg = np.asarray(graph.out_degrees, np.float32)
+    queue, cols, out = list(enumerate(queries)), [None] * B, []
+    new = sg.from_padded(np.asarray(eng.program.init(sg)))
+    total = 0
+
+    def fill():
+        filled = 0
+        for c in range(B):
+            if cols[c] is None and queue:
+                q, what = queue.pop(0)
+                reset = (pagerank.one_hot_resets(graph.nv, [what])[:, 0]
+                         if np.ndim(what) == 0 else what)
+                resets[:, c] = reset
+                new[:, c] = np.where(deg > 0, reset / np.maximum(deg, 1),
+                                     reset).astype(np.float32)
+                cols[c] = {"q": q, "t0": total, "seg": 0}
+                filled += 1
+        if filled:
+            eng.update_program_arrays(reset=sg.to_padded(resets))
+        return filled
+
+    fill()
+    state = eng.place(sg.to_padded(new))
+    while any(cols):
+        prev = new
+        state = eng.run(state, seg_iters)
+        total += seg_iters
+        new = sg.from_padded(np.asarray(state))
+        res = np.max(np.abs(new - prev), axis=0)
+        for c, s in enumerate(cols):
+            if s is None:
+                continue
+            s["seg"] += 1
+            if res[c] <= tol or s["seg"] >= max_segments:
+                out.append((s["q"], new[:, c].copy(), total - s["t0"],
+                            s["seg"], bool(res[c] <= tol)))
+                cols[c] = None
+        if fill():
+            state = eng.place(sg.to_padded(new))
+    return out
+
+
+class TestPullDeviceBoundary:
+    """PR 27: the pull boundary computes its residuals, retires and
+    refills columns on the DEVICE; nothing a caller sees may differ
+    from the protocol that moved the state through the host."""
+
+    @staticmethod
+    def _submit(srv, queries):
+        for q in queries:
+            if np.ndim(q) == 0:
+                srv.submit("pagerank", source=q)
+            else:
+                srv.submit("pagerank", reset=q)
+
+    @pytest.mark.parametrize("graph,num_parts,seg_iters,max_segments", [
+        ("gt", 1, 2, None), ("gt", 2, 2, None), ("gt", 2, 1, 7),
+        ("chain", 2, 2, None)])
+    def test_drain_is_the_host_protocol_bit_for_bit(
+            self, request, graph, num_parts, seg_iters, max_segments):
+        """More queries than columns, one of them with its own reset
+        vector: answers bitwise, retirement order, ``iters``,
+        ``segments`` and ``converged`` are the host protocol's.  The
+        sources are chosen so that boundaries retire none, some and
+        all of the three columns and the last queries leave columns
+        unrefilled; ``chain`` has a source without out-edges, and with
+        ``max_segments`` 7 the slow columns are cut short."""
+        graph = request.getfixturevalue(graph)
+        rng = np.random.default_rng(27)
+        own = rng.random(graph.nv).astype(np.float32)
+        own /= own.sum()
+        n = graph.nv
+        queries = [3, 17, 40, n - 6, n - 16, own, 50, n - 1, 30,
+                   n - 11, 60]
+        srv = serve.Server(graph, batch=3, num_parts=num_parts,
+                           seg_iters=seg_iters)
+        if max_segments:
+            srv._runner("pagerank").max_segments = max_segments
+        tip = _tip()
+        self._submit(srv, queries)
+        responses = srv.run()
+        want = _parent_pull_drain(graph, queries, 3, num_parts,
+                                  seg_iters, srv.tol,
+                                  max_segments or 500)
+        assert [(r.qid, r.iters, r.segments, r.converged)
+                for r in responses] \
+            == [(q, it, seg, conv) for q, _a, it, seg, conv in want]
+        for r, (_q, answer, *_rest) in zip(responses, want):
+            assert r.answer.dtype == answer.dtype
+            np.testing.assert_array_equal(r.answer, answer)
+        if max_segments:    # the case has both outcomes in it
+            assert {r.converged for r in responses} == {True, False}
+        bounds = [b["counts"] for b in telemetry.spans()
+                  if b["id"] > tip and b["name"] == "serve.boundary"]
+        assert {b["retired"] for b in bounds} >= {0, 1, 3}
+        assert [b for b in bounds if b["retired"] > b["filled"]]
+
+    def test_warm_boundary_compiles_nothing(self, gt):
+        """``TestDeviceBoundary``'s twin: after a warm drain that
+        only retired, a drain in which one, two and all columns turn
+        over at different boundaries compiles nothing."""
+        from lux_tpu import runtime
+        runtime.watch_compiles()
+        srv = serve.Server(gt, batch=3, num_parts=2, seg_iters=2)
+        self._submit(srv, (3, 17, 40))
+        assert len(srv.run()) == 3              # the warm-up
+        tip = _tip()
+        self._submit(srv, (3, 17, 40, NV - 6, 50, 30, NV - 1, 60, 99))
+        assert len(srv.run()) == 9
+        recs = [r for r in telemetry.spans() if r["id"] > tip]
+        turned = {(b["counts"]["retired"], b["counts"]["filled"])
+                  for b in recs if b["name"] == "serve.boundary"
+                  and b["counts"]["worked"]}
+        assert turned >= {(1, 1), (2, 2), (3, 3)}
+        assert not [r for r in recs if r["name"] == "jit.compile"]
+
+    def test_residual_ignores_padding_rows(self, gt):
+        """Two uneven parts: rows past a part's real vertices do not
+        enter the residual, whatever they hold."""
+        runner = serve.PullBatchRunner("pagerank", gt, 3, num_parts=2)
+        sg = runner.eng.sg
+        rows = np.diff(sg.starts)
+        assert rows[0] != rows[1] and rows.min() < sg.vpad
+        rng = np.random.default_rng(3)
+        new, prev = (rng.random((NV, 3)).astype(np.float32)
+                     for _ in range(2))
+        padded = sg.to_padded(new)
+        for p, r in enumerate(rows):
+            padded[p, r:] = 1e6
+        got = np.asarray(runner._residual(padded, sg.to_padded(prev),
+                                          runner._rows))
+        np.testing.assert_array_equal(
+            got, np.max(np.abs(new - prev), axis=0))
+
+    def test_reset_of_the_wrong_shape_is_refused(self, g):
+        srv = serve.Server(g, batch=2, num_parts=2)
+        srv.submit("pagerank", reset=np.ones(NV - 1, np.float32))
+        with pytest.raises(ValueError, match=r"reset must be \[nv\]"):
             srv.run()
 
 

@@ -256,6 +256,70 @@ def test_serving_column_programs_compile_at_cell_size(topo, v5e, chips):
                 "collective-permute"))
 
 
+@pytest.mark.parametrize("chips", [1, 4])
+def test_pull_serving_programs_compile_at_cell_size(topo, v5e, chips):
+    """The pull serving boundary's device programs (serve.py, PR 27)
+    at ``mixed.kron20.closed``'s state, ``[P, 2**20 / P, 16]`` float32
+    on one chip and sharded over the four-device parts mesh:
+    ``_start_columns`` with the reset table in the frontier's place
+    and ``_put_column`` rewrite their donated tables in place, and the
+    residual's only collective across parts is one all-reduce of its
+    ``[B]`` result; the live-graph correction (``_delta_degrees``,
+    ``_delta_mass``; one gather, one scatter) compiles at a delta
+    block of 4096 slots."""
+    import functools
+
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from lux_tpu import serve
+    from lux_tpu.parallel.mesh import PARTS_AXIS
+
+    if chips == 1:
+        parts = small = v5e
+    else:
+        mesh = Mesh(np.asarray(topo.devices), (PARTS_AXIS,))
+        parts = NamedSharding(mesh, P(PARTS_AXIS))
+        small = NamedSharding(mesh, P())
+    shape, B, cap = (chips, (1 << 20) // chips, 16), 16, 4096
+
+    def sds(shape, dtype, sharding=small):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+    table = 4 * (1 << 20) * B // chips              # bytes a device
+    state = sds(shape, jnp.float32, parts)
+    rows, cols = sds((chips,), jnp.int32), sds((B,), jnp.bool_)
+    reset = jax.jit(
+        serve._start_columns, donate_argnums=(0, 1),
+        out_shardings=(parts, parts)).lower(
+            state, state, rows, cols, sds((B,), jnp.int32),
+            sds((B,), jnp.float32), sds((), jnp.float32)).compile()
+    assert reset.memory_analysis().alias_size_in_bytes == 2 * table
+    put = jax.jit(serve._put_column, donate_argnums=0,
+                  out_shardings=parts).lower(
+        state, sds(shape[:2], jnp.float32), sds((), jnp.int32)).compile()
+    assert put.memory_analysis().alias_size_in_bytes == table
+    residual = jax.jit(serve._column_residuals).lower(
+        state, state, rows).compile()
+    assert residual.memory_analysis().output_size_in_bytes <= 512
+    for compiled in (reset, put, residual):
+        assert compiled.memory_analysis().temp_size_in_bytes < table // 8
+        text = compiled.as_text()
+        assert not any(op in text for op in (
+            "all-gather", "all-to-all", "collective-permute"))
+        assert ("all-reduce" in text) == (compiled is residual
+                                          and chips > 1)
+    delta = [sds((cap,), d) for d in (jnp.int32, jnp.int32, jnp.float32,
+                                      jnp.int32, jnp.int32)]
+    jax.jit(serve._delta_degrees, donate_argnums=0,
+            out_shardings=parts).lower(
+        state, cols, sds((B,), jnp.int32), *delta).compile()
+    jax.jit(functools.partial(serve._delta_mass, alpha=0.15),
+            donate_argnums=0, out_shardings=parts).lower(
+        state, state, sds(shape[:2], jnp.int32, parts), state,
+        sds((B,), jnp.int32), *delta).compile()
+
+
 @pytest.mark.parametrize("dtype,exact", [(jnp.float32, True),
                                          (jnp.int32, False)])
 def test_mxu_sum_of_floats_is_not_rounded_to_bfloat16(v5e, dtype, exact):
