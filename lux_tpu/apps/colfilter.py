@@ -20,6 +20,7 @@ from __future__ import annotations
 import jax.numpy as jnp
 import numpy as np
 
+from lux_tpu.engine.delivery import sharding_demands
 from lux_tpu.engine.program import PullProgram
 from lux_tpu.engine.pull import PullEngine
 from lux_tpu.graph import Graph, ShardedGraph
@@ -76,12 +77,11 @@ def build_engine(g: Graph, num_parts: int = 1, mesh=None,
     (ops/pairs.resolve_min_fill)."""
     if g.weights is None:
         raise ValueError("collaborative filtering needs a weighted graph")
+    vpad_align, tile_e = sharding_demands(gather, pair_threshold)
     if sg is None:
         sg = ShardedGraph.build(
             g, num_parts, starts=starts,
-            pair_threshold=pair_threshold,
-            vpad_align=128 if gather != "flat" else 8)
-    tile_e = 128 if pair_threshold is not None else 512
+            pair_threshold=pair_threshold, vpad_align=vpad_align)
     return PullEngine(sg, make_program(), mesh=mesh,
                       pair_threshold=pair_threshold,
                       pair_min_fill=pair_min_fill,

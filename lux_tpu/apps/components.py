@@ -13,6 +13,7 @@ from __future__ import annotations
 
 import numpy as np
 
+from lux_tpu.engine.delivery import sharding_demands
 from lux_tpu.engine.push import PushEngine, PushProgram
 from lux_tpu.graph import Graph, ShardedGraph
 
@@ -92,10 +93,10 @@ def build_engine(g: Graph, num_parts: int = 1, mesh=None,
     reachable from seed a with the seed's id (labels [vpad, B], one
     gather serving every query); pair_threshold must be off then."""
     if sg is None:
+        vpad_align, _ = sharding_demands(gather)
         sg = ShardedGraph.build(
             g, num_parts, starts=starts,
-            pair_threshold=pair_threshold,
-            vpad_align=128 if gather != "flat" else 8)
+            pair_threshold=pair_threshold, vpad_align=vpad_align)
     program = (make_program() if sources is None
                else make_batched_program(sources))
     return PushEngine(sg, program, mesh=mesh,

@@ -18,6 +18,7 @@ from __future__ import annotations
 import jax.numpy as jnp
 import numpy as np
 
+from lux_tpu.engine.delivery import sharding_demands
 from lux_tpu.engine.program import PullProgram
 from lux_tpu.engine.pull import PullEngine
 from lux_tpu.graph import Graph, ShardedGraph, degree_relabel  # noqa: F401
@@ -157,15 +158,14 @@ def build_engine(g: Graph, num_parts: int = 1, mesh=None,
                          "not both")
     if sources is not None:
         resets = one_hot_resets(g.nv, sources)
+    vpad_align, default_tile_e = sharding_demands(gather,
+                                                  pair_threshold)
     if sg is None:
-        # gather="paged"|"auto": the paged plan needs 128-aligned
-        # vertex padding, like pair delivery (ops/pagegather.py)
         sg = ShardedGraph.build(
             g, num_parts, starts=starts,
-            pair_threshold=pair_threshold,
-            vpad_align=128 if gather != "flat" else 8)
+            pair_threshold=pair_threshold, vpad_align=vpad_align)
     if tile_e is None:
-        tile_e = 128 if pair_threshold is not None else 512
+        tile_e = default_tile_e
     program = (make_program(dtype) if resets is None
                else make_batched_program(resets, dtype))
     return PullEngine(sg, program, mesh=mesh,

@@ -21,6 +21,7 @@ from __future__ import annotations
 import jax.numpy as jnp
 import numpy as np
 
+from lux_tpu.engine.delivery import sharding_demands
 from lux_tpu.engine.push import PushEngine, PushProgram
 from lux_tpu.graph import Graph, ShardedGraph
 
@@ -163,10 +164,10 @@ def build_engine(g: Graph, start_vertex: int | None = 0,
             delta = default_delta(g) if weighted else 1.0
         program = make_program(start_vertex, weighted)
     if sg is None:
+        vpad_align, _ = sharding_demands(gather)
         sg = ShardedGraph.build(
             g, num_parts, starts=starts,
-            pair_threshold=pair_threshold,
-            vpad_align=128 if gather != "flat" else 8)
+            pair_threshold=pair_threshold, vpad_align=vpad_align)
     return PushEngine(sg, program, mesh=mesh,
                       delta=delta, pair_threshold=pair_threshold,
                       pair_min_fill=pair_min_fill,

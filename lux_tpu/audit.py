@@ -41,7 +41,7 @@ Check catalogue (check name -> typed error):
   collective-schedule  CollectiveScheduleError
       The owner exchange must be a lax.scan over source parts (a
       vmapped batched gather still pays the big-table rate,
-      scripts/profile_owner.py); sum exchanges reduce-scatter; fused
+      PERF_NOTES.md round 3); sum exchanges reduce-scatter; fused
       min/max rings take exactly ndev-1 ppermute hops of full ndev
       cycles (cf. the collective-schedule discipline of portable
       reduce-scatter lowerings, PAPERS.md).
@@ -604,7 +604,7 @@ def check_collectives(closed, spec: ProgramSpec, where: str):
                 f"[vpad, ...] state shard (scan lengths seen: "
                 f"{sorted(set(lens))}) — a vmapped batched gather "
                 f"still pays the big-table rate "
-                f"(scripts/profile_owner.py)"))
+                f"(PERF_NOTES.md round 3)"))
     if spec.ppermute_hops is not None:
         perms = [eqn.params.get("perm")
                  for eqn, _, _ in _iter_eqns(closed.jaxpr)
@@ -919,14 +919,10 @@ def report_kwargs(engine) -> dict:
         # of the tiled/owner edge layout (memory_report prices the
         # actual plan array bytes)
         kw["page_plan"] = engine.page_plan
-        if not is_push:
-            from lux_tpu.engine.pull import _dot_kdim
-            kw["pair_kdim"] = _dot_kdim(engine.program)
+        kw["pair_kdim"] = engine.delivery.kdim
     if engine.pairs is not None:
         kw["pairs"] = engine.pairs
-        if not is_push:
-            from lux_tpu.engine.pull import _dot_kdim
-            kw["pair_kdim"] = _dot_kdim(engine.program)
+        kw["pair_kdim"] = engine.delivery.kdim
     if is_push:
         kw["push_sparse"] = bool(engine.enable_sparse)
         # query-batched labels [P, vpad, B]: the ledger must price

@@ -600,7 +600,7 @@ def _build_sg(args, g, num_parts, starts=None):
 def _timed_build(make_eng, mesh, t_start):
     """Build the engine and say which formulations the build RESOLVED
     to and where it runs — 'auto' reduce picks the Pallas kernel only
-    on a TPU backend (engine/pull.resolve_reduce_method), so this line
+    on a TPU backend (engine/delivery.resolve_reduce_method), so this line
     is what tells a chip run from one that quietly took the XLA path.
     On a mesh, also what each device actually HOLDS: a mesh that
     replicated instead of sharding would still compute right answers.

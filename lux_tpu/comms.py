@@ -411,7 +411,7 @@ def oracle_for(eng) -> list:
         if eng.exchange == "gather":
             out.append(entry("all_gather", shard, state_dt))
             return out
-        msg_dt = eng._msg_dtype(sds)
+        msg_dt = eng.delivery.msg_dtype(eng._msg, sds)
         if pagemajor:
             Mg = int(eng.page_plan.route)
             shape = (P_local, int(sg.num_parts), Mg, 128) + trail

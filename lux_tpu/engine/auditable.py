@@ -29,6 +29,24 @@ class AuditableEngine:
 
     _AUDIT_LAZY: tuple = ()
 
+    # what the engine's delivery (engine/delivery.py) resolved and
+    # built, readable on the engine: audit, comms, memwatch, observe,
+    # the CLI and the tests read these names here
+    exchange = property(lambda self: self.delivery.exchange)
+    gather = property(lambda self: self.delivery.gather)
+    pairs = property(lambda self: self.delivery.pairs)
+    owner = property(lambda self: self.delivery.owner)
+    tiles = property(lambda self: self.delivery.tiles)
+    page_plan = property(lambda self: self.delivery.page_plan)
+    use_mxu = property(lambda self: self.delivery.use_mxu)
+    reduce_method = property(lambda self: self.delivery.reduce_method)
+    pair_stream = property(lambda self: self.delivery.pair_stream)
+    pair_dot_stream = property(
+        lambda self: self.delivery.pair_dot_stream)
+    stream_chunks = property(lambda self: self.delivery.stream_chunks)
+    owner_minmax_fused = property(
+        lambda self: self.delivery.owner_minmax_fused)
+
     @property
     def ndev(self) -> int:
         """Devices this engine's state is placed over (1 = no mesh)."""
@@ -48,7 +66,7 @@ class AuditableEngine:
         return {"ndev": self.ndev,
                 "num_parts": int(sg.num_parts),
                 "vpad": int(sg.vpad),
-                "exchange": getattr(self, "exchange", None)}
+                "exchange": self.exchange}
 
     def _register_variant(self, name, jitted, args_thunk):
         """Expose one compiled loop variant to the static program

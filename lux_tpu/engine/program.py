@@ -70,7 +70,7 @@ class PullProgram:
                 error).  When set and the layout is tiled, the engine
                 computes the dot on the MXU from the destination TILE
                 (dst values are tile-positional, so the ~9 ns/edge dst
-                row-gather disappears; see PullEngine._part_step_dot).
+                row-gather disappears; see engine/delivery.py reduce_dot).
     state_bytes bytes per VERTEX of the iterated state (itemsize x
                 trailing dims), e.g. 80 for colfilter's [vpad, 20]
                 f32.  Feeds resolve_exchange's state-table size

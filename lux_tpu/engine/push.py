@@ -52,10 +52,9 @@ from jax.sharding import PartitionSpec
 from lux_tpu import telemetry
 from lux_tpu.engine import frontier as fr
 from lux_tpu.engine.auditable import AuditableEngine
+from lux_tpu.engine.delivery import Delivery
 from lux_tpu.engine.program import vmask_of
 from lux_tpu.graph import ShardedGraph
-from lux_tpu.ops.segment import segment_reduce
-from lux_tpu.ops.tiled import tiled_segment_reduce
 from lux_tpu.parallel.mesh import PARTS_AXIS, shard_over_parts
 from lux_tpu.partition import frontier_capacity
 
@@ -107,8 +106,8 @@ class PushEngine(AuditableEngine):
     is built (to dispatch, not to arrival)."""
 
     def __init__(self, sg: ShardedGraph, program: PushProgram, mesh=None,
-                 layout: str = "tiled", tile_w: int = 128,
-                 tile_e: int = 512, use_mxu: bool | str = "auto",
+                 layout: str = "tiled", tile_e: int = 512,
+                 use_mxu: bool | str = "auto",
                  enable_sparse: bool = True,
                  sparse_threshold: int = 16,
                  edge_budget: int | None = None,
@@ -122,18 +121,8 @@ class PushEngine(AuditableEngine):
                  gather: str = "flat",
                  owner_tile_e: int | None = None,
                  owner_minmax_fused: bool = False,
-                 stats_cap: int | None = None,
                  health: bool = False,
                  audit: str | None = None):
-        if mesh is not None and sg.num_parts % mesh.devices.size != 0:
-            raise ValueError(
-                f"num_parts={sg.num_parts} not divisible by mesh size "
-                f"{mesh.devices.size}")
-        from lux_tpu.engine.pull import (_check_local_parts,
-                                         resolve_exchange,
-                                         resolve_reduce_method,
-                                         resolve_use_mxu)
-        _check_local_parts(sg, mesh, pair_threshold)
         # query-batched labels [vpad, B] (program.batch = B): dense
         # masked iterations only — columns retire independently
         # through their own active masks; sparse queues, delta
@@ -145,22 +134,7 @@ class PushEngine(AuditableEngine):
                     "delta-stepping is single-query (one scalar "
                     "bucket bound); build batched engines with "
                     "delta=None")
-            if pair_threshold is not None:
-                raise ValueError(
-                    "pair_threshold does not support query-batched "
-                    "programs: pair delivery reads scalar vertex "
-                    "state (ops/pairs.pair_partial)")
             enable_sparse = False
-        # the auto-exchange table estimate is in BYTES of the whole
-        # label table — a B-wide batch is B tables
-        ident_dt = np.asarray(program.identity).dtype
-        exchange = resolve_exchange(
-            exchange, sg, program,
-            itemsize=ident_dt.itemsize * (self.batch or 1))
-        self.exchange = exchange
-        # fused (ring reduce-scatter) min/max owner exchange — opt-in,
-        # see ops/owner.owner_exchange
-        self.owner_minmax_fused = bool(owner_minmax_fused)
         if delta is not None:
             if program.reduce != "min":
                 raise ValueError("delta-stepping requires a 'min' program")
@@ -172,6 +146,19 @@ class PushEngine(AuditableEngine):
                 raise ValueError(
                     f"delta-stepping bucket width {delta!r} is not > 0 "
                     f"in label dtype {ldt}")
+        # The DENSE iterations' delivery (engine/delivery.py).  Pair
+        # rows and paged plans serve those only: the SPARSE path below
+        # keeps the FULL graph's src-sorted view — frontier expansion
+        # must see every edge — and the MXU flag rides its CSR-expand
+        # too (fr.expand_frontier use_mxu).
+        self.delivery, arrays = Delivery.build(
+            sg, program, mesh, layout=layout, tile_e=tile_e,
+            use_mxu=use_mxu, reduce_method=reduce_method,
+            pair_threshold=pair_threshold, pair_min_fill=pair_min_fill,
+            pair_stream=pair_stream, stream_msgs=stream_msgs,
+            exchange=exchange, gather=gather,
+            owner_tile_e=owner_tile_e,
+            owner_minmax_fused=owner_minmax_fused)
         self.sg = sg
         self.program = program
         self.mesh = mesh
@@ -180,66 +167,9 @@ class PushEngine(AuditableEngine):
         # variant (converge_health, compiled lazily); False leaves
         # every watchdog-free program untouched
         self.health = bool(health)
-        from lux_tpu.telemetry import DEFAULT_STATS_CAP
-        self.stats_cap = int(stats_cap or DEFAULT_STATS_CAP)
+        self.stats_cap = telemetry.DEFAULT_STATS_CAP
         self.sparse_threshold = sparse_threshold
-        self.reduce_method = resolve_reduce_method(reduce_method)
-        # MXU one-hot reduce (round 23, ops/tiled): auto-resolved from
-        # the program's K x B payload width; the sparse frontier's
-        # CSR-expand rides the same flag (fr.expand_frontier use_mxu)
-        self.use_mxu = resolve_use_mxu(use_mxu, program)
-        # Paged two-level gather for the DENSE iterations
-        # (ops/pagegather.py): page-binned rows + the Pallas lane
-        # shuffle replace the per-edge masked-label gather; the
-        # SPARSE path keeps the src-sorted view, like pairs below.
-        self.page_plan = None
-        self.gather = "flat"
-        if gather != "flat":
-            if gather in ("paged", "pagemajor") \
-                    and pair_threshold is not None:
-                raise ValueError(
-                    f"gather={gather!r} subsumes pair delivery (both "
-                    f"are row-granular layouts); build without "
-                    f"pair_threshold")
-            if pair_threshold is None:
-                from lux_tpu.ops.pagegather import engine_page_plan
-                self.page_plan = engine_page_plan(sg, gather, program,
-                                                  exchange)
-                if self.page_plan is not None:
-                    self.gather = self.page_plan.mode
-        # Pair-lane delivery for the DENSE iterations (ops/pairs.py):
-        # dense pair edges leave the per-edge gather path; the SPARSE
-        # path below keeps the FULL graph's src-sorted view — frontier
-        # expansion must see every edge.
-        self.pairs = None
-        dense_sg = sg
-        if pair_threshold is not None:
-            from lux_tpu.ops.pairs import plan_sharded_pairs
-            if layout != "tiled":
-                raise ValueError(
-                    "pair_threshold requires the tiled layout")
-            self.pairs, dense_sg = plan_sharded_pairs(
-                sg, pair_threshold, min_fill=pair_min_fill)
-        from lux_tpu.ops.pairs import resolve_pair_stream
-        from lux_tpu.ops.tiled import STREAM_MSG_BYTES
-        self.pair_stream = resolve_pair_stream(pair_stream, self.pairs)
-        # stream the dense iterations' gather+relax+partials once the
-        # [rows, C, E] candidate temporary passes the budget (same
-        # billion-edge OOM as the pull engine; PERF_NOTES ledger)
-        rows = len(sg.part_ids())
-        self.stream_chunks = (rows * dense_sg.epad * 4 > STREAM_MSG_BYTES
-                              if stream_msgs is None
-                              else bool(stream_msgs))
         dev = jnp.asarray if mesh is None else np.asarray
-        with telemetry.span("build.dense_layout"):
-            arrays = self._dense_layout(dev, dense_sg, layout, tile_w,
-                                        tile_e, owner_tile_e)
-        if self.pairs is not None:
-            arrays["pair_rowbind"] = dev(self.pairs.rowbind)
-            arrays["pair_rel"] = dev(self.pairs.rel_dst)
-            arrays["pair_tile_pos"] = dev(self.pairs.tile_pos)
-            if self.pairs.weight is not None:
-                arrays["pair_weight"] = dev(self.pairs.weight)
         self.enable_sparse = enable_sparse
         if enable_sparse:
             # The compressed source index's pad size is a compiled
@@ -291,55 +221,6 @@ class PushEngine(AuditableEngine):
             from lux_tpu import audit as _audit
             _audit.audit_engine(self, mode=audit)
 
-    def _dense_layout(self, dev, dense_sg, layout, tile_w, tile_e,
-                      owner_tile_e) -> dict:
-        """Arrays of the DENSE iterations' edge layout (paged plan,
-        owner chunks or tiled chunks), each through ``dev``; sets
-        ``self.owner`` / ``self.tiles``."""
-        sg = self.sg
-        if self.page_plan is not None:
-            # the paged plan IS the dense edge layout (sparse
-            # iterations keep the src-sorted view added below)
-            from lux_tpu.engine.pull import common_graph_arrays
-            from lux_tpu.ops.pagegather import plan_graph_arrays
-            self.owner = None
-            self.tiles = None
-            arrays = dict(
-                common_graph_arrays(dense_sg, dev),
-                **plan_graph_arrays(
-                    self.page_plan, dev,
-                    owner=self.exchange == "owner", dot=False,
-                    num_parts=sg.num_parts, vpad=sg.vpad))
-        elif self.exchange == "owner":
-            # dense iterations run owner-side (ops/owner.py): per-
-            # source-part small-shard gathers + reduce_scatter replace
-            # the label all_gather + big-table gather; the sparse path
-            # below is unchanged (queue exchange is already O(queue))
-            from lux_tpu.engine.pull import (_owner_edge_arrays,
-                                             common_graph_arrays)
-            from lux_tpu.ops.owner import OwnerLayout
-            self.owner = OwnerLayout.build(dense_sg, E=owner_tile_e or 256)
-            self.tiles = None
-            arrays = dict(
-                **common_graph_arrays(dense_sg, dev),
-                **_owner_edge_arrays(self.owner, dev),
-                own_cs=dev(self.owner.chunk_start),
-                own_lc=dev(self.owner.last_chunk))
-            if self.owner.weight is not None:
-                arrays["own_w"] = dev(self.owner.weight)
-            if self.owner.streams():
-                # fused streamed combine: never materializes [C, W]
-                ep, et = self.owner.extract_plan()
-                arrays["own_ep"] = dev(ep)
-                arrays["own_et"] = dev(et)
-        else:
-            from lux_tpu.engine.pull import build_graph_arrays
-            self.owner = None
-            arrays, self.tiles = build_graph_arrays(
-                dense_sg, layout, needs_dst=False, tile_w=tile_w,
-                tile_e=tile_e, device=self.mesh is None)
-        return arrays
-
     # ------------------------------------------------------------------
 
     def init_state(self):
@@ -390,121 +271,10 @@ class PushEngine(AuditableEngine):
         masked = jnp.where(full_active, full_label, ident_l)
         return masked.reshape((-1,) + masked.shape[2:])
 
-    def _dense_cand(self, flat_l, g):
-        """Phase 2 (relax): per-edge source gather + candidates."""
-        prog = self.program
-        ident_l = jnp.asarray(prog.identity, flat_l.dtype)
-        src_l = jnp.take(flat_l, g["src_slot"], axis=0)
-        cand = prog.relax(src_l, g.get("weight"))
-        ident = jnp.asarray(prog.identity, cand.dtype)
-        cand = jnp.where(src_l == ident_l, ident, cand)
-        return jax.lax.optimization_barrier(cand)
-
-    def _dense_red(self, flat_l, cand, g):
-        """Phase 3 (reduce): scatter-free segment reduction (+ the
-        pair-lane delivery, which fetches and reduces in one go).
-        cand=None: stream gather+relax+partials in chunk blocks
-        (billion-edge memory mode; PERF_NOTES ledger)."""
-        sg, prog, lay = self.sg, self.program, self.tiles
-        # relax + mask masked-source candidates back to the identity
-        # (shared by the streamed, pair, paged and owner deliveries)
-        msg = self._owner_msg(flat_l.dtype)
-
-        if self.page_plan is not None:
-            # paged two-level delivery (ops/pagegather.py): the page
-            # fetch + lane shuffle + compare-reduce replace both the
-            # masked-label gather and the tiled reduce (pg_vrs: the
-            # page-major plan's virtual-row binding)
-            from lux_tpu.ops.pagegather import paged_partial
-            return paged_partial(
-                self.page_plan, flat_l, g["pg_ids"], g["pg_sl"],
-                g["pg_rel"], g.get("pg_w"), g["pg_tp"], prog.reduce,
-                msg, reduce_method=self.reduce_method,
-                vrow_src=g.get("pg_vrs"))[:sg.vpad]
-        if cand is None:
-            from lux_tpu.ops.tiled import (combine_partials,
-                                           streamed_chunk_partials)
-            partials = streamed_chunk_partials(
-                flat_l, g["src_slot"], g["rel_dst"], g.get("weight"),
-                lay, prog.reduce, msg, self.reduce_method,
-                use_mxu=self.use_mxu)
-            red = combine_partials(partials, lay, g["chunk_start"],
-                                   g["last_chunk"], sg.vpad,
-                                   prog.reduce, use_mxu=self.use_mxu)
-        elif lay is None:
-            red = segment_reduce(cand, g["dst_local"], sg.vpad + 1,
-                                 prog.reduce)[:sg.vpad]
-        else:
-            red = tiled_segment_reduce(
-                cand, lay, g["chunk_start"], g["last_chunk"],
-                g["rel_dst"], sg.vpad, prog.reduce,
-                use_mxu=self.use_mxu,
-                method=("pallas"
-                        if self.reduce_method.startswith("pallas")
-                        else "xla"),
-                interpret=self.reduce_method == "pallas-interpret")
-        if self.pairs is not None:
-            from lux_tpu.ops.tiled import combine_op
-            red = combine_op(prog.reduce)(
-                red, self._pair_red(flat_l, g, msg))
-        return red
-
-    def _pair_red(self, flat_l, g, msg):
-        """Pair-lane delivery for one part -> [vpad] partial (shared
-        by the gather- and owner-exchange dense paths)."""
-        from lux_tpu.ops.pairs import (pair_partial,
-                                       pair_partial_streamed)
-
-        fn = pair_partial_streamed if self.pair_stream else pair_partial
-        return fn(
-            self.pairs, flat_l, g["pair_rowbind"], g["pair_rel"],
-            g.get("pair_weight"), g["pair_tile_pos"],
-            self.program.reduce, msg,
-            reduce_method=self.reduce_method)[:self.sg.vpad]
-
-    def _dense_update(self, old, red, g):
-        """Phase 4 (update): keep improvements, flag the new frontier
-        (per query on batched labels — the [vpad] vertex mask
-        broadcasts over the trailing query axis)."""
-        vm = vmask_of(g, self.sg.vpad)
-        vm = vm.reshape(vm.shape + (1,) * (red.ndim - 1))
-        improved = self.program.better(red, old) & vm
-        return jnp.where(improved, red, old), improved
-
-    _DENSE_KEYS = ("src_slot", "dst_local", "weight", "rel_dst",
-                   "chunk_start", "last_chunk", "chunk_tile", "nvp",
-                   "deg", "pair_rowbind", "pair_rel", "pair_weight",
-                   "pair_tile_pos", "pg_ids", "pg_sl", "pg_rel",
-                   "pg_w", "pg_tp", "pg_vrs")
-
-    @property
-    def _streams(self) -> bool:
-        return self.stream_chunks and self.tiles is not None
-
-    def _dense_parts(self, label, active, full_label, full_active, g):
-        with jax.named_scope("lux_exchange"):
-            flat_l = self._dense_flat(full_label, full_active)
-        # streamed and paged steps both fuse gather+relax+reduce into
-        # one delivery (the paged one: page fetch + lane shuffle +
-        # compare-reduce, ops/pagegather.py)
-        stream = self._streams or self.page_plan is not None
-
-        def one(old, g):
-            with jax.named_scope("lux_relax"):
-                cand = None if stream else self._dense_cand(flat_l, g)
-            with jax.named_scope("lux_reduce"):
-                red = self._dense_red(flat_l, cand, g)
-            with jax.named_scope("lux_update"):
-                return self._dense_update(old, red, g)
-
-        g = {k: g[k] for k in self._DENSE_KEYS if k in g}
-        return jax.vmap(one)(label, g)
-
-    # -- dense iteration, owner-side exchange (ops/owner.py) -----------
-
-    def _owner_msg(self, label_dtype):
-        """relax + mask identity-source candidates back to the
-        identity (same contract as _dense_cand/_dense_red's msg)."""
+    def _msg(self, label_dtype):
+        """The delivery's message function: relax, with masked
+        (identity) sources mapped back to the identity so they stay
+        absorbing whatever relax does to them."""
         prog = self.program
         ident_l = jnp.asarray(prog.identity, label_dtype)
 
@@ -515,73 +285,65 @@ class PushEngine(AuditableEngine):
 
         return msg
 
+    def _dense_cand(self, flat_l, g):
+        """Phase 2 (relax): per-edge source gather + candidates."""
+        cand = self.delivery.messages(flat_l, self._msg(flat_l.dtype),
+                                      g)
+        return jax.lax.optimization_barrier(cand)
+
+    def _dense_red(self, flat_l, cand, g):
+        """Phase 3 (reduce) -> [vpad, ...].  cand=None: the delivery
+        fuses gather+relax+reduce (streamed chunk blocks, the
+        billion-edge memory mode, PERF_NOTES ledger; paged rows)."""
+        d, msg = self.delivery, self._msg(flat_l.dtype)
+        if cand is None:
+            return d.reduce_fused(flat_l, msg, g)
+        return d.reduce(flat_l, cand, msg, g)
+
+    def _dense_update(self, old, red, g):
+        """Phase 4 (update): keep improvements, flag the new frontier
+        (per query on batched labels — the [vpad] vertex mask
+        broadcasts over the trailing query axis)."""
+        vm = vmask_of(g, self.sg.vpad)
+        vm = vm.reshape(vm.shape + (1,) * (red.ndim - 1))
+        improved = self.program.better(red, old) & vm
+        return jnp.where(improved, red, old), improved
+
+    def _dense_g(self, g):
+        """The dense iteration's arrays (the delivery's) out of the
+        step's: the sparse view's never ride its vmap."""
+        return {k: g[k] for k in self.delivery.keys}
+
+    def _dense_parts(self, label, active, full_label, full_active, g):
+        with jax.named_scope("lux_exchange"):
+            flat_l = self._dense_flat(full_label, full_active)
+        fused = self.delivery.fused
+
+        def one(old, g):
+            with jax.named_scope("lux_relax"):
+                cand = None if fused else self._dense_cand(flat_l, g)
+            with jax.named_scope("lux_reduce"):
+                red = self._dense_red(flat_l, cand, g)
+            with jax.named_scope("lux_update"):
+                return self._dense_update(old, red, g)
+
+        return jax.vmap(one)(label, self._dense_g(g))
+
     def _dense_parts_owner(self, label, active, g):
-        """One dense iteration with owner-side message generation:
-        each LOCAL source part masks its own label shard (inactive ->
-        identity, exactly _dense_flat's one-gather trick applied per
-        shard), gathers from it under the lax.scan, and routes
-        per-dst-part candidates through the all_to_all exchange —
-        no label/active all_gather at all (except for pair rows)."""
-        from lux_tpu.ops.owner import owner_contribs, owner_exchange
-
-        sg, prog = self.sg, self.program
-        on_mesh = self.mesh is not None
-        ident_l = jnp.asarray(prog.identity, label.dtype)
+        """One dense iteration with owner-side message generation
+        (delivery.owner_generate): each LOCAL source part masks its own
+        label shard (inactive -> identity, exactly _dense_flat's
+        one-gather trick applied per shard) — no label/active
+        all_gather at all (except for pair rows)."""
+        d = self.delivery
+        ident_l = jnp.asarray(self.program.identity, label.dtype)
         masked = jnp.where(active, label, ident_l)
-        msg = self._owner_msg(label.dtype)
-        msg_dtype = jax.eval_shape(
-            msg, jax.ShapeDtypeStruct((1, 1), label.dtype),
-            (jax.ShapeDtypeStruct((1, 1), jnp.float32)
-             if ("own_w" in g or "own_pg_w" in g or "own_pm_w" in g)
-             else None)).dtype
+        msg = self._msg(label.dtype)
         with jax.named_scope("lux_gen_exchange"):
-            if (self.page_plan is not None
-                    and self.page_plan.mode == "pagemajor"):
-                # page-major routing: complete message rows all_to_all
-                # to their destination parts, reduced receiver-side
-                # (ops/pagegather.pagemajor_owner_deliver) — the
-                # routing hop REPLACES the owner exchange
-                from lux_tpu.ops.pagegather import \
-                    pagemajor_owner_deliver
-                red = pagemajor_owner_deliver(
-                    self.page_plan, masked, g, prog.reduce, msg,
-                    msg_dtype, sg.num_parts, self.reduce_method,
-                    axis=PARTS_AXIS if on_mesh else None)
-            else:
-                if self.page_plan is not None:
-                    from lux_tpu.ops.pagegather import \
-                        paged_owner_contribs
-                    acc = paged_owner_contribs(
-                        self.page_plan, masked, g, prog.reduce, msg,
-                        msg_dtype, sg.num_parts, self.reduce_method)
-                else:
-                    acc = owner_contribs(
-                        self.owner, masked, g,
-                        prog.reduce, msg, msg_dtype, sg.num_parts,
-                        self.reduce_method, use_mxu=self.use_mxu)
-                red = owner_exchange(
-                    acc, prog.reduce,
-                    axis=PARTS_AXIS if on_mesh else None,
-                    ndev=1 if not on_mesh else self.mesh.devices.size,
-                    minmax_fused=self.owner_minmax_fused)
-        red = red[:, :sg.vpad]
-        if self.pairs is not None:
-            # pair rows fetch from the FULL masked table (row-granular
-            # fetches); the all_gather survives only for them
-            from lux_tpu.ops.tiled import combine_op
-
-            full = (masked if not on_mesh else
-                    jax.lax.all_gather(masked, PARTS_AXIS, tiled=True))
-            flat_l = full.reshape(-1)
-            pkeys = [k for k in ("pair_rowbind", "pair_rel",
-                                 "pair_weight", "pair_tile_pos")
-                     if k in g]
-            pred = jax.vmap(
-                lambda gp: self._pair_red(flat_l, gp, msg))(
-                {k: g[k] for k in pkeys})
-            red = combine_op(prog.reduce)(red, pred)
-        gd = {k: g[k] for k in self._DENSE_KEYS if k in g}
-        return jax.vmap(self._dense_update)(label, red, gd)
+            red = d.owner_generate(masked, msg, g)
+        red = d.owner_pairs(red, masked, msg, g)
+        return jax.vmap(self._dense_update)(label, red,
+                                            self._dense_g(g))
 
     # -- sparse iteration ----------------------------------------------
 
@@ -1246,34 +1008,28 @@ class PushEngine(AuditableEngine):
         from lux_tpu.engine.phased import cksum, mesh_wrap
 
         keys = sorted(self.arrays)
-        sg = self.sg
-        dkeys = [k for k in self._DENSE_KEYS if k in self.arrays]
 
         def gdict(gargs):
-            g = dict(zip(keys, gargs))
-            return {k: g[k] for k in dkeys}
+            return self._dense_g(dict(zip(keys, gargs)))
 
-        if self.exchange == "owner":
-            # owner mode has no separable gather phase: generation
-            # (scan over source parts) + reduce_scatter are one fused
-            # phase; update keeps its frontier-count fence
-            def gen_exchange(label, active, *gargs):
-                g = dict(zip(keys, gargs))
-                new, improved = self._dense_parts_owner(label, active,
-                                                        g)
-                cnt = jnp.sum(improved.astype(jnp.int32))
-                if self.mesh is not None:
-                    cnt = jax.lax.psum(cnt, PARTS_AXIS)
-                return (new, improved), cnt
-
-            fns = dict(gen_exchange=gen_exchange)
+        def count(improved):
+            # the fence doubles as the NEW global frontier count (psum'd
+            # under the mesh wrap's pmin — identical on every device).
+            # int32 keeps it exact past 2^24 active vertices (float32
+            # would round, misreporting 'frontier' and possibly the
+            # next iteration's sparse/dense classification)
+            cnt = jnp.sum(improved.astype(jnp.int32))
             if self.mesh is not None:
-                P = PartitionSpec
-                S, R = P(PARTS_AXIS), P()
-                wrap = mesh_wrap(self.mesh, len(keys), S, R)
-                fns = dict(gen_exchange=wrap(gen_exchange, (S, S),
-                                             (S, S)))
-            return {k: jax.jit(f) for k, f in fns.items()}
+                cnt = jax.lax.psum(cnt, PARTS_AXIS)
+            return cnt
+
+        def gen_exchange(label, active, *gargs):
+            # owner mode has no separable gather phase: generation
+            # (scan over source parts) + reduce_scatter + update are
+            # one fused phase
+            new, improved = self._dense_parts_owner(
+                label, active, dict(zip(keys, gargs)))
+            return (new, improved), count(improved)
 
         def exchange(label, active, *gargs):
             full_l, full_a = label, active
@@ -1285,56 +1041,47 @@ class PushEngine(AuditableEngine):
             return flat_l, cksum(flat_l)
 
         def relax(flat_l, *gargs):
-            g = gdict(gargs)
             cand = jax.vmap(
-                lambda gp: self._dense_cand(flat_l, gp))(g)
+                lambda gp: self._dense_cand(flat_l, gp))(gdict(gargs))
             return cand, cksum(cand)
 
         def reduce(flat_l, cand, *gargs):
-            g = gdict(gargs)
             red = jax.vmap(
-                lambda c, gp: self._dense_red(flat_l, c, gp))(cand, g)
+                lambda c, gp: self._dense_red(flat_l, c, gp))(
+                cand, gdict(gargs))
             return red, cksum(red)
 
         def relax_reduce(flat_l, *gargs):
-            # streamed engines fuse gather+relax+partials per chunk
-            # block; instrument it as ONE phase so the report matches
-            # the compiled step (and keeps its memory bound)
-            g = gdict(gargs)
+            # a fused delivery (streamed chunk blocks, paged rows) is
+            # ONE phase, so the report matches the compiled step (and
+            # keeps its memory bound)
             red = jax.vmap(
-                lambda gp: self._dense_red(flat_l, None, gp))(g)
+                lambda gp: self._dense_red(flat_l, None, gp))(
+                gdict(gargs))
             return red, cksum(red)
 
         def update(label, red, *gargs):
-            g = gdict(gargs)
-            new, improved = jax.vmap(self._dense_update)(label, red, g)
-            # fence doubles as the NEW global frontier count (psum'd
-            # under the mesh wrap's pmin — identical on every device).
-            # int32 keeps it exact past 2^24 active vertices (float32
-            # would round, misreporting 'frontier' and possibly the
-            # next iteration's sparse/dense classification)
-            cnt = jnp.sum(improved.astype(jnp.int32))
-            if self.mesh is not None:
-                cnt = jax.lax.psum(cnt, PARTS_AXIS)
-            return (new, improved), cnt
+            new, improved = jax.vmap(self._dense_update)(
+                label, red, gdict(gargs))
+            return (new, improved), count(improved)
 
-        streams = self._streams or self.page_plan is not None
-        if streams:
-            fns = dict(exchange=exchange, relax_reduce=relax_reduce,
-                       update=update)
+        P = PartitionSpec
+        S, R = P(PARTS_AXIS), P()
+        # name -> (fn, in_specs, out_spec) under the mesh wrap
+        if self.exchange == "owner":
+            phases = dict(gen_exchange=(gen_exchange, (S, S), (S, S)))
         else:
-            fns = dict(exchange=exchange, relax=relax, reduce=reduce,
-                       update=update)
+            mid = (dict(relax_reduce=(relax_reduce, (R,), S))
+                   if self.delivery.fused else
+                   dict(relax=(relax, (R,), S),
+                        reduce=(reduce, (R, S), S)))
+            phases = dict(exchange=(exchange, (S, S), R), **mid,
+                          update=(update, (S, S), (S, S)))
+        fns = {name: fn for name, (fn, _, _) in phases.items()}
         if self.mesh is not None:
-            P = PartitionSpec
-            S, R = P(PARTS_AXIS), P()
             wrap = mesh_wrap(self.mesh, len(keys), S, R)
-            fns = dict(exchange=wrap(exchange, (S, S), R),
-                       update=wrap(update, (S, S), (S, S)),
-                       **({"relax_reduce": wrap(relax_reduce, (R,), S)}
-                          if streams else
-                          {"relax": wrap(relax, (R,), S),
-                           "reduce": wrap(reduce, (R, S), S)}))
+            fns = {name: wrap(*phase)
+                   for name, phase in phases.items()}
         return {k: jax.jit(f) for k, f in fns.items()}
 
     def _sparse_mode(self):

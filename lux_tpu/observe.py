@@ -1,7 +1,8 @@
 """Performance observatory: calibrated measurement as a subsystem.
 
-The repo's numbers have been produced by ~20 one-off
-``scripts/profile_*.py`` runs and hand-assembled bench artifacts,
+The repo's numbers had been produced by ~20 one-off
+``scripts/profile_*.py`` runs (gone since PR 28; git history up to
+3651475) and hand-assembled bench artifacts,
 while PERF_NOTES documents standing measurement traps — run-to-run
 variance between processes, XLA loop-invariant hoisting, timing an
 asynchronous dispatch instead of the work — that have each burned a
@@ -210,7 +211,7 @@ def _page_resolve_method() -> str:
     """The paged resolution formulation this platform runs: the
     Pallas lane-shuffle kernel on real TPUs, the plain XLA
     take_along_axis everywhere else (matching the engines'
-    resolve_reduce_method split, engine/pull.py)."""
+    resolve_reduce_method split, engine/delivery.py)."""
     import jax
     return "pallas" if jax.default_backend() == "tpu" else "xla"
 
@@ -560,7 +561,7 @@ def _engine_model(eng, scale: float,
     # flag and its K x B payload width — with it the "reduce" phase
     # gets a modeled figure instead of None (unmodeled), so decompose
     # grades the contraction's drift like every other phase
-    from lux_tpu.engine.pull import mxu_wide_of
+    from lux_tpu.engine.delivery import mxu_wide_of
     return scalemodel.phase_model(
         engine=_engine_kind(eng), exchange=eng.exchange,
         ne=int(eng.sg.ne), nv=int(eng.sg.nv), kdim=kdim,

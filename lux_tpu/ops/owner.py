@@ -5,7 +5,7 @@ to every part and gathers per edge from the flattened ``[P*vpad]``
 table — the analogue of the reference's whole-region READ_ONLY
 requirement (reference pull_model.inl:454-461).  Past ~64-128 MB of
 table the XLA gather emitter steps from ~8.8 to ~14.6 ns/elem
-(scripts/profile_bigtable.py; a step, not locality decay — sorted
+(PERF_NOTES.md round 3; a step, not locality decay — sorted
 indices are WORSE), which capped every round-2 big-graph number at
 ~27 ns/edge.
 
@@ -24,7 +24,7 @@ structural cousin of the reference's per-source-part push processing
 - Contributions combine across source parts: on one chip a
   ``lax.scan`` accumulates them (measured 7.8-9.1 ns/elem vs 14.7 for
   both the flat AND the vmapped-batched gather — the scan is what
-  makes the emitter see the small table, scripts/profile_owner.py);
+  makes the emitter see the small table, PERF_NOTES.md round 3);
   on a mesh they ride a ``psum_scatter`` (sum) or ``all_to_all`` +
   local combine (min/max) over ICI, replacing the per-iteration
   all_gather entirely.
@@ -389,7 +389,7 @@ def owner_contribs(lay: OwnerLayout, state_rows, g: dict,
     """lax.scan over the locally-held SOURCE parts: each step gathers
     from ONE [vpad, ...] state shard (the scan is what makes the XLA
     emitter see the small table — a vmapped batched gather still pays
-    the big-table rate, scripts/profile_owner.py) and folds its
+    the big-table rate, PERF_NOTES.md round 3) and folds its
     [G, W] tile partials into the accumulated contribution
     ``[num_parts, n_tiles*W, ...]`` to every destination part.
 

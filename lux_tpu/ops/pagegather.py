@@ -1041,7 +1041,7 @@ def _lane_shuffle_pallas(rows, slot_lane, block_r: int = 512,
                          interpret: bool = False):
     """[R, 128] lane shuffle as a Pallas kernel — ``take_along_axis``
     axis=1 lowers to ``tpu.dynamic_gather`` dim 1, the measured 0.38
-    ns/elem primitive (PERF_NOTES round 2, scripts/profile_shuffle.py).
+    ns/elem primitive (PERF_NOTES round 2).
     R must be a multiple of 8 (PagedPlan pads to this)."""
     import jax
     from jax.experimental import pallas as pl

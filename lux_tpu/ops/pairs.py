@@ -17,8 +17,8 @@ Row layout: pair (s, t) with maximum per-source multiplicity m gets m
 rows; occurrence o of source lane c carries the o-th edge (s*128+c ->
 t*128+rel).  Unused lanes carry rel = -1 (matches no lane; int8).
 Rows are grouped per destination tile and depth-classed so the
-cross-row combine is a static reshape-reduce, like experiments/router.py's
-slotted classes.
+cross-row combine is a static reshape-reduce (the slotted classes of the
+routing study PERF_NOTES.md closed).
 
 Reference analogue: the CTA-shared staging of hub vertices in the
 reference's GPU kernels (reference colfilter_gpu.cu:41-102 stages a
@@ -928,7 +928,7 @@ def pair_partial_dot(sp: StackedPairPlan, state, rowbind, rel, weight,
 # S/T tiles + the [B, 128, 128] dot blocks + messages/partials).  The
 # [*, W, W] dot intermediate dominates for K < 128, so blocks land at
 # a few hundred rows — the same order as the monolithic path's
-# measured-best lax.map block (DOT_BLOCK_CHUNKS, engine/pull.py).
+# measured-best lax.map block (DOT_BLOCK_CHUNKS, engine/delivery.py).
 PAIR_DOT_BLOCK_BYTES = 64 << 20
 
 

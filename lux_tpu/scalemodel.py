@@ -41,7 +41,7 @@ from dataclasses import dataclass
 # (scale 21-26) because the owner layout pins the gather to the
 # small-shard regime and pair rows are row-granular.
 OWNER_SLOT_NS = 9.92     # scan gather + pallas partials + combine,
-                         # per padded owner slot ("profile_owner" table)
+                         # per padded owner slot (PERF_NOTES.md round 3)
 GATHER_SMALL_NS = 8.96   # per-edge gather, state table <= ~64 MB
 GATHER_BIG_NS = 14.6     # per-edge gather past the emitter step
 BIG_TABLE_BYTES = 96e6   # auto-exchange threshold (engine/pull.py)

@@ -292,7 +292,8 @@ def test_resolve_exchange_auto(graph):
     shape (dst-dependent, dot-path, local-parts)."""
     import dataclasses
 
-    from lux_tpu.engine.pull import OWNER_AUTO_BYTES, resolve_exchange
+    from lux_tpu.engine.delivery import (OWNER_AUTO_BYTES,
+                                         resolve_exchange)
 
     sg = ShardedGraph.build(graph, 4)
     prog = pagerank.make_program()
@@ -354,7 +355,7 @@ def test_owner_local_parts_engine(graph, ref5):
     """exchange='owner' on a local-parts build (the multi-host code
     path, degenerate single-process cover) matches the oracle, and
     'auto' no longer silently degrades to gather there."""
-    from lux_tpu.engine.pull import resolve_exchange
+    from lux_tpu.engine.delivery import resolve_exchange
 
     mesh = make_mesh(8)
     sg = ShardedGraph.build(graph, 8, parts=range(8))
@@ -364,7 +365,7 @@ def test_owner_local_parts_engine(graph, ref5):
     np.testing.assert_allclose(out, ref5, rtol=1e-5, atol=1e-8)
     # the auto rule now treats local-parts builds as eligible
     import dataclasses
-    from lux_tpu.engine.pull import OWNER_AUTO_BYTES
+    from lux_tpu.engine.delivery import OWNER_AUTO_BYTES
     big = dataclasses.replace(
         sg, vpad=OWNER_AUTO_BYTES // (sg.num_parts * 4) + 1)
     assert resolve_exchange("auto", big,
