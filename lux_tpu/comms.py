@@ -474,12 +474,18 @@ def oracle_for(eng) -> list:
     out += dense
 
     if use_sparse:
-        Q = int(eng.queue_cap)
-        out.append(entry("all_gather", (P_local, Q), np.int32,
-                         branch="sparse"))
-        out.append(entry("all_gather", (P_local, Q), lab_dt,
-                         branch="sparse"))
-        out.append(entry("pmin", (), np.int32, branch="sparse"))
+        # the ladder (engine/frontier.py): one alternative per queue
+        # rung, holding the queue exchange at that rung's size, the
+        # pmax'd out-edge total that picks the budget rung and the
+        # pmin'd processed prefix
+        for i, Q in enumerate(eng.queue_rungs):
+            rung = f"sparse#q{i}"
+            out.append(entry("all_gather", (P_local, Q), np.int32,
+                             branch=rung))
+            out.append(entry("all_gather", (P_local, Q), lab_dt,
+                             branch=rung))
+            out.append(entry("pmax", (), np.int32, branch=rung))
+            out.append(entry("pmin", (), np.int32, branch=rung))
     del jax
     return out
 

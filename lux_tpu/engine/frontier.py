@@ -14,10 +14,29 @@ shapes, so the design is:
   out-edges — a fixed edge budget ``EB`` of work instead of a full
   pass over every edge.
 - Queue capacity mirrors the reference's sizing rule
-  (``part_nv/SPARSE_THRESHOLD + 100``, push_model.inl:393-397); the
-  caller falls back to the dense step (lax.cond) when the frontier
-  overflows either the queue or the edge budget, which is exactly the
-  reference's sparse->dense overflow transition (sssp_gpu.cu:485-490).
+  (``part_nv/SPARSE_THRESHOLD + 100``, push_model.inl:393-397).  The
+  caller (engine/push.py) takes the dense step (``lax.cond``) only
+  when the frontier's COUNT overflows the queue or passes nv/16.  A
+  frontier whose out-edges overflow the edge budget is NOT
+  re-densified the way the reference does (sssp_gpu.cu:485-490): the
+  iteration is TRUNCATED — the queue prefix whose edges fit is
+  relaxed and cleared, the rest stays active for the next iteration
+  (``expand_extents`` masks past the budget, the caller's ``done``
+  prefix does the clearing).
+- Both static shapes are the TOP of a short ladder (``rungs``): every
+  gather, scatter and scan below costs per SLOT, real or not, so a
+  root with five out-edges on a 4 M-slot budget pays for 4 M.  The
+  work is therefore split where the sizes split: ``mask_ranks`` is
+  [vpad]-sized and the same on every rung; ``pick_queue`` and
+  ``frontier_extents`` are queue-sized and end in the frontier's real
+  out-edge ``total``; ``expand_extents`` is budget-sized.  The caller
+  picks the smallest queue rung that holds the frontier's count, then
+  the smallest budget rung that holds ``total`` (``rung_index``,
+  scalars chosen outside the per-part vmap so each branch stays a
+  branch).  A lower rung never truncates, so the ladder changes the
+  shapes an iteration runs on and nothing else.  Rungs are few: each
+  is one more compiled copy of its stage, and compiled code lives in
+  device memory.
 - Labels ride along with vertex ids in the queue (the reference
   gathers them from the all-parts dist region instead), so multi-chip
   sparse iterations exchange O(queue) bytes over ICI, not O(nv).
@@ -37,6 +56,13 @@ from lux_tpu.parallel.mesh import vary_like
 # int8 lower-triangular [B, B] matrix (64 KB) contracted per block,
 # same sizing rationale as ops/tiled.MXU_SCAN_BLOCK.
 FRONTIER_MXU_BLOCK = 256
+
+# jnp.searchsorted's method for the queue-sized binary searches: the
+# rolled loop.  Unrolled ("scan_unrolled") it runs in the same time
+# and compiles to 25 MB more code on a 2 M-vertex part, a third of the
+# whole push program, and compiled code is device memory (PERF.md,
+# PR 29).
+SEARCH = "scan"
 
 
 def _cumsum_matmul(x, block: int = FRONTIER_MXU_BLOCK):
@@ -67,6 +93,32 @@ def _cumsum_matmul(x, block: int = FRONTIER_MXU_BLOCK):
     return blocks.reshape(Np)[:N]
 
 
+def mask_ranks(mask):
+    """Dense bool mask [vpad] -> (ranks int32 [vpad], count int32):
+    the 1-based running count of set bits and its total — the
+    [vpad]-sized half of ``compact_mask``, the same on every queue
+    rung."""
+    with jax.named_scope("lux_sparse_compact"):
+        ranks = jnp.cumsum(mask.astype(jnp.int32))      # 1-based
+        return ranks, ranks[-1]
+
+
+def pick_queue(ranks, labels, capacity: int):
+    """The queue-sized half: (ids int32 [capacity], vals [capacity])
+    of the first ``capacity`` set bits behind ``mask_ranks``' ranks.
+    ids[i] for i >= count is vpad (an invalid slot)."""
+    with jax.named_scope("lux_sparse_compact"):
+        vpad = ranks.shape[0]
+        # i-th set bit = first position whose running count reaches
+        # i+1; vectorized binary search over the monotone ranks array.
+        want = jnp.arange(capacity, dtype=jnp.int32) + 1
+        ids = jnp.searchsorted(ranks, want, side="left",
+                               method=SEARCH).astype(jnp.int32)
+        ids = jnp.where(want <= ranks[-1], ids, vpad)
+        vals = jnp.take(labels, jnp.minimum(ids, vpad - 1), axis=0)
+        return ids, vals
+
+
 def compact_mask(mask, labels, capacity: int):
     """Dense bool mask [vpad] -> padded queue.
 
@@ -75,53 +127,75 @@ def compact_mask(mask, labels, capacity: int):
     position < count.  If count > capacity the queue is truncated —
     callers must branch to the dense path in that case.
     """
-    with jax.named_scope("lux_sparse_compact"):
-        vpad = mask.shape[0]
-        ranks = jnp.cumsum(mask.astype(jnp.int32))      # 1-based
-        count = ranks[-1]
-        # i-th set bit = first position whose running count reaches
-        # i+1; vectorized binary search over the monotone ranks array.
-        want = jnp.arange(capacity, dtype=jnp.int32) + 1
-        ids = jnp.searchsorted(ranks, want, side="left",
-                               method="scan_unrolled").astype(jnp.int32)
-        valid = want <= count
-        ids = jnp.where(valid, ids, vpad)
-        vals = jnp.take(labels, jnp.minimum(ids, vpad - 1), axis=0)
-        return ids, vals, count
+    ranks, count = mask_ranks(mask)
+    ids, vals = pick_queue(ranks, labels, capacity)
+    return ids, vals, count
 
 
-def expand_frontier(ids, vals, src_ids, src_off, nv: int,
-                    edge_budget: int, use_mxu: bool = False):
-    """Map a gathered queue to its out-edge slots in this part.
+def rungs(top: int, divisors=()) -> tuple:
+    """The ladder of one static shape: ``top`` and its fixed fractions
+    ``top // d``, ascending, distinct, none under 1.  The last rung is
+    ``top`` itself."""
+    return tuple(sorted({int(top)} | {max(1, int(top) // int(d))
+                                      for d in divisors}))
+
+
+def rung_index(need, ladder):
+    """int32 index of the smallest rung of the ascending ``ladder``
+    that holds ``need``; the top rung when none does."""
+    idx = jnp.int32(0)
+    for r in ladder[:-1]:
+        idx = idx + (need > r).astype(jnp.int32)
+    return idx
+
+
+def frontier_extents(ids, src_ids, src_off, nv: int):
+    """The queue-sized half of the expansion: where each queue item's
+    out-edges lie in this part.
 
     ids     int32 [Q]   vertex GLOBAL ids (graph numbering), nv=invalid
-    vals    [Q]         the queue vertices' labels
     src_ids int32 [S]   this part's present-source ids, sorted, pad=nv
     src_off int32 [S+1] END offsets into the part's src-sorted edge
                         arrays (ShardedGraph.src_sorted — the
                         compressed replacement for the reference's
                         nv-wide row pointers, push_model.inl:321-324)
-    Returns (edge_idx int32 [EB], src_val [EB], in_range bool [EB],
-             total int32, off int32 [Q]) where edge_idx indexes the
-    part's src-sorted edge arrays, src_val is the owning queue item's
-    label, off is the running END offset of each queue item's out-edge
-    extent (off[-1] == total), and total is the real number of
-    frontier out-edges here (may exceed EB — callers must then use the
-    dense path; entries past ``total`` are masked by in_range).
+    Returns (begin int32 [Q], off int32 [Q], total int32): begin is
+    each item's first slot in the part's src-sorted edge arrays, off
+    the running END offset of its extent among the frontier's
+    out-edges (so its degree is ``diff(off)``), and ``total ==
+    off[-1]`` the real number of frontier out-edges here — what the
+    caller sizes the budget stage by.
     """
     with jax.named_scope("lux_sparse_expand"):
-        Q = ids.shape[0]
         S = src_ids.shape[0]
         # binary-search each queue id in the compressed source index
         pos = jnp.searchsorted(src_ids, ids, side="left",
-                               method="scan_unrolled")
+                               method=SEARCH)
         posc = jnp.minimum(pos, S - 1).astype(jnp.int32)
         present = (jnp.take(src_ids, posc, axis=0) == ids) & (ids < nv)
         begin = jnp.where(present, jnp.take(src_off, posc, axis=0), 0)
         end = jnp.where(present, jnp.take(src_off, posc + 1, axis=0), 0)
-        deg = (end - begin).astype(jnp.int32)
-        off = jnp.cumsum(deg)                   # END offsets per item
+        off = jnp.cumsum((end - begin).astype(jnp.int32))
+        return begin, off, off[-1]
+
+
+def expand_extents(vals, begin, off, edge_budget: int,
+                   use_mxu: bool = False):
+    """The budget-sized half: one slot per frontier out-edge, up to
+    ``edge_budget`` of them.
+
+    vals [Q] are the queue items' labels; begin, off are
+    ``frontier_extents``'.  Returns (edge_idx int32 [EB], src_val
+    [EB], in_range bool [EB]): edge_idx indexes the part's src-sorted
+    edge arrays, src_val is the owning queue item's label, and slots
+    past ``min(off[-1], EB)`` are masked by in_range (with more
+    out-edges than slots the expansion is a prefix: the caller keeps
+    the un-expanded queue suffix active).
+    """
+    with jax.named_scope("lux_sparse_expand"):
+        Q = begin.shape[0]
         total = off[-1]
+        deg = jnp.diff(off, prepend=jnp.zeros((1,), off.dtype))
         start = off - deg                       # begin offset per item
         # Owner of each edge slot via the CSR-expand trick: drop each
         # item's 1-based queue index at its first slot, then a running
@@ -165,7 +239,21 @@ def expand_frontier(ids, vals, src_ids, src_off, nv: int,
                     + within).astype(jnp.int32)
         edge_idx = jnp.where(in_range, edge_idx, 0)
         src_val = jnp.take(vals, owner, axis=0)
-        return edge_idx, src_val, in_range, total, off
+        return edge_idx, src_val, in_range
+
+
+def expand_frontier(ids, vals, src_ids, src_off, nv: int,
+                    edge_budget: int, use_mxu: bool = False):
+    """Map a gathered queue to its out-edge slots in this part:
+    ``frontier_extents`` then ``expand_extents`` on one budget.
+    Returns (edge_idx int32 [EB], src_val [EB], in_range bool [EB],
+    total int32, off int32 [Q]); ``total`` may exceed EB (the
+    expansion is then a prefix, see ``expand_extents``).
+    """
+    begin, off, total = frontier_extents(ids, src_ids, src_off, nv)
+    edge_idx, src_val, in_range = expand_extents(
+        vals, begin, off, edge_budget, use_mxu=use_mxu)
+    return edge_idx, src_val, in_range, total, off
 
 
 def scatter_reduce(labels, dst_local, cand, kind: str):

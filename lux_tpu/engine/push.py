@@ -24,6 +24,12 @@ The TPU-native design:
   The cond predicate is replicated (a psum), so the branch stays a
   branch — it is deliberately hoisted OUTSIDE the per-part vmap,
   where it would decay into select-both-sides.
+- A sparse iteration costs per SLOT of its two static shapes (queue
+  and edge budget), filled or not, so both are the top of a short
+  ladder of rungs and each iteration runs on the smallest rungs that
+  hold its frontier's count and out-edge total — quantities the loop
+  observes, no option (engine/frontier.py).  Lower rungs never
+  truncate: the iteration sequence is the top rung's.
 - Sparse overflow safety: when a frontier's out-edges exceed the
   static edge budget, the un-expanded queue suffix simply STAYS
   ACTIVE (the globally-agreed processed prefix is cleared via a
@@ -57,6 +63,14 @@ from lux_tpu.engine.program import vmask_of
 from lux_tpu.graph import ShardedGraph
 from lux_tpu.parallel.mesh import PARTS_AXIS, shard_over_parts
 from lux_tpu.partition import frontier_capacity
+
+
+# The sparse iteration's ladder (engine/frontier.py): the lower rungs
+# of the queue and of the edge budget, as divisors of the top one.
+# One lower rung each: a rung more is a compiled copy more of its
+# stage for every rung of the other shape (PERF.md, PR 29).
+QUEUE_RUNG_DIVISORS = (8,)
+BUDGET_RUNG_DIVISORS = (16,)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -198,6 +212,14 @@ class PushEngine(AuditableEngine):
             default_eb = max(1024, sg.epad // sparse_threshold)
             self.edge_budget = int(edge_budget if edge_budget is not None
                                    else max(default_eb, max_deg + 128))
+            # Both are the TOP of a ladder of static shapes; an
+            # iteration runs on the smallest rungs that hold its
+            # frontier (_sparse_parts).  Only the top rung truncates,
+            # so only it must cover a hub.
+            self.queue_rungs = fr.rungs(self.queue_cap,
+                                        QUEUE_RUNG_DIVISORS)
+            self.budget_rungs = fr.rungs(self.edge_budget,
+                                         BUDGET_RUNG_DIVISORS)
             arrays = dict(arrays,
                           src_ids=dev(ss["src_ids"]),
                           src_off=dev(ss["src_off"]),
@@ -347,84 +369,115 @@ class PushEngine(AuditableEngine):
 
     # -- sparse iteration ----------------------------------------------
 
-    def _sparse_parts(self, label, active, g, gather_fn, pmin_fn):
-        """One frontier-queue iteration over this device's parts.
+    def _sparse_parts(self, label, active, count, g, gather_fn,
+                      pmin_fn, pmax_fn):
+        """One frontier-queue iteration over this device's parts, on
+        the smallest static shapes that hold the frontier (the ladder,
+        engine/frontier.py) -> (label, active, 1 if the edge budget
+        was a lower rung else 0).
 
-        gather_fn concatenates per-part queue arrays across the whole
-        mesh (identity + reshape on a single device); pmin_fn reduces a
-        scalar with min across the mesh.
+        count is the frontier's global size (it fits the top queue:
+        _sparse_mode).  gather_fn concatenates per-part queue arrays
+        across the whole mesh (identity + reshape on a single device);
+        pmin_fn / pmax_fn reduce a scalar across the mesh.  Both rung
+        indices are replicated scalars picked OUTSIDE the per-part
+        vmap, where a switch would decay into select-every-branch; the
+        branches hold the collectives, so every device takes the same.
         """
         sg, prog = self.sg, self.program
-        Q, EB = self.queue_cap, self.edge_budget
         nv = sg.nv
-
-        # 1. compact each local part's mask into a (global id, label)
-        #    queue.
-        def compact(mask, lab, start):
-            ids, vals, cnt = fr.compact_mask(mask, lab, Q)
-            gids = jnp.where(ids < sg.vpad, start[0] + ids, nv)
-            return gids.astype(jnp.int32), vals, cnt
-
-        gids, vals, cnts = jax.vmap(compact)(
-            active, label, g["part_start"])
-
-        # 2. exchange queues: [P_total * Q] flat, part-major order
-        #    (identical on every device).
-        all_gids = gather_fn(gids).reshape(-1)
-        all_vals = gather_fn(vals).reshape(-1)
-
-        # 3. each part relaxes the gathered frontier's edges that land
-        #    in its partition, through its compressed src-sorted view.
-        def relax_part(lab, sids, soff, ssd, ssw):
-            edge_idx, src_val, in_range, _total, off = fr.expand_frontier(
-                all_gids, all_vals, sids, soff, nv, EB,
-                use_mxu=self.use_mxu)
-            dst = jnp.take(ssd, edge_idx, axis=0)
-            w = jnp.take(ssw, edge_idx, axis=0) if ssw is not None \
-                else None
-            cand = prog.relax(src_val, w)
-            ident = jnp.asarray(prog.identity, cand.dtype)
-            cand = jnp.where(in_range & (dst < sg.vpad), cand, ident)
-            dst = jnp.where(in_range, dst, sg.vpad - 1)
-            new = fr.scatter_reduce(lab, dst, cand, prog.reduce)
-            improved = prog.better(new, lab)
-            # number of fully-expanded queue items (flat prefix)
-            done = jnp.searchsorted(off, jnp.asarray(EB, off.dtype),
-                                    side="right",
-                                    method="scan_unrolled")
-            return new, improved, done.astype(jnp.int32)
-
         ssw = g.get("ss_weight")
-        if ssw is None:
-            new_label, improved, done = jax.vmap(
-                lambda lab, sids, soff, ssd: relax_part(
-                    lab, sids, soff, ssd, None))(
-                label, g["src_ids"], g["src_off"], g["ss_dst"])
-        else:
-            new_label, improved, done = jax.vmap(relax_part)(
-                label, g["src_ids"], g["src_off"], g["ss_dst"], ssw)
-        improved = improved & vmask_of(g, sg.vpad)
-
-        # 4. clear the globally-agreed processed prefix of the queue;
-        #    everything else stays active (truncation safety).
-        done_min = pmin_fn(jnp.min(done))
-
-        # ids are global; convert back to local slots for clearing
-        def clear_local(mask, gid, cnt, start, pidx):
-            pos = jnp.arange(Q, dtype=jnp.int32)
-            flat_base = pidx * Q
-            processed = (flat_base + pos < done_min) & (pos < cnt) & \
-                (gid < nv)
-            loc = jnp.clip(gid - start[0], 0, sg.vpad - 1)
-            upd = jnp.zeros((sg.vpad,), bool).at[loc].max(
-                processed, mode="drop")
-            return mask & ~upd
-
         pidx = self._part_index()
-        cleared = jax.vmap(clear_local)(active, gids, cnts,
-                                        g["part_start"], pidx)
-        new_active = improved | cleared
-        return new_label, new_active
+        ranks, cnts = jax.vmap(fr.mask_ranks)(active)
+
+        def on_queue(Q):
+            # 1. compact each local part's mask into a (global id,
+            #    label) queue.
+            def compact(ranks, lab, start):
+                ids, vals = fr.pick_queue(ranks, lab, Q)
+                gids = jnp.where(ids < sg.vpad, start[0] + ids, nv)
+                return gids.astype(jnp.int32), vals
+
+            gids, vals = jax.vmap(compact)(ranks, label,
+                                           g["part_start"])
+
+            # 2. exchange queues: [P_total * Q] flat, part-major order
+            #    (identical on every device).
+            all_gids = gather_fn(gids).reshape(-1)
+            all_vals = gather_fn(vals).reshape(-1)
+
+            # 3. where the gathered frontier's out-edges lie in each
+            #    part's compressed src-sorted view, and how many they
+            #    are: the most any part holds picks the budget rung.
+            begin, off, total = jax.vmap(
+                lambda sids, soff: fr.frontier_extents(
+                    all_gids, sids, soff, nv))(
+                g["src_ids"], g["src_off"])
+            eb_rung = fr.rung_index(pmax_fn(jnp.max(total)),
+                                    self.budget_rungs)
+
+            # 4. each part relaxes the frontier's edges that land in
+            #    its partition.
+            def on_budget(EB):
+                def relax_part(lab, begin, off, ssd, ssw):
+                    edge_idx, src_val, in_range = fr.expand_extents(
+                        all_vals, begin, off, EB, use_mxu=self.use_mxu)
+                    dst = jnp.take(ssd, edge_idx, axis=0)
+                    w = jnp.take(ssw, edge_idx, axis=0) \
+                        if ssw is not None else None
+                    cand = prog.relax(src_val, w)
+                    ident = jnp.asarray(prog.identity, cand.dtype)
+                    cand = jnp.where(in_range & (dst < sg.vpad), cand,
+                                     ident)
+                    dst = jnp.where(in_range, dst, sg.vpad - 1)
+                    new = fr.scatter_reduce(lab, dst, cand, prog.reduce)
+                    improved = prog.better(new, lab)
+                    # number of fully-expanded queue items (flat
+                    # prefix): all of them on a lower rung
+                    done = jnp.searchsorted(
+                        off, jnp.asarray(EB, off.dtype), side="right",
+                        method=fr.SEARCH)
+                    return new, improved, done.astype(jnp.int32)
+
+                if ssw is None:
+                    return jax.vmap(
+                        lambda lab, begin, off, ssd: relax_part(
+                            lab, begin, off, ssd, None))(
+                        label, begin, off, g["ss_dst"])
+                return jax.vmap(relax_part)(label, begin, off,
+                                            g["ss_dst"], ssw)
+
+            new_label, improved, done = jax.lax.switch(
+                eb_rung, [jax.named_scope(f"lux_eb{i}")(
+                    functools.partial(on_budget, EB))
+                    for i, EB in enumerate(self.budget_rungs)])
+            improved = improved & vmask_of(g, sg.vpad)
+
+            # 5. clear the globally-agreed processed prefix of the
+            #    queue; everything else stays active (truncation
+            #    safety).
+            done_min = pmin_fn(jnp.min(done))
+
+            # ids are global; convert back to local slots for clearing
+            def clear_local(mask, gid, cnt, start, pidx):
+                pos = jnp.arange(Q, dtype=jnp.int32)
+                flat_base = pidx * Q
+                processed = (flat_base + pos < done_min) & \
+                    (pos < cnt) & (gid < nv)
+                loc = jnp.clip(gid - start[0], 0, sg.vpad - 1)
+                upd = jnp.zeros((sg.vpad,), bool).at[loc].max(
+                    processed, mode="drop")
+                return mask & ~upd
+
+            cleared = jax.vmap(clear_local)(active, gids, cnts,
+                                            g["part_start"], pidx)
+            low = eb_rung < len(self.budget_rungs) - 1
+            return new_label, improved | cleared, low.astype(jnp.int32)
+
+        return jax.lax.switch(
+            fr.rung_index(count, self.queue_rungs),
+            [jax.named_scope(f"lux_q{i}")(functools.partial(on_queue, Q))
+             for i, Q in enumerate(self.queue_rungs)])
 
     def _part_index(self):
         """Global part index of this device's parts [P_local] int32."""
@@ -486,6 +539,11 @@ class PushEngine(AuditableEngine):
                 return jax.lax.pmin(x, PARTS_AXIS)
             return x
 
+        def pmax_fn(x):
+            if on_mesh:
+                return jax.lax.pmax(x, PARTS_AXIS)
+            return x
+
         def replicate_parts(x):
             """Per-local-part counters [P_local] -> the full [P] row,
             IDENTICAL on every device.  A psum of each device's rows
@@ -544,26 +602,31 @@ class PushEngine(AuditableEngine):
             return self._dense_parts(label, active, full_l, full_a, g)
 
         def body(label, active, count, g):
-            """-> (label, active, 1 if the SPARSE branch ran else 0):
-            the int32 the loops sum into their ``sparse_iters``
-            carry (the one device-side counter telemetry reads)."""
+            """-> (label, active, took): took = int32 [2], 1 if the
+            SPARSE branch ran and 1 if it ran below the top edge
+            budget — what the loops sum into their ``sparse_iters`` /
+            ``low_rung_iters`` carry (the device-side counters
+            telemetry reads)."""
             if not use_sparse:
-                return (*dense_body(label, active, g), jnp.int32(0))
+                return (*dense_body(label, active, g),
+                        jnp.zeros((2,), jnp.int32))
 
             # Reference heuristic: frontier > nv/16 -> dense/pull mode
             # (sssp_gpu.cu:414), and the queue must fit (_sparse_mode).
             def sparse_branch():
                 with jax.named_scope("lux_sparse"):
-                    return self._sparse_parts(label, active, g,
-                                              gather_fn, pmin_fn)
+                    return self._sparse_parts(label, active, count, g,
+                                              gather_fn, pmin_fn,
+                                              pmax_fn)
 
             def dense_branch():
                 with jax.named_scope("lux_dense"):
-                    return dense_body(label, active, g)
+                    return (*dense_body(label, active, g), jnp.int32(0))
 
             q_fits = count <= jnp.int32(sparse_limit)
-            return (*jax.lax.cond(q_fits, sparse_branch, dense_branch),
-                    q_fits.astype(jnp.int32))
+            nl, na, low = jax.lax.cond(q_fits, sparse_branch,
+                                       dense_branch)
+            return nl, na, jnp.stack([q_fits.astype(jnp.int32), low])
 
         use_delta = converge and self.delta is not None
 
@@ -631,8 +694,9 @@ class PushEngine(AuditableEngine):
                 # active, raising B eventually makes the frontier
                 # non-empty.
                 # carry: (it, lbl, act, B, cnt, [4 stats buffers],
-                # [health word, stall], sparse_iters) — the counter
-                # rides LAST so every index before it stands
+                # [health word, stall], took) — the counters
+                # (sparse_iters, low_rung_iters: body's int32 [2])
+                # ride LAST so every index before it stands
                 def cond(c):
                     it, lbl, act, B, cnt = c[:5]
                     ok = (cnt > 0) & (it < max_iters)
@@ -708,13 +772,14 @@ class PushEngine(AuditableEngine):
                         jnp.zeros((cap_n, sg.num_parts), jnp.uint32))
                 if health:
                     init = init + (h0, stall0)
-                out = jax.lax.while_loop(cond, wbody,
-                                         init + (jnp.int32(0),))
-                # (lbl, act, it, [stats], [health], sparse_iters)
-                return (out[1], out[2], out[0], *out[5:])
+                out = jax.lax.while_loop(
+                    cond, wbody, init + (jnp.zeros((2,), jnp.int32),))
+                # (lbl, act, it, [stats], [health], sparse_iters,
+                # low_rung_iters)
+                return (out[1], out[2], out[0], *out[5:-1], *out[-1])
 
             # carry: (it, lbl, act, cnt, [4 stats buffers], [health
-            # word, stall], sparse_iters) — the counter rides LAST
+            # word, stall], took) — the counters ride LAST
             def cond(c):
                 it, lbl, act, cnt = c[:4]
                 ok = (cnt > 0) & (it < max_iters)
@@ -761,10 +826,11 @@ class PushEngine(AuditableEngine):
                     jnp.zeros((cap_n, sg.num_parts), jnp.uint32))
             if health:
                 init = init + (h0, stall0)
-            out = jax.lax.while_loop(cond, wbody,
-                                     init + (jnp.int32(0),))
-            # (lbl, act, it, [stats], [health], sparse_iters)
-            return (out[1], out[2], out[0], *out[4:])
+            out = jax.lax.while_loop(
+                cond, wbody, init + (jnp.zeros((2,), jnp.int32),))
+            # (lbl, act, it, [stats], [health], sparse_iters,
+            # low_rung_iters)
+            return (out[1], out[2], out[0], *out[4:-1], *out[-1])
 
         if prog.name:
             inner = jax.named_scope(f"lux_{prog.name}")(inner)
@@ -781,8 +847,9 @@ class PushEngine(AuditableEngine):
                 # psum/pmin'd scalars, identical on every device
                 out_specs = out_specs + (P(), P())
             if converge:
-                # sparse_iters sums a predicate of the psum'd count
-                out_specs = out_specs + (P(),)
+                # sparse_iters / low_rung_iters sum predicates of the
+                # psum'd count and the pmax'd out-edge total
+                out_specs = out_specs + (P(), P())
             in_specs = (P(PARTS_AXIS), P(PARTS_AXIS), P())
             if health:
                 in_specs = in_specs + (P(), P())    # h0, stall0
@@ -826,11 +893,12 @@ class PushEngine(AuditableEngine):
                      watch=None):
                 if watch is None:
                     watch = (_hw.init_word(), jnp.int32(0))
-                l, a, it, fsz, fed, fszp, fedp, h, stall, ns = jitted(
+                (l, a, it, fsz, fed, fszp, fedp, h, stall, ns,
+                 nlow) = jitted(
                     label, active, jnp.int32(max_iters), *watch,
                     *extra, *graph_args)
                 telemetry.mark("push.converge", iters=it,
-                               sparse_iters=ns)
+                               sparse_iters=ns, low_rung_iters=nlow)
                 return l, a, it, fsz, fed, fszp, fedp, (h, stall)
 
             return call
@@ -838,15 +906,17 @@ class PushEngine(AuditableEngine):
         def call(label, active, max_iters=np.iinfo(np.int32).max):
             """One dispatch; stays asynchronous.  A converge variant
             leaves a ``push.converge`` mark whose ``iters`` /
-            ``sparse_iters`` are the un-fetched device scalars
-            (fetched at ``telemetry.spans()``, never here)."""
+            ``sparse_iters`` / ``low_rung_iters`` (the sparse
+            iterations whose edge budget was a lower rung) are the
+            un-fetched device scalars (fetched at
+            ``telemetry.spans()``, never here)."""
             out = jitted(label, active, jnp.int32(max_iters), *extra,
                          *graph_args)
             if not converge:
                 return out
-            *out, ns = out
+            *out, ns, nlow = out
             telemetry.mark("push.converge", iters=out[2],
-                           sparse_iters=ns)
+                           sparse_iters=ns, low_rung_iters=nlow)
             return tuple(out)
 
         return call

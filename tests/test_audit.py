@@ -290,6 +290,44 @@ def test_frontier_pragma_is_honored():
     assert [f for f in findings if f.check == "identity-init"] == []
 
 
+@pytest.mark.parametrize("use_mxu", [False, True])
+def test_ladder_budgets_restated_per_rung(use_mxu, monkeypatch):
+    """PR 29: the sparse branch is instantiated once per (queue rung,
+    budget rung) of the ladder, and the static budgets hold for EACH
+    instantiation rather than as a loosened total: every program
+    variant carries exactly one CSR-expand marks scatter per pair
+    (operand ``[P_local, EB_r + 1]``, ``len(queue_rungs)`` of each
+    size); on the VPU form each is the one zero-initialized
+    scatter-max the frontier pragma allows (nothing else surfaces
+    with the pragma ignored), on the MXU form none needs it; and the
+    state-table gather budget of the fused loop stays what the dense
+    branch alone spends."""
+    from lux_tpu.apps import sssp
+    eng = sssp.build_engine(_graph(), 0, num_parts=2, use_mxu=use_mxu)
+    q_rungs, eb_rungs = eng.queue_rungs, eng.budget_rungs
+    assert len(q_rungs) >= 2 and len(eb_rungs) >= 2
+    assert q_rungs[-1] == eng.queue_cap
+    assert eb_rungs[-1] == eng.edge_budget
+    marks_prim = "scatter-add" if use_mxu else "scatter-max"
+    variants = eng.audit_programs()
+    for name, (jitted, thunk) in variants.items():
+        closed = audit.trace_variant(jitted, thunk())
+        sizes = [eqn.invars[0].aval.shape[-1] - 1
+                 for eqn, _, _ in audit._iter_eqns(closed.jaxpr)
+                 if eqn.primitive.name == marks_prim
+                 and eqn.invars[0].aval.dtype == np.int32
+                 and eqn.invars[0].aval.shape[-1] - 1 in eb_rungs]
+        assert sorted(sizes) == sorted(eb_rungs * len(q_rungs)), name
+    assert audit.audit_engine(eng, mode=None) == []
+    monkeypatch.setattr(audit, "_pragma_allows",
+                        lambda eqn, check, stack=(): False)
+    bare = audit.audit_engine(eng, mode=None)
+    assert {f.check for f in bare} <= {"identity-init"}
+    want = 0 if use_mxu else len(variants) * len(q_rungs) * len(eb_rungs)
+    assert len(bare) == want
+    assert all("frontier.py" in f.where for f in bare)
+
+
 # ---------------------------------------------------------------------
 # audit= is a bitwise no-op on compiled outputs
 
@@ -318,10 +356,11 @@ def test_audit_never_alters_push_outputs():
 @pytest.mark.parametrize("np_parts,mesh_n", [(2, 0), (8, 8)])
 def test_audited_converge_programs_carry_the_sparse_iters_counter(
         np_parts, mesh_n):
-    """PR 24: every converge variant the auditor walks (plain, stats,
-    health) returns ONE more output than its public signature, a
-    replicated int32 scalar LAST (the ``sparse_iters`` carry); the
-    single-step program does not.  The audit stays clean with it."""
+    """PR 24 / PR 29: every converge variant the auditor walks (plain,
+    stats, health) returns TWO more outputs than its public signature,
+    replicated int32 scalars LAST (the ``sparse_iters`` and
+    ``low_rung_iters`` carry); the single-step program does not.  The
+    audit stays clean with them."""
     import jax
 
     from lux_tpu.apps import sssp
@@ -334,11 +373,12 @@ def test_audited_converge_programs_carry_the_sparse_iters_counter(
         outs[name] = jax.eval_shape(jitted, *thunk())
     assert len(outs["step"]) == 3
     assert {n: len(o) for n, o in outs.items() if n != "step"} == {
-        "converge": 3 + 1, "converge_stats": 7 + 1,
-        "converge_health": 9 + 1}
+        "converge": 3 + 2, "converge_stats": 7 + 2,
+        "converge_health": 9 + 2}
     for name, out in outs.items():
         if name != "step":
-            assert out[-1].shape == () and out[-1].dtype == np.int32
+            for counter in out[-2:]:
+                assert counter.shape == () and counter.dtype == np.int32
     assert audit.audit_engine(eng, mode="error") == []
 
 

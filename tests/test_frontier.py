@@ -137,3 +137,94 @@ def test_components_sparse_enabled():
     labels, _ = components.run(g, num_parts=2, max_iters=300)
     ref = components.reference_components(g)
     np.testing.assert_array_equal(labels, ref)
+
+
+# ---------------------------------------------------------------------
+# the ladder: static rungs, the rule that picks one, and the two halves
+# of the expansion on every rung
+
+
+@pytest.mark.parametrize("top,divisors,want", [
+    (1600, (16, 4), (100, 400, 1600)),
+    (1600, (4, 16), (100, 400, 1600)),      # ascending whatever order
+    (1600, (), (1600,)),                    # the top rung alone
+    (356, (16,), (22, 356)),
+    (3, (16, 4), (1, 3)),                   # floor at 1, distinct
+    (1, (16, 4), (1,)),
+])
+def test_rungs(top, divisors, want):
+    got = fr.rungs(top, divisors)
+    assert got == want and got[-1] == top
+
+
+LADDER = (100, 400, 1600)
+
+
+@pytest.mark.parametrize("need,want", [
+    (0, 0), (99, 0), (100, 0), (101, 1),
+    (399, 1), (400, 1), (401, 2),
+    (1599, 2), (1600, 2), (1601, 2), (10**9, 2),
+])
+def test_rung_index_smallest_rung_that_holds(need, want):
+    """At, just under and just over every rung; what no rung holds
+    goes to the top one (which truncates)."""
+    got = fr.rung_index(jnp.int32(need), LADDER)
+    assert got.dtype == jnp.int32 and int(got) == want
+    assert int(fr.rung_index(jnp.int32(need), LADDER[-1:])) == 0
+
+
+def _star_queue(degs):
+    """A part whose source v has ``degs[v]`` out-edges, and the queue
+    of ALL its sources with labels 10, 11, ..."""
+    degs = np.asarray(degs)
+    nv = degs.size
+    sids, soff = _compress(np.concatenate([[0], np.cumsum(degs)]))
+    ids = jnp.arange(nv, dtype=jnp.int32)
+    vals = jnp.arange(nv, dtype=jnp.int32) + 10
+    return ids, vals, sids, soff, nv
+
+
+@pytest.mark.parametrize("use_mxu", [False, True])
+@pytest.mark.parametrize("budget", [5, 6, 7, 24, 25])
+def test_expand_extents_on_a_lower_rung_is_the_top_rungs_prefix(
+        budget, use_mxu):
+    """A frontier of 6 out-edges (one absent source, one of degree 0
+    among them) expanded on budgets under, at and over its total: the
+    slots a budget has are the TOP budget's first slots, bit for bit,
+    so a rung that holds the total loses nothing; one that does not is
+    a prefix (the truncation the top rung alone may meet)."""
+    ids, vals, sids, soff, nv = _star_queue([2, 0, 3, 1])
+    ids = jnp.concatenate([ids, jnp.asarray([nv], jnp.int32)])  # pad
+    vals = jnp.concatenate([vals, jnp.asarray([0], jnp.int32)])
+    top = fr.expand_frontier(ids, vals, sids, soff, nv=nv,
+                             edge_budget=25, use_mxu=use_mxu)
+    begin, off, total = fr.frontier_extents(ids, sids, soff, nv)
+    assert int(total) == int(top[3]) == 6
+    np.testing.assert_array_equal(np.asarray(off), np.asarray(top[4]))
+    edge_idx, src_val, in_range = fr.expand_extents(
+        vals, begin, off, budget, use_mxu=use_mxu)
+    k = min(budget, 6)
+    assert np.asarray(in_range).tolist() == [True] * k + \
+        [False] * (budget - k)
+    for got, want in zip((edge_idx, src_val), top[:2]):
+        np.testing.assert_array_equal(np.asarray(got)[:k],
+                                      np.asarray(want)[:k])
+    assert np.asarray(edge_idx)[:k].tolist() == list(range(6))[:k]
+    assert np.asarray(src_val)[:k].tolist() == \
+        [10, 10, 12, 12, 12, 13][:k]
+
+
+@pytest.mark.parametrize("capacity", [2, 3, 4, 8])
+def test_compact_mask_on_a_lower_rung_is_the_top_rungs_prefix(capacity):
+    """Three set bits compacted on queues under, at and over the
+    count: the slots a queue has are the top queue's first slots."""
+    mask = jnp.asarray([False, True, False, False, True, True, False])
+    labels = jnp.arange(7, dtype=jnp.int32) * 3
+    top_ids, top_vals, top_cnt = fr.compact_mask(mask, labels, 8)
+    ids, vals, cnt = fr.compact_mask(mask, labels, capacity)
+    assert int(cnt) == int(top_cnt) == 3
+    k = min(capacity, 3)
+    assert np.asarray(ids)[:k].tolist() == [1, 4, 5][:k]
+    np.testing.assert_array_equal(np.asarray(vals)[:k],
+                                  np.asarray(top_vals)[:k])
+    assert (np.asarray(ids)[k:] == 7).all()

@@ -1,6 +1,8 @@
 """Push engine: SSSP/BFS and Connected Components vs NumPy oracles,
 plus the fixed-point audits and the mesh path."""
 
+import functools
+
 import jax
 import numpy as np
 import pytest
@@ -248,3 +250,223 @@ def test_push_streamed_dense_matches_default(app):
     label, active, _ = eng.converge(label, active, 200)
     np.testing.assert_array_equal(
         eng.unpad(label).astype(np.int64), ref)
+
+
+# ---------------------------------------------------------------------
+# the sparse iteration's ladder (PR 29): each iteration runs on the
+# smallest queue / edge-budget rungs that hold its frontier, and
+# nothing but the shapes changes
+
+
+LADDER_NV = 8192
+LADDER_EB = 1600                  # top edge budget: rungs 100/400/1600
+_T0, _W0 = 6144, 8000             # target pools, in the LAST part
+
+
+def _ladder_graph(weighted=False):
+    """Vertices 0..10: sources of out-degree 1, 2, 4 .. 1024 (vertex
+    10, degree 1024, is a hub over every lower budget), all into the
+    target pool T = [_T0, _T0 + 1024); vertices 16..1000 have no
+    out-edges; every T vertex has one edge into W = [_W0, _W0 + 64).
+    Any (count, out-edge total) with total < 2048 and count >= 11 is a
+    frontier of sources (the total's binary digits) plus no-out-edge
+    vertices."""
+    src = [np.full(1 << k, k) for k in range(11)]
+    dst = [_T0 + np.arange(1 << k) for k in range(11)]
+    src.append(_T0 + np.arange(1024))
+    dst.append(_W0 + np.arange(1024) % 64)
+    src, dst = np.concatenate(src), np.concatenate(dst)
+    w = None
+    if weighted:
+        w = np.random.default_rng(5).integers(
+            1, 9, src.size).astype(np.float32)
+    return Graph.from_edges(src, dst, LADDER_NV, weights=w)
+
+
+def _ladder_frontier(count, total):
+    """Vertex ids of a frontier of ``count`` vertices and ``total``
+    out-edges on ``_ladder_graph``."""
+    srcs = [k for k in range(11) if total >> k & 1]
+    assert total < 2048 and len(srcs) <= count <= len(srcs) + 984
+    return np.asarray(srcs + list(range(16, 16 + count - len(srcs))))
+
+
+_LADDER_KINDS = {
+    # kind: (program, weighted, num_parts, mesh devices, use_mxu)
+    "bfs-np1": ("min", False, 1, 0, "auto"),
+    "bfs-np1-mxu": ("min", False, 1, 0, True),
+    "bfs-mesh2": ("min", False, 2, 2, "auto"),
+    "bfs-mesh4": ("min", False, 4, 4, "auto"),
+    "sssp-weighted-np2": ("min", True, 2, 0, "auto"),
+    "cc-max-mesh2": ("max", False, 2, 2, "auto"),
+}
+
+
+@functools.lru_cache(maxsize=None)
+def _ladder_engines(kind):
+    """-> (graph, ladder engine, top-rung-only engine), built once a
+    kind: every case of a kind is another initial frontier on the same
+    two compiled programs."""
+    from lux_tpu.engine import push
+    from lux_tpu.graph import ShardedGraph
+    reduce, weighted, num_parts, ndev, use_mxu = _LADDER_KINDS[kind]
+    g = _ladder_graph(weighted)
+    starts = np.linspace(0, LADDER_NV, num_parts + 1).astype(np.int64)
+    sg = ShardedGraph.build(g, num_parts, starts=starts)
+    prog = (sssp.make_program(0, weighted) if reduce == "min"
+            else components.make_program())
+    mesh = make_mesh(ndev) if ndev else None
+
+    def build():
+        return push.PushEngine(sg, prog, mesh=mesh, use_mxu=use_mxu,
+                               edge_budget=LADDER_EB)
+
+    # the tests' own ladder, three budget rungs, whatever divisors
+    # the engine ships with
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(push, "QUEUE_RUNG_DIVISORS", (16,))
+        mp.setattr(push, "BUDGET_RUNG_DIVISORS", (16, 4))
+        eng = build()
+        mp.setattr(push, "QUEUE_RUNG_DIVISORS", ())
+        mp.setattr(push, "BUDGET_RUNG_DIVISORS", ())
+        top = build()
+    assert top.queue_rungs == (eng.queue_cap,)
+    assert top.budget_rungs == (LADDER_EB,)
+    assert eng.queue_rungs == (eng.queue_cap // 16, eng.queue_cap)
+    assert eng.budget_rungs == (100, 400, LADDER_EB)
+    return g, eng, top
+
+
+def _ladder_state(eng, frontier):
+    """(label, active) host arrays with ``frontier`` active: BFS/SSSP
+    sources at distance 0; components' own-id labels."""
+    label, _ = eng.program.init(eng.sg)
+    label = eng.sg.from_padded(np.asarray(label)).copy()
+    if eng.program.reduce == "min":
+        label[:] = eng.program.identity
+        label[frontier] = 0
+    active = np.zeros(eng.sg.nv, bool)
+    active[frontier] = True
+    return eng.sg.to_padded(label), eng.sg.to_padded(active)
+
+
+def _last_mark():
+    from lux_tpu import telemetry
+    return [r for r in telemetry.spans()
+            if r["name"] == "push.converge"][-1]["counts"]
+
+
+def _rule(eng, g, active_host):
+    """The ladder's rule in NumPy from the frontier entering an
+    iteration -> (count, most out-edges landing in one part, sparse?,
+    lower budget rung?)."""
+    act = eng.sg.from_padded(active_host)
+    count = int(act.sum())
+    src, dst = g.edge_arrays()[:2]
+    part = np.searchsorted(eng.sg.starts, dst[act[src]],
+                           side="right") - 1
+    total = int(np.bincount(part, minlength=eng.sg.num_parts).max())
+    usable, limit = eng._sparse_mode()
+    sparse = usable and count <= limit
+    return count, total, sparse, sparse and total <= eng.budget_rungs[-2]
+
+
+def _check_ladder_case(kind, count, total):
+    """One initial frontier of (count, total) on the ladder engine and
+    on the top-rung-only engine: labels, ``iters`` and ``sparse_iters``
+    bit-identical; then the ladder engine again one iteration a call,
+    each iteration's ``sparse_iters`` / ``low_rung_iters`` against the
+    rule on the frontier that entered it."""
+    g, eng, top = _ladder_engines(kind)
+    frontier = _ladder_frontier(count, total)
+    runs = {}
+    for name, e in (("ladder", eng), ("top", top)):
+        label, _a, it = e.converge(*e.place(*_ladder_state(e, frontier)))
+        runs[name] = (e.unpad(label), int(it), _last_mark())
+        assert runs[name][2]["iters"] == int(it)
+    lab, it, counts = runs["ladder"]
+    tlab, tit, tcounts = runs["top"]
+    np.testing.assert_array_equal(lab, tlab)
+    assert (it, counts["sparse_iters"]) == (tit, tcounts["sparse_iters"])
+    assert tcounts["low_rung_iters"] == 0
+
+    label, active = eng.place(*_ladder_state(eng, frontier))
+    series, n_sparse, n_low = [], 0, 0
+    for _ in range(it):
+        cnt, tot, sparse, low = _rule(eng, g, np.asarray(active))
+        label, active, one = eng.converge(label, active, 1)
+        mark = _last_mark()
+        assert (int(one), mark["sparse_iters"], mark["low_rung_iters"]) \
+            == (1, int(sparse), int(low)), (cnt, tot)
+        series.append((cnt, tot))
+        n_sparse += sparse
+        n_low += low
+    np.testing.assert_array_equal(eng.unpad(label), lab)
+    assert not np.asarray(active).any()
+    assert (n_sparse, n_low) == (counts["sparse_iters"],
+                                 counts["low_rung_iters"])
+    return series, counts
+
+
+def _q_cases(kind):
+    """Counts just under, at and just over the lower queue rung, and
+    at the sparse limit (the top queue's last count)."""
+    _g, eng, _top = _ladder_engines(kind)
+    q0 = eng.queue_rungs[0]
+    return {"q0-1": q0 - 1, "q0": q0, "q0+1": q0 + 1,
+            "limit": eng._sparse_mode()[1]}
+
+
+_E_CASES = {"eb0-1": 99, "eb0": 100, "eb0+1": 101,
+            "eb1-1": 399, "eb1": 400, "eb1+1": 401,
+            "top-1": 1599, "top": 1600, "top+1": 1601}
+
+
+@pytest.mark.parametrize("e_case", list(_E_CASES))
+@pytest.mark.parametrize("q_case", ["q0-1", "q0", "q0+1", "limit"])
+def test_ladder_single_part_every_boundary(q_case, e_case):
+    """One part, so the first iteration's (count, total) is exactly
+    the case's: count fits a rung and total does not, and the reverse,
+    at / just under / just over every rung; one edge over the top
+    budget still truncates (a second sparse iteration finishes the
+    queue) and still converges."""
+    count, total = _q_cases("bfs-np1")[q_case], _E_CASES[e_case]
+    series, counts = _check_ladder_case("bfs-np1", count, total)
+    assert series[0] == (count, total)
+    assert series[0][1] > LADDER_EB or series[1][0] != series[0][0]
+    if total > LADDER_EB:             # truncated: the suffix came back
+        assert series[1][0] >= 1 and counts["sparse_iters"] >= 2
+    assert counts["low_rung_iters"] >= (total <= 400)
+
+
+@pytest.mark.parametrize("q_case,e_case", [
+    ("q0-1", "eb0"), ("q0", "eb0+1"), ("q0+1", "eb1"),
+    ("q0", "eb1+1"), ("limit", "eb0-1"), ("limit", "top"),
+    ("q0+1", "top+1"), ("limit", "top+1")])
+@pytest.mark.parametrize("kind", [k for k in _LADDER_KINDS
+                                  if k != "bfs-np1"])
+def test_ladder_kinds_boundaries(kind, q_case, e_case):
+    """The same on meshes of 2 and 4 devices (the rung is the psum'd
+    count's and the pmax'd total's, one for every device: the branches
+    hold the collectives), weighted, through the MXU expansion, and
+    for a max program.  Every target lies in the last part, so the
+    most any part holds is the whole total."""
+    count, total = _q_cases(kind)[q_case], _E_CASES[e_case]
+    series, _counts = _check_ladder_case(kind, count, total)
+    assert series[0] == (count, total)
+
+
+@pytest.mark.parametrize("kind", ["bfs-np1", "bfs-mesh4"])
+def test_ladder_hub_over_every_lower_budget_takes_the_top(kind):
+    """A frontier of ONE vertex whose degree (1024) exceeds both lower
+    budgets: a lower rung would have to truncate it and could never
+    finish it; the rule sends it to the top."""
+    g, eng, _top = _ladder_engines(kind)
+    assert 1024 > eng.budget_rungs[-2]
+    label, active = eng.place(*_ladder_state(eng, np.asarray([10])))
+    assert _rule(eng, g, np.asarray(active)) == (1, 1024, True, False)
+    label, active, _it = eng.converge(label, active, 1)
+    mark = _last_mark()
+    assert (mark["sparse_iters"], mark["low_rung_iters"]) == (1, 0)
+    got = eng.unpad(label)
+    assert (got[_T0:_T0 + 1024] == 1).all() and got[10] == 0

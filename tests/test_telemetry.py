@@ -892,7 +892,12 @@ def test_sparse_iters_counts_the_branch_the_loop_took(variant):
     assert isinstance(raw["counts"]["sparse_iters"], jax.Array)
     marks = [r for r in _since(tip) if r["name"] == "push.converge"]
     assert len(marks) == 1
-    assert marks[0]["counts"] == {"iters": it, "sparse_iters": want}
+    counts = marks[0]["counts"]
+    assert set(counts) == {"iters", "sparse_iters", "low_rung_iters"}
+    assert (counts["iters"], counts["sparse_iters"]) == (it, want)
+    # which sparse iterations ran below the top edge budget has its
+    # oracle in tests/test_push.py (the ladder)
+    assert 0 <= counts["low_rung_iters"] <= want
     assert int(it2) == it
     dense = sssp.build_engine(g, enable_sparse=False, **kw)
     want_label, _a, _it = dense.converge(*dense.init_state())
