@@ -337,7 +337,7 @@ class FleetServer:
     heartbeat-supervised failover and brownout shedding (module
     docstring has the full contract).  Duck-type compatible with
     serve.Server for scripts/loadgen.py: ``g``/``submit``/``run``/
-    ``set_metrics``/``emit_metrics_snapshot``/``_collectors``."""
+    ``serve``/``stop``/``set_metrics``/``emit_metrics_snapshot``."""
 
     def __init__(self, g, *, replicas: int = 2, batch: int = 4,
                  num_parts: int = 1, mesh=None, exchange: str = "auto",
@@ -432,6 +432,9 @@ class FleetServer:
         # and qid maps; _shed runs both under the lock (inside
         # _admission) and outside it
         self._lock = threading.RLock()
+        # serve()'s wait between two drains: submit and stop() wake it
+        self._wake = threading.Condition()
+        self._stopping = False
         # all kinds pre-created: _queues is never mutated after
         # construction, so the run loop / pending views can iterate
         # it while submitter threads insert requests (a lazy
@@ -904,6 +907,8 @@ class FleetServer:
             self._tenant_load[req.tenant] = \
                 self._tenant_load.get(req.tenant, 0) + 1
             q.put(req)
+        with self._wake:
+            self._wake.notify_all()
         return qid
 
     def warm(self, kinds=None) -> int:
@@ -1499,12 +1504,32 @@ class FleetServer:
 
     # -- serve.Server duck-type surface --------------------------------
 
-    @property
-    def _collectors(self) -> dict:
-        """Per-kind pending views (queued + replica-resident +
-        subprocess-in-flight) — the drain predicate
-        scripts/loadgen.py polls between Server.run calls."""
-        return {k: _PendingView(self, k) for k in self._queues}
+    def stop(self) -> None:
+        """End ``serve()`` once nothing admitted is pending (any
+        thread)."""
+        with self._wake:
+            self._stopping = True
+            self._wake.notify_all()
+
+    def serve(self, deliver) -> None:
+        """serve.Server.serve's surface: each ``run()`` (a drain of
+        everything admitted, failover and healing included) is
+        handed to ``deliver`` as it ends; between two drains the
+        caller blocks until a ``submit`` or ``stop()``, and once
+        stopped it returns when nothing is pending."""
+        try:
+            while True:
+                out = self.run()
+                if out:
+                    deliver(out)
+                with self._wake:
+                    self._wake.wait_for(
+                        lambda: self._stopping or self._pending_any())
+                    if not self._pending_any():
+                        return
+        finally:
+            with self._wake:
+                self._stopping = False
 
     def set_metrics(self, registry) -> None:
         self.metrics = registry
@@ -1636,18 +1661,6 @@ class FleetServer:
                   priority=req.priority,
                   queued=len(flt._queue(rec.kind)), recovered=True)
         return flt
-
-
-class _PendingView:
-    def __init__(self, fleet: FleetServer, kind: str):
-        self.fleet = fleet
-        self.kind = kind
-
-    def __len__(self) -> int:
-        n = len(self.fleet._queues[self.kind])
-        for rep in self.fleet._healthy():
-            n += rep.pending(self.kind)
-        return n
 
 
 # ---------------------------------------------------------------------

@@ -28,8 +28,8 @@ PRs 1-8 built:
   ``on_segment`` hook — so duration budgeting, telemetry segment
   events, iter-stats counters and the health watchdog all compose
   unchanged.
-- the **scheduling rule** across kinds (``Server.run``): one TURN — one
-  segment and its boundary — for each kind that has work (a queued
+- the **scheduling rule** across kinds (``Server.serve``): one TURN —
+  one segment and its boundary — for each kind that has work (a queued
   query or an occupied column), round-robin, until none has.  A
   runner is suspended between its turns with its state left on the
   device (``_RunnerBase.turn``; nothing is fetched or re-placed
@@ -38,6 +38,13 @@ PRs 1-8 built:
   the ring the turns are that runner's ``drain``: ``python -m
   lux_tpu.serve``, scripts/loadgen.py and the fleet's replicas all
   go through the same path.
+- the **serving loop** (``Server.serve(deliver)``): the responses a
+  turn retired are handed to ``deliver`` BEFORE the next turn starts,
+  so a caller learns of retirement at the boundary that retired its
+  query; while no kind has work the loop blocks (span ``serve.idle``)
+  until a ``submit`` from any thread or ``stop()``, and once stopped
+  it ends when no kind has work.  ``run()`` is that loop with the
+  stop already given, collected into a list.
 - at each segment boundary the hook RETIRES converged columns (push:
   the column's frontier is empty; pull: the column's residual fell
   under ``tol``), scatters their answers into per-query
@@ -60,10 +67,11 @@ PRs 1-8 built:
   ``serve_slo_good_total`` / ``serve_slo_violation_total`` counters
   plus a rolling burn-rate gauge (violating fraction over the last
   ``SLO_WINDOW`` retirements; ARCHITECTURE.md "Serving metrics &
-  SLOs" has the series catalogue).  ``run()`` publishes a
-  ``metrics_snapshot`` telemetry event per drain; scripts/loadgen.py
-  reads the snapshots back and scripts/events_summary.py cross-audits
-  them against the raw ``query_done`` stream.
+  SLOs" has the series catalogue).  The serving loop publishes a
+  ``metrics_snapshot`` telemetry event at a hand-over, at most one
+  per ``snapshot_every_s``; scripts/loadgen.py reads the snapshots
+  back and scripts/events_summary.py cross-audits them against the
+  raw ``query_done`` stream.
 
 Costs and debts: a boundary moves one padded ``[P, vpad]`` column per
 retired query to the host and a few ``[B]`` vectors back — the retire
@@ -1408,11 +1416,14 @@ class Server:
     """Route queries by kind to per-kind BatchRunners and drain them.
 
     One engine per kind is built lazily at the first query of that
-    kind (column count ``batch``); ``run()`` drains every kind's
-    queue through continuous-batching refill and returns the
-    responses in retirement order.  ``deadline_s`` is the batch
-    collector's wait-for-more budget (0 = serve whatever is queued —
-    the offline/smoke mode).
+    kind (column count ``batch``); ``serve(deliver)`` is the serving
+    loop (continuous-batching refill, responses handed over at the
+    turn that retires them, blocking while nothing is queued or
+    resident) and ``run()`` that loop with the stop given: it drains
+    every kind's queue and returns the responses in retirement
+    order.  ``submit`` may be called from any thread.  ``deadline_s``
+    is the batch collector's wait-for-more budget (0 = serve whatever
+    is queued — the offline/smoke mode).
 
     ``slo_ms`` maps query kinds to per-kind latency targets in
     milliseconds (SLO good/violation counters + the rolling burn-rate
@@ -1484,15 +1495,23 @@ class Server:
         self._last_snapshot = 0.0
         self._collectors: dict[str, BatchCollector] = {}
         self._runners: dict[str, _RunnerBase] = {}
-        self._last_turn: _RunnerBase | None = None   # run()'s ring
+        self._last_turn: _RunnerBase | None = None   # serve()'s ring
+        # what submitters (any thread) share with the serving loop:
+        # the qids, the kinds' collectors and the stop.  The loop
+        # waits on it while no kind has work
+        self._wake = threading.Condition()
         self._next_qid = 0
+        self._stopping = False
 
     def _collector(self, kind: str) -> BatchCollector:
         if kind not in KINDS:
             raise ValueError(f"unknown query kind {kind!r}; choose "
                              f"from {KINDS}")
-        return self._collectors.setdefault(
-            kind, BatchCollector(metrics=self.metrics, kind=kind))
+        with self._wake:
+            if kind not in self._collectors:
+                self._collectors[kind] = BatchCollector(
+                    metrics=self.metrics, kind=kind)
+            return self._collectors[kind]
 
     def _runner(self, kind: str) -> _RunnerBase:
         if kind not in self._runners:
@@ -1521,7 +1540,7 @@ class Server:
         series are fetched from the registry at use time, so the swap
         is complete at the next boundary."""
         self.metrics = registry
-        for coll in self._collectors.values():
+        for coll in list(self._collectors.values()):
             coll.metrics = registry
         for runner in self._runners.values():
             runner.metrics = registry
@@ -1543,28 +1562,34 @@ class Server:
                reset=None, tenant: str = "default",
                priority: int = 0,
                deadline_s: float | None = None) -> int:
-        qid = self._next_qid
-        self._next_qid += 1
-        req = Request(qid=qid, kind=kind,
-                      source=None if source is None else int(source),
-                      reset=(None if reset is None
-                             else np.asarray(reset, np.float32)),
-                      t_enqueue=time.monotonic(), tenant=str(tenant),
-                      priority=int(priority),
-                      deadline_s=(None if deadline_s is None
-                                  else float(deadline_s)),
-                      # stamp + admission-ledger entry in ONE lock
-                      # acquisition: the generation must survive
-                      # until this query retires, and resident pins
-                      # alone cannot protect it while QUEUED;
-                      # released per response in run()
-                      epoch=admit_query(self.live, kind))
+        coll = self._collector(kind)
+        # stamp + admission-ledger entry in ONE lock acquisition:
+        # the generation must survive until this query retires, and
+        # resident pins alone cannot protect it while QUEUED;
+        # released per response at its hand-over (serve())
+        epoch = admit_query(self.live, kind)
         if self.metrics is not None:
             self.metrics.counter("serve_queries_total",
                                  kind=kind).inc()
-        self._collector(kind).put(req)
+        with self._wake:
+            # qid and queue position under one lock: concurrent
+            # submitters get dense qids and are served in qid order
+            qid = self._next_qid
+            self._next_qid += 1
+            req = Request(qid=qid, kind=kind,
+                          source=None if source is None
+                          else int(source),
+                          reset=(None if reset is None
+                                 else np.asarray(reset, np.float32)),
+                          t_enqueue=time.monotonic(),
+                          tenant=str(tenant), priority=int(priority),
+                          deadline_s=(None if deadline_s is None
+                                      else float(deadline_s)),
+                          epoch=epoch)
+            coll.put(req)
+            self._wake.notify_all()
         _emit("query_enqueue", qid=qid, query_kind=kind,
-              source=req.source, queued=len(self._collector(kind)))
+              source=req.source, queued=len(coll))
         return qid
 
     def mutate(self, src, dst, weights=None,
@@ -1615,7 +1640,7 @@ class Server:
         if self.live is None:
             return
         # list(): a submitter thread may add a new kind's collector
-        # mid-iteration (same race run() guards against)
+        # mid-iteration (same race serve() guards against)
         for kind, coll in list(self._collectors.items()):
             stale = [req for req in coll.pending_requests()
                      if not _epoch_reproducible(self.live, req)]
@@ -1633,19 +1658,21 @@ class Server:
         self._runners.clear()
         self._last_turn = None      # or it keeps an old engine alive
 
-    def run(self) -> list[Response]:
-        """Drain every kind's queue; returns responses in retirement
-        order (continuous batching: later queries refill columns
-        freed by earlier retirements).  The kinds share the chip by
-        turns — one segment and its boundary each, round-robin over
-        the kinds that have work — so under sustained arrivals of
-        several kinds none waits for another's queue to empty; every
-        runner's state stays on the device between its turns.
-        Publishes a periodic
-        ``metrics_snapshot`` event (at most one per
-        ``snapshot_every_s`` of non-empty drains — the cadence a
-        long-lived serving loop rides; ``emit_metrics_snapshot()``
-        snapshots on demand)."""
+    def _wants_turn(self, kind: str, coll: BatchCollector) -> bool:
+        """A queued query or an occupied column of this kind."""
+        runner = self._runners.get(kind)
+        return len(coll) > 0 or (runner is not None and runner.resident)
+
+    def _has_work(self) -> bool:
+        # list(): submit() may add a NEW kind's collector from a
+        # submitter thread meanwhile
+        return any(self._wants_turn(kind, coll) for kind, coll
+                   in list(self._collectors.items()))
+
+    def _begin_drain(self) -> None:
+        """What holds from the first turn after an idle spell (or
+        the loop's start) to the next: the generation is the live
+        graph's, and the cache holds no epoch that no view exposes."""
         if self.live is not None and self.g is not self.live.base:
             # generation adoption is ENFORCED, not caller etiquette:
             # serving on a stale base after a compaction converges
@@ -1661,36 +1688,98 @@ class Server:
             # no view still exposes can never hit again — drop them
             self.cache.sweep({k: self._admission_epoch(k)
                               for k in KINDS})
-        out: list[Response] = []
-        # the scheduling rule: one turn (a segment and its boundary)
-        # for each kind that has work — a queued query or an occupied
-        # column — in the ring's order, round and round until none
-        # has.  With one kind in the ring this is that runner's drain.
-        served = True
-        while served:
-            served = False
-            # list(): submit() may add a NEW kind's collector from a
-            # submitter thread while an open-loop drain iterates
-            for kind, coll in list(self._collectors.items()):
-                runner = self._runners.get(kind)
-                if not (len(coll) or (runner is not None
-                                      and runner.resident)):
-                    continue
-                runner = self._runner(kind)
-                out += runner.turn(
-                    coll, self.deadline_s,
-                    switch=self._last_turn not in (None, runner))
-                self._last_turn = runner
-                served = True
-        if self.live is not None:
-            # one release per retired response: the admit() taken at
-            # submit ends exactly when the answer leaves the server
-            for _ in out:
-                self.live.release()
+
+    def _await_work(self) -> bool:
+        """Block until a kind has work or the stop is given; False
+        once stopped with no work left.  One ``serve.idle`` span a
+        wait: nothing is queued or resident inside it."""
+        with self._wake:
+            if self._has_work():
+                return True
+            if self._stopping:
+                return False
+        with telemetry.span("serve.idle"), self._wake:
+            self._wake.wait_for(
+                lambda: self._stopping or self._has_work())
+        return True
+
+    def _hand_over(self, deliver, responses: list) -> None:
+        """The responses one turn retired leave the server: span
+        ``serve.deliver`` (count ``responses``) round their release
+        and the caller's ``deliver``; then the snapshot cadence."""
+        if not responses:
+            return
+        with telemetry.span("serve.deliver", responses=len(responses)):
+            if self.live is not None:
+                # one release per retired response: the admit()
+                # taken at submit ends exactly when the answer
+                # leaves the server
+                for _ in responses:
+                    self.live.release()
+            deliver(responses)
         now = time.monotonic()
-        if out and now - self._last_snapshot >= self.snapshot_every_s:
+        if now - self._last_snapshot >= self.snapshot_every_s:
             self._last_snapshot = now
             self.emit_metrics_snapshot()
+
+    def stop(self) -> None:
+        """End ``serve()`` once no kind has work (any thread; before
+        the loop starts too: it then drains what is queued and
+        returns)."""
+        with self._wake:
+            self._stopping = True
+            self._wake.notify_all()
+
+    def serve(self, deliver: Callable[[list[Response]], None]) -> None:
+        """The serving loop, on the calling thread (continuous
+        batching: later queries refill columns freed by earlier
+        retirements).  The kinds share the chip by turns — one
+        segment and its boundary each, round-robin over the kinds
+        that have work (a queued query or an occupied column) — so
+        under sustained arrivals of several kinds none waits for
+        another's queue to empty; every runner's state stays on the
+        device between its turns.  The responses a turn retired are
+        handed to ``deliver`` (a list, in retirement order) before
+        the next turn starts.  While no kind has work the loop
+        blocks until a ``submit`` from any thread or ``stop()``;
+        once stopped it returns when no kind has work.  Publishes a
+        ``metrics_snapshot`` event at a hand-over, at most one per
+        ``snapshot_every_s`` (``emit_metrics_snapshot()`` snapshots
+        on demand)."""
+        try:
+            self._begin_drain()
+            while True:
+                served = False
+                # list(): submit() may add a NEW kind's collector
+                # from a submitter thread while the ring goes round
+                for kind, coll in list(self._collectors.items()):
+                    if not self._wants_turn(kind, coll):
+                        continue
+                    runner = self._runner(kind)
+                    got = runner.turn(
+                        coll, self.deadline_s,
+                        switch=self._last_turn not in (None, runner))
+                    # the loop outlives any drain: what was handed
+                    # over is the caller's, not the runner's to keep
+                    del runner.responses[:]
+                    self._last_turn = runner
+                    served = True
+                    self._hand_over(deliver, got)
+                if served:
+                    continue
+                if not self._await_work():
+                    return
+                self._begin_drain()
+        finally:
+            with self._wake:
+                self._stopping = False
+
+    def run(self) -> list[Response]:
+        """Drain every kind's queue: ``serve()`` with the stop
+        already given; returns the responses in retirement order."""
+        out: list[Response] = []
+        self.stop()
+        self.serve(out.extend)
         return out
 
 

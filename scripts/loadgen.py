@@ -12,8 +12,12 @@ latency-vs-offered-rate curves).  Per ramp step:
 - a submitter thread draws exponential inter-arrival gaps at the
   step's offered rate (seeded rng: the query set and schedule are
   reproducible) and submits a mixed-kind round-robin of query kinds;
-- the main thread drains the server continuously
-  (continuous-batching refill, ``Server.run``);
+- the main thread runs the serving loop (``Server.serve``:
+  continuous-batching refill; the responses a turn retired are handed
+  over before the next turn starts, and while nothing is queued or
+  resident the loop blocks until the next ``submit``).  The submitter
+  gives the stop (``Server.stop``) after its last query, so the loop
+  ends when everything submitted is answered: nothing polls;
 - the step's latency distribution is read BACK from the server's
   ``metrics_snapshot`` (lux_tpu/metrics.py) — per-kind log-linear
   histograms merged bucket-wise into one distribution — rather than
@@ -58,7 +62,6 @@ if REPO not in sys.path:
 # a step saturates when it achieves under this fraction of its
 # offered rate — the knee of the latency-vs-rate curve
 KNEE_FRACTION = 0.9
-DRAIN_POLL_S = 0.002
 
 
 @dataclasses.dataclass
@@ -130,7 +133,8 @@ def _slo_fraction(snapshot) -> float | None:
 def run_step(srv, rate: float, n: int, kinds, rng,
              step: int = 0) -> StepReport:
     """One open-loop step: submit ``n`` mixed-kind queries at Poisson
-    rate ``rate`` (qps) while continuously draining ``srv``; read the
+    rate ``rate`` (qps) while ``srv.serve`` runs on this thread
+    (responses arrive as the turn that retired them ends); read the
     step's metrics snapshot back (the published ``metrics_snapshot``
     event — the same aggregate every later SLO consumer reads) and
     measure offered/achieved.  The step swaps in a FRESH metrics
@@ -146,20 +150,22 @@ def run_step(srv, rate: float, n: int, kinds, rng,
              for i in range(n)]
     gaps = rng.exponential(1.0 / max(rate, 1e-9), size=n)
 
-    done = threading.Event()
     enq_last = [0.0]
     shed0 = len(getattr(srv, "shed_records", ()))
 
     def submit_all():
         from lux_tpu.fleet import AdmissionError
-        for (kind, s), gap in zip(specs, gaps):
-            time.sleep(gap)
-            try:
-                srv.submit(kind, source=s)
-            except AdmissionError:
-                pass        # typed shed: counted via shed_records
-            enq_last[0] = time.monotonic()
-        done.set()
+        try:
+            for (kind, s), gap in zip(specs, gaps):
+                time.sleep(gap)
+                try:
+                    srv.submit(kind, source=s)
+                except AdmissionError:
+                    pass    # typed shed: counted via shed_records
+                enq_last[0] = time.monotonic()
+        finally:
+            # the loop ends once everything submitted is answered
+            srv.stop()
 
     # copy_context: the submitter must emit query_enqueue events into
     # the CALLER's telemetry scope (contextvars do not cross threads
@@ -169,20 +175,14 @@ def run_step(srv, rate: float, n: int, kinds, rng,
                           daemon=True)
     responses = []
     t_start = time.monotonic()
-    t_last = t_start
+    t_last = [t_start]
+
+    def deliver(out):
+        responses.extend(out)
+        t_last[0] = time.monotonic()
+
     th.start()
-    while True:
-        out = srv.run()
-        if out:
-            responses += out
-            t_last = time.monotonic()
-        # list(): the submitter thread may insert a new kind's
-        # collector mid-iteration (the Server.run() hazard)
-        if done.is_set() \
-                and not any(len(c) for c in
-                            list(srv._collectors.values())):
-            break
-        time.sleep(DRAIN_POLL_S)
+    srv.serve(deliver)
     th.join()
 
     # the emitted event IS the published snapshot (None only without
@@ -194,7 +194,7 @@ def run_step(srv, rate: float, n: int, kinds, rng,
     p50 = merged.quantile(0.5)
     p99 = merged.quantile(0.99)
     offered = len(specs) / max(enq_last[0] - t_start, 1e-9)
-    achieved = len(responses) / max(t_last - t_start, 1e-9)
+    achieved = len(responses) / max(t_last[0] - t_start, 1e-9)
     shed = len(getattr(srv, "shed_records", ())) - shed0
     good, bad = _slo_counts(snapshot)
     per_kind = {
@@ -207,7 +207,7 @@ def run_step(srv, rate: float, n: int, kinds, rng,
     return StepReport(
         step=step, target_qps=rate, offered_qps=offered,
         achieved_qps=achieved, submitted=len(specs),
-        served=len(responses), elapsed_s=t_last - t_start,
+        served=len(responses), elapsed_s=t_last[0] - t_start,
         p50_ms=None if p50 is None else p50 * 1e3,
         p99_ms=None if p99 is None else p99 * 1e3,
         slo_good_fraction=_slo_fraction(snapshot),
