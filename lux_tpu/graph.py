@@ -33,7 +33,7 @@ import dataclasses
 import numpy as np
 
 from lux_tpu import format as luxfmt
-from lux_tpu import telemetry
+from lux_tpu import prepstore, telemetry
 from lux_tpu.partition import edge_balanced_bounds, part_edge_counts
 
 
@@ -76,6 +76,35 @@ class Graph:
     col_idx: np.ndarray           # uint32 [ne], edge sources, dst-sorted
     weights: np.ndarray | None    # [ne] or None
     out_degrees: np.ndarray       # uint32 [nv]
+    # (the arrays a key was taken from, the key): content_key()
+    _key_cache: tuple | None = dataclasses.field(
+        default=None, repr=False, compare=False)
+
+    def _arrays(self) -> tuple:
+        return (self.row_ptrs, self.col_idx, self.weights,
+                self.out_degrees)
+
+    def content_key(self) -> str:
+        """Key of this graph's CONTENT for the preparation store
+        (lux_tpu/prepstore.py): a digest of its arrays, taken once
+        and kept while the fields hold the same array objects (a
+        field that is reassigned gets a new key; the arrays
+        themselves are never written in place).  Path, size and mtime
+        of the file it came from play no part."""
+        arrays = self._arrays()
+        kept = self._key_cache
+        if kept is None or any(a is not b
+                               for a, b in zip(kept[0], arrays)):
+            kept = (arrays, prepstore.digest("graph", self.nv, self.ne,
+                                             *arrays))
+            self._key_cache = kept
+        return kept[1]
+
+    def _keyed(self, key: str) -> "Graph":
+        """This graph under ``key``: a product of the store is known
+        by the key it was derived under, not by a second digest."""
+        self._key_cache = (self._arrays(), key)
+        return self
 
     @classmethod
     def from_file(cls, path: str, weighted: bool | None = None,
@@ -251,7 +280,10 @@ def pair_relabel(g: Graph, num_parts: int = 1,
     Leaves a ``relabel`` span with one child per stage
     (``relabel.degree_sort``, ``.pair_histogram`` when num_parts > 1,
     ``.deal``, ``.rebuild_csc``); ``verbose`` prints one line per
-    stage from those records.
+    stage from those records.  A graph the preparation store engages
+    on (lux_tpu/prepstore.py) is looked up first, under the key of
+    its content and these parameters: a hit loads the result and has
+    no stage to show.
     """
     if vpad_cap < 1:
         # cap * P must cover every full tile, or the LPT's all-capped
@@ -259,14 +291,43 @@ def pair_relabel(g: Graph, num_parts: int = 1,
         # unbalanced
         raise ValueError(f"vpad_cap={vpad_cap} must be >= 1")
     with telemetry.span("relabel") as sp:
-        out = _pair_relabel(g, num_parts, pair_threshold, gather_cost,
-                            pair_cost, vpad_cap)
+        out = _stored_relabel(g, num_parts, pair_threshold,
+                              gather_cost, pair_cost, vpad_cap)
     if verbose:
         for rec in telemetry.spans():
-            if rec["parent"] == sp.id:
+            if (rec["parent"] == sp.id
+                    and rec["name"].startswith("relabel.")):
                 print(f"# pair_relabel/{rec['name'][len('relabel.'):]}: "
                       f"{rec['t1'] - rec['t0']:.1f}s", flush=True)
     return out
+
+
+def _stored_relabel(g, *params):
+    """``_pair_relabel`` through the preparation store.  Hit or miss,
+    the relabelled graph goes on under a key DERIVED from the lookup's
+    (``Graph._keyed``), so what is prepared from it next finds its
+    entry without a digest of the relabelled arrays."""
+    if not prepstore.engages(g.ne):
+        return _pair_relabel(g, *params)
+    key = prepstore.derive(g.content_key(), "relabel", *params)
+    g2, perm, starts = prepstore.through(
+        "relabel", key, lambda: _pair_relabel(g, *params),
+        _relabel_pack, _relabel_unpack)
+    return g2._keyed(key), perm, starts
+
+
+def _relabel_pack(product):
+    g2, perm, starts = product
+    return dict(row_ptrs=g2.row_ptrs, col_idx=g2.col_idx,
+                weights=g2.weights, out_degrees=g2.out_degrees,
+                perm=perm, starts=starts), {}
+
+
+def _relabel_unpack(a, _meta):
+    g2 = Graph(nv=len(a["row_ptrs"]), ne=len(a["col_idx"]),
+               row_ptrs=a["row_ptrs"], col_idx=a["col_idx"],
+               weights=a.get("weights"), out_degrees=a["out_degrees"])
+    return g2, a["perm"], a.get("starts")
 
 
 def _pair_relabel(g, num_parts, pair_threshold, gather_cost, pair_cost,
@@ -379,6 +440,33 @@ def _tile_costs(nv, src, dst, by_deg, n_tiles, pair_threshold,
             + gather_cost * (all_by_tile - pair_by_tile))
 
 
+def _src_sorted_pack(raw):
+    """The raw src-sorted view (per-part lists) as flat arrays and
+    the lengths that split them again."""
+    ids_l, off_l, dst_l, w_l, max_deg = raw
+    arrays = dict(ids=np.concatenate(ids_l), off=np.concatenate(off_l),
+                  dst=np.concatenate(dst_l),
+                  w=(np.concatenate(w_l) if w_l[0] is not None
+                     else None))
+    return arrays, dict(n_ids=[len(u) for u in ids_l],
+                        n_dst=[len(d) for d in dst_l],
+                        max_deg=int(max_deg))
+
+
+def _src_sorted_unpack(arrays, meta):
+    n_ids, n_dst = meta["n_ids"], meta["n_dst"]
+
+    def split(a, lens):
+        return np.split(a, np.cumsum(lens)[:-1])
+
+    w = arrays.get("w")
+    return (split(arrays["ids"], n_ids),
+            split(arrays["off"], [n + 1 for n in n_ids]),
+            split(arrays["dst"], n_dst),
+            split(w, n_dst) if w is not None else [None] * len(n_dst),
+            meta["max_deg"])
+
+
 @dataclasses.dataclass
 class ShardedGraph:
     """Padded part-major device layout (all arrays are host numpy;
@@ -413,6 +501,13 @@ class ShardedGraph:
     # Max out-degree over the WHOLE graph (push edge budgets must be
     # process-independent static shapes).
     max_out_degree: int = 0
+    # Key of this layout's content for the preparation store
+    # (lux_tpu/prepstore.py): derived in build() from the graph's key
+    # and what shaped the layout.  None where the store stays away: a
+    # graph under its size, a local-parts build, a layout assembled
+    # by hand.
+    content_key: str | None = dataclasses.field(
+        default=None, repr=False, compare=False)
 
     def compatible_mesh_sizes(self, available: int) -> list[int]:
         """Device counts this padded layout can run on UNCHANGED,
@@ -488,6 +583,12 @@ class ShardedGraph:
         v_slot = v_slot.astype(np.int64)
 
         local = None if parts is None else np.asarray(list(parts), np.int64)
+        content_key = None
+        if local is None and prepstore.engages(g.ne):
+            # pair_threshold acts through vpad_align and starts only
+            content_key = prepstore.derive(
+                g.content_key(), "shard", num_parts, vpad_align,
+                epad_align, starts)
         rows = np.arange(num_parts) if local is None else local
         R = len(rows)
         src_slot = np.zeros((R, epad), dtype=np.int32)
@@ -546,7 +647,8 @@ class ShardedGraph:
                    local_parts=local,
                    row_ptr_global=(g.row_ptrs if local is not None
                                    else None),
-                   max_out_degree=int(g.out_degrees.max(initial=0)))
+                   max_out_degree=int(g.out_degrees.max(initial=0)),
+                   content_key=content_key)
 
     @classmethod
     def build_from_file(cls, path: str, num_parts: int, parts=None,
@@ -645,33 +747,45 @@ class ShardedGraph:
         default=None, repr=False, compare=False)
 
     def _src_sorted_raw(self):
-        """Per-part src-sort + unique-source compression (host, once)."""
+        """Per-part src-sort + unique-source compression (host, once;
+        through the preparation store where this layout has a
+        ``content_key`` — the stored view is this raw one, so one
+        entry serves every ``s_pad`` and ``memory_report`` prices a
+        loaded view as a built one)."""
         if self._src_sorted_cache is None:
-            ids_l, off_l, dst_l, w_l = [], [], [], []
-            max_deg = 0
-            for r, p in enumerate(self.part_ids()):
-                nep = int(self.ne_part[p])
-                # the compressed index narrows edge offsets to int32
-                # (src_off, and the cumsum'd off in expand_frontier);
-                # safe because nep <= epad and build() rejects epad >=
-                # int32 max (the ValueError guard in ShardedGraph.build)
-                # global src of each real edge: src_slot is part-major
-                # slot; invert the slot translation
-                slot = self.src_slot[r, :nep].astype(np.int64)
-                sp = slot // self.vpad
-                src = self.starts[sp] + (slot - sp * self.vpad)
-                order = np.argsort(src, kind="stable")
-                uniq, counts = np.unique(src[order], return_counts=True)
-                if counts.size:
-                    max_deg = max(max_deg, int(counts.max()))
-                ids_l.append(uniq.astype(np.int32))
-                off_l.append(np.concatenate(
-                    ([0], np.cumsum(counts))).astype(np.int32))
-                dst_l.append(self.dst_local[r, :nep][order])
-                w_l.append(self.edge_weight[r, :nep][order]
-                           if self.weighted else None)
-            self._src_sorted_cache = (ids_l, off_l, dst_l, w_l, max_deg)
+            key = None
+            if self.content_key is not None and self.local_parts is None:
+                key = prepstore.derive(self.content_key, "src_sorted")
+            self._src_sorted_cache = prepstore.through(
+                "src_sorted", key, self._src_sort, _src_sorted_pack,
+                _src_sorted_unpack)
         return self._src_sorted_cache
+
+    def _src_sort(self):
+        ids_l, off_l, dst_l, w_l = [], [], [], []
+        max_deg = 0
+        for r, p in enumerate(self.part_ids()):
+            nep = int(self.ne_part[p])
+            # the compressed index narrows edge offsets to int32
+            # (src_off, and the cumsum'd off in expand_frontier);
+            # safe because nep <= epad and build() rejects epad >=
+            # int32 max (the ValueError guard in ShardedGraph.build)
+            # global src of each real edge: src_slot is part-major
+            # slot; invert the slot translation
+            slot = self.src_slot[r, :nep].astype(np.int64)
+            sp = slot // self.vpad
+            src = self.starts[sp] + (slot - sp * self.vpad)
+            order = np.argsort(src, kind="stable")
+            uniq, counts = np.unique(src[order], return_counts=True)
+            if counts.size:
+                max_deg = max(max_deg, int(counts.max()))
+            ids_l.append(uniq.astype(np.int32))
+            off_l.append(np.concatenate(
+                ([0], np.cumsum(counts))).astype(np.int32))
+            dst_l.append(self.dst_local[r, :nep][order])
+            w_l.append(self.edge_weight[r, :nep][order]
+                       if self.weighted else None)
+        return ids_l, off_l, dst_l, w_l, max_deg
 
     def src_unique_max(self) -> int:
         """Max unique-source count over the materialized parts (the
@@ -886,8 +1000,7 @@ class ShardedGraph:
                 # sources come from ANY part (nv ~ num_parts * vpad),
                 # not just this one's vpad — the old min(vpad, epad)
                 # under-priced exactly the multi-part big-scale fits
-                # this advisor gates (~200 MB/part at RMAT25 np=4,
-                # round-5 ADVICE #1)
+                # this advisor gates (~200 MB/part at RMAT25 np=4)
                 S = min(self.num_parts * self.vpad, self.epad)
             # src_ids + src_off int32 + ss_dst int32 (+ f32 ss_weight)
             sparse_bytes = 4 * (2 * S + 1) + self.epad * (4 + w)

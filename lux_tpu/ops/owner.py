@@ -122,7 +122,7 @@ class OwnerLayout:
         elif packed and E > np.iinfo(np.uint16).max:
             # n_valid is uint16 [R, C]; a bigger chunk would silently
             # wrap the live-lane count and corrupt the pad recovery
-            # (round-5 ADVICE #2 — the analogue of the vpad check)
+            # (the analogue of the vpad check)
             raise ValueError(
                 f"packed owner layout needs E <= "
                 f"{np.iinfo(np.uint16).max} (uint16 live-lane "

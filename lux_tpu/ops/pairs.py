@@ -563,15 +563,70 @@ def plan_sharded_pairs(sg, threshold: int,
     """``_plan_sharded_pairs`` under one ``build.pair_plan`` span
     (telemetry.span) whose counts say what the plan covers:
     ``pair_edges`` (edges served by pair rows) and ``residual_edges``
-    (left to the gather path)."""
-    from lux_tpu import telemetry
+    (left to the gather path).
+
+    A layout that carries a ``content_key`` (ShardedGraph.build) goes
+    through the preparation store (lux_tpu/prepstore.py) under that
+    key and ``threshold``, the resolved ``min_fill`` and ``kdim``: a
+    hit loads plan and residual, the miss's arrays byte for byte.
+    Multi-host local-parts builds stay away (the planner all-reduces
+    a profile, so every process would have to agree on hit or
+    miss)."""
+    from lux_tpu import prepstore, telemetry
 
     with telemetry.span("build.pair_plan") as span:
-        sp, residual = _plan_sharded_pairs(sg, threshold, min_fill,
-                                           kdim)
+        min_fill = resolve_min_fill(min_fill, kdim)
+        key = None
+        if sg.content_key is not None and sg.local_parts is None:
+            key = prepstore.derive(sg.content_key, "pair_plan",
+                                   threshold, min_fill, kdim)
+        sp, residual = prepstore.through(
+            "pair_plan", key,
+            lambda: _plan_sharded_pairs(sg, threshold, min_fill, kdim),
+            _plan_pack, lambda a, meta: _plan_unpack(sg, a, meta))
+        if key is not None and sp is not None:
+            # the residual is a layout of its own: its sparse view is
+            # not the full layout's
+            residual.content_key = prepstore.derive(key, "residual")
         covered = 0 if sp is None else int(sp.stats["covered"])
         span.count(pair_edges=covered,
                    residual_edges=int(np.sum(residual.ne_part)))
+    return sp, residual
+
+
+# the RESIDUAL layout's fields that _plan_sharded_pairs replaces
+_RESIDUAL_ARRAYS = ("src_slot", "dst_local", "edge_weight",
+                    "row_ptr_local", "ne_part")
+_PLAN_ARRAYS = ("rowbind", "rel_dst", "weight", "tile_pos", "row_tile")
+_PLAN_SCALARS = ("n_tiles", "n_slots", "R", "Rp")
+
+
+def _plan_pack(product):
+    """(arrays, meta) of a planner result for the store; the "no pair
+    anywhere" outcome is an entry without arrays."""
+    sp, residual = product
+    if sp is None:
+        return {}, {"none": True}
+    arrays = {n: getattr(sp, n) for n in _PLAN_ARRAYS}
+    arrays.update({"res_" + n: getattr(residual, n)
+                   for n in _RESIDUAL_ARRAYS})
+    meta = {n: int(getattr(sp, n)) for n in _PLAN_SCALARS}
+    meta.update(classes=[[int(c), int(L)] for c, L in sp.classes],
+                stats=sp.stats, epad=int(residual.epad))
+    return arrays, meta
+
+
+def _plan_unpack(sg, arrays, meta):
+    if meta.get("none"):
+        return None, sg
+    sp = StackedPairPlan(
+        classes=[(c, L) for c, L in meta["classes"]],
+        stats=meta["stats"],
+        **{n: arrays.get(n) for n in _PLAN_ARRAYS},
+        **{n: meta[n] for n in _PLAN_SCALARS})
+    residual = dataclasses.replace(
+        sg, epad=meta["epad"], _src_sorted_cache=None,
+        **{n: arrays.get("res_" + n) for n in _RESIDUAL_ARRAYS})
     return sp, residual
 
 
@@ -589,12 +644,9 @@ def _plan_sharded_pairs(sg, threshold: int, min_fill, kdim: int):
     agreement push uses, push.py), so every process compiles the SAME
     class structure and row shapes.
 
-    min_fill="auto" + kdim: K-aware break-even resolution (resolved
-    ONCE here so every part — and every process — caps on the same
-    fill; see resolve_min_fill)."""
-    import dataclasses as _dc
-
-    min_fill = resolve_min_fill(min_fill, kdim)
+    ``min_fill`` arrives RESOLVED (plan_sharded_pairs: "auto" + kdim
+    becomes the K-aware break-even fill ONCE, so every part — and
+    every process — caps on the same fill; see resolve_min_fill)."""
     if sg.vpad % W:
         raise ValueError("pair delivery needs vpad % 128 == 0; build "
                          "the ShardedGraph with vpad_align=128")
@@ -679,11 +731,11 @@ def _plan_sharded_pairs(sg, threshold: int, min_fill, kdim: int):
     # row_ptr_global, so sizing_row_ptr() (chunk geometry) is an
     # overestimate of the residual's chunks — consistent across
     # processes, just padded; pad chunks are isolated identities.
-    residual = _dc.replace(
+    residual = dataclasses.replace(
         sg, src_slot=src_slot, dst_local=dst_local, edge_weight=ew,
         row_ptr_local=row_ptr_local,
         ne_part=ne_part_r, epad=epad_r,
-        _src_sorted_cache=None)
+        _src_sorted_cache=None, content_key=None)
     return sp, residual
 
 

@@ -1,7 +1,9 @@
 """Process-level runtime set-up shared by every entry point.
 
-Two jobs: decide where JAX keeps its persistent compilation cache,
-and record what JAX compiles.  ``cli.main``, ``bench.main``,
+Three jobs: decide where JAX keeps its persistent compilation cache,
+decide where the host preparation's products are kept
+(``prep_store_dir``, read by ``lux_tpu/prepstore.py`` alone), and
+record what JAX compiles.  ``cli.main``, ``bench.main``,
 ``serve.main``, ``fleet.main`` and ``chip_smoke.py`` call
 ``use_compile_cache()`` first thing, before anything compiles; nothing
 else in the repo sets a cache directory.
@@ -33,6 +35,16 @@ def use_compile_cache() -> str:
     path = os.path.join(_CHECKOUT, ".jax_cache")
     jax.config.update("jax_compilation_cache_dir", path)
     return path
+
+
+def prep_store_dir() -> str:
+    """Where ``lux_tpu.prepstore`` keeps the relabelled graphs, pair
+    plans and sparse views it has computed: ``LUX_PREP_STORE_DIR``
+    where that is set (placed from outside, as the compile cache is),
+    else ``<checkout>/.prep_store`` (git-ignored, beside
+    ``.jax_cache``).  Deleting the directory clears the store."""
+    return (os.environ.get("LUX_PREP_STORE_DIR")
+            or os.path.join(_CHECKOUT, ".prep_store"))
 
 
 # JAX's own timers (jax.monitoring) -> telemetry ring records: the

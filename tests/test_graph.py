@@ -140,3 +140,35 @@ def test_src_sorted_compressed_index_oracle():
     with _pytest.raises(ValueError):
         sg.src_sorted(s_pad=1)
     assert sg.src_sorted(s_pad=64)["src_ids"].shape[1] == 64
+
+
+@pytest.mark.parametrize("weighted", [False, True])
+def test_memory_report_of_a_loaded_sparse_view_is_the_built_ones(
+        tmp_path, monkeypatch, weighted):
+    """A src-sorted view LOADED from the preparation store sets the
+    field the advisor prices the compressed source index from, so its
+    report is the built view's and not the no-view upper bound."""
+    from lux_tpu import prepstore
+    monkeypatch.setenv("LUX_PREP_STORE_DIR", str(tmp_path / "store"))
+    monkeypatch.setattr(prepstore, "MIN_EDGES", 0)
+    rng = np.random.default_rng(4)
+    nv, ne = 400, 900
+    src = rng.integers(0, 40, ne)        # few sources: S far below nv
+    dst = rng.integers(0, nv, ne)
+    w = rng.integers(1, 6, ne).astype(np.float32) if weighted else None
+
+    def layout():
+        return ShardedGraph.build(
+            Graph.from_edges(src, dst, nv, weights=w), 3)
+
+    bound = layout().memory_report(push_sparse=True)
+    built = layout()
+    built.src_sorted()
+    loaded = layout()
+    assert loaded._src_sorted_cache is None
+    loaded.src_unique_max()              # a hit: nothing is sorted
+    assert loaded._src_sorted_cache is not None
+    want = built.memory_report(push_sparse=True)
+    assert loaded.memory_report(push_sparse=True) == want
+    assert (want["push_sparse_bytes_per_part"]
+            < bound["push_sparse_bytes_per_part"])
