@@ -291,8 +291,12 @@ class Delivery:
                     "(the dot path passes per-edge weights)")
         self.exchange = exchange
         # psum_scatter-style fused min/max owner exchange (ring
-        # reduce-scatter, ops/owner.py) — opt-in until measured on a
-        # real mesh
+        # reduce-scatter, ops/owner.py).  Measured on the four-chip
+        # host (bfs.kron23.mesh4, PR 32; PERF.md section 6): the
+        # collectives fall from 0.317 to 0.277 ms an iteration and the
+        # dense branch rises from 134.8 to 136.5, ms_per_iter 212.9 ->
+        # 214.7: nothing to gain while the exchange is 0.15% of an
+        # iteration, so it stays opt-in
         self.owner_minmax_fused = bool(owner_minmax_fused)
         self.use_mxu = resolve_use_mxu(use_mxu, program)
         self.reduce_method = resolve_reduce_method(reduce_method)

@@ -390,6 +390,13 @@ class PushEngine(AuditableEngine):
         pidx = self._part_index()
         ranks, cnts = jax.vmap(fr.mask_ranks)(active)
 
+        def exchanged(fn, x):
+            # the sparse branch's collectives under one scope of a
+            # trace; on one device fn is an identity and the scope
+            # names nothing
+            with jax.named_scope("lux_sparse_exchange"):
+                return fn(x)
+
         def on_queue(Q):
             # 1. compact each local part's mask into a (global id,
             #    label) queue.
@@ -403,8 +410,8 @@ class PushEngine(AuditableEngine):
 
             # 2. exchange queues: [P_total * Q] flat, part-major order
             #    (identical on every device).
-            all_gids = gather_fn(gids).reshape(-1)
-            all_vals = gather_fn(vals).reshape(-1)
+            all_gids = exchanged(gather_fn, gids).reshape(-1)
+            all_vals = exchanged(gather_fn, vals).reshape(-1)
 
             # 3. where the gathered frontier's out-edges lie in each
             #    part's compressed src-sorted view, and how many they
@@ -413,7 +420,7 @@ class PushEngine(AuditableEngine):
                 lambda sids, soff: fr.frontier_extents(
                     all_gids, sids, soff, nv))(
                 g["src_ids"], g["src_off"])
-            eb_rung = fr.rung_index(pmax_fn(jnp.max(total)),
+            eb_rung = fr.rung_index(exchanged(pmax_fn, jnp.max(total)),
                                     self.budget_rungs)
 
             # 4. each part relaxes the frontier's edges that land in
@@ -456,7 +463,7 @@ class PushEngine(AuditableEngine):
             # 5. clear the globally-agreed processed prefix of the
             #    queue; everything else stays active (truncation
             #    safety).
-            done_min = pmin_fn(jnp.min(done))
+            done_min = exchanged(pmin_fn, jnp.min(done))
 
             # ids are global; convert back to local slots for clearing
             def clear_local(mask, gid, cnt, start, pidx):
