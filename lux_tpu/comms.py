@@ -438,7 +438,7 @@ def oracle_for(eng) -> list:
     lab_dt = lab_sds.dtype
     shard = (P_local, int(sg.vpad)) + trail
     out = [entry("psum", (), np.int32), entry("psum", (), np.int32)]
-    use_sparse, _limit = eng._sparse_mode()
+    use_sparse, _limit, pull = eng._sparse_mode()
     dense_branch = "dense" if use_sparse else ""
 
     dense = []
@@ -473,6 +473,14 @@ def oracle_for(eng) -> list:
                            branch=dense_branch))
     out += dense
 
+    if pull:
+        # the bottom-up step's choice (PushEngine._choose), before the
+        # cond: the largest part's unreached count, their edges, and
+        # the guard's best frontier label against the worst reached
+        out.append(entry("pmax", (), np.int32))
+        out.append(entry("psum", (), np.uint32))
+        out.append(entry("pmin", (), lab_dt))
+        out.append(entry("pmax", (), lab_dt))
     if use_sparse:
         # the ladder (engine/frontier.py): one alternative per queue
         # rung, holding the queue exchange at that rung's size, the
@@ -486,6 +494,12 @@ def oracle_for(eng) -> list:
                              branch=rung))
             out.append(entry("pmax", (), np.int32, branch=rung))
             out.append(entry("pmin", (), np.int32, branch=rung))
+            if pull:
+                # the pulled buffer of the gathered queue, combined
+                # across the mesh whichever way the slots ran
+                out.append(entry(
+                    "pmin" if eng.program.reduce == "min" else "pmax",
+                    (int(sg.num_parts) * Q,), lab_dt, branch=rung))
     del jax
     return out
 

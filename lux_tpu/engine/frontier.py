@@ -186,8 +186,9 @@ def expand_extents(vals, begin, off, edge_budget: int,
 
     vals [Q] are the queue items' labels; begin, off are
     ``frontier_extents``'.  Returns (edge_idx int32 [EB], src_val
-    [EB], in_range bool [EB]): edge_idx indexes the part's src-sorted
-    edge arrays, src_val is the owning queue item's label, and slots
+    [EB], in_range bool [EB], owner int32 [EB]): edge_idx indexes the
+    part's src-sorted edge arrays, owner is the queue index of the
+    item a slot belongs to and src_val that item's label, and slots
     past ``min(off[-1], EB)`` are masked by in_range (with more
     out-edges than slots the expansion is a prefix: the caller keeps
     the un-expanded queue suffix active).
@@ -239,7 +240,7 @@ def expand_extents(vals, begin, off, edge_budget: int,
                     + within).astype(jnp.int32)
         edge_idx = jnp.where(in_range, edge_idx, 0)
         src_val = jnp.take(vals, owner, axis=0)
-        return edge_idx, src_val, in_range
+        return edge_idx, src_val, in_range, owner
 
 
 def expand_frontier(ids, vals, src_ids, src_off, nv: int,
@@ -251,7 +252,7 @@ def expand_frontier(ids, vals, src_ids, src_off, nv: int,
     expansion is then a prefix, see ``expand_extents``).
     """
     begin, off, total = frontier_extents(ids, src_ids, src_off, nv)
-    edge_idx, src_val, in_range = expand_extents(
+    edge_idx, src_val, in_range, _owner = expand_extents(
         vals, begin, off, edge_budget, use_mxu=use_mxu)
     return edge_idx, src_val, in_range, total, off
 

@@ -24,6 +24,38 @@ The TPU-native design:
   The cond predicate is replicated (a psum), so the branch stays a
   branch — it is deliberately hoisted OUTSIDE the per-part vmap,
   where it would decay into select-both-sides.
+- The sparse branch has a third outcome, BOTTOM-UP (Beamer's
+  direction-optimizing BFS): a frontier too wide for the queue still
+  runs on it where the UNREACHED vertices fit instead.  The queue
+  then holds those, and each looks at its own neighbours for a
+  reached one: the budget stage takes a slot's candidate from the
+  neighbour's label and reduces it into the queue item that owns the
+  slot, where top-down it takes the item's label and scatters into
+  the neighbour.  Same stages, same compiled ladder, the direction a
+  replicated flag.  It is chosen only where the choice was DENSE and
+  three conditions hold, all observed by the loop (``_choose``), none
+  an option:
+  * the src-sorted view is also every vertex's in-edge list: the
+    graph is SYMMETRIC (decided once on the host,
+    ``ShardedGraph.edges_symmetric``; any other graph builds no such
+    step and keeps its program);
+  * no relaxation into a REACHED vertex can improve it: the best
+    candidate the frontier can offer (its best label, relaxed over
+    the view's extreme weights) is not better than the worst label a
+    reached vertex holds (hops: ``max_reached <= min_active + 1``).
+    Then everything the frontier can still improve is unreached, an
+    unreached vertex's extent holds the mirror of every edge into it,
+    and a reached neighbour that is not active was expanded with the
+    label it has, so pulling from every neighbour finds exactly what
+    pushing from the active ones would;
+  * the unreached vertices that have an edge fit WITHOUT truncation:
+    the largest part's count of them the top queue rung, their edges
+    the top budget rung.  A truncated pull would leave an item with
+    a label from some of its neighbours and nobody to revisit it.
+  Components (no label ever at the identity) and weighted SSSP away
+  from level-like fronts never meet them; the delta schedule builds
+  no step (a bucket's front leaves reached vertices unexpanded behind
+  it).
 - A sparse iteration costs per SLOT of its two static shapes (queue
   and edge budget), filled or not, so both are the top of a short
   ladder of rungs and each iteration runs on the smallest rungs that
@@ -185,6 +217,11 @@ class PushEngine(AuditableEngine):
         self.sparse_threshold = sparse_threshold
         dev = jnp.asarray if mesh is None else np.asarray
         self.enable_sparse = enable_sparse
+        # whether the bottom-up step is built (decided below, where
+        # the sparse view is: _sparse_mode), and the view's extreme
+        # weights for its guard (unweighted: relax takes None)
+        self.pull = False
+        self._weight_ends = (None,)
         if enable_sparse:
             # The compressed source index's pad size is a compiled
             # SHAPE: on multi-host runs agree on the max across every
@@ -197,6 +234,16 @@ class PushEngine(AuditableEngine):
                         multihost_utils.process_allgather(
                             np.asarray([s_pad]))))
                 ss = sg.src_sorted(s_pad=s_pad)
+                # The bottom-up step walks this view as every
+                # vertex's IN-edge list, which it is on a symmetric
+                # graph only; any other graph keeps the program it
+                # had.  Off under the delta schedule: a bucket's front
+                # leaves reached vertices unexpanded behind it, so the
+                # step's guard (module docstring) would seldom hold.
+                # The out-edge total the loop sums is uint32.
+                self.pull = (delta is None and sg.ne < 2 ** 32
+                             and program.reduce in ("min", "max")
+                             and sg.edges_symmetric())
             # Reference queue sizing rule (push_model.inl:393-397).
             self.queue_cap = frontier_capacity(sg.vpad, sparse_threshold)
             # The edge budget must cover any single vertex's out-edges
@@ -229,6 +276,13 @@ class PushEngine(AuditableEngine):
                                   np.int32)[:, None]))
             if ss["ss_weight"] is not None:
                 arrays["ss_weight"] = dev(ss["ss_weight"])
+                if self.pull:
+                    # the view's extreme weights, for the step's guard
+                    real = [w[:int(n)] for w, n in zip(
+                        ss["ss_weight"], sg.ne_part[sg.part_ids()])]
+                    self._weight_ends = (
+                        min(w.min(initial=np.inf) for w in real),
+                        max(w.max(initial=-np.inf) for w in real))
         if mesh is not None:
             arrays = shard_over_parts(mesh, arrays, sg.num_parts)
         self.arrays = arrays
@@ -369,26 +423,41 @@ class PushEngine(AuditableEngine):
 
     # -- sparse iteration ----------------------------------------------
 
-    def _sparse_parts(self, label, active, count, g, gather_fn,
-                      pmin_fn, pmax_fn):
+    def _sparse_parts(self, label, active, need, g, gather_fn,
+                      pmin_fn, pmax_fn, pull=None, unreached=None):
         """One frontier-queue iteration over this device's parts, on
-        the smallest static shapes that hold the frontier (the ladder,
+        the smallest static shapes that hold the queue (the ladder,
         engine/frontier.py) -> (label, active, 1 if the edge budget
         was a lower rung else 0).
 
-        count is the frontier's global size (it fits the top queue:
-        _sparse_mode).  gather_fn concatenates per-part queue arrays
-        across the whole mesh (identity + reshape on a single device);
-        pmin_fn / pmax_fn reduce a scalar across the mesh.  Both rung
-        indices are replicated scalars picked OUTSIDE the per-part
-        vmap, where a switch would decay into select-every-branch; the
-        branches hold the collectives, so every device takes the same.
+        need is what the queue must hold: the frontier's global size
+        (it fits the top queue: _sparse_mode).  gather_fn concatenates
+        per-part queue arrays across the whole mesh (identity +
+        reshape on a single device); pmin_fn / pmax_fn reduce across
+        the mesh.  Both rung indices are replicated scalars picked
+        OUTSIDE the per-part vmap, where a switch would decay into
+        select-every-branch; the branches hold the collectives, so
+        every device takes the same.
+
+        pull (engines with the bottom-up step, else None) is the
+        replicated flag of _choose: where it is set the queue holds
+        ``unreached`` instead of ``active``, need is the largest
+        part's count of them, and the budget stage runs the other way
+        round on the same slots: a slot's candidate comes from its
+        NEIGHBOUR's label and is reduced into the queue ITEM that owns
+        the slot (the [P_total * Q] queue behind the labels, combined
+        across parts under ``lux_pull`` and written into the items'
+        own labels).  One gather and one scatter a slot either way,
+        and one scatter of the queue back onto the vertices, selected
+        by the flag: the ladder is compiled once.
         """
         sg, prog = self.sg, self.program
         nv = sg.nv
         ssw = g.get("ss_weight")
         pidx = self._part_index()
-        ranks, cnts = jax.vmap(fr.mask_ranks)(active)
+        queued = active if pull is None else \
+            jnp.where(pull, unreached, active)
+        ranks, cnts = jax.vmap(fr.mask_ranks)(queued)
 
         def exchanged(fn, x):
             # the sparse branch's collectives under one scope of a
@@ -413,9 +482,9 @@ class PushEngine(AuditableEngine):
             all_gids = exchanged(gather_fn, gids).reshape(-1)
             all_vals = exchanged(gather_fn, vals).reshape(-1)
 
-            # 3. where the gathered frontier's out-edges lie in each
-            #    part's compressed src-sorted view, and how many they
-            #    are: the most any part holds picks the budget rung.
+            # 3. where the gathered queue's edges lie in each part's
+            #    compressed src-sorted view, and how many they are:
+            #    the most any part holds picks the budget rung.
             begin, off, total = jax.vmap(
                 lambda sids, soff: fr.frontier_extents(
                     all_gids, sids, soff, nv))(
@@ -423,68 +492,212 @@ class PushEngine(AuditableEngine):
             eb_rung = fr.rung_index(exchanged(pmax_fn, jnp.max(total)),
                                     self.budget_rungs)
 
-            # 4. each part relaxes the frontier's edges that land in
-            #    its partition.
+            # 4. each part relaxes the queue's edges that land in its
+            #    partition.  Where the bottom-up step is built the
+            #    labels carry the gathered queue's behind them: one
+            #    array is table and target whichever way a slot runs.
+            #    Top-down a slot reads its item's label there and
+            #    reduces into the neighbour; bottom-up it reads the
+            #    neighbour (an unreached one offers nothing) and
+            #    reduces into its item's slot, which holds the
+            #    identity an unreached vertex has.
+            wide = label if pull is None else jax.vmap(
+                lambda lab: jnp.concatenate([lab, all_vals]))(label)
+
             def on_budget(EB):
                 def relax_part(lab, begin, off, ssd, ssw):
-                    edge_idx, src_val, in_range = fr.expand_extents(
-                        all_vals, begin, off, EB, use_mxu=self.use_mxu)
+                    edge_idx, src_val, in_range, owner = \
+                        fr.expand_extents(all_vals, begin, off, EB,
+                                          use_mxu=self.use_mxu)
                     dst = jnp.take(ssd, edge_idx, axis=0)
                     w = jnp.take(ssw, edge_idx, axis=0) \
                         if ssw is not None else None
-                    cand = prog.relax(src_val, w)
-                    ident = jnp.asarray(prog.identity, cand.dtype)
-                    cand = jnp.where(in_range & (dst < sg.vpad), cand,
-                                     ident)
-                    dst = jnp.where(in_range, dst, sg.vpad - 1)
+                    if pull is None:
+                        cand = prog.relax(src_val, w)
+                        cand = jnp.where(
+                            in_range & (dst < sg.vpad), cand,
+                            jnp.asarray(prog.identity, cand.dtype))
+                        dst = jnp.where(in_range, dst, sg.vpad - 1)
+                    else:
+                        ok = in_range & (dst < sg.vpad)
+                        nbr = jnp.where(ok, dst, sg.vpad - 1)
+                        item = sg.vpad + owner
+                        src_val = jnp.take(
+                            lab, jnp.where(pull, nbr, item), axis=0)
+                        ok = ok & (src_val != jnp.asarray(
+                            prog.identity, lab.dtype))
+                        cand = prog.relax(src_val, w)
+                        cand = jnp.where(
+                            ok, cand,
+                            jnp.asarray(prog.identity, cand.dtype))
+                        dst = jnp.where(pull, item, nbr)
                     new = fr.scatter_reduce(lab, dst, cand, prog.reduce)
-                    improved = prog.better(new, lab)
+                    # (behind a queue the improvement is read off
+                    # the labels' part, after the switch)
+                    improved = () if pull is not None else \
+                        (prog.better(new, lab),)
                     # number of fully-expanded queue items (flat
                     # prefix): all of them on a lower rung
                     done = jnp.searchsorted(
                         off, jnp.asarray(EB, off.dtype), side="right",
                         method=fr.SEARCH)
-                    return new, improved, done.astype(jnp.int32)
+                    return new, *improved, done.astype(jnp.int32)
 
                 if ssw is None:
                     return jax.vmap(
                         lambda lab, begin, off, ssd: relax_part(
                             lab, begin, off, ssd, None))(
-                        label, begin, off, g["ss_dst"])
-                return jax.vmap(relax_part)(label, begin, off,
+                        wide, begin, off, g["ss_dst"])
+                return jax.vmap(relax_part)(wide, begin, off,
                                             g["ss_dst"], ssw)
 
-            new_label, improved, done = jax.lax.switch(
+            new_label, *improved, done = jax.lax.switch(
                 eb_rung, [jax.named_scope(f"lux_eb{i}")(
                     functools.partial(on_budget, EB))
                     for i, EB in enumerate(self.budget_rungs)])
-            improved = improved & vmask_of(g, sg.vpad)
+            if pull is None:
+                improved, = improved
+            else:
+                new_label, pulled = (new_label[:, :sg.vpad],
+                                     new_label[:, sg.vpad:])
+                improved = prog.better(new_label, label)
 
             # 5. clear the globally-agreed processed prefix of the
             #    queue; everything else stays active (truncation
             #    safety).
+            if pull is None:
+                improved = improved & vmask_of(g, sg.vpad)
             done_min = exchanged(pmin_fn, jnp.min(done))
 
-            # ids are global; convert back to local slots for clearing
-            def clear_local(mask, gid, cnt, start, pidx):
+            def processed_local(gid, cnt, pidx):
                 pos = jnp.arange(Q, dtype=jnp.int32)
-                flat_base = pidx * Q
-                processed = (flat_base + pos < done_min) & \
-                    (pos < cnt) & (gid < nv)
-                loc = jnp.clip(gid - start[0], 0, sg.vpad - 1)
-                upd = jnp.zeros((sg.vpad,), bool).at[loc].max(
-                    processed, mode="drop")
-                return mask & ~upd
+                return (pidx * Q + pos < done_min) & (pos < cnt) & \
+                    (gid < nv)
 
-            cleared = jax.vmap(clear_local)(active, gids, cnts,
-                                            g["part_start"], pidx)
+            if pull is None:
+                # ids are global; convert back to local slots for
+                # clearing
+                def clear_local(mask, gid, cnt, start, pidx):
+                    processed = processed_local(gid, cnt, pidx)
+                    loc = jnp.clip(gid - start[0], 0, sg.vpad - 1)
+                    upd = jnp.zeros((sg.vpad,), bool).at[loc].max(
+                        processed, mode="drop")
+                    return mask & ~upd
+
+                cleared = jax.vmap(clear_local)(active, gids, cnts,
+                                                g["part_start"], pidx)
+                low = eb_rung < len(self.budget_rungs) - 1
+                return (new_label, improved | cleared,
+                        low.astype(jnp.int32))
+
+            # 5'. with the bottom-up step ONE scatter takes the queue
+            #     back onto the vertices whichever way the slots ran
+            #     (a cond round two tails compiled to 6 MB more code):
+            #     top-down it marks the processed prefix, bottom-up it
+            #     carries each item's result, every part's share of it
+            #     combined (never truncated, so there is no prefix to
+            #     agree on).  Bottom-up the items that got a label are
+            #     the new frontier, and every vertex that was active is
+            #     spent: _choose's guard says it could improve none
+            #     but these.
+            ident = jnp.asarray(prog.identity, label.dtype)
+            across, over_parts, of_two = (
+                (pmin_fn, jnp.min, jnp.minimum) if prog.reduce == "min"
+                else (pmax_fn, jnp.max, jnp.maximum))
+            with jax.named_scope("lux_pull"):
+                mine = exchanged(across, over_parts(pulled, axis=0)) \
+                    .reshape(-1, Q)[pidx]
+
+            def onto_vertices(gid, cnt, start, pidx, val):
+                # a mark is any label that wins the reduce against
+                # the identity; a slot past the queue's count holds no
+                # item and brings the identity, a no-op wherever it
+                # lands
+                val = jnp.where(
+                    pull, jnp.where(gid < nv, val, ident),
+                    jnp.where(processed_local(gid, cnt, pidx),
+                              self._guard_floor(label.dtype), ident))
+                return fr.scatter_reduce(
+                    jnp.full((sg.vpad,), ident), gid - start[0], val,
+                    prog.reduce)
+
+            back = jax.vmap(onto_vertices)(gids, cnts, g["part_start"],
+                                           pidx, mine)
+            got = of_two(new_label, back)
+            kept = (improved & vmask_of(g, sg.vpad)) | \
+                (active & (back == ident))
             low = eb_rung < len(self.budget_rungs) - 1
-            return new_label, improved | cleared, low.astype(jnp.int32)
+            return (jnp.where(pull, got, new_label),
+                    jnp.where(pull, prog.better(got, new_label), kept),
+                    low.astype(jnp.int32))
 
         return jax.lax.switch(
-            fr.rung_index(count, self.queue_rungs),
+            fr.rung_index(need, self.queue_rungs),
             [jax.named_scope(f"lux_q{i}")(functools.partial(on_queue, Q))
              for i, Q in enumerate(self.queue_rungs)])
+
+    def _guard_floor(self, dtype):
+        """The label that wins the program's reduce against every
+        other: the least of ``dtype`` for a min program, the most for
+        a max one."""
+        info = (jnp.finfo if jnp.issubdtype(dtype, jnp.inexact)
+                else jnp.iinfo)(dtype)
+        return jnp.asarray(info.min if self.program.reduce == "min"
+                           else info.max, dtype)
+
+    def _choose(self, label, active, count, g):
+        """The per-iteration choice, traced -> (sparse, pull, need,
+        unreached): replicated booleans (run the sparse branch; run it
+        bottom-up), what its queue must hold, and the mask it then
+        compacts.  pull and unreached are None where the step is not
+        built (_sparse_mode).  Every reduction here is [P, vpad]-sized
+        or a scalar across the mesh."""
+        _usable, limit, pull_built = self._sparse_mode()
+        q_fits = count <= jnp.int32(limit)
+        if not pull_built:
+            return q_fits, None, count, None
+        prog, on_mesh = self.program, self.mesh is not None
+
+        def across(fn, x):
+            return fn(x, PARTS_AXIS) if on_mesh else x
+
+        ident = jnp.asarray(prog.identity, label.dtype)
+        reached = (label != ident) & vmask_of(g, self.sg.vpad)
+        # T: unreached vertices with an edge.  (deg is the out-degree,
+        # on a symmetric graph also the in-degree; 0 on padding.)
+        T = (label == ident) & (g["deg"] != 0) & ~active
+        t_most = across(jax.lax.pmax,
+                        jnp.max(jnp.sum(T.astype(jnp.int32), axis=1)))
+        t_edges = across(jax.lax.psum, jnp.sum(
+            jnp.where(T, g["deg"], 0).astype(jnp.uint32)))
+        # the frontier's best label and the worst label a reached
+        # vertex holds, whichever way the program reduces: (of an
+        # array, across the mesh)
+        lower, upper = (jnp.min, jax.lax.pmin), (jnp.max, jax.lax.pmax)
+        best, worst = (lower, upper) if prog.reduce == "min" \
+            else (upper, lower)
+        bottom = self._guard_floor(label.dtype)
+        best_active = across(best[1], best[0](
+            jnp.where(active, label, ident)))[None]
+        worst_reached = across(worst[1], worst[0](
+            jnp.where(reached, label, bottom)))
+        # no relaxation into a REACHED vertex can improve it: the best
+        # candidate the frontier can offer (its best label over the
+        # view's extreme weights; relax is monotone) is not better
+        # than the worst label a reached vertex holds
+        offers = [prog.relax(best_active,
+                             None if w is None
+                             else jnp.full((1,), w, jnp.float32))[0]
+                  for w in self._weight_ends]
+        guard = functools.reduce(
+            jnp.logical_and,
+            [~prog.better(c, worst_reached.astype(c.dtype))
+             for c in offers])
+        fits = ((t_most > 0)
+                & (t_most <= jnp.int32(self.queue_rungs[-1]))
+                & (t_edges <= jnp.uint32(self.budget_rungs[-1])))
+        pull = ~q_fits & fits & guard
+        return (q_fits | pull, pull, jnp.where(pull, t_most, count), T)
 
     def _part_index(self):
         """Global part index of this device's parts [P_local] int32."""
@@ -527,7 +740,10 @@ class PushEngine(AuditableEngine):
         graph_args = tuple(self.arrays[k] for k in keys)
         on_mesh = self.mesh is not None
         sg, prog = self.sg, self.program
-        use_sparse, sparse_limit = self._sparse_mode()
+        use_sparse, _limit, pull_built = self._sparse_mode()
+        # the loops' counter carry, last: sparse_iters, low_rung_iters
+        # and, where the bottom-up step is built, pull_iters
+        n_took = 2 + int(pull_built)
         cap_n = self.stats_cap
 
         def global_sum(x):
@@ -609,31 +825,39 @@ class PushEngine(AuditableEngine):
             return self._dense_parts(label, active, full_l, full_a, g)
 
         def body(label, active, count, g):
-            """-> (label, active, took): took = int32 [2], 1 if the
-            SPARSE branch ran and 1 if it ran below the top edge
-            budget — what the loops sum into their ``sparse_iters`` /
-            ``low_rung_iters`` carry (the device-side counters
-            telemetry reads)."""
+            """-> (label, active, took): took = int32 [n_took], 1 if
+            the SPARSE branch ran, 1 if it ran below the top edge
+            budget and (engines with the bottom-up step) 1 if it ran
+            bottom-up — what the loops sum into their ``sparse_iters``
+            / ``low_rung_iters`` / ``pull_iters`` carry (the
+            device-side counters telemetry reads)."""
             if not use_sparse:
                 return (*dense_body(label, active, g),
                         jnp.zeros((2,), jnp.int32))
 
             # Reference heuristic: frontier > nv/16 -> dense/pull mode
-            # (sssp_gpu.cu:414), and the queue must fit (_sparse_mode).
+            # (sssp_gpu.cu:414), and the queue must fit; a frontier
+            # too wide for it still takes the sparse branch, bottom-up,
+            # where the UNREACHED vertices fit instead (_choose).
+            sparse, pull, need, unreached = self._choose(
+                label, active, count, g)
+
             def sparse_branch():
                 with jax.named_scope("lux_sparse"):
-                    return self._sparse_parts(label, active, count, g,
+                    return self._sparse_parts(label, active, need, g,
                                               gather_fn, pmin_fn,
-                                              pmax_fn)
+                                              pmax_fn, pull, unreached)
 
             def dense_branch():
                 with jax.named_scope("lux_dense"):
                     return (*dense_body(label, active, g), jnp.int32(0))
 
-            q_fits = count <= jnp.int32(sparse_limit)
-            nl, na, low = jax.lax.cond(q_fits, sparse_branch,
+            nl, na, low = jax.lax.cond(sparse, sparse_branch,
                                        dense_branch)
-            return nl, na, jnp.stack([q_fits.astype(jnp.int32), low])
+            took = [sparse.astype(jnp.int32), low]
+            if pull is not None:
+                took.append(pull.astype(jnp.int32))
+            return nl, na, jnp.stack(took)
 
         use_delta = converge and self.delta is not None
 
@@ -702,8 +926,9 @@ class PushEngine(AuditableEngine):
                 # non-empty.
                 # carry: (it, lbl, act, B, cnt, [4 stats buffers],
                 # [health word, stall], took) — the counters
-                # (sparse_iters, low_rung_iters: body's int32 [2])
-                # ride LAST so every index before it stands
+                # (sparse_iters, low_rung_iters[, pull_iters]: body's
+                # int32 [n_took]) ride LAST so every index before it
+                # stands
                 def cond(c):
                     it, lbl, act, B, cnt = c[:5]
                     ok = (cnt > 0) & (it < max_iters)
@@ -780,9 +1005,10 @@ class PushEngine(AuditableEngine):
                 if health:
                     init = init + (h0, stall0)
                 out = jax.lax.while_loop(
-                    cond, wbody, init + (jnp.zeros((2,), jnp.int32),))
+                    cond, wbody,
+                    init + (jnp.zeros((n_took,), jnp.int32),))
                 # (lbl, act, it, [stats], [health], sparse_iters,
-                # low_rung_iters)
+                # low_rung_iters[, pull_iters])
                 return (out[1], out[2], out[0], *out[5:-1], *out[-1])
 
             # carry: (it, lbl, act, cnt, [4 stats buffers], [health
@@ -834,9 +1060,9 @@ class PushEngine(AuditableEngine):
             if health:
                 init = init + (h0, stall0)
             out = jax.lax.while_loop(
-                cond, wbody, init + (jnp.zeros((2,), jnp.int32),))
+                cond, wbody, init + (jnp.zeros((n_took,), jnp.int32),))
             # (lbl, act, it, [stats], [health], sparse_iters,
-            # low_rung_iters)
+            # low_rung_iters[, pull_iters])
             return (out[1], out[2], out[0], *out[4:-1], *out[-1])
 
         if prog.name:
@@ -854,9 +1080,9 @@ class PushEngine(AuditableEngine):
                 # psum/pmin'd scalars, identical on every device
                 out_specs = out_specs + (P(), P())
             if converge:
-                # sparse_iters / low_rung_iters sum predicates of the
-                # psum'd count and the pmax'd out-edge total
-                out_specs = out_specs + (P(), P())
+                # the counters sum predicates of the psum'd count,
+                # the pmax'd out-edge total and _choose's scalars
+                out_specs = out_specs + (P(),) * n_took
             in_specs = (P(PARTS_AXIS), P(PARTS_AXIS), P())
             if health:
                 in_specs = in_specs + (P(), P())    # h0, stall0
@@ -893,6 +1119,12 @@ class PushEngine(AuditableEngine):
 
         self._register_variant(vname, jitted, _args_thunk)
 
+        def mark(it, took):
+            # pull_iters is 0 where the step is not built
+            telemetry.mark("push.converge", iters=it,
+                           **dict(zip(("sparse_iters", "low_rung_iters",
+                                       "pull_iters"), (*took, 0))))
+
         if health:
             from lux_tpu import health as _hw
 
@@ -900,12 +1132,11 @@ class PushEngine(AuditableEngine):
                      watch=None):
                 if watch is None:
                     watch = (_hw.init_word(), jnp.int32(0))
-                (l, a, it, fsz, fed, fszp, fedp, h, stall, ns,
-                 nlow) = jitted(
+                (l, a, it, fsz, fed, fszp, fedp, h, stall,
+                 *took) = jitted(
                     label, active, jnp.int32(max_iters), *watch,
                     *extra, *graph_args)
-                telemetry.mark("push.converge", iters=it,
-                               sparse_iters=ns, low_rung_iters=nlow)
+                mark(it, took)
                 return l, a, it, fsz, fed, fszp, fedp, (h, stall)
 
             return call
@@ -914,16 +1145,16 @@ class PushEngine(AuditableEngine):
             """One dispatch; stays asynchronous.  A converge variant
             leaves a ``push.converge`` mark whose ``iters`` /
             ``sparse_iters`` / ``low_rung_iters`` (the sparse
-            iterations whose edge budget was a lower rung) are the
+            iterations whose edge budget was a lower rung) /
+            ``pull_iters`` (those that ran bottom-up) are the
             un-fetched device scalars (fetched at
             ``telemetry.spans()``, never here)."""
             out = jitted(label, active, jnp.int32(max_iters), *extra,
                          *graph_args)
             if not converge:
                 return out
-            *out, ns, nlow = out
-            telemetry.mark("push.converge", iters=out[2],
-                           sparse_iters=ns, low_rung_iters=nlow)
+            out, took = out[:-n_took], out[-n_took:]
+            mark(out[2], took)
             return tuple(out)
 
         return call
@@ -1162,16 +1393,41 @@ class PushEngine(AuditableEngine):
         return {k: jax.jit(f) for k, f in fns.items()}
 
     def _sparse_mode(self):
-        """Single source of truth for the sparse-vs-dense choice (also
-        traced inside the compiled step, _build's q_fits): returns
-        (usable, count_limit) — the reference's frontier > nv/16 pull
-        switch (sssp_gpu.cu:414) AND the queue capacity."""
+        """Single source of truth for the per-iteration choice (traced
+        inside the compiled step by _choose): returns (usable,
+        count_limit, pull) — the reference's frontier > nv/16 pull
+        switch (sssp_gpu.cu:414) AND the queue capacity, and whether a
+        frontier over that limit may still run sparse, bottom-up
+        (_choose's three conditions; built on symmetric graphs
+        only)."""
         usable = (self.enable_sparse
                   and self.program.reduce in ("min", "max"))
         limit = min(self.queue_cap,
                     max(1, self.sg.nv // self.sparse_threshold)) \
             if self.enable_sparse else 0
-        return usable, limit
+        return usable, limit, self.pull
+
+    @functools.cached_property
+    def _runs_sparse(self):
+        """(label, active, *graph arrays) -> _choose's ``sparse`` as a
+        program of its own, for the stepwise twin of the fused loop
+        (_relax_once)."""
+        keys = sorted(self.arrays)
+
+        def runs_sparse(label, active, *gargs):
+            count = jnp.sum(active.astype(jnp.int32))
+            if self.mesh is not None:
+                count = jax.lax.psum(count, PARTS_AXIS)
+            return self._choose(label, active, count,
+                                dict(zip(keys, gargs)))[0]
+
+        if self.mesh is not None:
+            S = PartitionSpec(PARTS_AXIS)
+            runs_sparse = jax.shard_map(
+                runs_sparse, mesh=self.mesh,
+                in_specs=(S,) * (2 + len(keys)),
+                out_specs=PartitionSpec())
+        return jax.jit(runs_sparse)
 
     def _relax_once(self, label, active, cnt, t, jits, gargs):
         """One instrumented relaxation of ``active``, recording phase
@@ -1184,8 +1440,9 @@ class PushEngine(AuditableEngine):
         from lux_tpu.engine.phased import PhaseTimer
         from lux_tpu.timing import fetch
 
-        use_sparse, sparse_limit = self._sparse_mode()
-        if use_sparse and cnt <= sparse_limit:
+        use_sparse, sparse_limit, pull = self._sparse_mode()
+        if use_sparse and (cnt <= sparse_limit or (pull and bool(
+                fetch(self._runs_sparse(label, active, *gargs))))):
             t0 = _time.perf_counter()
             label, na, c = self.step(label, active)
             cnt = int(fetch(c))

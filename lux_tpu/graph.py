@@ -761,6 +761,13 @@ class ShardedGraph:
                 _src_sorted_unpack)
         return self._src_sorted_cache
 
+    def _global_src(self, r: int, nep: int) -> np.ndarray:
+        """Global source id of row ``r``'s first ``nep`` edges:
+        src_slot is the part-major slot; invert the translation."""
+        of_slot = (self.starts[:-1, None]
+                   + np.arange(self.vpad, dtype=np.int64)).reshape(-1)
+        return of_slot[self.src_slot[r, :nep]]
+
     def _src_sort(self):
         ids_l, off_l, dst_l, w_l = [], [], [], []
         max_deg = 0
@@ -770,11 +777,7 @@ class ShardedGraph:
             # (src_off, and the cumsum'd off in expand_frontier);
             # safe because nep <= epad and build() rejects epad >=
             # int32 max (the ValueError guard in ShardedGraph.build)
-            # global src of each real edge: src_slot is part-major
-            # slot; invert the slot translation
-            slot = self.src_slot[r, :nep].astype(np.int64)
-            sp = slot // self.vpad
-            src = self.starts[sp] + (slot - sp * self.vpad)
+            src = self._global_src(r, nep)
             order = np.argsort(src, kind="stable")
             uniq, counts = np.unique(src[order], return_counts=True)
             if counts.size:
@@ -786,6 +789,70 @@ class ShardedGraph:
             w_l.append(self.edge_weight[r, :nep][order]
                        if self.weighted else None)
         return ids_l, off_l, dst_l, w_l, max_deg
+
+    # ---- is the src-sorted view also every vertex's IN-edge list? ----
+
+    _symmetric_cache: bool | None = dataclasses.field(
+        default=None, repr=False, compare=False)
+
+    def edges_symmetric(self) -> bool:
+        """Whether the stored edge multiset equals its own transpose
+        (``(src, dst[, weight])`` against ``(dst, src[, weight])``,
+        duplicates and self-loops counted): then each part's
+        src-sorted view is also the in-edge list of the sources it
+        names, which is what the push engine's bottom-up step walks
+        (engine/push.py).  Exact, decided on the host once: a vertex
+        whose in- and out-degree differ settles it in O(nv); a graph
+        that passes that is compared key for key, and the bit is kept
+        in the preparation store under a key of its own (the stored
+        view does not change).  False on a local-parts build, where no
+        process sees every edge."""
+        if self._symmetric_cache is None:
+            self._symmetric_cache = self._edges_symmetric()
+        return self._symmetric_cache
+
+    def _edges_symmetric(self) -> bool:
+        if self.local_parts is not None:
+            return False
+        in_deg = np.diff(self.row_ptr_local, axis=1)
+        if not np.array_equal(in_deg, self.deg_padded):
+            return False
+        key = None
+        if self.content_key is not None:
+            key = prepstore.derive(self.content_key, "symmetric")
+        return bool(prepstore.through(
+            "symmetric", key, self._compare_with_transpose,
+            lambda bit: (dict(bit=np.asarray([bit], np.uint8)), {}),
+            lambda arrays, _meta: arrays["bit"][0]))
+
+    def _compare_with_transpose(self) -> bool:
+        """Every edge as the key ``src * nv + dst`` and as its
+        transpose's, both sorted: the multisets are equal where the
+        sorted keys are (weighted: keys and weights under one
+        lexicographic order).  The keys are written in place, part by
+        part: fresh arrays of this size cost more to fault in than to
+        fill."""
+        ends = np.concatenate(([0], np.cumsum(self.ne_part)))
+        fwd = np.empty(int(ends[-1]), np.int64)
+        rev = np.empty_like(fwd)
+        for p in range(self.num_parts):
+            nep, at = int(self.ne_part[p]), slice(ends[p], ends[p + 1])
+            src = self._global_src(p, nep)
+            dst = self.dst_local[p, :nep].astype(np.int64)
+            dst += self.starts[p]
+            np.multiply(src, self.nv, out=fwd[at])
+            fwd[at] += dst
+            np.multiply(dst, self.nv, out=rev[at])
+            rev[at] += src
+        if not self.weighted:
+            fwd.sort()
+            rev.sort()
+            return np.array_equal(fwd, rev)
+        w = np.concatenate([self.edge_weight[p, :int(n)]
+                            for p, n in enumerate(self.ne_part)])
+        of, orv = np.lexsort((w, fwd)), np.lexsort((w, rev))
+        return (np.array_equal(fwd[of], rev[orv])
+                and np.array_equal(w[of], w[orv]))
 
     def src_unique_max(self) -> int:
         """Max unique-source count over the materialized parts (the

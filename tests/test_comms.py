@@ -131,6 +131,23 @@ def _mode_engines():
     out.append(("ksssp_batched_mesh2",
                 sssp.build_engine(g, num_parts=2, mesh=mesh_of(2),
                                   sources=[0, 3, 7, 11])))
+    # a symmetric graph builds the bottom-up step: its choice's four
+    # scalars before the cond, the pulled buffer's combine in every
+    # queue rung (min and max programs)
+    from lux_tpu.graph import Graph
+    src, dst = g.edge_arrays()
+    sym = Graph.from_edges(np.concatenate([src, dst]),
+                           np.concatenate([dst, src]), g.nv)
+    out.append(("pull_step_min_mesh2",
+                sssp.build_engine(sym, 0, num_parts=2,
+                                  mesh=mesh_of(2))))
+    out.append(("pull_step_min_owner_mesh8",
+                sssp.build_engine(sym, 0, num_parts=8, mesh=mesh_of(8),
+                                  exchange="owner")))
+    out.append(("pull_step_max_mesh2",
+                components.build_engine(sym, num_parts=2,
+                                        mesh=mesh_of(2))))
+    assert all(e.pull for _l, e in out[-3:])
     return out
 
 

@@ -201,7 +201,7 @@ def test_expand_extents_on_a_lower_rung_is_the_top_rungs_prefix(
     begin, off, total = fr.frontier_extents(ids, sids, soff, nv)
     assert int(total) == int(top[3]) == 6
     np.testing.assert_array_equal(np.asarray(off), np.asarray(top[4]))
-    edge_idx, src_val, in_range = fr.expand_extents(
+    edge_idx, src_val, in_range, _owner = fr.expand_extents(
         vals, begin, off, budget, use_mxu=use_mxu)
     k = min(budget, 6)
     assert np.asarray(in_range).tolist() == [True] * k + \
@@ -228,3 +228,30 @@ def test_compact_mask_on_a_lower_rung_is_the_top_rungs_prefix(capacity):
     np.testing.assert_array_equal(np.asarray(vals)[:k],
                                   np.asarray(top_vals)[:k])
     assert (np.asarray(ids)[k:] == 7).all()
+
+
+@pytest.mark.parametrize("use_mxu", [False, True])
+@pytest.mark.parametrize("degs,budget", [
+    ([2, 0, 3, 1], 25), ([2, 0, 3, 1], 6), ([2, 0, 3, 1], 4),
+    ([0, 0, 5], 8), ([1, 1, 1, 1, 1, 1, 1], 7), ([9, 0, 0, 2, 4], 12)])
+def test_expand_extents_owner_is_the_repeated_queue_index(degs, budget,
+                                                          use_mxu):
+    """The owning queue INDEX of every in-range slot against NumPy's
+    ``np.repeat`` of the queue positions by their degrees (what the
+    bottom-up step reduces a slot's candidate into), with an absent
+    source in the queue and budgets over, at and under the total; the
+    slot's value is the owner's."""
+    ids, vals, sids, soff, nv = _star_queue(degs)
+    ids = jnp.concatenate([jnp.asarray([nv], jnp.int32), ids])  # absent
+    vals = jnp.concatenate([jnp.asarray([0], jnp.int32), vals])
+    begin, off, total = fr.frontier_extents(ids, sids, soff, nv)
+    _edge_idx, src_val, in_range, owner = fr.expand_extents(
+        vals, begin, off, budget, use_mxu=use_mxu)
+    want = np.repeat(np.arange(len(degs) + 1), [0, *degs])
+    k = min(budget, int(total))
+    assert int(total) == want.size and int(np.asarray(in_range).sum()) == k
+    np.testing.assert_array_equal(np.asarray(owner)[:k], want[:k])
+    np.testing.assert_array_equal(np.asarray(src_val)[:k],
+                                  np.asarray(vals)[want[:k]])
+    assert (np.asarray(owner) >= 0).all() and \
+        (np.asarray(owner) <= len(degs)).all()

@@ -455,3 +455,97 @@ def test_relabel_span_has_stages_on_a_miss_and_none_on_a_hit(store,
     # -verbose prints the stages that are there: the miss's
     assert [ln.split("/")[1].split(":")[0] for ln in printed] == [
         "degree_sort", "pair_histogram", "deal", "rebuild_csc"]
+
+
+# ---- the symmetry bit (PR 33): an entry of its own -------------------
+
+
+def sym_edges(weighted=False, seed=11, nv=3 * W, ne=4000):
+    """A symmetrized edge list with duplicates (zipf draws repeat
+    pairs) and self-loops (mirrored onto themselves, so doubled)."""
+    src, dst, nv, w = edges(weighted, seed=seed, nv=nv, ne=ne)
+    src, dst = src.astype(np.int64), dst.astype(np.int64)
+    loops = np.arange(0, nv, 37)
+    src, dst = (np.concatenate([src, loops]),
+                np.concatenate([dst, loops]))
+    if w is not None:
+        w = np.concatenate([w, np.full(loops.size, 2, np.float32)])
+    assert (src == dst).any() and \
+        np.unique(src * nv + dst).size < src.size
+    return (np.concatenate([src, dst]), np.concatenate([dst, src]), nv,
+            None if w is None else np.concatenate([w, w]))
+
+
+def _sharded(src, dst, nv, w, num_parts):
+    return ShardedGraph.build(Graph.from_edges(src, dst, nv, weights=w),
+                              num_parts)
+
+
+@pytest.mark.parametrize("num_parts", [1, 3])
+@pytest.mark.parametrize("case,want", [
+    ("symmetrized", True), ("one-edge-dropped", False),
+    ("balanced-cycle", False), ("weighted", True),
+    ("one-weight-changed", False), ("directed", False)])
+def test_the_symmetry_bit(store, case, want, num_parts):
+    """True for symmetrized inputs with duplicates and self-loops
+    (weights mirrored too), false after one edge is dropped, for a
+    directed cycle laid over a symmetric graph (every vertex's in- and
+    out-degree still equal, so only the key comparison can tell), and
+    after one mirror's weight is changed."""
+    weighted = case in ("weighted", "one-weight-changed")
+    src, dst, nv, w = sym_edges(weighted)
+    if case == "one-edge-dropped":
+        keep = np.flatnonzero(src != dst)[5]
+        src, dst = np.delete(src, keep), np.delete(dst, keep)
+    elif case == "balanced-cycle":
+        src = np.concatenate([src, [3, 50, 200]])
+        dst = np.concatenate([dst, [50, 200, 3]])
+    elif case == "one-weight-changed":
+        w = w.copy()
+        w[np.flatnonzero(src != dst)[5]] += 1
+    elif case == "directed":
+        src, dst, nv, w = edges()
+    sg = _sharded(src, dst, nv, w, num_parts)
+    assert sg.edges_symmetric() is want
+    # the degree test alone settles a graph whose degrees differ: it
+    # never reaches the store
+    reached_store = case not in ("one-edge-dropped", "directed")
+    assert any(e.startswith("symmetric-") for e in entries(store)) \
+        == reached_store
+    # a second process: the bit comes from the store
+    tip = ring_tip()
+    assert _sharded(src, dst, nv, w, num_parts).edges_symmetric() is want
+    assert lookups(tip) == ([(1, 0)] if reached_store else [])
+
+
+def test_a_store_from_before_the_bit_still_hits(store):
+    """The bit is an entry of its own: the format version did not
+    move, so every entry a store held before it (relabel, pair plan,
+    src-sorted view) is still a hit, and the one new lookup is a miss
+    that is written once."""
+    assert prepstore.FORMAT_VERSION == 1
+    src, dst, nv, _w = sym_edges()
+
+    def build():
+        got = prepare(Graph.from_edges(src, dst, nv), 2, sparse=True)
+        return got, got["sg"].edges_symmetric()
+
+    first, bit = build()
+    assert bit is True
+    held = entries(store)
+    assert sorted(e.split("-")[0] for e in held) == \
+        ["pair_plan", "relabel", "src_sorted", "symmetric"]
+    # a store as the parent commit left it: no such entry yet
+    import shutil
+    for e in held:
+        if e.startswith("symmetric-"):
+            shutil.rmtree(store / e)
+    tip = ring_tip()
+    again, bit = build()
+    assert bit is True
+    assert lookups(tip) == [(1, 0), (1, 0), (1, 0), (0, 1)]
+    assert entries(store) == held
+    assert_same_products(first, again)
+    tip = ring_tip()
+    build()
+    assert lookups(tip) == [(1, 0)] * 4

@@ -366,7 +366,7 @@ def _rule(eng, g, active_host):
     part = np.searchsorted(eng.sg.starts, dst[act[src]],
                            side="right") - 1
     total = int(np.bincount(part, minlength=eng.sg.num_parts).max())
-    usable, limit = eng._sparse_mode()
+    usable, limit, _pull = eng._sparse_mode()
     sparse = usable and count <= limit
     return count, total, sparse, sparse and total <= eng.budget_rungs[-2]
 
@@ -470,3 +470,291 @@ def test_ladder_hub_over_every_lower_budget_takes_the_top(kind):
     assert (mark["sparse_iters"], mark["low_rung_iters"]) == (1, 0)
     got = eng.unpad(label)
     assert (got[_T0:_T0 + 1024] == 1).all() and got[10] == 0
+
+
+# ---------------------------------------------------------------------
+# the bottom-up step (PR 33): a frontier too wide for the queue still
+# runs on the sparse ladder where the UNREACHED vertices fit it, the
+# graph is symmetric and no reached vertex can improve
+
+
+def _mark_after(eng, label, active, max_iters=None):
+    """converge from a host state -> (labels [nv], iterations, mark)."""
+    label, _a, it = eng.converge(*eng.place(label, active), max_iters)
+    return eng.unpad(label), int(it), _last_mark()
+
+
+def _bfs_levels(g, root):
+    """Plain frontier BFS in NumPy -> hop labels, HOP_INF unreached."""
+    src, dst = g.edge_arrays()
+    order = np.argsort(src, kind="stable")
+    nbr = dst[order]
+    off = np.concatenate([[0], np.cumsum(np.bincount(src,
+                                                     minlength=g.nv))])
+    levels = np.full(g.nv, int(sssp.HOP_INF), np.int64)
+    levels[root] = 0
+    frontier, depth = np.asarray([root]), 0
+    while frontier.size:
+        depth += 1
+        seen = np.unique(np.concatenate(
+            [nbr[off[v]:off[v + 1]] for v in frontier]))
+        frontier = seen[levels[seen] > depth]
+        levels[frontier] = depth
+    return levels
+
+
+@functools.lru_cache(maxsize=None)
+def _kron_engines(num_parts=1):
+    """A symmetrized Kronecker graph (scale 12) -> (graph, engine,
+    the same engine with the step unavailable): the queue shrunk
+    (``sparse_threshold`` 32), and still a search's wide late level
+    leaves fewer unreached vertices than it holds."""
+    from lux_tpu.engine.push import PushEngine
+    from lux_tpu.graph import ShardedGraph
+    src, dst, nv = rmat_edges(12, 16, 3)
+    g = Graph.from_edges(np.concatenate([src, dst]),
+                         np.concatenate([dst, src]), nv)
+    sg = ShardedGraph.build(g, num_parts)
+
+    def build():
+        return PushEngine(sg, sssp.make_program(0), sparse_threshold=32)
+
+    eng = build()
+    with pytest.MonkeyPatch.context() as mp:
+        # the seam: the view is not taken for an in-edge list
+        sg._symmetric_cache = None
+        mp.setattr(ShardedGraph, "edges_symmetric", lambda self: False)
+        off = build()
+    assert eng.pull and not off.pull
+    assert eng._sparse_mode() == off._sparse_mode()[:2] + (True,)
+    return g, eng, off
+
+
+def _root_state(eng, root):
+    label = np.full(eng.sg.nv, sssp.HOP_INF, np.int32)
+    active = np.zeros(eng.sg.nv, bool)
+    label[root], active[root] = 0, True
+    return eng.sg.to_padded(label), eng.sg.to_padded(active)
+
+
+def _sweep_roots(g):
+    deg = np.asarray(g.out_degrees)
+    some = np.flatnonzero(deg > 0)
+    return [int(np.argmax(deg)), int(np.flatnonzero(deg == 1)[0]),
+            int(np.flatnonzero(deg == 16)[0]),
+            *map(int, some[:: len(some) // 5][:5])]
+
+
+@pytest.mark.parametrize("k", range(8))
+def test_pull_step_sweep_equals_the_frontier_bfs(k):
+    """(a) every root of a sweep: labels are the NumPy BFS's, the
+    iteration count is the one without the step (a dense iteration
+    became a bottom-up one that finds the same vertices), and the step
+    ran."""
+    g, eng, off = _kron_engines()
+    root = _sweep_roots(g)[k]
+    got, it, mark = _mark_after(eng, *_root_state(eng, root))
+    want, it_off, mark_off = _mark_after(off, *_root_state(off, root))
+    np.testing.assert_array_equal(got, _bfs_levels(g, root))
+    np.testing.assert_array_equal(got, want)
+    assert it == it_off
+    assert mark_off["pull_iters"] == 0
+    assert mark["pull_iters"] >= 1
+    assert mark["sparse_iters"] == \
+        mark_off["sparse_iters"] + mark["pull_iters"]
+
+
+def test_pull_step_is_not_built_on_a_directed_graph():
+    """(b) a directed graph keeps the program it had: no step, two
+    counters behind the public outputs, no ``lux_pull`` in the lowered
+    text, ``pull_iters`` 0 on the mark."""
+    src, dst, nv = rmat_edges(10, 8, 0)
+    eng = sssp.build_engine(Graph.from_edges(src, dst, nv), 0)
+    assert not eng.pull and eng._sparse_mode()[2] is False
+    jitted, args = eng.audit_variant("converge")
+    assert len(jax.eval_shape(jitted, *args())) == 3 + 2
+    assert "lux_pull" not in jitted.lower(*args()).as_text(
+        debug_info=True)
+    _labels, it = eng.run()
+    mark = _last_mark()
+    assert mark["pull_iters"] == 0 and mark["iters"] == it
+    _g, on, _off = _kron_engines()
+    jitted, args = on.audit_variant("converge")
+    assert len(jax.eval_shape(jitted, *args())) == 3 + 3
+    assert "lux_pull" in jitted.lower(*args()).as_text(debug_info=True)
+
+
+# a hand-built symmetric graph for the three conditions: root 0, a
+# wide level A (600 vertices, past the count limit of 256), and the
+# unreached T behind it, each T vertex tied to ``fan`` vertices of A
+_HUB_NV, _HUB_A = 4096, 600
+
+
+@functools.lru_cache(maxsize=None)
+def _hub_case(n_t, fan, edge_budget):
+    from lux_tpu.engine.push import PushEngine
+    from lux_tpu.graph import ShardedGraph
+    a = 1 + np.arange(_HUB_A)
+    t = 1 + _HUB_A + np.arange(n_t)
+    src = [np.zeros(_HUB_A, np.int64), np.repeat(t, fan)]
+    dst = [a, a[(np.arange(n_t * fan) * 7) % _HUB_A]]
+    src, dst = np.concatenate(src), np.concatenate(dst)
+    g = Graph.from_edges(np.concatenate([src, dst]),
+                         np.concatenate([dst, src]), _HUB_NV)
+    eng = PushEngine(ShardedGraph.build(g, 1), sssp.make_program(0),
+                     edge_budget=edge_budget)
+    assert eng.pull and eng.queue_cap == 356
+    assert eng._sparse_mode()[1] == 256
+    return g, eng, t
+
+
+@pytest.mark.parametrize("n_t,fan,edge_budget,pulls", [
+    (8, 5, 1000, True),            # fits both, on the LOW budget rung
+    (8, 100, 1000, True),          # fits both, on the top one
+    (8, 100, 800, True),           # its edges AT the top budget
+    (8, 100, 799, False),          # one edge over it: dense
+    (356, 1, 1000, True),          # its count AT the queue's capacity
+    (357, 1, 1000, False),         # one vertex over it: dense
+    (400, 2, 700, False),          # over both
+], ids=["fits-low", "fits", "edges-at", "edges-over", "count-at",
+        "count-over", "both-over"])
+def test_pull_step_never_truncates(n_t, fan, edge_budget, pulls):
+    """(d) the unreached set must fit the queue's top rung and its
+    edges the top budget, or the wide level runs dense; the answer is
+    the BFS's either way."""
+    g, eng, t = _hub_case(n_t, fan, edge_budget)
+    assert eng.budget_rungs == (edge_budget // 16, edge_budget)
+    label, active = _root_state(eng, 0)
+    label, active, _ = eng.converge(*eng.place(label, active), 1)
+    assert int(np.asarray(active).sum()) == _HUB_A
+    label, active, _ = eng.converge(label, active, 1)
+    mark = _last_mark()
+    assert (mark["sparse_iters"], mark["pull_iters"]) == \
+        (int(pulls), int(pulls))
+    assert mark["low_rung_iters"] == int(
+        pulls and n_t * fan <= eng.budget_rungs[0])
+    got = eng.unpad(label)
+    assert (got[t] == 2).all()
+    # the new frontier is T, whichever way it was found
+    np.testing.assert_array_equal(
+        np.flatnonzero(eng.sg.from_padded(np.asarray(active))), t)
+    label, _a, _ = eng.converge(label, active)
+    np.testing.assert_array_equal(eng.unpad(label), _bfs_levels(g, 0))
+
+
+def test_pull_step_declines_on_a_stale_label():
+    """(c) the guard: one vertex of the next level already holds a
+    label, too long by three.  Pushing from the frontier repairs it;
+    pulling into the unreached would leave it, so the step declines
+    and the answer stays exact.  Without the stale label the same
+    state runs bottom-up."""
+    g, eng, t = _hub_case(8, 100, 1000)
+    label, active = _root_state(eng, 0)
+    label, active, _ = eng.converge(*eng.place(label, active), 1)
+    label, active = np.asarray(label).copy(), np.asarray(active)
+    assert (eng.sg.from_padded(label)[t] == sssp.HOP_INF).all()
+    stale = label.copy()
+    stale[0, t[3]] = 2 + 3
+    for lab, pulls in ((label, 1), (stale, 0)):
+        one, act, _ = eng.converge(*eng.place(lab, active), 1)
+        assert _last_mark()["pull_iters"] == pulls
+        assert eng.unpad(one)[t[3]] == 2
+        done, _a, _ = eng.converge(one, act)
+        np.testing.assert_array_equal(eng.unpad(done),
+                                      _bfs_levels(g, 0))
+
+
+def test_pull_step_stays_off_an_empty_unreached_set():
+    """Nothing left to find and a frontier past the count limit: dense
+    as before, never a bottom-up step that only clears the frontier."""
+    g, eng, t = _hub_case(8, 100, 1000)
+    label = np.full(_HUB_NV, 1, np.int32)
+    label[0] = 0
+    label[1 + _HUB_A + 8:] = sssp.HOP_INF      # the isolated rest
+    label[t] = 2
+    active = np.zeros(_HUB_NV, bool)
+    active[1:1 + _HUB_A] = True
+    got, it, mark = _mark_after(eng, eng.sg.to_padded(label),
+                                eng.sg.to_padded(active))
+    assert (mark["pull_iters"], mark["sparse_iters"]) == (0, 0)
+    np.testing.assert_array_equal(got, _bfs_levels(g, 0))
+
+
+@pytest.mark.parametrize("app", ["components", "sssp-weighted",
+                                 "sssp-weighted-delta"])
+@pytest.mark.parametrize("num_parts", [1, 4])
+def test_pull_step_leaves_the_other_programs_answers(app, num_parts):
+    """(f) on a symmetric graph the max-reduce program and weighted
+    SSSP, with and without the delta schedule, equal their references;
+    components never has a vertex at the identity, the delta schedule
+    builds no step."""
+    src, dst, nv = rmat_edges(10, 8, 5)
+    w = np.random.default_rng(2).integers(1, 9, src.size).astype(
+        np.float32)
+    src, dst, w = (np.concatenate([src, dst]),
+                   np.concatenate([dst, src]), np.concatenate([w, w]))
+    if app == "components":
+        g = Graph.from_edges(src, dst, nv)
+        eng = components.build_engine(g, num_parts=num_parts)
+        want = components.reference_components(g)
+    else:
+        g = Graph.from_edges(src, dst, nv, weights=w)
+        eng = sssp.build_engine(
+            g, 0, num_parts=num_parts, weighted=True,
+            delta="auto" if app.endswith("delta") else None)
+        want = sssp.reference_sssp(g, 0, weighted=True)
+    assert eng.pull == (not app.endswith("delta"))
+    got, _it = eng.run()
+    mark = _last_mark()
+    if app == "components":
+        assert mark["pull_iters"] == 0
+    np.testing.assert_array_equal(
+        np.where(np.isfinite(got), got, np.inf) if got.dtype.kind == "f"
+        else got, want)
+
+
+def test_pull_step_on_weighted_levels_where_its_guard_holds():
+    """Weighted SSSP with every weight equal is BFS in disguise: the
+    guard (best frontier label + the least weight against the worst
+    reached label) holds on the wide level and the step engages; the
+    answer is the reference's."""
+    g0, eng0, t = _hub_case(8, 100, 1000)
+    from lux_tpu.engine.push import PushEngine
+    src, dst = g0.edge_arrays()
+    g = Graph.from_edges(src, dst, g0.nv,
+                         weights=np.full(src.size, 2.5, np.float32))
+    eng = PushEngine(eng0.sg.__class__.build(g, 1),
+                     sssp.make_program(0, weighted=True),
+                     edge_budget=1000)
+    assert eng.pull and eng._weight_ends == (2.5, 2.5)
+    got, _it = eng.run()
+    assert _last_mark()["pull_iters"] == 1
+    np.testing.assert_array_equal(
+        got, sssp.reference_sssp(g, 0, weighted=True))
+
+
+@pytest.mark.parametrize("layout", ["np1", "mesh4-owner"])
+def test_timed_phases_choose_as_the_fused_loop(layout):
+    """(g) the stepwise twin: ``timed_phases`` times an iteration as
+    'sparse' exactly where the fused loop takes the sparse branch, the
+    bottom-up step included (its choice is a program of its own,
+    collectives and all), and ends on the same labels."""
+    g, eng, _off = _kron_engines()
+    root = _sweep_roots(g)[2]
+    if layout != "np1":
+        eng = sssp.build_engine(g, root, num_parts=4, mesh=make_mesh(4),
+                                exchange="owner")
+        assert eng.pull
+    label, active = eng.place(*_root_state(eng, root))
+    fused = []
+    while np.asarray(active).any():
+        label, active, _ = eng.converge(label, active, 1)
+        mark = _last_mark()
+        fused.append((mark["sparse_iters"], mark["pull_iters"]))
+    assert (1, 1) in fused and (0, 0) in fused
+    lab2, act2, report = eng.timed_phases(
+        *eng.place(*_root_state(eng, root)), iters=len(fused))
+    assert [int("sparse" in t) for t in report] == \
+        [s for s, _p in fused]
+    np.testing.assert_array_equal(eng.unpad(lab2), eng.unpad(label))
+    assert not np.asarray(act2).any()

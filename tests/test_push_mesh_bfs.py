@@ -110,7 +110,7 @@ def _roots():
     _g, offsets, _n = _graph()
     eng, _perm = _engine(4, False, True)
     deg = np.diff(offsets)
-    _usable, limit = eng._sparse_mode()
+    _usable, limit, _pull = eng._sparse_mode()
 
     def overflows(v):
         profile = _frontier_bfs(int(v))[1]
@@ -146,7 +146,9 @@ def test_the_ladder_ran_across_the_mesh(fused, pairs):
     for root in ROOTS:
         _levels, mark = _search(eng, perm, _roots()[root])
         assert mark["sparse_iters"] > 0 and mark["low_rung_iters"] > 0
-        assert mark["iters"] > mark["sparse_iters"]     # and dense ones
+        # and a level too wide for the queue: dense, or bottom-up
+        assert mark["iters"] - mark["sparse_iters"] \
+            + mark["pull_iters"] > 0
     _levels, mark = _search(eng, perm, _roots()["overflow"])
     assert mark["sparse_iters"] > mark["low_rung_iters"]
 
@@ -169,3 +171,51 @@ def test_one_part_names_no_sparse_exchange():
     jitted, args = eng.audit_variant("step")
     text = jitted.lower(*args()).as_text(debug_info=True)
     assert "lux_sparse_exchange" not in text and "lux_sparse" in text
+
+
+# ---- the bottom-up step across parts (PR 33) ------------------------
+
+
+@functools.lru_cache(maxsize=None)
+def _engine_four_parts_one_device():
+    """Four parts with no mesh: the cross-part combine of the pulled
+    buffer is a reduce over the part axis instead of a collective."""
+    g, _o, _n = _graph()
+    g_run, perm, starts = pair_relabel(
+        g, 4, pair_threshold=ENGINE["pair_threshold"])
+    sg = ShardedGraph.build(g_run, 4, starts=starts,
+                            pair_threshold=ENGINE["pair_threshold"])
+    opts = {k: v for k, v in ENGINE.items() if k != "exchange"}
+    return sssp.build_engine(g_run, start_vertex=0, num_parts=4,
+                             weighted=False, sg=sg, **opts), perm
+
+
+@pytest.mark.parametrize("root", ROOTS)
+@pytest.mark.parametrize("layout", ["mesh4", "mesh4-fused-nopairs",
+                                    "parts4"])
+def test_the_bottom_up_step_combines_across_parts(layout, root):
+    """A search whose wide level is found bottom-up: every part
+    reduces its local neighbours' candidates into the gathered queue's
+    buffer, one ``pmin`` over the mesh (a reduce over the part axis on
+    one device) combines them, and the levels are the one-part
+    engine's and the frontier BFS's."""
+    eng, perm = {"mesh4": lambda: _engine(4, False, True),
+                 "mesh4-fused-nopairs": lambda: _engine(4, True, False),
+                 "parts4": _engine_four_parts_one_device}[layout]()
+    assert eng.pull
+    v = _roots()[root]
+    got, mark = _search(eng, perm, v)
+    np.testing.assert_array_equal(got, _frontier_bfs(v)[0])
+    one, mark1 = _search(*_engine(1, False, True), v)
+    np.testing.assert_array_equal(got, one)
+    assert mark["iters"] == mark1["iters"]
+    assert mark["pull_iters"] >= 1 and mark1["pull_iters"] >= 1
+
+
+def test_the_pulled_buffer_is_exchanged_under_the_sparse_scope():
+    """On the mesh the step's combine is a collective of the sparse
+    branch's exchange scope, inside ``lux_pull``."""
+    eng, _perm = _engine(4, False, True)
+    jitted, args = eng.audit_variant("step")
+    text = jitted.lower(*args()).as_text(debug_info=True)
+    assert "lux_pull/lux_sparse_exchange" in text

@@ -868,7 +868,7 @@ def test_sparse_iters_counts_the_branch_the_loop_took(variant):
     if variant == "delta":
         kw["delta"] = 40
     eng = sssp.build_engine(g, health=variant == "health", **kw)
-    usable, limit = eng._sparse_mode()
+    usable, limit, _pull = eng._sparse_mode()
     assert usable
     _l, _a, it, fsz, *_ = eng.converge_stats(*eng.init_state())
     it = int(it)
@@ -893,7 +893,8 @@ def test_sparse_iters_counts_the_branch_the_loop_took(variant):
     marks = [r for r in _since(tip) if r["name"] == "push.converge"]
     assert len(marks) == 1
     counts = marks[0]["counts"]
-    assert set(counts) == {"iters", "sparse_iters", "low_rung_iters"}
+    assert set(counts) == {"iters", "sparse_iters", "low_rung_iters",
+                           "pull_iters"}
     assert (counts["iters"], counts["sparse_iters"]) == (it, want)
     # which sparse iterations ran below the top edge budget has its
     # oracle in tests/test_push.py (the ladder)

@@ -153,7 +153,7 @@ def test_pull_step_compiles_with_pallas(v5e):
     assert "tpu_custom_call" in _step_text(eng, v5e)
 
 
-@pytest.mark.parametrize("family", ["pull", "push"])
+@pytest.mark.parametrize("family", ["pull", "push", "push-symmetric"])
 def test_mesh_owner_step_compiles_with_pallas(topo, monkeypatch,
                                               family):
     """The WHOLE per-iteration program of a four-part owner-exchange
@@ -176,7 +176,13 @@ def test_mesh_owner_step_compiles_with_pallas(topo, monkeypatch,
     def abstract_shard(_mesh, tree, num_parts=None):
         return _on(parts, jax.tree.map(np.asarray, tree))
 
-    sg = ShardedGraph.build(_rmat12(), 4)
+    g = _rmat12()
+    if family == "push-symmetric":      # builds the bottom-up step
+        from lux_tpu.graph import Graph
+        src, dst = g.edge_arrays()
+        g = Graph.from_edges(np.concatenate([src, dst]),
+                             np.concatenate([dst, src]), g.nv)
+    sg = ShardedGraph.build(g, 4)
     if family == "pull":
         monkeypatch.setattr(pull, "shard_over_parts", abstract_shard)
         eng = pull.PullEngine(sg, pagerank.make_program(), mesh=mesh,
@@ -187,6 +193,7 @@ def test_mesh_owner_step_compiles_with_pallas(topo, monkeypatch,
         eng = push.PushEngine(sg, sssp.make_program(0), mesh=mesh,
                               exchange="owner",
                               reduce_method="pallas")
+        assert eng.pull == (family == "push-symmetric")
     jitted, args = eng.audit_variant("step")
     replicated = NamedSharding(mesh, P())
     args = [a if getattr(a, "sharding", None) is not None
@@ -195,15 +202,28 @@ def test_mesh_owner_step_compiles_with_pallas(topo, monkeypatch,
     assert "tpu_custom_call" in _compiled_text(jitted, *args)
 
 
-def test_push_step_compiles_with_pallas(v5e):
+@pytest.mark.parametrize("symmetric", [False, True],
+                         ids=["directed", "symmetric-bottom-up"])
+def test_push_step_compiles_with_pallas(v5e, symmetric):
     """One push engine's per-iteration program (SSSP, RMAT12: dense
-    iteration + sparse-frontier branch), reduce_method='pallas'."""
+    iteration + sparse-frontier branch), reduce_method='pallas'; on
+    the symmetrized graph with the bottom-up step (two-way slots on
+    the labels with the queue behind them, the pulled buffer's
+    write-back)."""
+    import numpy as np
+
     from lux_tpu.apps import sssp
     from lux_tpu.engine.push import PushEngine
-    from lux_tpu.graph import ShardedGraph
-    sg = ShardedGraph.build(_rmat12(), 1)
+    from lux_tpu.graph import Graph, ShardedGraph
+    g = _rmat12()
+    if symmetric:
+        src, dst = g.edge_arrays()
+        g = Graph.from_edges(np.concatenate([src, dst]),
+                             np.concatenate([dst, src]), g.nv)
+    sg = ShardedGraph.build(g, 1)
     eng = PushEngine(sg, sssp.make_program(0),
                      reduce_method="pallas")
+    assert eng.pull == symmetric
     assert "tpu_custom_call" in _step_text(eng, v5e)
 
 
