@@ -110,18 +110,23 @@ class PullEngine(AuditableEngine):
 
     def init_state(self):
         """Fresh state on the engine's devices, under a ``state.init``
-        span (``bytes``; the transfer is asynchronous, so the span
-        ends at dispatch)."""
+        span (``bytes``) whose two children cover it:
+        ``state.init.build`` (the host makes the state) and
+        ``state.init.put`` (the transfer is asynchronous, so it ends
+        at dispatch), ``bytes`` on each."""
         with telemetry.span("state.init") as sp:
-            state = self._consume_pending_init()
-            if state is None:
-                state = self.program.init(self.sg)
-            state = np.asarray(state)
+            with telemetry.span("state.init.build") as build:
+                state = self._consume_pending_init()
+                if state is None:
+                    state = self.program.init(self.sg)
+                state = np.asarray(state)
+                build.count(bytes=state.nbytes)
             sp.count(bytes=state.nbytes)
-            if self.mesh is not None:
-                return shard_over_parts(self.mesh, [state],
-                                        self.sg.num_parts)[0]
-            return jnp.asarray(state)
+            with telemetry.span("state.init.put", bytes=state.nbytes):
+                if self.mesh is not None:
+                    return shard_over_parts(self.mesh, [state],
+                                            self.sg.num_parts)[0]
+                return jnp.asarray(state)
 
     def place(self, state):
         """Put a host state pytree on the engine's devices with the
@@ -654,12 +659,19 @@ class PullEngine(AuditableEngine):
         """Padded device state -> [nv, ...] user order (host).
         Multi-host runs gather remote shards over the process group.
         Leaves a ``state.fetch`` span (``bytes``: what came to the
-        host)."""
+        host) whose two children cover it: ``state.fetch.get`` (device
+        to host, the same ``bytes``) and ``state.fetch.unpad``
+        (``from_padded``; ``bytes`` of its result)."""
         from lux_tpu.parallel.multihost import fetch_global
         with telemetry.span("state.fetch") as sp:
-            host = fetch_global(state)
+            with telemetry.span("state.fetch.get") as get:
+                host = fetch_global(state)
+                get.count(bytes=host.nbytes)
             sp.count(bytes=host.nbytes)
-            return self.sg.from_padded(host)
+            with telemetry.span("state.fetch.unpad") as unpad:
+                out = self.sg.from_padded(host)
+                unpad.count(bytes=out.nbytes)
+            return out
 
     # -- per-iteration phase observability ----------------------------
 

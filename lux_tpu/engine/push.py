@@ -301,15 +301,21 @@ class PushEngine(AuditableEngine):
 
     def init_state(self):
         """Fresh (label, active) on the engine's devices, under a
-        ``state.init`` span (``bytes``; ends at dispatch)."""
+        ``state.init`` span (``bytes``) whose two children cover it:
+        ``state.init.build`` (the host makes the arrays) and
+        ``state.init.put`` (ends at dispatch), ``bytes`` on each."""
         with telemetry.span("state.init") as sp:
-            pending = self._consume_pending_init()
-            if pending is not None:
-                label0, active0 = pending
-            else:
-                label0, active0 = self.program.init(self.sg)
-            sp.count(bytes=label0.nbytes + active0.nbytes)
-            return self._place(label0, active0)
+            with telemetry.span("state.init.build") as build:
+                pending = self._consume_pending_init()
+                if pending is not None:
+                    label0, active0 = pending
+                else:
+                    label0, active0 = self.program.init(self.sg)
+                nbytes = label0.nbytes + active0.nbytes
+                build.count(bytes=nbytes)
+            sp.count(bytes=nbytes)
+            with telemetry.span("state.init.put", bytes=nbytes):
+                return self._place(label0, active0)
 
     def place(self, label, active):
         """Put host (or replicated) state arrays on the engine's
@@ -1298,12 +1304,20 @@ class PushEngine(AuditableEngine):
 
     def unpad(self, state) -> np.ndarray:
         """Device state -> host array in vertex order, under a
-        ``state.fetch`` span (``bytes``: what came to the host)."""
+        ``state.fetch`` span (``bytes``: what came to the host) whose
+        two children cover it: ``state.fetch.get`` (device to host,
+        the same ``bytes``) and ``state.fetch.unpad`` (``from_padded``;
+        ``bytes`` of its result)."""
         from lux_tpu.parallel.multihost import fetch_global
         with telemetry.span("state.fetch") as sp:
-            host = fetch_global(state)
+            with telemetry.span("state.fetch.get") as get:
+                host = fetch_global(state)
+                get.count(bytes=host.nbytes)
             sp.count(bytes=host.nbytes)
-            return self.sg.from_padded(host)
+            with telemetry.span("state.fetch.unpad") as unpad:
+                out = self.sg.from_padded(host)
+                unpad.count(bytes=out.nbytes)
+            return out
 
     # -- per-iteration phase observability ----------------------------
 

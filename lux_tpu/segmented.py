@@ -168,7 +168,14 @@ def each_run_segment(eng, state, num_iters: int, segment,
     ``segment`` event with its fenced seconds, and with iter-stats the
     slice runs ``eng.run_stats`` — the device-side per-iteration
     counters are fetched once per segment boundary (a few KB) and
-    accumulated across segments."""
+    accumulated across segments.
+
+    Every slice leaves one ``segment.run`` span (telemetry.span;
+    counts ``iters`` = the slice's size).  The driver
+    fences only when timed, counted or guarded: where it does not,
+    ``segment.run`` ends at DISPATCH and the device's work runs on
+    under whatever the caller does next.  No span is held across a
+    ``yield``."""
     from lux_tpu import telemetry
     from lux_tpu.profiling import step_annotation
 
@@ -187,7 +194,8 @@ def each_run_segment(eng, state, num_iters: int, segment,
     while done < num_iters:
         n = _next_n(segment, num_iters - done)
         t0 = time.perf_counter()
-        with step_annotation("lux_segment", seg_idx):
+        with telemetry.span("segment.run", iters=n), \
+                step_annotation("lux_segment", seg_idx):
             if guarded:
                 state, _itd, res_b, chg_b, res_p, chg_p, watch = \
                     eng.run_health(state, n, watch)
@@ -266,6 +274,13 @@ def each_converge_segment(eng, label, active, segment,
     with iter-stats the slice runs ``eng.converge_stats`` — frontier/
     edge counters fetched once per boundary and accumulated across
     segments (a resumed run keeps accumulating).
+
+    Every slice leaves three kinds of span (telemetry.span), each
+    opened and closed inside one ``next()``: ``segment.run`` (the
+    slice to its completion fence; counts ``iters`` = the fetched
+    iteration count), ``segment.count`` (the fetch of the
+    active count that follows the fence) and, after a hook that
+    replaced the state, ``segment.recount``.
     """
     import jax
     import jax.numpy as jnp
@@ -287,7 +302,8 @@ def each_converge_segment(eng, label, active, segment,
     while total < cap:
         n = _next_n(segment, cap - total)
         t0 = time.perf_counter()
-        with step_annotation("lux_segment", seg_idx):
+        with telemetry.span("segment.run") as sp, \
+                step_annotation("lux_segment", seg_idx):
             if guarded:
                 label, active, it, fsz, fed, fszp, fedp, watch = \
                     eng.converge_health(label, active, n, watch)
@@ -299,6 +315,7 @@ def each_converge_segment(eng, label, active, segment,
             # the scalar fetch depends on the whole while_loop: it is
             # the completion fence (O(1) bytes to the host)
             it = int(np.asarray(jax.device_get(it)))
+            sp.count(iters=it)
         dt = time.perf_counter() - t0
         if guarded:
             # raise BEFORE the segment hook: a corrupted/livelocked
@@ -311,7 +328,8 @@ def each_converge_segment(eng, label, active, segment,
         if budget is not None and it > 0:
             budget.observe(it, dt)
         total += it
-        cnt = int(np.asarray(jax.device_get(jnp.sum(active))))
+        with telemetry.span("segment.count"):
+            cnt = int(np.asarray(jax.device_get(jnp.sum(active))))
         tel.emit("segment", engine="push", iters=it, total=total,
                  active=cnt, seconds=round(dt, 6))
         seg_idx += 1

@@ -211,6 +211,61 @@ class TestBoundarySpans:
                     if r["name"] in ("state.place", pre + "pad")]
 
 
+    @pytest.mark.parametrize("kind, family, sources, kw", [
+        ("sssp", "push", (3, 17, 40, 99, 200), {}),
+        ("components", "push", (255, 180, 7), {}),
+        ("pagerank", "pull", (3, 17, 40), {"tol": 1e-9})])
+    def test_a_turn_holds_its_segment_spans_beside_the_boundary(
+            self, g, kind, family, sources, kw):
+        """PR 35: the driver's ``segment.run`` (and, push only,
+        ``segment.count``; ``segment.recount`` after a refill) are
+        children of the turn like its ``serve.boundary``, once a turn,
+        before it and never round it; ``iters`` is what the driver
+        counted for that segment."""
+        _srv, responses, recs = self._drain(g, kind, sources, **kw)
+        assert len(responses) == len(sources)
+        # a first call's compile marks (runtime.watch_compiles, where
+        # an earlier test installed it) are not the turn's own
+        recs = [r for r in recs if not r["name"].startswith("jit.")]
+        turns = [r for r in recs if r["name"] == "serve.turn." + family]
+        assert len(turns) >= 3
+        for t in turns:
+            kids = [r for r in recs if r["parent"] == t["id"]]
+            if t is turns[0]:       # the drain's first placement
+                assert kids[0]["name"] == "serve.boundary.place"
+                kids = kids[1:]
+            names = [k["name"] for k in kids]
+            want = ["segment.run"] + ["segment.count"] * (
+                family == "push") + ["serve.boundary"]
+            assert names[:len(want)] == want
+            assert names[len(want):] in ([], ["segment.recount"])
+            run, bound = kids[0], kids[len(want) - 1]
+            # ``iters`` alone: the turn's name says which engine ran
+            assert set(run["counts"]) == {"iters"}
+            assert 0 <= run["counts"]["iters"] <= 2     # seg_iters
+            if family == "pull":
+                assert run["counts"]["iters"] == 2
+            # one after the other: a leaf each, the boundary apart
+            assert all(a["t1"] <= b["t0"] for a, b in zip(kids, kids[1:]))
+            assert t["t0"] <= run["t0"] and bound["t1"] <= t["t1"]
+            # leaves: under them only the loop's zero-length mark
+            assert {r["name"] for r in recs if r["parent"] in
+                    {k["id"] for k in kids
+                     if k["name"].startswith("segment.")}} \
+                <= {"push.converge"}
+        # every segment span of the drain lies in a turn
+        segs = [r for r in recs if r["name"].startswith("segment.")]
+        assert {r["parent"] for r in segs} <= {t["id"] for t in turns}
+        assert sum(r["name"] == "segment.run" for r in segs) \
+            == len(turns)
+        if family == "push":
+            total = sum(r["counts"]["iters"] for r in segs
+                        if r["name"] == "segment.run")
+            assert total == sum(
+                r["counts"]["iters"] for r in recs
+                if r["name"] == "push.converge")
+
+
 def _dense_column(runner, source):
     """Host statement of a fresh query column, ``[nv]`` label and
     frontier: the unit everywhere but at the source."""

@@ -833,7 +833,14 @@ def test_engine_build_leaves_every_child_once(engine):
     assert plan["pair_edges"] == eng.pairs.stats["covered"] > 0
 
 
+def _names_and_bytes(recs):
+    return [(r["name"], r["counts"]["bytes"]) for r in recs]
+
+
 def test_state_spans_carry_the_bytes_they_move():
+    """The whole ring of an ``init_state`` / ``place`` / ``unpad``, in
+    the order the records close (children before their parent), and
+    nothing else."""
     g = small_graph()
     eng = sssp.build_engine(g, start_vertex=1, num_parts=2)
     tip = _ring_tip()
@@ -841,18 +848,159 @@ def test_state_spans_carry_the_bytes_they_move():
     nbytes = label.nbytes + active.nbytes
     label, active = eng.place(np.asarray(label), np.asarray(active))
     dist = eng.unpad(label)
-    recs = _since(tip)
-    assert [(r["name"], r["counts"]["bytes"]) for r in recs] == [
+    padded = np.asarray(label).nbytes
+    assert _names_and_bytes(_own(_since(tip))) == [
+        ("state.init.build", nbytes), ("state.init.put", nbytes),
         ("state.init", nbytes), ("state.place", nbytes),
-        ("state.fetch", np.asarray(label).nbytes)]
+        ("state.fetch.get", padded), ("state.fetch.unpad", dist.nbytes),
+        ("state.fetch", padded)]
     assert dist.shape == (g.nv,)
     peng = pagerank.build_engine(g, 2, None)
     tip = _ring_tip()
     state = peng.init_state()
-    peng.unpad(peng.place(np.asarray(state)))
-    assert [(r["name"], r["counts"]["bytes"]) for r in _since(tip)] == [
+    rank = peng.unpad(peng.place(np.asarray(state)))
+    assert _names_and_bytes(_own(_since(tip))) == [
+        ("state.init.build", state.nbytes),
+        ("state.init.put", state.nbytes),
         ("state.init", state.nbytes), ("state.place", state.nbytes),
+        ("state.fetch.get", state.nbytes),
+        ("state.fetch.unpad", rank.nbytes),
         ("state.fetch", state.nbytes)]
+
+
+def _state_engine(engine, mesh_n, nv=40000, ne=160000):
+    g = small_graph(nv=nv, ne=ne)
+    parts = max(mesh_n, 1)
+    mesh = make_mesh(mesh_n) if mesh_n else None
+    if engine == "push":
+        return g, sssp.build_engine(g, start_vertex=1, num_parts=parts,
+                                    mesh=mesh)
+    return g, pagerank.build_engine(g, parts, mesh)
+
+
+def _own(recs):
+    """Without the compile marks of a first call (where an earlier
+    test of this process installed ``runtime.watch_compiles``)."""
+    return [r for r in recs if not r["name"].startswith("jit.")]
+
+
+@pytest.mark.parametrize("mesh_n", [0, 4], ids=["np1", "mesh4"])
+@pytest.mark.parametrize("engine", ["push", "pull"])
+def test_state_children_split_their_parent(engine, mesh_n):
+    """PR 35: ``state.init`` = ``.build`` + ``.put``, ``state.fetch``
+    = ``.get`` + ``.unpad``: the children carry the parent's id, do
+    not overlap, cover it (90% in the best of a few repeats: the rest
+    is the spans' own cost) and count the stated bytes; ``state.place``
+    stays a leaf."""
+    g, eng = _state_engine(engine, mesh_n)
+    best = {"state.init": 0.0, "state.fetch": 0.0}
+    for _ in range(5):
+        tip = _ring_tip()
+        state = eng.init_state()
+        first = state[0] if engine == "push" else state
+        nbytes = sum(x.nbytes for x in jax.tree.leaves(state))
+        answer = eng.unpad(first)
+        recs = _own(_since(tip))
+        for parent, kids, sizes in (
+                ("state.init", ("build", "put"), (nbytes, nbytes)),
+                ("state.fetch", ("get", "unpad"),
+                 (first.nbytes, answer.nbytes))):
+            (top,) = [r for r in recs if r["name"] == parent]
+            got = _children(recs, top["id"])
+            assert [k["name"] for k in got] \
+                == [f"{parent}.{k}" for k in kids]
+            assert [k["counts"]["bytes"] for k in got] == list(sizes)
+            assert top["counts"]["bytes"] == sizes[0]
+            a, b = got
+            assert top["t0"] <= a["t0"] <= a["t1"] <= b["t0"] \
+                <= b["t1"] <= top["t1"]
+            covered = (a["t1"] - a["t0"] + b["t1"] - b["t0"]) \
+                / (top["t1"] - top["t0"])
+            best[parent] = max(best[parent], covered)
+        assert answer.shape[0] == g.nv
+        # nothing else was recorded, and no child has a child
+        assert len(recs) == 6
+    assert min(best.values()) >= 0.9, best
+    tip = _ring_tip()
+    eng.place(*[np.asarray(x) for x in jax.tree.leaves(state)])
+    assert [r["name"] for r in _own(_since(tip))] == ["state.place"]
+
+
+def _segment_spans(recs):
+    return [r for r in recs if r["name"].startswith("segment.")]
+
+
+@pytest.mark.parametrize("mesh_n", [0, 4], ids=["np1", "mesh4"])
+def test_push_driver_spans_every_segment_once(mesh_n):
+    """``segment.run`` (to the completion fence; ``iters`` = the
+    fetched count the driver itself adds up) and ``segment.count``
+    (the active count after it), once a segment, in this order."""
+    from lux_tpu.segmented import each_converge_segment
+    _g, eng = _state_engine("push", mesh_n, nv=600, ne=5000)
+    log = telemetry.EventLog()
+    tip = _ring_tip()
+    totals = []
+    with telemetry.use(events=log):
+        for _label, _active, total in each_converge_segment(
+                eng, *eng.init_state(), 2):
+            totals.append(total)
+            # suspended between two segments: no span is open
+            assert telemetry._enclosing.get() == 0
+    segs = _segment_spans(_since(tip))
+    events = [e for e in log.events if e["kind"] == "segment"]
+    assert len(totals) >= 3 and len(events) == len(totals)
+    assert [r["name"] for r in segs] \
+        == ["segment.run", "segment.count"] * len(totals)
+    runs = segs[0::2]
+    assert [r["counts"] for r in runs] \
+        == [{"iters": e["iters"]} for e in events]
+    assert np.cumsum([r["counts"]["iters"] for r in runs]).tolist() \
+        == totals
+    assert all(r["parent"] == 0 and not r["counts"] for r in segs[1::2])
+    assert all(a["t1"] <= b["t0"] for a, b in zip(segs, segs[1:]))
+
+
+@pytest.mark.parametrize("timed", [False, True], ids=["dispatch", "fenced"])
+@pytest.mark.parametrize("mesh_n", [0, 4], ids=["np1", "mesh4"])
+def test_pull_driver_spans_every_segment_once(mesh_n, timed):
+    """``segment.run`` once a slice with ``iters`` = the slice's size,
+    with an event sink (the driver fences) and without one (the span
+    ends at dispatch); the pull driver counts nothing after it."""
+    import contextlib
+
+    from lux_tpu.segmented import each_run_segment
+    _g, eng = _state_engine("pull", mesh_n, nv=600, ne=5000)
+    log = telemetry.EventLog()
+    tip = _ring_tip()
+    with telemetry.use(events=log) if timed \
+            else contextlib.nullcontext():
+        for state in each_run_segment(eng, eng.init_state(), 7, 3):
+            assert telemetry._enclosing.get() == 0
+    jax.block_until_ready(state)
+    segs = _segment_spans(_since(tip))
+    assert [(r["name"], r["counts"]) for r in segs] == [
+        ("segment.run", {"iters": n})
+        for n in (3, 3, 1)]
+    if timed:
+        assert [e["n"] for e in log.events
+                if e["kind"] == "segment"] == [3, 3, 1]
+
+
+@pytest.mark.parametrize("program", ["push.step", "push.converge",
+                                     "pull.step", "pull.run"])
+def test_spans_do_not_enter_a_lowered_program(program):
+    """A span is host bookkeeping: a program lowered under open spans
+    is, byte for byte and with debug info, the program lowered under
+    none (the check against the PARENT's text is PERF.md's, made off
+    the chip from two checkouts)."""
+    kind, name = program.split(".")
+    _g, eng = _state_engine(kind, 0, nv=600, ne=5000)
+    jitted, args = eng.audit_programs()[name]
+    plain = jitted.lower(*args()).as_text(debug_info=True)
+    with telemetry.span("state.init"), telemetry.span("segment.run"):
+        spanned = jitted.lower(*args()).as_text(debug_info=True)
+    assert spanned == plain
+    assert "segment.run" not in plain and "state.init" not in plain
 
 
 @pytest.mark.parametrize("variant", ["plain", "stats", "health",
