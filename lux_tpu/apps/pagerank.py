@@ -36,16 +36,24 @@ def make_program(dtype=jnp.float32) -> PullProgram:
         deg = ctx.deg.astype(pr.dtype)
         return jnp.where(ctx.deg > 0, pr / jnp.maximum(deg, 1), pr)
 
+    def seed(xp, deg, nv):
+        # the ONE formula of the first state, on the host (xp = np)
+        # and on the device (xp = jnp): divided in the state's dtype,
+        # the arithmetic apply uses for every later iteration
+        rank = xp.asarray(1.0 / nv, np.dtype(dtype))
+        return xp.where(deg > 0, rank / xp.maximum(deg, 1).astype(
+            rank.dtype), rank)
+
     def init(sg: ShardedGraph):
-        rank = 1.0 / sg.nv
-        deg = sg.deg_padded
-        state = np.where(deg > 0, rank / np.maximum(deg, 1), rank)
-        return state.astype(np.dtype(dtype))
+        return seed(np, np.asarray(sg.deg_padded), sg.nv)
+
+    def init_device(ctx):
+        return seed(jnp, ctx.deg, ctx.nv)
 
     return PullProgram(reduce="sum", edge_value=edge_value, apply=apply,
                        init=init, needs_dst=False,
                        state_bytes=np.dtype(dtype).itemsize,
-                       name="pagerank")
+                       name="pagerank", init_device=init_device)
 
 
 def one_hot_resets(nv: int, sources) -> np.ndarray:

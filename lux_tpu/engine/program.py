@@ -61,6 +61,16 @@ class PullProgram:
                 post-scan code, e.g. pagerank_gpu.cu:97-100).
     init        (sharded_graph) -> initial padded state
                 [num_parts, vpad, ...] (numpy).
+    init_device optional (ctx: PartCtx) -> this part's rows [vpad,
+                ...] of the initial state, pad rows included: a pure
+                ``jnp`` function of the same PartCtx ``apply``
+                receives.  When set, ``PullEngine.init_state`` makes
+                each solve's first state on the devices from arrays
+                they already hold, with no host array and no
+                transfer.  ``init`` stays (shapes, checkpoints and the
+                audit read it) and the two are ONE formula: equal
+                bitwise on a CPU backend, within one unit in the last
+                place where the device's divide is not IEEE-exact.
     needs_dst   whether edge_value reads dst_val (skips a gather when
                 False).
     edge_value_from_dot
@@ -107,3 +117,4 @@ class PullProgram:
     name: str | None = None
     extra_arrays: Callable | None = None
     batch: int | None = None
+    init_device: Callable | None = None

@@ -109,24 +109,73 @@ class PullEngine(AuditableEngine):
     # -- state placement ----------------------------------------------
 
     def init_state(self):
-        """Fresh state on the engine's devices, under a ``state.init``
-        span (``bytes``) whose two children cover it:
-        ``state.init.build`` (the host makes the state) and
-        ``state.init.put`` (the transfer is asynchronous, so it ends
-        at dispatch), ``bytes`` on each."""
+        """Fresh state on the engine's devices.  Where the program has
+        ``init_device`` the devices make it, with a compiled program
+        over graph arrays they already hold (``_init_program``): no
+        host array, no transfer, and the sharding ``shard_over_parts``
+        would have given, so ``run`` takes it as its donated argument
+        without a reshard.  Any other program, and an audit's stashed
+        host init, take the host path: ``program.init`` in NumPy, then
+        the transfer.
+
+        Leaves a ``state.init`` span (``bytes``: the state's;
+        ``device_bytes``: the same where the devices made it, 0 on the
+        host path) whose two children cover it, ``bytes`` on each:
+        ``state.init.build`` (what the host does to make the state: on
+        the device path, fetching the compiled program) and
+        ``state.init.put`` (whatever places the state on the devices,
+        the program or the transfer: asynchronous, so it ends at
+        dispatch)."""
         with telemetry.span("state.init") as sp:
             with telemetry.span("state.init.build") as build:
                 state = self._consume_pending_init()
-                if state is None:
-                    state = self.program.init(self.sg)
-                state = np.asarray(state)
-                build.count(bytes=state.nbytes)
-            sp.count(bytes=state.nbytes)
-            with telemetry.span("state.init.put", bytes=state.nbytes):
+                make = None if state is not None else self._init_program
+                if make is not None:
+                    sds = self._audit_state_sds
+                    nbytes = sds.size * sds.dtype.itemsize
+                else:
+                    if state is None:
+                        state = self.program.init(self.sg)
+                    state = np.asarray(state)
+                    nbytes = state.nbytes
+                build.count(bytes=nbytes)
+            sp.count(bytes=nbytes,
+                     device_bytes=0 if make is None else nbytes)
+            with telemetry.span("state.init.put", bytes=nbytes):
+                if make is not None:
+                    program, keys = make
+                    return program(*(self.arrays[k] for k in keys))
                 if self.mesh is not None:
                     return shard_over_parts(self.mesh, [state],
                                             self.sg.num_parts)[0]
                 return jnp.asarray(state)
+
+    @functools.cached_property
+    def _init_program(self):
+        """(program, keys): the jitted program that makes the fresh
+        state on the devices from the graph arrays named ``keys``, a
+        vmap of ``program.init_device`` over the local parts (under
+        shard_map on a mesh, parts in and out exactly as the step);
+        ``None`` where the program has no such hook.  Graph arrays are
+        ARGUMENTS, read from ``self.arrays`` at call time like every
+        loop's, and only the ones a PartCtx is made of."""
+        init = self.program.init_device
+        if init is None:
+            return None
+        keys = [k for k in self._graph_keys
+                if k in ("deg", "nvp") or k.startswith("prog_")]
+
+        def core(*gargs):
+            return jax.vmap(lambda g: init(self._part_ctx(g)))(
+                dict(zip(keys, gargs)))
+
+        if self.mesh is not None:
+            P = PartitionSpec
+            core = jax.shard_map(
+                core, mesh=self.mesh,
+                in_specs=(P(PARTS_AXIS),) * len(keys),
+                out_specs=P(PARTS_AXIS))
+        return jax.jit(core), keys
 
     def place(self, state):
         """Put a host state pytree on the engine's devices with the
@@ -197,13 +246,17 @@ class PullEngine(AuditableEngine):
 
     # -- one part's work ----------------------------------------------
 
-    def _apply_epilogue(self, old_p, red, g):
-        sg, prog = self.sg, self.program
-        vm = vmask_of(g, sg.vpad)
+    def _part_ctx(self, g) -> PartCtx:
+        """What a program callback sees of one part's graph arrays."""
+        sg = self.sg
         extra = {k[5:]: g[k] for k in g if k.startswith("prog_")}
-        ctx = PartCtx(deg=g["deg"], vmask=vm, nv=sg.nv, ne=sg.ne,
-                      extra=extra or None)
-        new = prog.apply(old_p, red, ctx)
+        return PartCtx(deg=g["deg"], vmask=vmask_of(g, sg.vpad),
+                       nv=sg.nv, ne=sg.ne, extra=extra or None)
+
+    def _apply_epilogue(self, old_p, red, g):
+        ctx = self._part_ctx(g)
+        vm = ctx.vmask
+        new = self.program.apply(old_p, red, ctx)
         keep = vm.reshape(vm.shape + (1,) * (new.ndim - 1))
         return jnp.where(keep, new, old_p)
 
@@ -323,13 +376,19 @@ class PullEngine(AuditableEngine):
 
     @functools.cached_property
     def _audit_state_sds(self):
-        """Abstract stand-in for the iterated state (shape/dtype from
-        the program's init, no device placement).  The materialized
-        init is STASHED for the next ``init_state`` call, so an
+        """Abstract stand-in for the iterated state (shape and dtype,
+        no device placement): from ``jax.eval_shape`` of the device
+        init program where the program has one, with nothing
+        materialized.  Otherwise from the program's host init, which
+        is STASHED for the next ``init_state`` call, so an
         audited-then-run engine (bench.py -audit) pays for exactly
         one host init, same as an unaudited one."""
-        st = np.asarray(self.program.init(self.sg))
-        self._pending_init = st
+        if self._init_program is not None:
+            program, keys = self._init_program
+            st = jax.eval_shape(program, *(self.arrays[k] for k in keys))
+        else:
+            st = self._pending_init = np.asarray(
+                self.program.init(self.sg))
         return jax.ShapeDtypeStruct(st.shape, st.dtype)
 
     # -- public API ---------------------------------------------------

@@ -43,6 +43,14 @@ def _on(sharding, tree):
                                        sharding=sharding), tree)
 
 
+def _abstract_shard(parts):
+    """A stand-in for ``shard_over_parts`` on a described topology,
+    whose devices cannot hold data: abstract arrays on ``parts``."""
+    import numpy as np
+    return lambda _mesh, tree, num_parts=None: _on(
+        parts, jax.tree.map(np.asarray, tree))
+
+
 def _compiled_text(jitted, *args, **static):
     return jitted.lower(*args, **static).compile().as_text()
 
@@ -173,9 +181,6 @@ def test_mesh_owner_step_compiles_with_pallas(topo, monkeypatch,
     mesh = Mesh(np.asarray(topo.devices), (PARTS_AXIS,))
     parts = NamedSharding(mesh, P(PARTS_AXIS))
 
-    def abstract_shard(_mesh, tree, num_parts=None):
-        return _on(parts, jax.tree.map(np.asarray, tree))
-
     g = _rmat12()
     if family == "push-symmetric":      # builds the bottom-up step
         from lux_tpu.graph import Graph
@@ -184,12 +189,14 @@ def test_mesh_owner_step_compiles_with_pallas(topo, monkeypatch,
                              np.concatenate([dst, src]), g.nv)
     sg = ShardedGraph.build(g, 4)
     if family == "pull":
-        monkeypatch.setattr(pull, "shard_over_parts", abstract_shard)
+        monkeypatch.setattr(pull, "shard_over_parts",
+                            _abstract_shard(parts))
         eng = pull.PullEngine(sg, pagerank.make_program(), mesh=mesh,
                               exchange="owner",
                               reduce_method="pallas")
     else:
-        monkeypatch.setattr(push, "shard_over_parts", abstract_shard)
+        monkeypatch.setattr(push, "shard_over_parts",
+                            _abstract_shard(parts))
         eng = push.PushEngine(sg, sssp.make_program(0), mesh=mesh,
                               exchange="owner",
                               reduce_method="pallas")
@@ -200,6 +207,47 @@ def test_mesh_owner_step_compiles_with_pallas(topo, monkeypatch,
             else _on(parts if a.ndim else replicated, a)
             for a in args()]
     assert "tpu_custom_call" in _compiled_text(jitted, *args)
+
+
+@pytest.mark.parametrize("chips", [1, 4])
+def test_pull_init_program_compiles_for_the_chip(topo, v5e, monkeypatch,
+                                                 chips):
+    """PR 36: the program that makes PageRank's first state on the
+    devices (``PullEngine._init_program``), compiled for one v5e chip
+    and for the four-device parts mesh: elementwise in arrays the
+    devices hold, so no collective, no gather, and an output laid out
+    as the parts sharding lays the state."""
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from lux_tpu.apps import pagerank
+    from lux_tpu.engine import pull
+    from lux_tpu.graph import ShardedGraph
+    from lux_tpu.parallel.mesh import PARTS_AXIS
+
+    mesh, parts = None, v5e
+    if chips == 4:
+        mesh = Mesh(np.asarray(topo.devices), (PARTS_AXIS,))
+        parts = NamedSharding(mesh, P(PARTS_AXIS))
+        monkeypatch.setattr(pull, "shard_over_parts",
+                            _abstract_shard(parts))
+    sg = ShardedGraph.build(_rmat12(), chips)
+    eng = pull.PullEngine(sg, pagerank.make_program(), mesh=mesh,
+                          reduce_method="pallas")
+    program, keys = eng._init_program
+    assert keys == ["deg", "nvp"]
+    compiled = program.lower(
+        *_on(parts, [eng.arrays[k] for k in keys])).compile()
+    text = compiled.as_text()
+    assert not any(op in text for op in (
+        "all-reduce", "all-gather", "all-to-all", "collective-permute",
+        "gather(", "tpu_custom_call"))
+    (out,) = jax.tree.leaves(compiled.output_shardings)
+    assert out.is_equivalent_to(parts, 2)
+    state = 4 * sg.vpad * sg.num_parts // chips         # bytes a device
+    memory = compiled.memory_analysis()     # the chip pads rows to tiles
+    assert state <= memory.output_size_in_bytes < 2 * state
+    assert memory.temp_size_in_bytes <= memory.output_size_in_bytes
 
 
 @pytest.mark.parametrize("symmetric", [False, True],
