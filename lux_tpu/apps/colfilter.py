@@ -99,15 +99,28 @@ def run(g: Graph, num_iters: int, num_parts: int = 1, mesh=None):
 
 
 def reference_colfilter(g: Graph, num_iters: int,
-                        k: int = K) -> np.ndarray:
-    """NumPy oracle with identical semantics."""
+                        k: int = K, init=None) -> np.ndarray:
+    """NumPy oracle with identical semantics (float64).  ``init``
+    [nv, k] replaces the uniform sqrt(1/k) start.
+
+    The per-destination sum is a segment sum over the file's
+    dst-sorted edges (``np.add.reduceat`` at each non-empty
+    destination's first edge): the same float64 answer as
+    ``np.add.at`` over ``[ne, k]`` up to the order of the additions,
+    in seconds instead of minutes past a few 10^5 edges."""
     src, dst = g.edge_arrays()
     w = np.asarray(g.weights, dtype=np.float64)
-    state = np.full((g.nv, k), np.sqrt(1.0 / k), dtype=np.float64)
+    state = (np.full((g.nv, k), np.sqrt(1.0 / k), dtype=np.float64)
+             if init is None else np.array(init, dtype=np.float64))
+    indeg = g.in_degrees()
+    has = np.flatnonzero(indeg)             # destinations with edges
+    first = (np.cumsum(indeg) - indeg)[has]
     for _ in range(num_iters):
         err = w - np.einsum("ek,ek->e", state[src], state[dst])
         acc = np.zeros_like(state)
-        np.add.at(acc, dst, err[:, None] * state[src])
+        if len(has):
+            acc[has] = np.add.reduceat(err[:, None] * state[src],
+                                       first, axis=0)
         state = state + GAMMA * (acc - LAMBDA * state)
     return state
 

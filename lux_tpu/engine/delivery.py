@@ -548,6 +548,25 @@ class Delivery:
                 self.page_plan, flat_table, g["pg_ids"], g["pg_sl"],
                 g["pg_rel"], g["pg_w"], g["pg_rt"], g["pg_tp"],
                 g["pg_t0"][0], msg_dot)[:sg.vpad]
+        with jax.named_scope("lux_dot_residual"):
+            red = self._residual_dot(flat_table, msg_dot, g, table_p)
+        if self.pairs is not None:
+            fn = (pair_ops.pair_partial_dot_streamed
+                  if self.pair_dot_stream
+                  else pair_ops.pair_partial_dot)
+            with jax.named_scope("lux_dot_pairs"):
+                pred = fn(
+                    self.pairs, flat_table, g["pair_rowbind"],
+                    g["pair_rel"], g["pair_weight"],
+                    g["pair_row_tile"], g["pair_tile_pos"],
+                    g["pair_tile0"][0], msg_dot)
+            red = red + pred[:sg.vpad]
+        return red
+
+    def _residual_dot(self, flat_table, msg_dot, g, table_p):
+        """reduce_dot's chunked SDDMM over the tiled (residual)
+        layout -> ``[vpad, K]``."""
+        sg, lay = self.sg, self.tiles
         W, E = lay.W, lay.E
         C = lay.n_chunks
         Kdim = table_p.shape[-1]
@@ -580,12 +599,14 @@ class Delivery:
             t = jnp.take(tiles, jnp.minimum(ct_b, n_tiles - 1),
                          axis=0)                           # [B, W, K]
             D = jnp.einsum("bek,bwk->bew", s, t,
-                           preferred_element_type=s.dtype)
+                           preferred_element_type=s.dtype,
+                           precision=pair_ops.dot_precision(s.dtype))
             mask = r[..., None] == lanes                   # [B, E, W]
             dot = jnp.sum(jnp.where(mask, D, 0), axis=-1)  # [B, E]
             msgs = msg_dot(s, dot, w)                      # [B, E, K]
-            return jnp.einsum("bew,bek->bwk", mask.astype(s.dtype),
-                              msgs)                        # [B, W, K]
+            return jnp.einsum(
+                "bew,bek->bwk", mask.astype(s.dtype), msgs,
+                precision=pair_ops.dot_precision(msgs.dtype))
 
         args = (pad_c(g["src_slot"]).reshape(nB, B, E),
                 pad_c(g["chunk_tile"]).reshape(nB, B),
@@ -595,17 +616,7 @@ class Delivery:
         red = combine_chunks(partials, lay, g["chunk_start"],
                              g["last_chunk"], self.kind,
                              use_mxu=self.use_mxu)
-        red = red.reshape(n_tiles * W, Kdim)[:sg.vpad]
-        if self.pairs is not None:
-            fn = (pair_ops.pair_partial_dot_streamed
-                  if self.pair_dot_stream
-                  else pair_ops.pair_partial_dot)
-            pred = fn(
-                self.pairs, flat_table, g["pair_rowbind"],
-                g["pair_rel"], g["pair_weight"], g["pair_row_tile"],
-                g["pair_tile_pos"], g["pair_tile0"][0], msg_dot)
-            red = red + pred[:sg.vpad]
-        return red
+        return red.reshape(n_tiles * W, Kdim)[:sg.vpad]
 
     # -- the owner form (ops/owner.py) ------------------------------------
 

@@ -1168,7 +1168,7 @@ def paged_partial_dot(pp: PagedPlan, state, page_ids, slot_lane, rel,
     import jax
     import jax.numpy as jnp
 
-    from lux_tpu.ops.pairs import _class_combine
+    from lux_tpu.ops.pairs import _class_combine, dot_precision
 
     if weight is None:
         raise ValueError("paged_partial_dot needs per-lane weights")
@@ -1194,7 +1194,8 @@ def paged_partial_dot(pp: PagedPlan, state, page_ids, slot_lane, rel,
         lane = (sl & jnp.uint32(0x7F)).astype(jnp.int32)
         sel = (lane[..., None] == lanes32).astype(state.dtype)
         S = jnp.einsum("rcl,rlk->rck", sel, Pg,
-                       preferred_element_type=state.dtype)
+                       preferred_element_type=state.dtype,
+                       precision=dot_precision(state.dtype))
         # dst-tile block fetch: row-granular [*, 128K] movement (the
         # 24 ns/row static class) — the SAME fetch pair_partial_dot
         # makes, exempt there because its operand shape differs from
@@ -1203,12 +1204,14 @@ def paged_partial_dot(pp: PagedPlan, state, page_ids, slot_lane, rel,
         # audit: allow(gather-budget)
         T = jnp.take(s3, part_tile0 + rt, axis=0).reshape(-1, W, Kdim)
         D = jnp.einsum("rck,rwk->rcw", S, T,
-                       preferred_element_type=S.dtype)
+                       preferred_element_type=S.dtype,
+                       precision=dot_precision(S.dtype))
         mask = rl[..., None] == lanes8               # [B, 128, 128]
         dot = jnp.sum(jnp.where(mask, D, 0), axis=-1)
         msgs = msg_dot_fn(S, dot, wt)                # [B, 128, K]
         # dead lanes (rel == -1) match no output lane -> contribute 0
-        return jnp.einsum("rcw,rck->rwk", mask.astype(S.dtype), msgs)
+        return jnp.einsum("rcw,rck->rwk", mask.astype(S.dtype), msgs,
+                          precision=dot_precision(msgs.dtype))
 
     partials = jax.lax.map(
         block, (pad(slot_lane).reshape(nB, B, W),

@@ -87,6 +87,7 @@ def occurrence_index(pair: np.ndarray, slot: np.ndarray) -> np.ndarray:
     native.sort_kv(kp, (ks, idx))        # then stable by pair
     newg = np.ones(n, bool)
     newg[1:] = (kp[1:] != kp[:-1]) | (ks[1:] != ks[:-1])
+    del kp, ks              # 16 B an edge, before 24 more are made
     pos = np.arange(n)
     gst = np.maximum.accumulate(np.where(newg, pos, 0))
     occ = np.empty(n, np.int64)
@@ -204,9 +205,12 @@ def analyze_pairs(src_slot: np.ndarray, dst_local: np.ndarray,
     src_slot = np.asarray(src_slot, np.int64)
     dst_local = np.asarray(dst_local, np.int64)
 
-    st = src_slot // W
-    dt = dst_local // W
-    pair = st * n_tiles + dt
+    # every [ne] int64 temporary below is 1.6 GB at 2e8 edges (the
+    # NetFlix shape: 33 GB of them at once reached the one-chip
+    # machine's 40 GiB): each is dropped where its last reader is
+    pair = (src_slot // W) * n_tiles
+    pair += dst_local // W
+    del dst_local
     # fused radix sort carrying the edge index: replaces argsort +
     # key gather on the whole edge list (native.sort_kv, PERF_NOTES
     # round-4 host prep)
@@ -220,15 +224,19 @@ def analyze_pairs(src_slot: np.ndarray, dst_local: np.ndarray,
         ([0], np.nonzero(pp[1:] != pp[:-1])[0] + 1, [ne]))
         if ne else np.zeros(1, np.int64))
     sizes = np.diff(starts)
-    pair_id = np.repeat(np.arange(len(sizes)), sizes)
+    pair_keys = pp[starts[:-1]]                   # sorted unique keys
+    del pp
 
     sel_pair = sizes >= threshold
-    esel_sorted = sel_pair[pair_id]               # in pair-sorted order
+    # in pair-sorted order
+    esel_sorted = np.repeat(sel_pair, sizes)
+    # original edge idx of each covered edge
+    cov = order[esel_sorted]
+    del order, esel_sorted
     residual = np.ones(ne, bool)
-    residual[order[esel_sorted]] = False
+    residual[cov] = False
 
     # occurrence index of each covered edge within (pair, src lane)
-    cov = order[esel_sorted]                      # original edge idx
     occ = occurrence_index(pair[cov], src_slot[cov])
 
     # Optional occurrence-depth cap (edges beyond it ride the residual
@@ -244,7 +252,8 @@ def analyze_pairs(src_slot: np.ndarray, dst_local: np.ndarray,
 
     # per-pair row count = max occurrence + 1 (pair ids of the
     # possibly-reduced covered set, via the sorted unique pair keys)
-    pid_cov = np.searchsorted(pp[starts[:-1]], pair[cov])
+    pid_cov = np.searchsorted(pair_keys, pair[cov])
+    del pair
     # remap selected pair ids to dense [0, P)
     sel_ids = np.nonzero(sel_pair)[0]
     remap = np.full(len(sizes), -1, np.int64)
@@ -280,7 +289,7 @@ def analyze_pairs(src_slot: np.ndarray, dst_local: np.ndarray,
         np.maximum.at(nrows_pair, pidx, occ + 1)
 
     # order pairs by dst tile (for the per-tile combine), then src tile
-    pair_dt = (pp[starts[:-1]][sel_pair] % n_tiles)
+    pair_dt = (pair_keys[sel_pair] % n_tiles)
     tile_sort = np.argsort(pair_dt, kind="stable")
     # per-tile total rows -> depth classes
     rows_by_tile = np.zeros(n_tiles, np.int64)
@@ -589,8 +598,12 @@ def plan_sharded_pairs(sg, threshold: int,
             # not the full layout's
             residual.content_key = prepstore.derive(key, "residual")
         covered = 0 if sp is None else int(sp.stats["covered"])
+        # lanes the pair rows offer (128 a delivery row, every part):
+        # pair_edges over pair_lanes is the rows' fill
+        lanes = 0 if sp is None else W * sp.R * sp.rowbind.shape[0]
         span.count(pair_edges=covered,
-                   residual_edges=int(np.sum(residual.ne_part)))
+                   residual_edges=int(np.sum(residual.ne_part)),
+                   pair_lanes=lanes)
     return sp, residual
 
 
@@ -906,6 +919,21 @@ def resolve_pair_stream(pair_stream, pairs) -> bool:
     return True if pair_stream is None else bool(pair_stream)
 
 
+def dot_precision(dtype):
+    """The contraction precision of the dot (SDDMM) path: D = S @ T^T,
+    the one-hot gradient matmul and the residual's two einsums.  On
+    the TPU a float32 operand of a default-precision contraction is
+    rounded to bfloat16, which puts 2**-9 on every term of <src, dst>
+    and of the gradient: at the NetFlix shape the learned displacement
+    was then off by the bfloat16 control's level (PERF.md section 2,
+    cf.netflix).  HIGHEST keeps the float32 operands, as
+    ops/tiled._exact_sum_precision does for the sums; integer
+    operands are exact as they are."""
+    import jax
+    return (jax.lax.Precision.HIGHEST
+            if np.dtype(dtype).kind == "f" else None)
+
+
 def pair_partial_dot(sp: StackedPairPlan, state, rowbind, rel, weight,
                      row_tile, tile_pos, part_tile0, msg_dot_fn,
                      block_rows: int = 256):
@@ -959,12 +987,14 @@ def pair_partial_dot(sp: StackedPairPlan, state, rowbind, rel, weight,
         S = jnp.take(s3, rb, axis=0).reshape(-1, W, Kdim)
         T = jnp.take(s3, part_tile0 + rt, axis=0).reshape(-1, W, Kdim)
         D = jnp.einsum("rck,rwk->rcw", S, T,
-                       preferred_element_type=S.dtype)
+                       preferred_element_type=S.dtype,
+                       precision=dot_precision(S.dtype))
         mask = rl[..., None] == lanes                  # [B, 128, 128]
         dot = jnp.sum(jnp.where(mask, D, 0), axis=-1)  # [B, 128]
         msgs = msg_dot_fn(S, dot, wt)                  # [B, 128, K]
         # dead lanes (rel == -1) match no output lane -> contribute 0
-        return jnp.einsum("rcw,rck->rwk", mask.astype(S.dtype), msgs)
+        return jnp.einsum("rcw,rck->rwk", mask.astype(S.dtype), msgs,
+                          precision=dot_precision(msgs.dtype))
 
     partials = jax.lax.map(
         block, (pad(rowbind).reshape(nB, B),
@@ -1003,7 +1033,8 @@ def pair_partial_dot_streamed(sp: StackedPairPlan, state, rowbind, rel,
     reduces through the one-hot gradient matmul AND folds the
     cross-row (occurrence-depth) sum inside the step — emitting
     per-SLOT results [S, 128, K], so live memory is one block at any
-    scale.  The scalar analogue (and the original of the slot-block
+    scale; a slot deeper than a block is cut into blocks of rows
+    (``deep_class``).  The scalar analogue (and the original of the slot-block
     discipline) is ``pair_partial_streamed``.
     """
     import jax
@@ -1022,23 +1053,60 @@ def pair_partial_dot_streamed(sp: StackedPairPlan, state, rowbind, rel,
         Sv = jnp.take(s3, rb, axis=0).reshape(-1, W, Kdim)
         T = jnp.take(s3, part_tile0 + rt, axis=0).reshape(-1, W, Kdim)
         D = jnp.einsum("rck,rwk->rcw", Sv, T,
-                       preferred_element_type=Sv.dtype)
+                       preferred_element_type=Sv.dtype,
+                       precision=dot_precision(Sv.dtype))
         mask = rl[..., None] == lanes                  # [S*L, 128, 128]
         dot = jnp.sum(jnp.where(mask, D, 0), axis=-1)  # [S*L, 128]
         msgs = msg_dot_fn(Sv, dot, wt)                 # [S*L, 128, K]
         # dead lanes (rel == -1) match no output lane -> contribute 0
-        p = jnp.einsum("rcw,rck->rwk", mask.astype(Sv.dtype), msgs)
+        p = jnp.einsum("rcw,rck->rwk", mask.astype(Sv.dtype), msgs,
+                       precision=dot_precision(msgs.dtype))
         return jnp.sum(p.reshape(S, L, W, Kdim), axis=1)
 
     # per-row live bytes: S + T + msgs + partials tiles [W, K] each,
     # plus the [W, W] dot/mask blocks
     row_bytes = 4 * W * (W + 4 * Kdim)
+    block_rows = max(1, block_bytes // row_bytes)
+
+    def deep_class(row0, cnt, L):
+        """A class whose ONE slot is deeper than a block (a hub tile
+        pair: 252,981 rows at the NetFlix shape, 27 GB as one block):
+        each slot's rows go through the scan ``block_rows`` at a time,
+        read in place with dynamic slices (as scan inputs the chunks
+        compiled to 40% more code, which is device memory), and the
+        per-chunk sums [W, K] are added up per slot afterwards."""
+        nF, rem = divmod(L, block_rows)
+
+        def chunk(start, n):
+            def cut(x):
+                return jax.lax.dynamic_slice_in_dim(x, start, n)
+            return slot_results(cut(rowbind), cut(rel), cut(weight),
+                                cut(row_tile), 1, n)[0]
+
+        def full(_, i):
+            return None, chunk(row0 + (i // nF) * L
+                               + (i % nF) * block_rows, block_rows)
+
+        _, parts = jax.lax.scan(full, None, jnp.arange(cnt * nF))
+        out = jnp.sum(parts.reshape(cnt, nF, W, Kdim), axis=1)
+        if rem:
+            def tail(_, slot):
+                return None, chunk(row0 + slot * L + nF * block_rows,
+                                   rem)
+            _, tails = jax.lax.scan(tail, None, jnp.arange(cnt))
+            out = out + tails
+        return out
+
     outs = []
     row0 = 0
     for (cnt, L) in sp.classes:
-        # whole slots per block, >= 1, sized so one block's rows stay
-        # under block_bytes
-        S = max(1, min(cnt, block_bytes // max(1, L * row_bytes)))
+        if L > block_rows:
+            outs.append(deep_class(row0, cnt, L))
+            row0 += cnt * L
+            continue
+        # whole slots per block (>= 1: L <= block_rows here), sized so
+        # one block's rows stay under block_bytes
+        S = min(cnt, block_rows // L)
         nB, rem = divmod(cnt, S)
 
         def seg(lo, n):
