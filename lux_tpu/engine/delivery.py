@@ -50,7 +50,7 @@ from lux_tpu.ops import pagegather, pairs as pair_ops
 from lux_tpu.ops.segment import segment_reduce
 from lux_tpu.ops.tiled import (STREAM_MSG_BYTES, TiledLayout,
                                combine_chunks, combine_op,
-                               combine_partials,
+                               combine_partials, method_args,
                                streamed_chunk_partials,
                                tiled_segment_reduce)
 from lux_tpu.parallel.mesh import PARTS_AXIS, local_part_rows
@@ -468,15 +468,13 @@ class Delivery:
             red = segment_reduce(msgs, g["dst_local"], vpad + 1,
                                  self.kind)[:vpad]
         else:
-            # the Pallas kernel takes scalar payloads only
-            # (tiled_segment_reduce falls to XLA for the rest)
+            # the Pallas partial kernel takes scalar payloads only
+            # (tiled_segment_reduce falls to XLA for the rest); the
+            # chunk combine's kernel takes both
             red = tiled_segment_reduce(
                 msgs, lay, g["chunk_start"], g["last_chunk"],
                 g["rel_dst"], vpad, self.kind, use_mxu=self.use_mxu,
-                method=("pallas"
-                        if self.reduce_method.startswith("pallas")
-                        else "xla"),
-                interpret=self.reduce_method == "pallas-interpret")
+                **method_args(self.reduce_method))
         return self._with_pairs(red, flat_table, msg, g)
 
     def _pair_rows(self, flat_table, msg, g):
@@ -519,7 +517,8 @@ class Delivery:
             use_mxu=self.use_mxu)
         red = combine_partials(partials, lay, g["chunk_start"],
                                g["last_chunk"], vpad, self.kind,
-                               use_mxu=self.use_mxu)
+                               use_mxu=self.use_mxu,
+                               **method_args(self.reduce_method))
         return self._with_pairs(red, flat_table, msg, g)
 
     # -- the dot form (SDDMM) -------------------------------------------
@@ -615,7 +614,8 @@ class Delivery:
         partials = jax.lax.map(block, args).reshape(Cp, W, Kdim)[:C]
         red = combine_chunks(partials, lay, g["chunk_start"],
                              g["last_chunk"], self.kind,
-                             use_mxu=self.use_mxu)
+                             use_mxu=self.use_mxu,
+                             **method_args(self.reduce_method))
         return red.reshape(n_tiles * W, Kdim)[:sg.vpad]
 
     # -- the owner form (ops/owner.py) ------------------------------------

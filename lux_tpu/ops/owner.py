@@ -504,6 +504,7 @@ def owner_part_tiles(lay: OwnerLayout, state_s, src, rel, weight, cs,
     import jax.numpy as jnp
 
     from lux_tpu.ops.tiled import (chunk_partials, combine_chunks,
+                                   method_args,
                                    streamed_chunk_combined,
                                    streamed_chunk_partials)
 
@@ -533,5 +534,5 @@ def owner_part_tiles(lay: OwnerLayout, state_s, src, rel, weight, cs,
             msgs = jax.lax.optimization_barrier(msgs)
             partials = chunk_partials(msgs, rel, lay.W, kind,
                                       use_mxu=use_mxu)
-    return combine_chunks(partials, lay, cs, lc, kind,
-                          use_mxu=use_mxu)                 # [G, W, ...]
+    return combine_chunks(partials, lay, cs, lc, kind, use_mxu=use_mxu,
+                          **method_args(reduce_method))    # [G, W, ...]

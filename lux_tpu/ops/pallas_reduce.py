@@ -6,7 +6,10 @@ the reference's CUB BlockScan + atomic scatter CTA pattern
 §3.3).  It consumes the tiled chunk layout of ops/tiled.py: edge
 messages ``vals [C, E]`` with relative destinations ``rel_dst [C, E]``
 in ``[0, W)`` (negative = padding lane) and produces per-chunk partials
-``[C, W]``, which ops/tiled.combine_chunks folds into vertex tiles.
+``[C, W]``, which ops/tiled.combine_chunks folds into vertex tiles: on
+the ``pallas`` reduce method with the one-pass kernel beside this one
+(ops/pallas_combine.py, scalar and lane-minor vector payloads alike),
+on ``xla`` with the segmented ``associative_scan`` (ops/tiled._segscan).
 
 Why a kernel instead of the XLA broadcast-compare reduction
 (ops/tiled.chunk_partials):

@@ -16,11 +16,18 @@ static-shape VPU/MXU work plus one short segmented scan:
   ``[0, W)`` (``W`` marks padding lanes).  The chunk's partial result
   ``[W]`` is a masked broadcast-reduce (VPU) or a one-hot matmul (MXU)
   — both fuse in XLA, neither scatters.
-- Chunks of the same tile are combined with a segmented
-  ``associative_scan`` over the chunk axis (flag-reset, exact — no
-  cumsum boundary-difference cancellation), then the last chunk of
-  each tile is gathered.  When every tile fits in one chunk the scan
-  is skipped statically.
+- Chunks of the same tile are combined along the chunk axis
+  (flag-reset, exact — no cumsum boundary-difference cancellation),
+  then the last chunk of each tile is gathered (``combine_chunks``,
+  scope ``lux_combine``).  Where the reduce method is ``pallas`` the
+  combine is ONE sequential pass with a carry
+  (ops/pallas_combine.segmented_combine_pallas); the portable ``xla``
+  method keeps the segmented ``associative_scan`` (``_segscan``,
+  blocked above SCAN_BLOCKED_ABOVE chunks) as the oracle, and the MXU
+  sum keeps ``_segscan_matmul``.  Vector payloads are combined in the
+  lane-dense order ``[C, K, W]`` and only the ``n_tiles`` rows that
+  survive the last-chunk take are moved to ``[n_tiles, W, K]``.  When
+  every tile fits in one chunk the combine is skipped statically.
 
 Degree skew (the Twitter/RMAT power-law "hard part", SURVEY.md §7) is
 absorbed by construction: a hub vertex simply owns many chunks, and
@@ -337,8 +344,15 @@ def _mxu_compare_reduce(vals, rel_dst, W: int, kind: str):
     return jnp.where(occb, out, ident)
 
 
-def chunk_partials(vals, rel_dst, W: int, kind: str, use_mxu: bool = False):
+def chunk_partials(vals, rel_dst, W: int, kind: str, use_mxu: bool = False,
+                   lane_minor: bool = False):
     """Per-chunk reduction [C, E, ...] -> [C, W, ...].
+
+    lane_minor=True leaves a vector payload's result in the order the
+    VPU reduce produces it, [C, K, W] (the W lanes minor), for
+    ``combine_chunks(..., lane_minor=True)``, which moves only the
+    tile rows it keeps; scalar payloads and the MXU contraction are
+    [C, W, ...] either way.
 
     use_mxu=True contracts against an int8 one-hot lane-membership
     matrix on the MXU: sum is one mixed-dtype contraction
@@ -373,7 +387,7 @@ def chunk_partials(vals, rel_dst, W: int, kind: str, use_mxu: bool = False):
         match = match[:, :, None, :]        # [C, E, 1, W]
         masked = jnp.where(match, vals[..., None], ident)
         red = _reduce_axis(masked, 1, kind)     # [C, K, W]
-        return jnp.moveaxis(red, -1, 1)         # [C, W, K]
+        return red if lane_minor else jnp.moveaxis(red, -1, 1)
     masked = jnp.where(match, vals[..., None], ident)   # [C, E, W]
     return _reduce_axis(masked, 1, kind)
 
@@ -383,7 +397,9 @@ def _reduce_axis(x, axis, kind):
         x, axis=axis)
 
 
-# combine_chunks switches to the BLOCKED segmented scan once the
+# The ``xla`` reduce method only (``pallas`` combines in one pass with
+# a carry and holds no tree, ops/pallas_combine.py): combine_chunks
+# switches to the BLOCKED segmented scan once the
 # chunk axis passes this length: jax.lax.associative_scan over
 # [C, W] materializes O(log C) tree levels of BOTH tuple operands, ~2
 # * log2(C) * C * W * 4 bytes of program memory — measured as the
@@ -410,11 +426,42 @@ def _segscan(partials, flags, kind):
     return vals
 
 
+def method_args(reduce_method: str) -> dict:
+    """A resolved ``reduce_method`` ('xla' | 'pallas' |
+    'pallas-interpret') as the ``method`` / ``interpret`` arguments
+    of tiled_segment_reduce / combine_partials / combine_chunks."""
+    return dict(
+        method="pallas" if reduce_method.startswith("pallas") else "xla",
+        interpret=reduce_method == "pallas-interpret")
+
+
+def _kernel_takes(partials, lane_minor: bool) -> bool:
+    """Whether the one-pass combine kernel lays these partials out:
+    the W lanes minor ([C, W], or [C, K, W] when lane_minor) and a
+    shape and dtype ops/pallas_combine.kernel_takes accepts."""
+    if not (lane_minor or partials.ndim == 2):
+        return False
+    from lux_tpu.ops.pallas_combine import kernel_takes
+    return kernel_takes(partials.shape, partials.dtype)
+
+
 def combine_chunks(partials, layout: TiledLayout, chunk_start, last_chunk,
-                   kind: str, use_mxu: bool = False):
+                   kind: str, use_mxu: bool = False, method: str = "xla",
+                   interpret: bool = False, lane_minor: bool = False):
     """Segmented combine of per-chunk partials [C, W, ...] into tile
     results [n_tiles, W, ...]; chunk_start/last_chunk are this part's
     rows of the layout arrays (device).
+
+    lane_minor=True: the partials are [C, K, W] (``chunk_partials(...,
+    lane_minor=True)``); they are combined in that order and only the
+    n_tiles rows the last-chunk take keeps are moved to [n_tiles, W,
+    K].
+
+    method 'pallas' combines in one sequential pass with a carry
+    (ops/pallas_combine.py) wherever the payload has the kernel's
+    shape (``kernel_takes``: 4-byte, the W = 128 lanes minor, [C, W]
+    or lane-minor [C, K, W]).  Everything else, and method 'xla', runs
+    the flag-reset associative scan (the portable oracle).
 
     use_mxu=True routes the sum-kind scan through _segscan_matmul (the
     TCU-paper scan-as-matmul recurrence); min/max segmented scans stay
@@ -423,21 +470,29 @@ def combine_chunks(partials, layout: TiledLayout, chunk_start, last_chunk,
     matmul form here (each row of the segment matrix would need its
     own vote), and the flag-reset associative scan is already
     O(C log C) compares."""
-    if layout.needs_scan:
-        C = partials.shape[0]
-        if use_mxu and kind == "sum":
-            partials = _segscan_matmul(partials, chunk_start)
-        elif C <= SCAN_BLOCKED_ABOVE:
-            flags = chunk_start.reshape(
-                chunk_start.shape + (1,) * (partials.ndim - 1))
-            partials = _segscan(partials, flags, kind)
-        else:
-            partials = _segscan_blocked(partials, chunk_start, kind)
-    ident = identity_for(kind, partials.dtype)
-    out = jnp.take(partials, jnp.maximum(last_chunk, 0), axis=0)
-    empty = (last_chunk < 0).reshape(
-        last_chunk.shape + (1,) * (out.ndim - 1))
-    return jnp.where(empty, ident, out)
+    with jax.named_scope("lux_combine"):
+        if layout.needs_scan:
+            C = partials.shape[0]
+            if use_mxu and kind == "sum":
+                partials = _segscan_matmul(partials, chunk_start)
+            elif method == "pallas" and _kernel_takes(partials, lane_minor):
+                from lux_tpu.ops.pallas_combine import (
+                    segmented_combine_pallas)
+                partials = segmented_combine_pallas(
+                    partials, chunk_start, kind, interpret=interpret)
+            elif C <= SCAN_BLOCKED_ABOVE:
+                flags = chunk_start.reshape(
+                    chunk_start.shape + (1,) * (partials.ndim - 1))
+                partials = _segscan(partials, flags, kind)
+            else:
+                partials = _segscan_blocked(partials, chunk_start, kind)
+        ident = identity_for(kind, partials.dtype)
+        out = jnp.take(partials, jnp.maximum(last_chunk, 0), axis=0)
+        if lane_minor:
+            out = jnp.moveaxis(out, -1, 1)      # [n_tiles, W, K]
+        empty = (last_chunk < 0).reshape(
+            last_chunk.shape + (1,) * (out.ndim - 1))
+        return jnp.where(empty, ident, out)
 
 
 def _segscan_blocked(partials, chunk_start, kind,
@@ -811,12 +866,14 @@ def streamed_chunk_combined(flat_state, src_slot, rel_dst, weight,
 
 def combine_partials(partials, layout: TiledLayout, chunk_start,
                      last_chunk, vpad: int, kind: str,
-                     use_mxu: bool = False):
+                     use_mxu: bool = False, method: str = "xla",
+                     interpret: bool = False, lane_minor: bool = False):
     """Per-chunk partials [C, W, ...] -> flat [vpad, ...] (the shared
     tail of tiled_segment_reduce, also used by the streamed engines
     that produce partials block-wise)."""
     tiles = combine_chunks(partials, layout, chunk_start, last_chunk,
-                           kind, use_mxu=use_mxu)
+                           kind, use_mxu=use_mxu, method=method,
+                           interpret=interpret, lane_minor=lane_minor)
     flatshape = (layout.n_tiles * layout.W,) + tiles.shape[2:]
     return tiles.reshape(flatshape)[:vpad]
 
@@ -830,16 +887,21 @@ def tiled_segment_reduce(vals, layout: TiledLayout, chunk_start,
     vals [C, E, ...] chunked edge messages; returns [vpad, ...] —
     drop-in for ``segment_reduce(msgs, dst_local, vpad+1, kind)[:vpad]``.
 
-    method 'pallas' runs the per-chunk partial reduction as a Pallas
-    TPU kernel (ops/pallas_reduce.py) — scalar payloads only; 'xla'
-    is the portable broadcast-compare formulation.
+    method 'pallas' runs the per-chunk partial reduction (scalar
+    payloads only, ops/pallas_reduce.py) and the chunk combine
+    (ops/pallas_combine.py) as Pallas TPU kernels; 'xla' is the
+    portable broadcast-compare formulation with the associative scan.
     """
+    # the VPU reduce of a vector payload comes out [C, K, W]
+    lane_minor = vals.ndim > 2 and not use_mxu
     if method == "pallas" and vals.ndim == 2:
         from lux_tpu.ops.pallas_reduce import chunk_partials_pallas
         partials = chunk_partials_pallas(vals, rel_dst, layout.W, kind,
                                          interpret=interpret)
     else:
         partials = chunk_partials(vals, rel_dst, layout.W, kind,
-                                  use_mxu=use_mxu)
+                                  use_mxu=use_mxu,
+                                  lane_minor=lane_minor)
     return combine_partials(partials, layout, chunk_start, last_chunk,
-                            vpad, kind, use_mxu=use_mxu)
+                            vpad, kind, use_mxu=use_mxu, method=method,
+                            interpret=interpret, lane_minor=lane_minor)

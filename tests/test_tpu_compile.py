@@ -72,6 +72,30 @@ def test_chunk_partials_kernel_compiles(v5e, block_c, E, kind, dtype):
     assert "tpu_custom_call" in text
 
 
+@pytest.mark.parametrize("shape,dtype,kind", [
+    ((37000, 16, 128), jnp.int32, "min"),     # kron20-serve, B = 16
+    ((37003, 16, 128), jnp.float32, "min"),   # C no multiple of a block
+    ((139000, 128), jnp.float32, "sum"),      # pr.kron21's residual
+    ((1400000, 128), jnp.int32, "min"),       # past SCAN_BLOCKED_ABOVE
+    ((20000, 20, 128), jnp.float32, "sum"),   # K = 20: 24 padded sublanes
+    ((512, 128, 128), jnp.float32, "max"),    # a tall row: block of 128
+])
+def test_chunk_combine_kernel_compiles(v5e, shape, dtype, kind):
+    """The one-pass chunk combine (ops/pallas_combine.py) at the
+    cells' shapes with the block the payload's shape picks: the row
+    kernel and the 8-chunk tile kernel lower through Mosaic, their
+    blocks fit scoped VMEM, the SMEM flag block's layout is the one
+    XLA gives it, and the partials are rewritten in place."""
+    from lux_tpu.ops.pallas_combine import segmented_combine_pallas
+    compiled = segmented_combine_pallas.lower(
+        jax.ShapeDtypeStruct(shape, dtype, sharding=v5e),
+        jax.ShapeDtypeStruct(shape[:1], jnp.bool_, sharding=v5e),
+        kind=kind).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    assert "output_to_operand_aliasing" in text
+
+
 def test_block_64x512_is_refused_and_block_partials_avoids_it(v5e):
     """(64, 512) overflows scoped VMEM — the case _block_partials'
     sizing rule guards: handed a 64-chunk E=512 block it must pick
@@ -107,7 +131,7 @@ def test_lane_shuffle_kernel_compiles(v5e, dtype):
 
 
 def test_kernels_compile_inside_shard_map(topo):
-    """Both Pallas kernels under a VMA-checked ``shard_map`` over the
+    """The Pallas kernels under a VMA-checked ``shard_map`` over the
     four-device parts mesh — the owner exchange's position on a real
     mesh.  ``pallas_call`` refuses an ``out_shape`` without ``vma``
     there; the CPU mesh tests never reach it (off-TPU ``auto``
@@ -118,6 +142,7 @@ def test_kernels_compile_inside_shard_map(topo):
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
     from lux_tpu.ops.pagegather import _lane_shuffle_pallas
+    from lux_tpu.ops.pallas_combine import segmented_combine_pallas
     from lux_tpu.ops.pallas_reduce import chunk_partials_pallas
     from lux_tpu.parallel.mesh import PARTS_AXIS
 
@@ -137,11 +162,36 @@ def test_kernels_compile_inside_shard_map(topo):
         reduce_, sds(jnp.float32), sds(jnp.int8))
     assert "tpu_custom_call" in _compiled_text(
         shuffle, sds(jnp.float32), sds(jnp.int32))
+    # the chunk combine: scalar tiles [C, 128] and rows [C, 16, 128]
+    for trail in ((128,), (16, 128)):
+        combine = jax.jit(on_mesh(
+            lambda v, f: segmented_combine_pallas(v[0], f[0],
+                                                  "min")[None]))
+        assert "tpu_custom_call" in _compiled_text(
+            combine,
+            jax.ShapeDtypeStruct((4, 2048) + trail, jnp.int32,
+                                 sharding=parts),
+            jax.ShapeDtypeStruct((4, 2048), jnp.bool_, sharding=parts))
 
 
 def _rmat12():
     from lux_tpu.convert import rmat_graph
     return rmat_graph(scale=12, edge_factor=8, seed=0)
+
+
+def _assert_combine_kernel(layout, text):
+    """Tiles span chunks, so the program holds the chunk combine: as
+    the kernel under ``lux_combine``, with no tree scan's interleave
+    (``lax.pad`` of the payload) beside it."""
+    import re
+    assert layout.needs_scan
+    calls = [ln for ln in text.splitlines()
+             if "tpu_custom_call" in ln and "lux_combine" in ln]
+    assert calls and all("segmented_combine_pallas" in c for c in calls)
+    pads = [ln for ln in text.splitlines()
+            if re.search(r"= [fs]32\[[^]]*,128\]\S* pad\(", ln)
+            and "lux_combine" in ln]
+    assert not pads, pads[:2]
 
 
 def _step_text(eng, v5e):
@@ -158,7 +208,9 @@ def test_pull_step_compiles_with_pallas(v5e):
     sg = ShardedGraph.build(_rmat12(), 1)
     eng = PullEngine(sg, pagerank.make_program(),
                      reduce_method="pallas")
-    assert "tpu_custom_call" in _step_text(eng, v5e)
+    text = _step_text(eng, v5e)
+    assert "tpu_custom_call" in text
+    _assert_combine_kernel(eng.delivery.tiles, text)
 
 
 @pytest.mark.parametrize("family", ["pull", "push", "push-symmetric"])
@@ -206,7 +258,9 @@ def test_mesh_owner_step_compiles_with_pallas(topo, monkeypatch,
     args = [a if getattr(a, "sharding", None) is not None
             else _on(parts if a.ndim else replicated, a)
             for a in args()]
-    assert "tpu_custom_call" in _compiled_text(jitted, *args)
+    text = _compiled_text(jitted, *args)
+    assert "tpu_custom_call" in text
+    _assert_combine_kernel(eng.delivery.owner, text)
 
 
 @pytest.mark.parametrize("chips", [1, 4])
@@ -272,7 +326,9 @@ def test_push_step_compiles_with_pallas(v5e, symmetric):
     eng = PushEngine(sg, sssp.make_program(0),
                      reduce_method="pallas")
     assert eng.pull == symmetric
-    assert "tpu_custom_call" in _step_text(eng, v5e)
+    text = _step_text(eng, v5e)
+    assert "tpu_custom_call" in text
+    _assert_combine_kernel(eng.delivery.tiles, text)
 
 
 @pytest.mark.parametrize("chips", [1, 4])
