@@ -4,9 +4,14 @@ Matches the reference's algorithm (reference components_gpu.cu:57-59,
 733-739): every vertex starts active with label = its own id; each
 iteration a destination takes the max label over its in-neighbors;
 convergence when no label changes.  On a symmetrized (undirected)
-graph every component converges to the max vertex id in the component.
-The check audits the fixed point: labels[dst] >= labels[src] for every
-edge (components_gpu.cu:788).
+graph every component converges to the max vertex id in the component
+(weakly connected components).  On a DIRECTED graph, loaded as it is
+(what ``lux_tpu/cli.py components`` and upstream do; only ``bench.py``
+symmetrizes), a label travels along the arc's direction only, and the
+fixed point is, for every vertex, the largest id among its ANCESTORS:
+the vertices that reach it, itself included.  The check audits the
+fixed point either way: labels[dst] >= labels[src] for every edge
+(components_gpu.cu:788).
 """
 
 from __future__ import annotations

@@ -484,8 +484,8 @@ def oracle_for(eng) -> list:
     if use_sparse:
         # the ladder (engine/frontier.py): one alternative per queue
         # rung, holding the queue exchange at that rung's size, the
-        # pmax'd out-edge total that picks the budget rung and the
-        # pmin'd processed prefix
+        # pmax'd out-edge total that picks the budget rung, the psum
+        # of the fill counts and the pmin'd processed prefix
         for i, Q in enumerate(eng.queue_rungs):
             rung = f"sparse#q{i}"
             out.append(entry("all_gather", (P_local, Q), np.int32,
@@ -493,6 +493,8 @@ def oracle_for(eng) -> list:
             out.append(entry("all_gather", (P_local, Q), lab_dt,
                              branch=rung))
             out.append(entry("pmax", (), np.int32, branch=rung))
+            # the fill counts: every part's items and expanded edges
+            out.append(entry("psum", (2,), np.uint32, branch=rung))
             out.append(entry("pmin", (), np.int32, branch=rung))
             if pull:
                 # the pulled buffer of the gathered queue, combined

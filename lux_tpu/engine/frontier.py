@@ -149,6 +149,45 @@ def rung_index(need, ladder):
     return idx
 
 
+def rung_size(idx, ladder):
+    """int32 size of rung ``idx`` (a ``rung_index``) of the ascending
+    ``ladder``, by selects: a scalar stays a scalar (no table
+    lookup)."""
+    size = jnp.int32(ladder[0])
+    for i, r in enumerate(ladder[1:], 1):
+        size = jnp.where(idx >= i, jnp.int32(r), size)
+    return size
+
+
+def wide_add(low, high, x):
+    """The count kept in the uint32 words (``low``, ``high``) plus
+    ``x`` uint32, the carry going into the high word -> (low, high): a
+    count that may pass 2^32 inside one loop, kept exact without
+    64-bit types.  Elementwise; on scalars nothing but scalar
+    arithmetic."""
+    new = low + x
+    return new, high + (new < x).astype(jnp.uint32)
+
+
+class Folded:
+    """Row ``index`` of ``wide_add`` words stacked [N, 2] (low, high),
+    still on the device, read as ONE number: ``item()`` folds the two words into a
+    Python int.  The surface ``telemetry``'s ring settles a count by
+    (``is_ready`` / ``item``), so marking one fetches nothing."""
+
+    __slots__ = ("words", "index")
+
+    def __init__(self, words, index: int):
+        self.words, self.index = words, index
+
+    def is_ready(self) -> bool:
+        return self.words.is_ready()
+
+    def item(self) -> int:
+        low, high = jax.device_get(self.words)[self.index]
+        return (int(high) << 32) | int(low)
+
+
 def frontier_extents(ids, src_ids, src_off, nv: int):
     """The queue-sized half of the expansion: where each queue item's
     out-edges lie in this part.

@@ -61,7 +61,20 @@ The TPU-native design:
   ladder of rungs and each iteration runs on the smallest rungs that
   hold its frontier's count and out-edge total — quantities the loop
   observes, no option (engine/frontier.py).  Lower rungs never
-  truncate: the iteration sequence is the top rung's.
+  truncate: the iteration sequence is the top rung's.  How FULL the
+  slots were is counted beside ``sparse_iters`` / ``low_rung_iters``
+  / ``pull_iters`` in the loops' carry and left on the
+  ``push.converge`` mark: over a call's sparse iterations
+  ``queue_items`` (vertices the queue stage compacted, all parts) and
+  ``queue_slots`` (the queue rungs they ran on x parts),
+  ``budget_edges`` (edges the budget stage expanded: a part's
+  out-edge total, or the budget where it truncated) and
+  ``budget_slots`` (the budget rungs x parts).  The carry keeps each
+  in two uint32 scalar words (``frontier.wide_add``: hundreds of
+  iterations on a 12 M-slot rung pass 2^32) and the mark folds them
+  into ONE number when the ring is read (``frontier.Folded``).  Engines
+  without a ladder (batched, ``enable_sparse=False``) carry none and
+  mark zeros.
 - Sparse overflow safety: when a frontier's out-edges exceed the
   static edge budget, the un-expanded queue suffix simply STAYS
   ACTIVE (the globally-agreed processed prefix is cleared via a
@@ -430,17 +443,23 @@ class PushEngine(AuditableEngine):
     # -- sparse iteration ----------------------------------------------
 
     def _sparse_parts(self, label, active, need, g, gather_fn,
-                      pmin_fn, pmax_fn, pull=None, unreached=None):
+                      pmin_fn, pmax_fn, psum_fn, pull=None,
+                      unreached=None):
         """One frontier-queue iteration over this device's parts, on
         the smallest static shapes that hold the queue (the ladder,
         engine/frontier.py) -> (label, active, 1 if the edge budget
-        was a lower rung else 0).
+        was a lower rung else 0, fill): fill = four uint32 scalars,
+        how full the iteration's slots were: (queue_items, queue_slots,
+        budget_edges, budget_slots), the vertices compacted and the
+        edges expanded (a part's out-edge total, or the budget where
+        it truncated) summed over ALL parts, beside the rungs they ran
+        on x parts.
 
         need is what the queue must hold: the frontier's global size
         (it fits the top queue: _sparse_mode).  gather_fn concatenates
         per-part queue arrays across the whole mesh (identity +
-        reshape on a single device); pmin_fn / pmax_fn reduce across
-        the mesh.  Both rung indices are replicated scalars picked
+        reshape on a single device); pmin_fn / pmax_fn / psum_fn reduce
+        across the mesh.  Both rung indices are replicated scalars picked
         OUTSIDE the per-part vmap, where a switch would decay into
         select-every-branch; the branches hold the collectives, so
         every device takes the same.
@@ -495,8 +514,25 @@ class PushEngine(AuditableEngine):
                 lambda sids, soff: fr.frontier_extents(
                     all_gids, sids, soff, nv))(
                 g["src_ids"], g["src_off"])
-            eb_rung = fr.rung_index(exchanged(pmax_fn, jnp.max(total)),
+            most = jnp.max(total)
+            eb_rung = fr.rung_index(exchanged(pmax_fn, most),
                                     self.budget_rungs)
+            # how full this iteration's slots are (the loops' ``fill``
+            # carry): every part's items and expanded edges, one psum.
+            # With one local part (every cell) its total IS ``most``:
+            # ``total`` keeps the one reader it had
+            eb_size = fr.rung_size(eb_rung, self.budget_rungs)
+            if total.shape[0] == 1:
+                local = [jnp.minimum(cnts[0], Q),
+                         jnp.minimum(most, eb_size)]
+            else:
+                local = [jnp.sum(jnp.minimum(cnts, Q)),
+                         jnp.sum(jnp.minimum(total, eb_size))]
+            used = exchanged(psum_fn,
+                             jnp.stack(local).astype(jnp.uint32))
+            fill = (used[0], jnp.uint32(Q * sg.num_parts), used[1],
+                    eb_size.astype(jnp.uint32)
+                    * jnp.uint32(sg.num_parts))
 
             # 4. each part relaxes the queue's edges that land in its
             #    partition.  Where the bottom-up step is built the
@@ -594,7 +630,7 @@ class PushEngine(AuditableEngine):
                                                 g["part_start"], pidx)
                 low = eb_rung < len(self.budget_rungs) - 1
                 return (new_label, improved | cleared,
-                        low.astype(jnp.int32))
+                        low.astype(jnp.int32), fill)
 
             # 5'. with the bottom-up step ONE scatter takes the queue
             #     back onto the vertices whichever way the slots ran
@@ -635,7 +671,7 @@ class PushEngine(AuditableEngine):
             low = eb_rung < len(self.budget_rungs) - 1
             return (jnp.where(pull, got, new_label),
                     jnp.where(pull, prog.better(got, new_label), kept),
-                    low.astype(jnp.int32))
+                    low.astype(jnp.int32), fill)
 
         return jax.lax.switch(
             fr.rung_index(need, self.queue_rungs),
@@ -747,9 +783,35 @@ class PushEngine(AuditableEngine):
         on_mesh = self.mesh is not None
         sg, prog = self.sg, self.program
         use_sparse, _limit, pull_built = self._sparse_mode()
-        # the loops' counter carry, last: sparse_iters, low_rung_iters
-        # and, where the bottom-up step is built, pull_iters
+        # the loops' counter carry, last: (took, fill).  took: int32
+        # sparse_iters, low_rung_iters and, where the bottom-up step
+        # is built, pull_iters.  fill (engines with a ladder, else
+        # None: no leaf, the program they had): the sparse iterations'
+        # queue_items, queue_slots, budget_edges, budget_slots, each
+        # a (low word, high word) pair of uint32 SCALARS (hundreds of
+        # iterations on a 12 M-slot rung pass 2^32; scalars, so the
+        # loop does scalar arithmetic on them and nothing else),
+        # stacked into one uint32 [4, 2] behind the loop
         n_took = 2 + int(pull_built)
+        n_counts = n_took + int(use_sparse)
+
+        def tally(ctr, took, fill):
+            return (ctr[0] + took,
+                    None if fill is None else tuple(
+                        fr.wide_add(low, high, x)
+                        for (low, high), x in zip(ctr[1], fill)))
+
+        def tally0():
+            zero = jnp.uint32(0)
+            return (jnp.zeros((n_took,), jnp.int32),
+                    ((zero, zero),) * 4 if use_sparse else None)
+
+        def counts_out(ctr):
+            # -> sparse_iters, low_rung_iters[, pull_iters][, fill]
+            took, fill = ctr
+            if fill is None:
+                return tuple(took)
+            return (*took, jnp.stack([jnp.stack(w) for w in fill]))
         cap_n = self.stats_cap
 
         def global_sum(x):
@@ -771,6 +833,11 @@ class PushEngine(AuditableEngine):
         def pmax_fn(x):
             if on_mesh:
                 return jax.lax.pmax(x, PARTS_AXIS)
+            return x
+
+        def psum_fn(x):
+            if on_mesh:
+                return jax.lax.psum(x, PARTS_AXIS)
             return x
 
         def replicate_parts(x):
@@ -831,15 +898,17 @@ class PushEngine(AuditableEngine):
             return self._dense_parts(label, active, full_l, full_a, g)
 
         def body(label, active, count, g):
-            """-> (label, active, took): took = int32 [n_took], 1 if
-            the SPARSE branch ran, 1 if it ran below the top edge
+            """-> (label, active, took, fill): took = int32 [n_took],
+            1 if the SPARSE branch ran, 1 if it ran below the top edge
             budget and (engines with the bottom-up step) 1 if it ran
             bottom-up — what the loops sum into their ``sparse_iters``
             / ``low_rung_iters`` / ``pull_iters`` carry (the
-            device-side counters telemetry reads)."""
+            device-side counters telemetry reads); fill = the sparse
+            branch's four uint32 scalars (_sparse_parts; zeros from
+            the dense one), None on an engine without a ladder."""
             if not use_sparse:
                 return (*dense_body(label, active, g),
-                        jnp.zeros((2,), jnp.int32))
+                        jnp.zeros((2,), jnp.int32), None)
 
             # Reference heuristic: frontier > nv/16 -> dense/pull mode
             # (sssp_gpu.cu:414), and the queue must fit; a frontier
@@ -852,18 +921,20 @@ class PushEngine(AuditableEngine):
                 with jax.named_scope("lux_sparse"):
                     return self._sparse_parts(label, active, need, g,
                                               gather_fn, pmin_fn,
-                                              pmax_fn, pull, unreached)
+                                              pmax_fn, psum_fn, pull,
+                                              unreached)
 
             def dense_branch():
                 with jax.named_scope("lux_dense"):
-                    return (*dense_body(label, active, g), jnp.int32(0))
+                    return (*dense_body(label, active, g), jnp.int32(0),
+                            (jnp.uint32(0),) * 4)
 
-            nl, na, low = jax.lax.cond(sparse, sparse_branch,
-                                       dense_branch)
+            nl, na, low, fill = jax.lax.cond(sparse, sparse_branch,
+                                             dense_branch)
             took = [sparse.astype(jnp.int32), low]
             if pull is not None:
                 took.append(pull.astype(jnp.int32))
-            return nl, na, jnp.stack(took)
+            return nl, na, jnp.stack(took), fill
 
         use_delta = converge and self.delta is not None
 
@@ -906,7 +977,8 @@ class PushEngine(AuditableEngine):
 
             if not converge:
                 cnt0 = global_sum(active)
-                new_label, new_active, _ = body(label, active, cnt0, g)
+                new_label, new_active, _, _ = body(label, active, cnt0,
+                                                   g)
                 return new_label, new_active, global_sum(new_active)
 
             if use_delta:
@@ -931,10 +1003,9 @@ class PushEngine(AuditableEngine):
                 # active, raising B eventually makes the frontier
                 # non-empty.
                 # carry: (it, lbl, act, B, cnt, [4 stats buffers],
-                # [health word, stall], took) — the counters
-                # (sparse_iters, low_rung_iters[, pull_iters]: body's
-                # int32 [n_took]) ride LAST so every index before it
-                # stands
+                # [health word, stall], counters) — the counters
+                # ((took, fill): tally) ride LAST so every index
+                # before it stands
                 def cond(c):
                     it, lbl, act, B, cnt = c[:5]
                     ok = (cnt > 0) & (it < max_iters)
@@ -965,7 +1036,7 @@ class PushEngine(AuditableEngine):
                                                    mode="drop"),
                                    fedp.at[it].set(ep, mode="drop")) \
                                 + buf[4:]
-                        nl, na, took = body(lbl, front, nf, g)
+                        nl, na, took, fill = body(lbl, front, nf, g)
                         merged = (act & ~front) | na
                         if health:
                             # the watchdog watches relax steps only:
@@ -976,7 +1047,7 @@ class PushEngine(AuditableEngine):
                                 global_sum(merged))
                             buf = buf[:4] + (h, stall) + buf[6:]
                         return (it + 1, nl, merged, B, *buf[:-1],
-                                buf[-1] + took)
+                                tally(buf[-1], took, fill))
 
                     def advance(it, lbl, act, B, *buf):
                         # Strict progress: with float labels a delta
@@ -1010,15 +1081,13 @@ class PushEngine(AuditableEngine):
                         jnp.zeros((cap_n, sg.num_parts), jnp.uint32))
                 if health:
                     init = init + (h0, stall0)
-                out = jax.lax.while_loop(
-                    cond, wbody,
-                    init + (jnp.zeros((n_took,), jnp.int32),))
-                # (lbl, act, it, [stats], [health], sparse_iters,
-                # low_rung_iters[, pull_iters])
-                return (out[1], out[2], out[0], *out[5:-1], *out[-1])
+                out = jax.lax.while_loop(cond, wbody, init + (tally0(),))
+                # (lbl, act, it, [stats], [health], *counts_out)
+                return (out[1], out[2], out[0], *out[5:-1],
+                        *counts_out(out[-1]))
 
             # carry: (it, lbl, act, cnt, [4 stats buffers], [health
-            # word, stall], took) — the counters ride LAST
+            # word, stall], counters) — the counters ride LAST
             def cond(c):
                 it, lbl, act, cnt = c[:4]
                 ok = (cnt > 0) & (it < max_iters)
@@ -1036,9 +1105,9 @@ class PushEngine(AuditableEngine):
                     ep = esum_parts(act)
                     fed = fed.at[it].set(jnp.sum(ep), mode="drop")
                     fedp = fedp.at[it].set(ep, mode="drop")
-                nl, na, took = body(lbl, act, cnt, g)
+                nl, na, took, fill = body(lbl, act, cnt, g)
                 ncnt = global_sum(na)
-                ns = c[-1] + took
+                ns = tally(c[-1], took, fill)
                 if stats:
                     # frontier AFTER the iteration — exactly the
                     # series the stepwise -verbose path printed
@@ -1065,11 +1134,10 @@ class PushEngine(AuditableEngine):
                     jnp.zeros((cap_n, sg.num_parts), jnp.uint32))
             if health:
                 init = init + (h0, stall0)
-            out = jax.lax.while_loop(
-                cond, wbody, init + (jnp.zeros((n_took,), jnp.int32),))
-            # (lbl, act, it, [stats], [health], sparse_iters,
-            # low_rung_iters[, pull_iters])
-            return (out[1], out[2], out[0], *out[4:-1], *out[-1])
+            out = jax.lax.while_loop(cond, wbody, init + (tally0(),))
+            # (lbl, act, it, [stats], [health], *counts_out)
+            return (out[1], out[2], out[0], *out[4:-1],
+                    *counts_out(out[-1]))
 
         if prog.name:
             inner = jax.named_scope(f"lux_{prog.name}")(inner)
@@ -1088,7 +1156,7 @@ class PushEngine(AuditableEngine):
             if converge:
                 # the counters sum predicates of the psum'd count,
                 # the pmax'd out-edge total and _choose's scalars
-                out_specs = out_specs + (P(),) * n_took
+                out_specs = out_specs + (P(),) * n_counts
             in_specs = (P(PARTS_AXIS), P(PARTS_AXIS), P())
             if health:
                 in_specs = in_specs + (P(), P())    # h0, stall0
@@ -1125,11 +1193,20 @@ class PushEngine(AuditableEngine):
 
         self._register_variant(vname, jitted, _args_thunk)
 
-        def mark(it, took):
-            # pull_iters is 0 where the step is not built
-            telemetry.mark("push.converge", iters=it,
-                           **dict(zip(("sparse_iters", "low_rung_iters",
-                                       "pull_iters"), (*took, 0))))
+        def mark(it, counts):
+            # pull_iters is 0 where the step is not built; the four
+            # fill counts are 0 on an engine without a ladder, else
+            # each ONE number folded from the carry's two words when
+            # the ring is read (fr.Folded: no fetch here)
+            took, fill = counts[:n_took], counts[n_took:]
+            names = ("queue_items", "queue_slots", "budget_edges",
+                     "budget_slots")
+            telemetry.mark(
+                "push.converge", iters=it,
+                **dict(zip(("sparse_iters", "low_rung_iters",
+                            "pull_iters"), (*took, 0))),
+                **{n: fr.Folded(fill[0], i) if fill else 0
+                   for i, n in enumerate(names)})
 
         if health:
             from lux_tpu import health as _hw
@@ -1139,10 +1216,10 @@ class PushEngine(AuditableEngine):
                 if watch is None:
                     watch = (_hw.init_word(), jnp.int32(0))
                 (l, a, it, fsz, fed, fszp, fedp, h, stall,
-                 *took) = jitted(
+                 *counts) = jitted(
                     label, active, jnp.int32(max_iters), *watch,
                     *extra, *graph_args)
-                mark(it, took)
+                mark(it, counts)
                 return l, a, it, fsz, fed, fszp, fedp, (h, stall)
 
             return call
@@ -1154,13 +1231,17 @@ class PushEngine(AuditableEngine):
             iterations whose edge budget was a lower rung) /
             ``pull_iters`` (those that ran bottom-up) are the
             un-fetched device scalars (fetched at
-            ``telemetry.spans()``, never here)."""
+            ``telemetry.spans()``, never here), and so are
+            ``queue_items`` / ``queue_slots`` / ``budget_edges`` /
+            ``budget_slots``: over the call's sparse iterations the
+            vertices compacted and the edges expanded, summed over
+            the parts, beside the rungs they ran on x parts."""
             out = jitted(label, active, jnp.int32(max_iters), *extra,
                          *graph_args)
             if not converge:
                 return out
-            out, took = out[:-n_took], out[-n_took:]
-            mark(out[2], took)
+            out, counts = out[:-n_counts], out[-n_counts:]
+            mark(out[2], counts)
             return tuple(out)
 
         return call

@@ -357,10 +357,11 @@ def test_audit_never_alters_push_outputs():
 def test_audited_converge_programs_carry_the_sparse_iters_counter(
         np_parts, mesh_n):
     """PR 24 / PR 29: every converge variant the auditor walks (plain,
-    stats, health) returns TWO more outputs than its public signature,
-    replicated int32 scalars LAST (the ``sparse_iters`` and
-    ``low_rung_iters`` carry); the single-step program does not.  The
-    audit stays clean with them."""
+    stats, health) returns TWO more int32 scalars than its public
+    signature (the ``sparse_iters`` and ``low_rung_iters`` carry) and,
+    since PR 39, the ladder's fill words uint32 [4, 2] LAST, all
+    replicated; the single-step program does not.  The audit stays
+    clean with them."""
     import jax
 
     from lux_tpu.apps import sssp
@@ -373,12 +374,14 @@ def test_audited_converge_programs_carry_the_sparse_iters_counter(
         outs[name] = jax.eval_shape(jitted, *thunk())
     assert len(outs["step"]) == 3
     assert {n: len(o) for n, o in outs.items() if n != "step"} == {
-        "converge": 3 + 2, "converge_stats": 7 + 2,
-        "converge_health": 9 + 2}
+        "converge": 3 + 3, "converge_stats": 7 + 3,
+        "converge_health": 9 + 3}
     for name, out in outs.items():
         if name != "step":
-            for counter in out[-2:]:
+            for counter in out[-3:-1]:
                 assert counter.shape == () and counter.dtype == np.int32
+            assert out[-1].shape == (4, 2)
+            assert out[-1].dtype == np.uint32
     assert audit.audit_engine(eng, mode="error") == []
 
 

@@ -566,13 +566,14 @@ def test_pull_step_sweep_equals_the_frontier_bfs(k):
 
 def test_pull_step_is_not_built_on_a_directed_graph():
     """(b) a directed graph keeps the program it had: no step, two
-    counters behind the public outputs, no ``lux_pull`` in the lowered
-    text, ``pull_iters`` 0 on the mark."""
+    counters (and, since PR 39, the ladder's fill words) behind the
+    public outputs, no ``lux_pull`` in the lowered text,
+    ``pull_iters`` 0 on the mark."""
     src, dst, nv = rmat_edges(10, 8, 0)
     eng = sssp.build_engine(Graph.from_edges(src, dst, nv), 0)
     assert not eng.pull and eng._sparse_mode()[2] is False
     jitted, args = eng.audit_variant("converge")
-    assert len(jax.eval_shape(jitted, *args())) == 3 + 2
+    assert len(jax.eval_shape(jitted, *args())) == 3 + 2 + 1
     assert "lux_pull" not in jitted.lower(*args()).as_text(
         debug_info=True)
     _labels, it = eng.run()
@@ -580,7 +581,7 @@ def test_pull_step_is_not_built_on_a_directed_graph():
     assert mark["pull_iters"] == 0 and mark["iters"] == it
     _g, on, _off = _kron_engines()
     jitted, args = on.audit_variant("converge")
-    assert len(jax.eval_shape(jitted, *args())) == 3 + 3
+    assert len(jax.eval_shape(jitted, *args())) == 3 + 3 + 1
     assert "lux_pull" in jitted.lower(*args()).as_text(debug_info=True)
 
 

@@ -1175,7 +1175,13 @@ def test_sparse_iters_counts_the_branch_the_loop_took(variant):
     assert len(marks) == 1
     counts = marks[0]["counts"]
     assert set(counts) == {"iters", "sparse_iters", "low_rung_iters",
-                           "pull_iters"}
+                           "pull_iters", "queue_items", "queue_slots",
+                           "budget_edges", "budget_slots"}
+    # the four fill counts settle to plain ints (fr.Folded)
+    assert all(type(counts[k]) is int for k in (
+        "queue_items", "queue_slots", "budget_edges", "budget_slots"))
+    assert 0 < counts["queue_items"] <= counts["queue_slots"]
+    assert 0 < counts["budget_edges"] <= counts["budget_slots"]
     assert (counts["iters"], counts["sparse_iters"]) == (it, want)
     # which sparse iterations ran below the top edge budget has its
     # oracle in tests/test_push.py (the ladder)

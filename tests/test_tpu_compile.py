@@ -331,6 +331,52 @@ def test_push_step_compiles_with_pallas(v5e, symmetric):
     _assert_combine_kernel(eng.delivery.tiles, text)
 
 
+def test_components_ladder_without_the_bottom_up_step_compiles(v5e):
+    """``cc.indochina``'s program at the cell's SHAPE (the web-crawl
+    generator's directed graph at the configuration's rehearsal size,
+    degree-relabelled, pair rows on, sparse view on; the cell's full
+    size takes minutes of host preparation and is compiled by hand
+    before a chip run): the whole ``converge`` loop of the unbatched
+    max-label engine, ``reduce_method='pallas'``, compiled for v5e.  A
+    directed graph builds no bottom-up step, so the ladder is the
+    one-way one, and the carry's fill words come out as uint32
+    [4, 2]."""
+    import json
+    import os
+
+    from benchmarks.reference import webgraph
+    from lux_tpu.apps import components
+    from lux_tpu.engine.push import PushEngine
+    from lux_tpu.graph import Graph, ShardedGraph, pair_relabel
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmarks", "configs",
+                           "indochina-components.json")) as f:
+        c = json.load(f)
+    nv, arcs = c["rehearsal"]["vertices"], c["rehearsal"]["arcs"]
+    src, dst = webgraph.web_arcs(
+        nv, arcs, c["graph_seed"],
+        **{k: c[k] for k in webgraph.PARAMETERS})
+    pair = c["engine"]["pair_threshold"]
+    g, _perm, starts = pair_relabel(Graph.from_edges(src, dst, nv), 1,
+                                    pair_threshold=pair)
+    sg = ShardedGraph.build(g, 1, starts=starts, pair_threshold=pair)
+    eng = PushEngine(sg, components.make_program(),
+                     reduce_method="pallas", pair_threshold=pair,
+                     pair_min_fill=c["engine"]["pair_min_fill"],
+                     enable_sparse=c["engine"]["enable_sparse"])
+    assert not eng.pull and len(eng.queue_rungs) == 2 \
+        and len(eng.budget_rungs) == 2
+    jitted, args = eng.audit_variant("converge")
+    compiled = jitted.lower(*_on(v5e, args())).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    assert "lux_q0" in text and "lux_eb1" in text
+    assert "lux_pull" not in text
+    fill = jax.tree.leaves(jax.eval_shape(jitted, *args()))[-1]
+    assert (fill.shape, fill.dtype) == ((4, 2), jnp.uint32)
+    assert compiled.memory_analysis().generated_code_size_in_bytes > 0
+
+
 @pytest.mark.parametrize("chips", [1, 4])
 def test_serving_column_programs_compile_at_cell_size(topo, v5e, chips):
     """The push serving boundary's two device programs (serve.py
