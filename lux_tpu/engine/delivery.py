@@ -198,10 +198,11 @@ def common_graph_arrays(sg: ShardedGraph, dev) -> dict:
 
 
 def build_graph_arrays(sg: ShardedGraph, layout: str, needs_dst: bool,
-                       tile_e: int, dev):
+                       tile_e: int, dev, aligned: bool = False):
     """Per-part graph arrays (all leading dim num_parts) of the flat or
     the tiled edge layout, each through ``dev``; returns (arrays dict,
-    TiledLayout|None)."""
+    TiledLayout|None).  aligned: the tiled layout's lane-aligned
+    placement (ops/tiled.py), which adds ``tile_rank``."""
     common = common_graph_arrays(sg, dev)
     if layout == "flat":
         arrays = dict(src_slot=dev(sg.src_slot),
@@ -214,11 +215,14 @@ def build_graph_arrays(sg: ShardedGraph, layout: str, needs_dst: bool,
     lay = TiledLayout.build(
         sg.row_ptr_local, sg.dst_local, sg.vpad, E=tile_e,
         sizing_row_ptr=(None if sg.local_parts is None
-                        else sg.sizing_row_ptr()))
+                        else sg.sizing_row_ptr()),
+        aligned=aligned)
     arrays = dict(src_slot=dev(lay.chunk(sg.src_slot)),
                   rel_dst=dev(lay.rel_dst),
                   chunk_start=dev(lay.chunk_start),
                   last_chunk=dev(lay.last_chunk), **common)
+    if aligned:
+        arrays["tile_rank"] = dev(lay.tile_rank)
     if sg.weighted:
         arrays["weight"] = dev(lay.chunk(sg.edge_weight))
     if needs_dst:
@@ -231,7 +235,7 @@ class Delivery:
     (``pairs``, ``page_plan``, ``owner``, ``tiles``), the resolved
     options (``exchange``, ``gather``, ``use_mxu``, ``reduce_method``,
     ``pair_stream``, ``pair_dot_stream``, ``stream_chunks``,
-    ``owner_minmax_fused``) and the per-part reductions over its
+    ``owner_minmax_fused``, ``aligned``) and the per-part reductions over its
     arrays.  Read-only after ``build``; the arrays themselves belong
     to the engine (``keys`` names the ones built here).
 
@@ -257,7 +261,8 @@ class Delivery:
         only (``reduce``, ``needs_dst``, ``edge_value_from_dot``,
         ``batch``, ``state_bytes`` / ``identity``).  Leaves the spans
         ``build.pair_plan`` (ops/pairs.plan_sharded_pairs) and
-        ``build.dense_layout``; on a single device each array is
+        ``build.dense_layout`` (a tiled layout's ``TiledLayout.counts``);
+        on a single device each array is
         dispatched to the device as it is built, on a mesh the arrays
         stay host numpy for the engine's ``shard_over_parts``."""
         self = cls()
@@ -366,10 +371,22 @@ class Delivery:
                               if stream_msgs is None
                               else bool(stream_msgs))
 
+        # query-batched programs (no pair rows: the chunks hold EVERY
+        # edge, and every iteration is dense) get the tiled layout's
+        # lane-aligned placement (ops/tiled.py): the per-chunk reduce
+        # of their [.., B] messages is then a fold over depth on all
+        # but the hub tiles.  A program that reads its destination
+        # through chunk_tile x W + rel_dst (dst_values) keeps the
+        # vertex-ordered tiles, and so do chunks that hold no whole
+        # depth row
+        self.aligned = (batch is not None and tile_e % 128 == 0
+                        and not (self.needs_dst or self.dot))
         dev = jnp.asarray if mesh is None else np.asarray
-        with telemetry.span("build.dense_layout"):
+        with telemetry.span("build.dense_layout") as sp:
             arrays = self._dense_layout(dev, layout, tile_e,
                                         owner_tile_e)
+            if self.tiles is not None:
+                sp.count(**self.tiles.counts())
         if self.pairs is not None:
             arrays["pair_rowbind"] = dev(self.pairs.rowbind)
             arrays["pair_rel"] = dev(self.pairs.rel_dst)
@@ -431,7 +448,8 @@ class Delivery:
                 arrays["own_et"] = dev(et)
             return arrays
         arrays, self.tiles = build_graph_arrays(
-            sg, layout, self.needs_dst or self.dot, tile_e, dev)
+            sg, layout, self.needs_dst or self.dot, tile_e, dev,
+            aligned=self.aligned)
         return arrays
 
     # -- the two-step form: messages, then reduce -----------------------
@@ -474,6 +492,7 @@ class Delivery:
             red = tiled_segment_reduce(
                 msgs, lay, g["chunk_start"], g["last_chunk"],
                 g["rel_dst"], vpad, self.kind, use_mxu=self.use_mxu,
+                tile_rank=g.get("tile_rank"),
                 **method_args(self.reduce_method))
         return self._with_pairs(red, flat_table, msg, g)
 
@@ -518,6 +537,7 @@ class Delivery:
         red = combine_partials(partials, lay, g["chunk_start"],
                                g["last_chunk"], vpad, self.kind,
                                use_mxu=self.use_mxu,
+                               tile_rank=g.get("tile_rank"),
                                **method_args(self.reduce_method))
         return self._with_pairs(red, flat_table, msg, g)
 

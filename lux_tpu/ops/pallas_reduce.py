@@ -69,9 +69,15 @@ def chunk_partials_pallas(vals, rel_dst, W: int, kind: str,
     """Per-chunk partial reduction [C, E] -> [C, W] on the TPU.
 
     C must be a multiple of block_c (TiledLayout pads to this).
-    Scalar payloads only — vector payloads (colfilter) use the XLA
-    path, whose [C, E, W, K] broadcast XLA handles acceptably once the
-    gather is materialized.
+    Scalar payloads only.  Vector payloads take the XLA path
+    (ops/tiled.chunk_partials), and pay for it: at the serving cells'
+    [36864, 512, 16] messages its [C, E, K, W] compare-reduce and the
+    relayout copy that feeds it (16 lanes padded to 128, 9.66 GB
+    written) were 98 of a dense iteration's 224.6 ms (PERF.md section
+    5, PR 38).  Query-batched programs therefore run on lane-aligned
+    chunks, which need no compare (ops/tiled.aligned_partials, PR 40),
+    and only their hub tiles' chunks still take that path; colfilter's
+    K-vectors go through the dot path's matmuls instead.
     """
     C, E = vals.shape
     if C % block_c:

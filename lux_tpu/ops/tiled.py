@@ -33,11 +33,24 @@ Degree skew (the Twitter/RMAT power-law "hard part", SURVEY.md §7) is
 absorbed by construction: a hub vertex simply owns many chunks, and
 every chunk is the same shape — the TPU analogue of the reference's
 edge-parallel load balancing.
+
+Lane-aligned placement (``TiledLayout.build(aligned=True)``; the
+delivery asks for it where the program is query-batched): the
+destinations are ranked by in-degree and tile ``t`` holds ranks
+``128 t .. 128 t + 127``, so a tile's destinations have almost the same
+depth and slot ``e`` of a chunk can be given to lane ``e mod 128``
+alone (a SELL-128-sigma sparse format).  Such a chunk needs no lane
+compare: its partial is a fold over its ``E / 128`` depth rows
+(``aligned_partials``, scope ``lux_aligned``).  The few tiles whose
+depths differ too much (the hubs) keep the one-hot chunks above; the
+result leaves in rank order and one row gather (``tile_rank``) puts it
+back in vertex order.
 """
 
 from __future__ import annotations
 
 import dataclasses
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -67,6 +80,33 @@ def warn_sub128_tile(E: int) -> None:
             stacklevel=3)
 
 
+# A tile of the degree-ranked order is lane-ALIGNED where its edges
+# fill at least this share of its 128 x (largest in-degree) aligned
+# slots, else it keeps one-hot chunks.  From the unit costs of a dense
+# batched iteration on the chip (ksssp.kron20.closed; PERF.md section
+# 6, PR 40): a slot costs the row gather's 6.38 ns either way
+# (lux_relax, whatever order the slots are in), an aligned slot 0.13
+# ns more (the fold over depth) and a one-hot slot 5.2 ns more (the
+# compare-reduce and the relayout copy that feeds it), so the aligned
+# placement of a tile with n edges and depth D is the cheaper one
+# while 128 D x 6.51 <= n x 11.58: n / (128 D) >= 0.56.  The ledger's
+# figures before the first run gave 0.55 and the layout is the same
+# from 0.3 to 0.55 on that graph, so 0.55 stands.  Read at call time,
+# so tests can move it.
+ALIGNED_MIN_FILL = 0.55
+
+
+class _RankedPart(NamedTuple):
+    """One part's destinations by in-degree rank (stable, descending),
+    padded to whole tiles, and the chunks each tile takes."""
+    order: np.ndarray       # int64 [vpad] the vertex of each rank
+    deg: np.ndarray         # int64 [n_tiles * W] in-degree by rank
+    lo: np.ndarray          # int64 [n_tiles * W] first in-edge by rank
+    total: np.ndarray       # int64 [n_tiles] edges of each tile
+    n_aligned: np.ndarray   # int64 [n_tiles] aligned chunks (0 = none)
+    n_onehot: np.ndarray    # int64 [n_tiles] one-hot chunks (0 = none)
+
+
 @dataclasses.dataclass
 class TiledLayout:
     """Host-side chunk plan for one partitioned graph (stacked over
@@ -88,24 +128,42 @@ class TiledLayout:
     chunk_start: np.ndarray     # bool  [P, C] True at each tile's 1st chunk
     last_chunk: np.ndarray      # int32 [P, n_tiles] index of tile's last
                                 #   chunk, -1 for edge-less tiles
+    # the lane-aligned placement (build(aligned=True)); today's layout
+    # has none of it
+    n_aligned: int = 0          # C_a: chunks [0, C_a) are lane-aligned
+                                #   (slot e = lane e mod W), the rest
+                                #   one-hot; max over parts
+    tile_vertex: np.ndarray | None = None   # int32 [P, vpad] the vertex
+                                #   of each rank (stable, by in-degree,
+                                #   descending); None = vertex order
+    tile_rank: np.ndarray | None = None     # int32 [P, vpad] its
+                                #   inverse: the row of the tile-order
+                                #   result that is vertex v's
 
     @classmethod
     def build(cls, row_ptr_local: np.ndarray, dst_local: np.ndarray,
               vpad: int, W: int = 128, E: int = 512,
-              sizing_row_ptr: np.ndarray | None = None) -> "TiledLayout":
+              sizing_row_ptr: np.ndarray | None = None,
+              aligned: bool = False) -> "TiledLayout":
         """row_ptr_local: int [P, vpad+1] END offsets; dst_local:
         int32 [P, epad] part-local sorted destinations (pad -> vpad).
 
         sizing_row_ptr: row_ptr_local rows of ALL parts, when
         ``row_ptr_local`` holds only a process's local parts — chunk
         count and scan-necessity are program SHAPE/structure and must
-        be identical on every process of a multi-host run."""
+        be identical on every process of a multi-host run.
+
+        aligned: the lane-aligned placement over the degree-ranked
+        destinations (module docstring; ``_build_aligned``)."""
         if W > 128:
             raise ValueError(
                 f"tile width W={W} > 128: rel_dst is int8 (valid lane "
                 f"offsets 0..127, -1 = pad) and wider tiles would wrap "
                 f"offsets >= 128 negative, silently dropping edges")
         warn_sub128_tile(E)
+        if aligned:
+            return cls._build_aligned(row_ptr_local, vpad, W, E,
+                                      sizing_row_ptr)
         P = row_ptr_local.shape[0]
         n_tiles = max(1, _ceil_div(vpad, W))
 
@@ -141,9 +199,7 @@ class TiledLayout:
             if nc == 0:
                 continue
             # chunk -> owning tile, and chunk's index within that tile
-            ct = np.repeat(np.arange(n_tiles, dtype=np.int64), n_ch)
-            tile_first = np.concatenate(([0], np.cumsum(n_ch)[:-1]))
-            cj = np.arange(nc, dtype=np.int64) - tile_first[ct]
+            ct, cj = _tile_chunks(n_ch)
             start = tile_lo[ct] + cj * E
             idx = start[:, None] + lanes[None, :]          # [nc, E]
             valid = idx < tile_hi[ct][:, None]
@@ -160,15 +216,145 @@ class TiledLayout:
                    rel_dst=rel_dst, chunk_tile=chunk_tile,
                    chunk_start=chunk_start, last_chunk=last_chunk)
 
+    @classmethod
+    def _build_aligned(cls, row_ptr_local, vpad: int, W: int, E: int,
+                       sizing_row_ptr) -> "TiledLayout":
+        """The lane-aligned placement.  Per part the destinations are
+        ranked by in-degree (stable, descending); tile ``t`` is ranks
+        ``W t .. W t + W - 1``.  In an ALIGNED tile (ALIGNED_MIN_FILL)
+        slot ``e`` of the tile's chunk ``j`` holds in-edge number
+        ``(E / W) j + e div W`` of the destination in lane ``e mod W``
+        (a destination's in-edges keep their source order down the
+        depth axis), or a pad; a ONE-HOT tile lays its edges out in
+        lane order, E at a time, as the default layout does.  Aligned
+        chunks come first on the chunk axis, one-hot chunks after;
+        both counts are padded to the kernels' block of 8."""
+        if E % W:
+            raise ValueError(
+                f"the lane-aligned placement needs whole depth rows a "
+                f"chunk: E={E} is no multiple of W={W}")
+        R = E // W                          # depth rows a chunk
+        P = row_ptr_local.shape[0]
+        n_tiles = max(1, _ceil_div(vpad, W))
+        min_fill = ALIGNED_MIN_FILL
+
+        def plan(rp_row):
+            rp = rp_row.astype(np.int64)
+            deg = np.diff(rp)
+            order = np.argsort(-deg, kind="stable")
+            # by rank, padded to whole tiles: in-degree, first edge
+            deg_r = np.zeros(n_tiles * W, np.int64)
+            deg_r[:vpad] = deg[order]
+            lo_r = np.zeros(n_tiles * W, np.int64)
+            lo_r[:vpad] = rp[:-1][order]
+            tiles = deg_r.reshape(n_tiles, W)
+            total, depth = tiles.sum(axis=1), tiles[:, 0]
+            is_al = (depth > 0) & (total >= min_fill * W * depth)
+            return _RankedPart(
+                order, deg_r, lo_r, total,
+                n_aligned=np.where(is_al, _ceil_div_arr(depth, R), 0),
+                n_onehot=np.where(is_al, 0, _ceil_div_arr(total, E)))
+
+        plans = [plan(row_ptr_local[p]) for p in range(P)]
+        sizing = (plans if sizing_row_ptr is None else
+                  [plan(r) for r in sizing_row_ptr])
+        Ca = _ceil_div(max(int(x.n_aligned.sum()) for x in sizing), 8) * 8
+        Ch = _ceil_div(max(int(x.n_onehot.sum()) for x in sizing), 8) * 8
+        if Ca + Ch == 0:
+            Ch = 8
+        C = Ca + Ch
+        needs_scan = any(max(x.n_aligned.max(initial=0),
+                             x.n_onehot.max(initial=0)) > 1
+                         for x in sizing)
+
+        edge_gather = np.zeros((P, C, E), dtype=np.int64)
+        rel_dst = np.full((P, C, E), -1, dtype=np.int8)
+        chunk_tile = np.full((P, C), n_tiles, dtype=np.int32)
+        chunk_start = np.ones((P, C), dtype=bool)   # pad chunks isolated
+        last_chunk = np.full((P, n_tiles), -1, dtype=np.int32)
+        tile_vertex = np.zeros((P, vpad), dtype=np.int32)
+        tile_rank = np.zeros((P, vpad), dtype=np.int32)
+
+        slots = np.arange(E, dtype=np.int64)
+        rows = np.arange(R, dtype=np.int64)
+        lanes = np.arange(W, dtype=np.int8)
+        for p in range(P):
+            order, deg_r, lo_r, total, n_a, n_h = plans[p]
+            tile_vertex[p] = order
+            tile_rank[p, order] = np.arange(vpad, dtype=np.int32)
+            na = int(n_a.sum())
+            if na:
+                ct, cj = _tile_chunks(n_a)
+                # [na, R, W]: a chunk's depth rows over its tile's lanes
+                depth = (cj * R)[:, None, None] + rows[None, :, None]
+                valid = depth < deg_r.reshape(n_tiles, 1, W)[ct]
+                edge_gather[p, :na] = np.where(
+                    valid, lo_r.reshape(n_tiles, 1, W)[ct] + depth,
+                    0).reshape(na, E)
+                rel_dst[p, :na] = np.where(valid, lanes, -1).reshape(na, E)
+                chunk_tile[p, :na] = ct
+                chunk_start[p, :na] = cj == 0
+            nh = int(n_h.sum())
+            if nh:
+                # the one-hot tiles' edges in lane order, end to end
+                hub = np.nonzero(n_h)[0]
+                ranks = (hub[:, None] * W +
+                         np.arange(W, dtype=np.int64)).ravel()
+                cnt = deg_r[ranks]
+                off = np.cumsum(cnt) - cnt
+                edge_of = (np.repeat(lo_r[ranks] - off, cnt) +
+                           np.arange(int(cnt.sum()), dtype=np.int64))
+                lane_of = np.repeat(ranks % W, cnt).astype(np.int8)
+                ct, cj = _tile_chunks(n_h[hub])
+                tile_lo = off[::W]
+                pos = (tile_lo[ct] + cj * E)[:, None] + slots[None, :]
+                valid = pos < (tile_lo + total[hub])[ct][:, None]
+                pos = np.where(valid, pos, 0)
+                edge_gather[p, Ca:Ca + nh] = np.where(
+                    valid, edge_of[pos], 0)
+                rel_dst[p, Ca:Ca + nh] = np.where(
+                    valid, lane_of[pos], -1)
+                chunk_tile[p, Ca:Ca + nh] = hub[ct]
+                chunk_start[p, Ca:Ca + nh] = cj == 0
+            last_chunk[p] = np.where(
+                n_a > 0, np.cumsum(n_a) - 1,
+                np.where(n_h > 0, Ca + np.cumsum(n_h) - 1, -1))
+
+        return cls(W=W, E=E, n_tiles=n_tiles, n_chunks=C,
+                   needs_scan=needs_scan, edge_gather=edge_gather,
+                   rel_dst=rel_dst, chunk_tile=chunk_tile,
+                   chunk_start=chunk_start, last_chunk=last_chunk,
+                   n_aligned=Ca, tile_vertex=tile_vertex,
+                   tile_rank=tile_rank)
+
     def chunk(self, flat: np.ndarray) -> np.ndarray:
         """Re-lay a per-part flat edge array [P, epad, ...] into chunk
         form [P, C, E, ...] (host, done once at build time)."""
         parts = np.arange(flat.shape[0])[:, None, None]
         return flat[parts, self.edge_gather]
 
+    def counts(self) -> dict:
+        """Edges and slots of the chunk arrays (the materialized
+        parts'), whole layout and aligned chunks alone: the counts of
+        the ``build.dense_layout`` span."""
+        live = self.rel_dst >= 0
+        P, C, E = self.rel_dst.shape
+        return dict(tiled_edges=int(live.sum()),
+                    aligned_edges=int(live[:, :self.n_aligned].sum()),
+                    tiled_slots=P * C * E,
+                    aligned_slots=P * self.n_aligned * E)
+
 
 def _ceil_div_arr(a, b):
     return (a + b - 1) // b
+
+
+def _tile_chunks(n_ch):
+    """For tiles owning ``n_ch`` chunks each, laid end to end: every
+    chunk's tile and its index within that tile (int64 [sum n_ch])."""
+    ct = np.repeat(np.arange(len(n_ch), dtype=np.int64), n_ch)
+    first = np.cumsum(n_ch) - n_ch
+    return ct, np.arange(len(ct), dtype=np.int64) - first[ct]
 
 
 def combine_op(kind: str):
@@ -395,6 +581,38 @@ def chunk_partials(vals, rel_dst, W: int, kind: str, use_mxu: bool = False,
 def _reduce_axis(x, axis, kind):
     return {"sum": jnp.sum, "min": jnp.min, "max": jnp.max}[kind](
         x, axis=axis)
+
+
+def aligned_partials(vals, rel_dst, W: int, kind: str,
+                     lane_minor: bool = False):
+    """Per-chunk reduction [C, E, ...] -> [C, W, ...] of LANE-ALIGNED
+    chunks (TiledLayout.build(aligned=True)): slot ``e`` holds an edge
+    of lane ``e mod W`` or a pad (rel -1), so the partial is the
+    masked fold over the chunk's E / W depth rows — one combine an
+    element, no lane compare and nothing broadcast over W.
+    lane_minor as in ``chunk_partials``.
+
+    The fold is written over the depth rows' SLICES of the slot axis,
+    not as a reshape to [C, E / W, W] and a reduce: the chip keeps the
+    messages with the slots minor, in (8, 128) tiles, where a slice at
+    a multiple of 128 is a whole tile and the fold an elementwise
+    combine of tiles, while the reshape is a relayout of the whole
+    array that the reduce then does not fuse with (compiled for v5e
+    at the kron20 shape, PR 40: two more passes over 1.08 GB)."""
+    with jax.named_scope("lux_aligned"):
+        ident = identity_for(kind, vals.dtype)
+        pad = (rel_dst < 0).reshape(
+            rel_dst.shape + (1,) * (vals.ndim - 2))
+        comb = combine_op(kind)
+
+        def row(lo):    # masked per row: each select has one consumer
+            return jnp.where(pad[:, lo:lo + W], ident,
+                             vals[:, lo:lo + W])
+
+        red = row(0)
+        for lo in range(W, vals.shape[1], W):
+            red = comb(red, row(lo))
+        return jnp.moveaxis(red, -1, 1) if lane_minor else red
 
 
 # The ``xla`` reduce method only (``pallas`` combines in one pass with
@@ -624,17 +842,19 @@ def unpack_src_rel(packed, n_valid):
 
 def _block_partials(flat_state, src_b, rel_b, w_b, msg_fn, kind: str,
                     E: int, W: int, reduce_method: str,
-                    use_mxu: bool, nv_b=None):
+                    use_mxu: bool, nv_b=None, aligned: bool = False):
     """One chunk block's gather + message + per-chunk partials
     [B, E, ...] -> [B, W, ...] (shared by the streamed partial and
     FUSED streamed combine paths — keep the Pallas VMEM sizing and
     the barrier rationale in ONE place).  nv_b set => src_b is the
     packed owner encoding (see unpack_src_rel) and rel_b must be
-    None."""
+    None.  aligned: the block's chunks are lane-aligned."""
     if nv_b is not None:
         src_b, rel_b = unpack_src_rel(src_b, nv_b)
     vals = jnp.take(flat_state, src_b, axis=0)
     msgs = msg_fn(vals, w_b)
+    if aligned:
+        return aligned_partials(msgs, rel_b, W, kind)
     if reduce_method.startswith("pallas") and msgs.ndim == 2:
         from lux_tpu.ops.pallas_reduce import chunk_partials_pallas
         # the kernel's [bc, E, W] masked intermediate must fit
@@ -664,18 +884,43 @@ def streamed_chunk_partials(flat_state, src_slot, rel_dst, weight,
     single-chip runs (PERF_NOTES RMAT26 ledger).  msg_fn(vals [B, E,
     ...], weight [B, E]|None) -> messages; dead lanes are masked by
     rel == -1 (matching no output lane) downstream.  Shared by the pull engine's step and the
-    push engine's dense iterations."""
-    C, E, W = layout.n_chunks, layout.E, layout.W
+    push engine's dense iterations.  A layout's lane-aligned chunks
+    (``n_aligned`` of them, first on the chunk axis) are streamed
+    first, its one-hot chunks after them."""
+    C, Ca = layout.n_chunks, getattr(layout, "n_aligned", 0)
+
+    def stream(lo, hi, aligned):
+        def cut(x):     # the whole axis stays unsliced: a layout
+            # without aligned chunks lowers to the program it had
+            return x if x is None or (lo, hi) == (0, C) else x[lo:hi]
+
+        return _stream_blocks(
+            flat_state, cut(src_slot),
+            cut(rel_dst if nvalid is None else nvalid), cut(weight),
+            layout.E, layout.W, kind, msg_fn, reduce_method, use_mxu,
+            block_chunks, packed=nvalid is not None, aligned=aligned)
+
+    if not Ca:
+        return stream(0, C, False)
+    cuts = [(0, Ca, True)] + [(Ca, C, False)] * (Ca < C)
+    return jnp.concatenate([stream(*c) for c in cuts], axis=0)
+
+
+def _stream_blocks(flat_state, src_slot, second, weight, E: int, W: int,
+                   kind: str, msg_fn, reduce_method: str, use_mxu: bool,
+                   block_chunks: int, packed: bool, aligned: bool):
+    """streamed_chunk_partials over one run of like chunks; ``second``
+    is their rel_dst, or the live-lane counts where ``src_slot`` is
+    the packed owner encoding."""
+    C = src_slot.shape[0]
     B = max(8, min(block_chunks, C))
     nB, rem = divmod(C, B)
 
     def partial_block(src_b, rel_b, w_b, nv_b=None):
         return _block_partials(flat_state, src_b, rel_b, w_b, msg_fn,
                                kind, E, W, reduce_method, use_mxu,
-                               nv_b=nv_b)
+                               nv_b=nv_b, aligned=aligned)
 
-    packed = nvalid is not None
-    second = nvalid if packed else rel_dst   # rides the block split
     parts = []
     if nB:
         def seg(x):
@@ -867,21 +1112,28 @@ def streamed_chunk_combined(flat_state, src_slot, rel_dst, weight,
 def combine_partials(partials, layout: TiledLayout, chunk_start,
                      last_chunk, vpad: int, kind: str,
                      use_mxu: bool = False, method: str = "xla",
-                     interpret: bool = False, lane_minor: bool = False):
+                     interpret: bool = False, lane_minor: bool = False,
+                     tile_rank=None):
     """Per-chunk partials [C, W, ...] -> flat [vpad, ...] (the shared
     tail of tiled_segment_reduce, also used by the streamed engines
-    that produce partials block-wise)."""
+    that produce partials block-wise).  tile_rank: this part's row of
+    a lane-aligned layout's ``tile_rank``, whose tiles hold the
+    destinations in rank order: one row gather takes the result back
+    to vertex order."""
     tiles = combine_chunks(partials, layout, chunk_start, last_chunk,
                            kind, use_mxu=use_mxu, method=method,
                            interpret=interpret, lane_minor=lane_minor)
     flatshape = (layout.n_tiles * layout.W,) + tiles.shape[2:]
-    return tiles.reshape(flatshape)[:vpad]
+    flat = tiles.reshape(flatshape)
+    if tile_rank is None:
+        return flat[:vpad]
+    return jnp.take(flat, tile_rank, axis=0)
 
 
 def tiled_segment_reduce(vals, layout: TiledLayout, chunk_start,
                          last_chunk, rel_dst, vpad: int, kind: str,
                          use_mxu: bool = False, method: str = "xla",
-                         interpret: bool = False):
+                         interpret: bool = False, tile_rank=None):
     """Full scatter-free segment reduce for ONE part.
 
     vals [C, E, ...] chunked edge messages; returns [vpad, ...] —
@@ -891,17 +1143,34 @@ def tiled_segment_reduce(vals, layout: TiledLayout, chunk_start,
     payloads only, ops/pallas_reduce.py) and the chunk combine
     (ops/pallas_combine.py) as Pallas TPU kernels; 'xla' is the
     portable broadcast-compare formulation with the associative scan.
+
+    A lane-aligned layout's first ``n_aligned`` chunks take the fold
+    over depth (``aligned_partials``: no compare, so nothing for
+    ``use_mxu`` to contract), the rest the formulation above;
+    ``tile_rank`` is this part's row of the layout's (see
+    ``combine_partials``).
     """
     # the VPU reduce of a vector payload comes out [C, K, W]
     lane_minor = vals.ndim > 2 and not use_mxu
-    if method == "pallas" and vals.ndim == 2:
-        from lux_tpu.ops.pallas_reduce import chunk_partials_pallas
-        partials = chunk_partials_pallas(vals, rel_dst, layout.W, kind,
+
+    def onehot(vals, rel_dst):
+        if method == "pallas" and vals.ndim == 2:
+            from lux_tpu.ops.pallas_reduce import chunk_partials_pallas
+            return chunk_partials_pallas(vals, rel_dst, layout.W, kind,
                                          interpret=interpret)
+        return chunk_partials(vals, rel_dst, layout.W, kind,
+                              use_mxu=use_mxu, lane_minor=lane_minor)
+
+    Ca = layout.n_aligned
+    if Ca:
+        parts = [aligned_partials(vals[:Ca], rel_dst[:Ca], layout.W,
+                                  kind, lane_minor=lane_minor)]
+        if Ca < layout.n_chunks:
+            parts.append(onehot(vals[Ca:], rel_dst[Ca:]))
+        partials = jnp.concatenate(parts, axis=0)
     else:
-        partials = chunk_partials(vals, rel_dst, layout.W, kind,
-                                  use_mxu=use_mxu,
-                                  lane_minor=lane_minor)
+        partials = onehot(vals, rel_dst)
     return combine_partials(partials, layout, chunk_start, last_chunk,
                             vpad, kind, use_mxu=use_mxu, method=method,
-                            interpret=interpret, lane_minor=lane_minor)
+                            interpret=interpret, lane_minor=lane_minor,
+                            tile_rank=tile_rank)

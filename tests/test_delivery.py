@@ -123,6 +123,99 @@ def test_reduction_equals_plain_segment_reduce(engines, name, kind):
                                        rtol=1e-5, atol=1e-6)
 
 
+# -- (a2) the lane-aligned placement follows the program's batch --------
+
+def _tiled_arrays(sg, aligned):
+    from lux_tpu.ops.tiled import TiledLayout
+    lay = TiledLayout.build(sg.row_ptr_local, sg.dst_local, sg.vpad,
+                            aligned=aligned)
+    want = dict(src_slot=lay.chunk(sg.src_slot), rel_dst=lay.rel_dst,
+                chunk_start=lay.chunk_start, last_chunk=lay.last_chunk)
+    if aligned:
+        want["tile_rank"] = lay.tile_rank
+    return lay, want
+
+
+def _last_dense_layout():
+    from lux_tpu import telemetry
+    return [r for r in telemetry.spans()
+            if r["name"] == "build.dense_layout"][-1]["counts"]
+
+
+@pytest.mark.parametrize("family", ["pull", "push"])
+def test_unbatched_delivery_builds_the_default_layout(rmat, family):
+    """A program without a query batch gets TiledLayout.build's
+    default arrays, element for element, no ``tile_rank``, and marks
+    no aligned edge."""
+    sg = ShardedGraph.build(rmat, NUM_PARTS, vpad_align=128)
+    eng = _build(family, sg)
+    counts = _last_dense_layout()
+    lay, want = _tiled_arrays(sg, aligned=False)
+    assert not eng.delivery.aligned
+    assert eng.delivery.tiles.n_aligned == 0
+    assert "tile_rank" not in eng.delivery.keys
+    for k, v in want.items():
+        np.testing.assert_array_equal(np.asarray(eng.arrays[k]), v,
+                                      err_msg=k)
+    assert counts == dict(lay.counts(), aligned_edges=0, aligned_slots=0)
+    assert counts["tiled_edges"] == sg.ne
+
+
+@pytest.mark.parametrize("streamed", [False, True],
+                         ids=["two-step", "streamed"])
+@pytest.mark.parametrize("family", ["pull", "push"])
+def test_batched_delivery_builds_the_aligned_layout(rmat, family,
+                                                    streamed):
+    """A query-batched program (sum through the pull engine, min
+    through the push engine) gets the aligned arrays, and its dense
+    reduction of a [vpad, B] table equals the plain segment reduce of
+    the same messages, in vertex order."""
+    sg = ShardedGraph.build(rmat, NUM_PARTS, vpad_align=128)
+    eng = _build(family, sg, batched=True, stream_msgs=streamed)
+    counts = _last_dense_layout()
+    lay, want = _tiled_arrays(sg, aligned=True)
+    d = eng.delivery
+    assert d.aligned and d.fused == streamed
+    assert 0 < d.tiles.n_aligned <= d.tiles.n_chunks
+    for k, v in want.items():
+        np.testing.assert_array_equal(np.asarray(eng.arrays[k]), v,
+                                      err_msg=k)
+    assert counts == lay.counts() and counts["aligned_edges"] > 0
+    kind = d.kind
+    rng = np.random.default_rng(9)
+    rows = jnp.asarray(rng.integers(0, 256, (sg.num_parts, sg.vpad, 2))
+                       .astype(np.float32 if kind == "sum" else np.int32))
+
+    def msg(vals, w):
+        return vals * 2 + 1
+
+    g = _delivery_arrays(eng)
+    flat = rows.reshape(-1, 2)
+    if d.fused:
+        got = jax.vmap(lambda gp: d.reduce_fused(flat, msg, gp))(g)
+    else:
+        got = jax.vmap(lambda gp: d.reduce(
+            flat, d.messages(flat, msg, gp), msg, gp))(g)
+    for p in range(sg.num_parts):
+        want_p = segment_reduce(
+            msg(jnp.take(flat, jnp.asarray(sg.src_slot[p]), axis=0),
+                None),
+            jnp.asarray(sg.dst_local[p]), sg.vpad + 1, kind)[:sg.vpad]
+        # small integers: the float32 sums are exact as well
+        np.testing.assert_array_equal(np.asarray(got[p]),
+                                      np.asarray(want_p))
+
+
+def test_batched_delivery_with_short_chunks_keeps_the_default(rmat):
+    """Chunks that hold no whole depth row (tile_e under 128) cannot be
+    aligned: the batched engine runs on the default layout."""
+    sg = ShardedGraph.build(rmat, NUM_PARTS, vpad_align=128)
+    with pytest.warns(UserWarning, match="not a multiple of 128"):
+        eng = _build("push", sg, batched=True, tile_e=64)
+    assert not eng.delivery.aligned
+    assert eng.delivery.tiles.n_aligned == 0
+
+
 # -- (b) rejected combinations ----------------------------------------
 
 def _build(family, sg, batched=False, **opts):

@@ -116,6 +116,30 @@ class TestPullServing:
         assert not r.converged and r.segments == 3
 
 
+class TestAlignedDelivery:
+    """PR 40: the three batched kinds run on the lane-aligned layout
+    (ops/tiled.py) and answer as their references do, on a graph whose
+    hub tile keeps one-hot chunks beside the aligned ones."""
+
+    @pytest.fixture(scope="class")
+    def skewed(self):
+        from lux_tpu.convert import rmat_graph
+        return rmat_graph(scale=10, edge_factor=8, seed=3)
+
+    @pytest.mark.parametrize("kind", ["sssp", "components", "pagerank"])
+    def test_kind_answers_as_its_reference(self, skewed, kind):
+        srv = serve.Server(skewed, batch=4, num_parts=1, seg_iters=2)
+        deg = np.asarray(skewed.out_degrees)
+        sources = [int(s) for s in np.nonzero(deg)[0][[1, 7, 30, 90, 200]]]
+        for s in sources:
+            srv.submit(kind, source=s)
+        responses = srv.run()
+        lay = srv._runner(kind).eng.delivery.tiles
+        assert 0 < lay.n_aligned < lay.n_chunks
+        assert sorted(r.source for r in responses) == sorted(sources)
+        assert serve._check_answers(skewed, responses) == 0
+
+
 class TestBoundarySpans:
     """PR 24: every segment boundary is one ``serve.boundary`` span
     (lux_tpu/telemetry.py) whose children split it."""
