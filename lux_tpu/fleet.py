@@ -1024,6 +1024,11 @@ class FleetServer:
                             # longer pin the generation; the
                             # re-dispatch pins again at _start
                             runner.live.unpin()
+                # queries whose columns were freed at a boundary and
+                # whose answers were never made (serve.py: the
+                # boundary's second half runs behind the NEXT
+                # dispatch, which the dead replica never made good)
+                inflight += runner.unanswered()
             for coll in rep._collectors.values():
                 # suppress the dead collector's metrics for this
                 # drain: the requests are about to re-queue on a

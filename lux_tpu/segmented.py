@@ -22,6 +22,13 @@ Three extensions beyond plain fixed-size slicing:
   ``each_converge_segment``) that a caller may suspend between two
   segments; ``run_segments`` / ``converge_segments`` are the same
   generators run to their end.
+- the two generators offer the serving tier two seams, both unused
+  by every batch caller: ``while_running()`` is called between the
+  DISPATCH of a slice and the wait for it, so host work that nothing
+  in the slice reads runs while the device computes
+  (lux_tpu/serve.py: the answers of columns that have left the
+  batch); and a caller that changed the state while the driver was
+  suspended resumes it with ``send(state)`` instead of ``next()``.
 
 Both drivers are telemetry emitters (lux_tpu/telemetry.py): with an
 active handle, every slice emits a ``segment`` event (sizes, fenced
@@ -151,7 +158,8 @@ def run_segments(eng, state, num_iters: int, segment,
 
 def each_run_segment(eng, state, num_iters: int, segment,
                      on_segment: Callable | None = None,
-                     start_iter: int = 0, mem=None):
+                     start_iter: int = 0, mem=None,
+                     while_running: Callable | None = None):
     """The pull driver, one slice at a time: a generator that runs ONE
     slice (``segment``: int size or DurationBudget) and its
     ``on_segment(state, done_iters)`` hook — which may return a
@@ -159,7 +167,11 @@ def each_run_segment(eng, state, num_iters: int, segment,
     on with.  Between two ``next()`` calls the driver is suspended
     with its state on the device and its watchdog, budget and counter
     history intact, so a caller may run something else in between
-    (lux_tpu/serve.py time-shares one chip among runners this way).
+    (lux_tpu/serve.py time-shares one chip among runners this way);
+    one that CHANGED the state meanwhile resumes with ``send(state)``.
+    ``while_running()``, where given, is called once a slice, after
+    the slice's dispatch and before anything waits for it (inside
+    ``segment.run``): what it does runs while the device computes.
     ``mem`` is a memwatch.MemoryTrail sampled at every segment
     boundary (the round-22 occupancy trail — O(1) host work, outside
     the fused loop by construction).
@@ -204,6 +216,8 @@ def each_run_segment(eng, state, num_iters: int, segment,
                     state, n)
             else:
                 state = eng.run(state, n)
+            if while_running is not None:
+                while_running()
             if timed or st is not None or guarded:
                 from lux_tpu.timing import fence
                 fence(state)   # O(1)-byte fence, not a download
@@ -235,7 +249,9 @@ def each_run_segment(eng, state, num_iters: int, segment,
         # this slice, so appending earlier would double-count it
         if st is not None:
             st.extend_pull(res_b, chg_b, n, res_p, chg_p)
-        yield state
+        res = yield state
+        if res is not None:
+            state = res
 
 
 def converge_segments(eng, label, active, segment,
@@ -256,12 +272,15 @@ def converge_segments(eng, label, active, segment,
 def each_converge_segment(eng, label, active, segment,
                           max_iters: int | None = None,
                           on_segment: Callable | None = None,
-                          start_iter: int = 0, mem=None):
+                          start_iter: int = 0, mem=None,
+                          while_running: Callable | None = None):
     """The push driver, one slice at a time: a generator that runs ONE
     slice (``segment``: int size or DurationBudget) and its hook per
     ``next()``, yields ``(label, active, total_iters)``, and ends when
-    the active mask is empty or ``max_iters`` is reached (suspension
-    as in ``each_run_segment``).
+    the active mask is empty or ``max_iters`` is reached (suspension,
+    ``send((label, active))`` and ``while_running`` as in
+    ``each_run_segment``: the call lies between the dispatch of
+    ``eng.converge*`` and the completion fence).
 
     ``on_segment(label, active, total_iters, active_count)`` runs after
     each slice (may raise to abort, or return a replacement
@@ -312,6 +331,8 @@ def each_converge_segment(eng, label, active, segment,
                     eng.converge_stats(label, active, n)
             else:
                 label, active, it = eng.converge(label, active, n)
+            if while_running is not None:
+                while_running()
             # the scalar fetch depends on the whole while_loop: it is
             # the completion fence (O(1) bytes to the host)
             it = int(np.asarray(jax.device_get(it)))
@@ -350,6 +371,8 @@ def each_converge_segment(eng, label, active, segment,
         # this slice, so appending earlier would double-count it
         if st is not None:
             st.extend_push(fsz, fed, it, fszp, fedp)
-        yield label, active, total
-        if cnt == 0:
+        res = yield label, active, total
+        if res is not None:
+            label, active = res     # changed while suspended: go on
+        elif cnt == 0:
             break

@@ -38,20 +38,33 @@ PRs 1-8 built:
   the ring the turns are that runner's ``drain``: ``python -m
   lux_tpu.serve``, scripts/loadgen.py and the fleet's replicas all
   go through the same path.
-- the **serving loop** (``Server.serve(deliver)``): the responses a
-  turn retired are handed to ``deliver`` BEFORE the next turn starts,
-  so a caller learns of retirement at the boundary that retired its
-  query; while no kind has work the loop blocks (span ``serve.idle``)
-  until a ``submit`` from any thread or ``stop()``, and once stopped
-  it ends when no kind has work.  ``run()`` is that loop with the
-  stop already given, collected into a list.
-- at each segment boundary the hook RETIRES converged columns (push:
+- the **serving loop** (``Server.serve(deliver)``): responses are
+  handed to ``deliver`` the moment they are made (no turn starts
+  between a ``query_done`` and its hand-over); while no kind has
+  work the loop blocks (span ``serve.idle``) until a ``submit`` from
+  any thread or ``stop()``, and once stopped it ends when no kind
+  has work.  ``run()`` is that loop with the stop already given,
+  collected into a list.
+- each segment boundary has TWO HALVES.  The first steers the next
+  segment and is serial: the hook finds the converged columns (push:
   the column's frontier is empty; pull: the column's residual fell
-  under ``tol``), scatters their answers into per-query
-  :class:`Response` objects, and REFILLS the freed columns from the
-  queue (pull refills also rewrite the column of the reset table on
-  the device and hand the table back through
-  ``PullEngine.update_program_arrays`` — no recompile).
+  under ``tol``), takes each on the device (``_take_column``: an
+  array of its own, not fetched), frees its slot and REFILLS the
+  freed columns from the queue (pull refills also rewrite the column
+  of the reset table on the device and hand the table back through
+  ``PullEngine.update_program_arrays`` — no recompile).  The second
+  only concerns answers that have already left the batch — the
+  ``device_get`` of the taken columns, the unpadding, the cache
+  insert, the per-query :class:`Response`, ``query_done``, the
+  hand-over — and runs BEHIND THE NEXT DISPATCH, whichever runner's
+  it is (``_AnswerWork``; the drivers' ``while_running`` seam), so
+  the device computes while the host makes the answers; where no
+  dispatch follows it is flushed at once (``_RunnerBase.turn``).  A
+  suspended runner that comes back to the chip with free columns and
+  queued queries starts them before its segment (``_refill``): a
+  closed-loop caller that heard of its retirement behind another
+  kind's dispatch has its next query in a column by its own kind's
+  next segment.
 - per-query telemetry: ``query_enqueue`` / ``query_start`` /
   ``query_done`` events (latency, wait, iterations, segments) plus a
   ``serve_refill`` event per boundary — rendered and validated by
@@ -74,7 +87,8 @@ PRs 1-8 built:
   raw ``query_done`` stream.
 
 Costs and debts: a boundary moves one padded ``[P, vpad]`` column per
-retired query to the host and a few ``[B]`` vectors back — the retire
+retired query to the host (behind the next segment) and a few ``[B]``
+vectors back — the retire
 (``_take_column``) and the refill (``_start_columns``) are device
 programs of fixed shape, and so are the pull runner's per-column
 residual (``_column_residuals``; 4 B a column come to the host) and
@@ -92,7 +106,9 @@ mismatch).
 
 from __future__ import annotations
 
+import collections
 import dataclasses
+import functools
 import queue as _queuemod
 import threading
 import time
@@ -550,6 +566,61 @@ class _Slot:
     segments: int = 0
 
 
+@dataclasses.dataclass(eq=False)
+class _Leaving:
+    """One query between the two halves of its last boundary: its
+    column has been taken on the device (``_take_column``) and freed,
+    its answer has not reached the host yet."""
+    col: int
+    slot: _Slot
+    taken: object               # [P, vpad] device array
+    total_iters: int            # the engine's count at the boundary
+    converged: bool
+    answer_epoch: int | None    # the column's own (``_answer_epoch``)
+    twins: list = dataclasses.field(default_factory=list)
+
+    @property
+    def cache_epoch(self) -> int:
+        """The epoch the retirement caches the answer under."""
+        if self.answer_epoch is not None:
+            return self.answer_epoch
+        return self.slot.req.epoch or 0
+
+
+class _AnswerWork:
+    """The second halves of segment boundaries, waiting for a segment
+    to run behind.  A boundary's first half steers the next segment
+    (which columns are done, which queries start) and stays serial;
+    what is left only concerns answers that have already left the
+    batch — the ``device_get`` of the taken columns, the unpadding,
+    the cache insert, the ``Response``, ``query_done``, the hand-over
+    — and nothing in the next segment reads it.  A runner defers that
+    half here as one job a boundary; ``run(hidden=True)`` is what the
+    segment drivers' ``while_running`` seam calls right after the
+    next dispatch, WHICHEVER runner's it is (a ``Server`` gives all
+    its runners one queue; a runner on its own has its own), and
+    ``run(hidden=False)`` is the flush where no dispatch follows
+    (``_RunnerBase.turn``).  Jobs run oldest first; ``after`` (the
+    Server's hand-over) is called once a job, outside any span."""
+
+    def __init__(self):
+        self._jobs: collections.deque = collections.deque()
+        self.after: Callable | None = None
+
+    def __len__(self) -> int:
+        return len(self._jobs)
+
+    def defer(self, job: Callable[[bool], None]) -> None:
+        self._jobs.append(job)
+
+    def run(self, hidden: bool) -> None:
+        while self._jobs:
+            self._jobs.popleft()(hidden)
+            if self.after is not None:
+                with telemetry.under(None):
+                    self.after()
+
+
 def _emit(event: str, **fields):
     from lux_tpu import telemetry
     telemetry.current().emit(event, **fields)
@@ -579,8 +650,17 @@ class _RunnerBase:
         self.responses: list[Response] = []
         # the suspended segment driver (a generator of
         # lux_tpu/segmented.py) while any column is occupied; it holds
-        # the device state between turns.  None: idle, nothing resident
+        # the device state between turns (``_state``: what it last
+        # yielded; ``_iters``: the engine's count at its last
+        # boundary).  None: idle, nothing resident
         self._segments = None
+        self._state = None
+        self._iters = 0
+        # the boundaries' second halves (a Server replaces this by the
+        # queue all its runners share) and the queries that are
+        # between the two halves of theirs
+        self.answers = _AnswerWork()
+        self._leaving: list[_Leaving] = []
         self.metrics = metrics
         self.slo_ms = None if slo_ms is None else float(slo_ms)
         # live-graph serving (round 20, lux_tpu/livegraph.py): the
@@ -616,41 +696,67 @@ class _RunnerBase:
     def turn(self, collector: BatchCollector, deadline_s: float = 0.0,
              switch: bool = False) -> list[Response]:
         """One turn at the chip: start from ``collector`` if nothing
-        is resident, then ONE segment and its boundary (retire,
-        refill from ``collector``); returns the responses retired in
-        it.  Afterwards the runner is suspended with its state on the
-        device (``resident``), or idle: every column retired and the
-        collector empty.  The span ``serve.turn.<family>`` holds the
-        turn (counts: ``kind``; ``switch`` 1 where the scheduler ran
-        another runner's turn before this one), with the segment's
-        ``serve.boundary`` as its child."""
+        is resident (a suspended runner with free columns starts
+        queued queries in them first: ``_refill``), then ONE segment
+        and the FIRST half of its boundary (which columns are done,
+        their ``_take_column`` dispatches, the slots freed, the
+        refill from ``collector``, the placement).  The second half —
+        the answers of the columns that left — is deferred to
+        ``self.answers`` and runs right after the NEXT dispatch, this
+        runner's or another's (``_AnswerWork``), so the device
+        computes while the host fetches, unpads and delivers.  Where
+        no dispatch of this runner follows — every column idle and
+        the collector empty, no column taken at all, or the turn
+        raised — the turn runs it before it ends: no answer waits
+        for an idle runner.  Returns this runner's responses that
+        became ready during the turn (a Server, whose runners share
+        one queue, takes them from ``responses`` as they come).
+        Afterwards the runner is suspended with its state on the
+        device (``resident``), or idle.  The span
+        ``serve.turn.<family>`` holds the turn (counts: ``kind``;
+        ``switch`` 1 where the scheduler ran another runner's turn
+        before this one), with the segment's ``serve.boundary`` as
+        its child."""
         n0 = len(self.responses)
         with telemetry.span("serve.turn." + self.family,
                             kind=self.kind, switch=int(switch)):
-            if self._segments is None:
-                self._segments = self._begin(collector, deadline_s)
-            if self._segments is not None:
-                try:
-                    next(self._segments)
-                except StopIteration:
-                    self._segments = None
-                except BaseException:
-                    # a mid-drain death (fleet kill plans, a tripped
-                    # watchdog): the columns' state is gone with it
-                    self._segments = None
-                    raise
-                else:
+            try:
+                resume = None
+                if self._segments is None:
+                    self._segments = self._begin(collector, deadline_s)
+                elif self._free_cols() and len(collector):
+                    resume = self._refill(collector, deadline_s)
+                if self._segments is not None:
+                    try:
+                        self._state = self._segments.send(resume)
+                    except StopIteration:
+                        self._segments = None
                     # every column idle after a boundary that found
                     # the collector empty: nothing is resident
                     if not self._occupied():
                         self._segments = None
+            except BaseException:
+                # a mid-drain death (fleet kill plans, a tripped
+                # watchdog): the columns' state is gone with it; the
+                # answers of columns that had left before it are not
+                self._segments = self._state = None
+                try:
+                    self.answers.run(hidden=False)
+                except Exception:   # noqa: BLE001 - the turn's own
+                    pass            # failure is the one to report;
+                    #                 ``unanswered()`` keeps the rest
+                raise
+            if self._segments is None:
+                self._state = None
+                self.answers.run(hidden=False)
         return self.responses[n0:]
 
     def drain(self, collector: BatchCollector,
               deadline_s: float = 0.0) -> list[Response]:
         """Serve until the collector is empty and every column is
-        idle — turns until no work is left; returns the responses
-        retired during this drain."""
+        idle — turns until no work is left (the last one flushes its
+        own answers); returns the responses retired during this
+        drain."""
         n0 = len(self.responses)
         self.turn(collector, deadline_s)
         while self.resident:
@@ -689,11 +795,14 @@ class _RunnerBase:
 
     def _retire(self, col: int, answer: np.ndarray, total_iters: int,
                 converged: bool = True):
-        slot = self.slots[col]
-        answer_epoch = self._answer_epoch(col)
-        self.slots[col] = None
-        if self.live is not None:
-            self.live.unpin()
+        """The second half of one retirement, once the ``answer``
+        ([nv]) of the query that left column ``col`` is on the host:
+        the Response, the cache insert, the SLO series,
+        ``query_done`` — and the queries that asked the same thing
+        while the answer was on its way (``_Leaving.twins``)."""
+        left = next(x for x in self._leaving if x.col == col)
+        self._leaving.remove(left)
+        slot, answer_epoch = left.slot, left.answer_epoch
         now = time.monotonic()
         resp = Response(
             qid=slot.req.qid, kind=self.kind, source=slot.req.source,
@@ -705,8 +814,7 @@ class _RunnerBase:
         self.responses.append(resp)
         if self.cache is not None and converged:
             self.cache.put(self.kind, slot.req, answer, resp.iters,
-                           (answer_epoch if answer_epoch is not None
-                            else slot.req.epoch or 0), now)
+                           left.cache_epoch, now)
         slo = {}
         if self.slo_ms is not None:
             slo_ok = resp.latency_s * 1e3 <= self.slo_ms
@@ -743,23 +851,62 @@ class _RunnerBase:
               latency_s=round(resp.latency_s, 6),
               wait_s=round(resp.wait_s, 6), converged=converged,
               **ep, **slo, **self._rep())
+        for req in left.twins:
+            # the entry just put, unless it has gone already (a TTL
+            # of milliseconds, another replica's inserts): the answer
+            # is here either way
+            t = time.monotonic()
+            self._respond_cached(
+                req, self._cached(req, t) or _CacheEntry(
+                    answer, resp.iters, left.cache_epoch, t), t)
         return resp
 
-    def _serve_cached(self, req: Request) -> bool:
-        """Serve ``req`` straight from the epoch-keyed answer cache
-        when possible — no column, no engine dispatch (ROADMAP item
-        5a).  The entry's epoch equals the request's admission epoch
-        BY KEY, so a hit can never be stale-epoch."""
-        if self.cache is None or req.no_cache:
-            return False
-        now = time.monotonic()
+    def unanswered(self) -> list[Request]:
+        """The queries whose columns are freed and whose answers were
+        never made (a second half that failed, or never ran): what a
+        failover must re-dispatch besides the occupied columns
+        (lux_tpu/fleet.py ``_mark_lost``).  Forgets them."""
+        out = [q for left in self._leaving
+               for q in (left.slot.req, *left.twins)]
+        del self._leaving[:]
+        return out
+
+    def _cached(self, req: Request, now: float):
+        """The cache's entry for ``req`` (or None), counted as the
+        hit or miss it is."""
         ent = self.cache.get(self.kind, req, now)
         if self.metrics is not None:
             self.metrics.counter(
                 "serve_cache_hit_total" if ent is not None
                 else "serve_cache_miss_total", kind=self.kind).inc()
+        return ent
+
+    def _serve_cached(self, req: Request) -> bool:
+        """Serve ``req`` straight from the epoch-keyed answer cache
+        when possible — no column, no engine dispatch (ROADMAP item
+        5a).  The entry's epoch equals the request's admission epoch
+        BY KEY, so a hit can never be stale-epoch.  A query whose
+        twin has just left its column (``_leaving``: the entry is
+        one ``device_get`` away) takes no column either: it is
+        answered with the twin, from the entry the twin puts."""
+        if self.cache is None or req.no_cache:
+            return False
+        key = (AnswerCache.query_key(req), req.epoch or 0)
+        for left in self._leaving:
+            if left.converged and key == (
+                    AnswerCache.query_key(left.slot.req),
+                    left.cache_epoch):
+                left.twins.append(req)
+                return True
+        now = time.monotonic()
+        ent = self._cached(req, now)
         if ent is None:
             return False
+        self._respond_cached(req, ent, now)
+        return True
+
+    def _respond_cached(self, req: Request, ent: _CacheEntry,
+                        now: float) -> None:
         resp = Response(
             qid=req.qid, kind=self.kind, source=req.source,
             answer=ent.answer.copy(), iters=ent.iters, segments=0,
@@ -788,7 +935,6 @@ class _RunnerBase:
               latency_s=round(resp.latency_s, 6),
               wait_s=round(resp.wait_s, 6), converged=True,
               cached=True, **ep, **slo, **self._rep())
-        return True
 
     # -- columns on the device -------------------------------------------
     #
@@ -845,20 +991,78 @@ class _RunnerBase:
         return (mask, np.full(self.B, -1, np.int32),
                 np.full(self.B, self._inf, self._dtype))
 
-    def _fetch_columns(self, label, cols) -> list:
-        """The ``[nv]`` answers of the columns ``cols``:
-        ``serve.boundary.fetch`` (one ``_take_column`` dispatch per
-        column, then one device_get of them all; ``bytes``) and
-        ``serve.boundary.unpad``."""
+    def _take_columns(self, bsp, label, cols, total_iters,
+                      converged) -> None:
+        """The first half of the retirements of one boundary
+        (``serve.boundary.take``): one ``_take_column`` dispatch a
+        column — a device array of its own, not fetched: the copy to
+        the host runs beside the next segment (measured on the chip,
+        PR 41: 3 ms for 8 columns; asking for it here, with
+        ``copy_to_host_async``, cost the serial half 1.5 ms more) —
+        and the slots freed, so that ``_fill`` can give the columns
+        away.  The second half (``_answer_columns``) is deferred to
+        ``self.answers``."""
+        left = []
+        with telemetry.span("serve.boundary.take"):
+            for c, conv in zip(cols, converged):
+                left.append(_Leaving(c, self.slots[c],
+                                     self._take(label, np.int32(c)),
+                                     total_iters, conv,
+                                     self._answer_epoch(c)))
+                self.slots[c] = None
+                if self.live is not None:
+                    self.live.unpin()
+        self._leaving += left
+        self.answers.defer(
+            functools.partial(self._answer_columns, bsp, left))
+
+    def _answer_columns(self, bsp, left, hidden: bool) -> None:
+        """The second half: ``serve.boundary.fetch`` (one device_get
+        of the taken columns; ``bytes``), ``.unpad`` and ``.retire``,
+        children of the boundary ``bsp`` that took them though it has
+        closed (``telemetry.under``).  ``hidden`` (1 behind a
+        dispatch, 0 flushed with the device idle) goes onto ``bsp``."""
         import jax
 
         sg = self.eng.sg
-        with telemetry.span("serve.boundary.fetch") as sp:
-            padded = jax.device_get(
-                [self._take(label, np.int32(c)) for c in cols])
-            sp.count(bytes=sum(x.nbytes for x in padded))
-        with telemetry.span("serve.boundary.unpad"):
-            return [sg.from_padded(x) for x in padded]
+        bsp.count(hidden=int(hidden))
+        with telemetry.under(bsp):
+            with telemetry.span("serve.boundary.fetch") as sp:
+                padded = jax.device_get([x.taken for x in left])
+                sp.count(bytes=sum(x.nbytes for x in padded))
+            with telemetry.span("serve.boundary.unpad"):
+                answers = [sg.from_padded(x) for x in padded]
+            with telemetry.span("serve.boundary.retire"):
+                for x, answer in zip(left, answers):
+                    self._retire(x.col, answer, x.total_iters,
+                                 x.converged)
+
+    def _behind_dispatch(self) -> None:
+        """The segment drivers' ``while_running``: the device has
+        just been given a segment, the deferred answers run now."""
+        self.answers.run(hidden=True)
+
+    def _refill(self, collector, deadline_s: float):
+        """A suspended runner comes back to the chip with free
+        columns and queued queries (they came while other runners
+        had their turns, or with the answers that left at its last
+        boundary): they start now, not at the boundary after one
+        more segment.  ``serve.boundary.fill`` and ``.place`` as a
+        boundary's, children of the turn.  Returns the state to
+        resume the driver with (None: the cache answered them all)."""
+        turnover = self._turnover()
+        with telemetry.span("serve.boundary.fill"):
+            n_filled = self._fill(turnover, collector, self._iters,
+                                  deadline_s)
+        if not n_filled:
+            return None
+        _emit("serve_refill", query_kind=self.kind, retired=0,
+              filled=n_filled, occupied=len(self._occupied()),
+              queued=len(collector))
+        if self.metrics is not None:
+            self.metrics.counter("serve_refilled_total",
+                                 kind=self.kind).inc(n_filled)
+        return self._restart(turnover)
 
     def _fill(self, turnover, collector, total_iters,
               deadline_s) -> int:
@@ -890,21 +1094,33 @@ class _RunnerBase:
     #
     # Every segment boundary is one ``serve.boundary`` span (counts:
     # retired, filled, occupied, queued, worked 0/1, family "push" /
-    # "pull": what tells the two boundaries below apart), the child
-    # of its turn's ``serve.turn.<family>``.  A push boundary
-    # that neither retires nor refills has ``worked`` 0 and only the
+    # "pull": what tells the two boundaries below apart; where it
+    # worked also hidden 0/1), the child of its turn's
+    # ``serve.turn.<family>``.  The span itself holds the boundary's
+    # FIRST half, what the device waits for.  A push boundary that
+    # neither retires nor refills has ``worked`` 0 and only the
     # ``.counts`` child (the device-to-host fetch of the [B] active
     # counts; after ``.delta`` on live graphs).  One that works has
-    # ``.counts``, ``.fetch`` (one padded label column per retired
-    # query: ``bytes``), ``.unpad``, ``.retire``, ``.fill`` (host
-    # bookkeeping only) and ``.place`` (the device reset of the
-    # retired and refilled columns; ``bytes`` = the [B] vectors that
-    # steer it; ends at dispatch).  A pull boundary has ``.residual``
-    # (the per-column residuals: a device program and the fetch of
-    # its [B] result; after ``.delta``, the device correction, on
-    # live graphs), ``.fetch`` and ``.unpad`` where a column retires,
-    # ``.retire`` and ``.fill`` (host bookkeeping) always, and
-    # ``.place`` after a refill, as the push one.
+    # ``.counts``, ``.take`` where a column retires (one
+    # ``_take_column`` dispatch a retired query, the slots freed),
+    # ``.fill`` (host bookkeeping only) and ``.place`` (the device
+    # reset of the retired and refilled columns; ``bytes`` = the [B]
+    # vectors that steer it; ends at dispatch).  A pull boundary has
+    # ``.residual`` (the per-column residuals: a device program and
+    # the fetch of its [B] result; after ``.delta``, the device
+    # correction, on live graphs), ``.take`` where a column retires,
+    # ``.fill`` always, and ``.place`` after a refill, as the push
+    # one.  The SECOND half of a boundary that retired — ``.fetch``
+    # (one padded label column per retired query: ``bytes``),
+    # ``.unpad`` and ``.retire`` — are its children too, but run
+    # after it has closed: behind the next segment's dispatch
+    # (``hidden`` 1; in time they lie inside that turn's
+    # ``segment.run``) or, where none follows, flushed at the end of
+    # the turn (``hidden`` 0).  A boundary that worked without
+    # retiring has no answers for the device to wait for: ``hidden``
+    # 1.  A ``.fill`` / ``.place`` pair that is a child of the TURN
+    # is the refill of a runner that came back to the chip with free
+    # columns (``_refill``).
 
     def _enter_boundary(self) -> None:
         """The top of every segment boundary: the serving tier's hook
@@ -927,6 +1143,8 @@ class _RunnerBase:
         bsp.count(worked=int(worked), retired=retired, filled=filled,
                   occupied=len(self._occupied()), queued=queued,
                   family=self.family)
+        if worked and not retired:
+            bsp.count(hidden=1)     # no answers to wait for
         if self.metrics is None:
             return
         m = self.metrics
@@ -1060,6 +1278,7 @@ class PushBatchRunner(_RunnerBase):
         label, active = self._place_columns(*self._blank(), turnover)
 
         def hook(label, active, total, cnt):
+            self._iters = total
             with telemetry.span("serve.boundary") as bsp:
                 return boundary(bsp, label, active, total)
 
@@ -1089,14 +1308,13 @@ class PushBatchRunner(_RunnerBase):
                 # hand the updated arrays back to the driver
                 return (label, active) if self.live is not None \
                     else None
-            # only the labels of the retiring columns come to the
-            # host: a column leaves with an empty frontier or is cut
-            # at max_segments, and its mask is discarded either way
-            answers = self._fetch_columns(label, done)
-            with telemetry.span("serve.boundary.retire"):
-                for c, answer in zip(done, answers):
-                    self._retire(c, answer, total,
-                                 converged=bool(counts[c] == 0))
+            # only the labels of the retiring columns will come to
+            # the host, behind the next dispatch: a column leaves
+            # with an empty frontier or is cut at max_segments, and
+            # its mask is discarded either way
+            if done:
+                self._take_columns(bsp, label, done, total,
+                                   [bool(counts[c] == 0) for c in done])
             turnover = self._turnover(done)
             with telemetry.span("serve.boundary.fill"):
                 n_filled = self._fill(turnover, collector, total,
@@ -1109,8 +1327,14 @@ class PushBatchRunner(_RunnerBase):
                                    len(collector))
             return self._place_columns(label, active, turnover)
 
-        return each_converge_segment(self.eng, label, active,
-                                     self.seg_iters, on_segment=hook)
+        return each_converge_segment(
+            self.eng, label, active, self.seg_iters, on_segment=hook,
+            while_running=self._behind_dispatch)
+
+    def _restart(self, turnover):
+        """``_refill``'s placement on the suspended state."""
+        label, active, _total = self._state
+        return self._place_columns(label, active, turnover)
 
     def _apply_delta(self, label, active):
         """One live delta-relax application (livegraph.delta_step —
@@ -1268,6 +1492,8 @@ class PullBatchRunner(_RunnerBase):
         self._inf, self._dtype = np.float32(0), np.float32
         self._column_programs(mesh, self._dtype)
         self._deg = np.asarray(g.out_degrees, np.float32)
+        # the state the segment in flight began from (a device copy)
+        self._prev = None
         self._residual = jax.jit(_column_residuals)
         self._snapshot = jax.jit(jnp.copy, out_shardings=self._parts)
         self._put = jax.jit(_put_column, donate_argnums=0,
@@ -1357,18 +1583,19 @@ class PullBatchRunner(_RunnerBase):
             return None                  # cache hits take no column
         state = self._place_columns(*self._blank(), turnover)
         # what the segment begins from: ``eng.run`` donates its input
-        prev = self._snapshot(state)
+        self._prev = self._snapshot(state)
 
         def hook(state, done_iters):
             # the pull driver dispatches a segment and waits for it
             # only where it times one: wait here, so that the
             # boundary's spans hold the boundary and not the segment
             fence(state)
+            self._iters = done_iters
             with telemetry.span("serve.boundary") as bsp:
                 return boundary(bsp, state, done_iters)
 
         def boundary(bsp, state, done_iters):
-            nonlocal prev
+            prev = self._prev
             self._enter_boundary()
             if self.live is not None and self.live.count:
                 # after it ``state`` is one exact PPR iteration of
@@ -1386,11 +1613,10 @@ class PullBatchRunner(_RunnerBase):
             done = [c for c in self._occupied()
                     if res[c] <= self.tol
                     or self.slots[c].segments >= self.max_segments]
-            answers = self._fetch_columns(state, done) if done else ()
-            with telemetry.span("serve.boundary.retire"):
-                for c, answer in zip(done, answers):
-                    self._retire(c, answer, done_iters,
-                                 converged=bool(res[c] <= self.tol))
+            if done:
+                self._take_columns(
+                    bsp, state, done, done_iters,
+                    [bool(res[c] <= self.tol) for c in done])
             turnover = self._turnover()
             with telemetry.span("serve.boundary.fill"):
                 n_filled = self._fill(turnover, collector, done_iters,
@@ -1404,12 +1630,20 @@ class PullBatchRunner(_RunnerBase):
                                    len(done), n_filled, len(collector))
             if n_filled:
                 state = self._place_columns(state, turnover)
-            prev = self._snapshot(state)
+            self._prev = self._snapshot(state)
             return state
 
         return each_run_segment(self.eng, state,
                                 np.iinfo(np.int32).max,
-                                self.seg_iters, on_segment=hook)
+                                self.seg_iters, on_segment=hook,
+                                while_running=self._behind_dispatch)
+
+    def _restart(self, turnover):
+        """``_refill``'s placement on the suspended state, and the
+        snapshot the next segment's residuals are taken against."""
+        state = self._place_columns(self._state, turnover)
+        self._prev = self._snapshot(state)
+        return state
 
 
 class Server:
@@ -1496,6 +1730,9 @@ class Server:
         self._collectors: dict[str, BatchCollector] = {}
         self._runners: dict[str, _RunnerBase] = {}
         self._last_turn: _RunnerBase | None = None   # serve()'s ring
+        # the boundaries' second halves, of every runner: whichever
+        # turn dispatches next runs them (``_AnswerWork``)
+        self._answers = _AnswerWork()
         # what submitters (any thread) share with the serving loop:
         # the qids, the kinds' collectors and the stop.  The loop
         # waits on it while no kind has work
@@ -1531,6 +1768,7 @@ class Server:
             self._runners[kind].on_boundary = self.on_boundary
             self._runners[kind].replica = self.replica
             self._runners[kind].mem = self.mem
+            self._runners[kind].answers = self._answers
         return self._runners[kind]
 
     def set_metrics(self, registry) -> None:
@@ -1703,10 +1941,22 @@ class Server:
                 lambda: self._stopping or self._has_work())
         return True
 
+    def _hand_over_ready(self, deliver) -> None:
+        """Hand over whatever the runners have ready: the answers one
+        boundary's second half has just made (``_AnswerWork.after``),
+        or what a turn answered from the cache."""
+        responses = []
+        for runner in self._runners.values():
+            # the loop outlives any drain: what is handed over is
+            # the caller's, not the runner's to keep
+            responses += runner.responses
+            del runner.responses[:]
+        self._hand_over(deliver, responses)
+
     def _hand_over(self, deliver, responses: list) -> None:
-        """The responses one turn retired leave the server: span
-        ``serve.deliver`` (count ``responses``) round their release
-        and the caller's ``deliver``; then the snapshot cadence."""
+        """Responses leave the server: span ``serve.deliver`` (count
+        ``responses``) round their release and the caller's
+        ``deliver``; then the snapshot cadence."""
         if not responses:
             return
         with telemetry.span("serve.deliver", responses=len(responses)):
@@ -1738,15 +1988,23 @@ class Server:
         that have work (a queued query or an occupied column) — so
         under sustained arrivals of several kinds none waits for
         another's queue to empty; every runner's state stays on the
-        device between its turns.  The responses a turn retired are
-        handed to ``deliver`` (a list, in retirement order) before
-        the next turn starts.  While no kind has work the loop
-        blocks until a ``submit`` from any thread or ``stop()``;
-        once stopped it returns when no kind has work.  Publishes a
-        ``metrics_snapshot`` event at a hand-over, at most one per
-        ``snapshot_every_s`` (``emit_metrics_snapshot()`` snapshots
-        on demand)."""
+        device between its turns.  A boundary's answers are made
+        behind the NEXT turn's dispatch, whichever kind's it is
+        (``_RunnerBase.turn``, ``_AnswerWork``), and handed to
+        ``deliver`` (a list, in retirement order) the moment they
+        are made — the device computes meanwhile; where no dispatch
+        follows (the turn left nothing resident of its kind, or
+        raised) they are made and handed over before the turn ends,
+        so nothing waits while the loop blocks.  What a turn
+        answered from the cache is handed over when it ends.  While
+        no kind has work the loop blocks until a ``submit`` from any
+        thread or ``stop()``; once stopped it returns when no kind
+        has work.  Publishes a ``metrics_snapshot`` event at a
+        hand-over, at most one per ``snapshot_every_s``
+        (``emit_metrics_snapshot()`` snapshots on demand)."""
         try:
+            self._answers.after = functools.partial(
+                self._hand_over_ready, deliver)
             self._begin_drain()
             while True:
                 served = False
@@ -1756,21 +2014,23 @@ class Server:
                     if not self._wants_turn(kind, coll):
                         continue
                     runner = self._runner(kind)
-                    got = runner.turn(
+                    runner.turn(
                         coll, self.deadline_s,
                         switch=self._last_turn not in (None, runner))
-                    # the loop outlives any drain: what was handed
-                    # over is the caller's, not the runner's to keep
-                    del runner.responses[:]
                     self._last_turn = runner
                     served = True
-                    self._hand_over(deliver, got)
+                    self._hand_over_ready(deliver)
                 if served:
                     continue
+                # nothing to dispatch behind: a turn that leaves its
+                # runner idle has flushed already, this is the loop's
+                # own word that nothing waits while it blocks
+                self._answers.run(hidden=False)
                 if not self._await_work():
                     return
                 self._begin_drain()
         finally:
+            self._answers.after = None
             with self._wake:
                 self._stopping = False
 

@@ -67,6 +67,10 @@ supervisor (resilience.py), the CLI and bench.py all emit into:
   asynchronous the site's doc line says so.  With an event sink or an
   observer installed the record also goes out once as a ``span``
   event (``-events`` log, flight recorder, ``tracing.trace_export``).
+  ``under(sp)`` makes the records inside it children of ``sp`` after
+  ``sp`` has closed (``under(None)``: roots), and a ``sp.count(...)``
+  after the close still lands on its record in the ring (not in the
+  event that went out).
 
 Counter semantics (what the buffers mean, engine by engine):
 
@@ -714,6 +718,21 @@ def span(name: str, **counts) -> Span:
     sp.count(rows=n)``.  Never fences: a span round asynchronous work
     ends at dispatch."""
     return Span(name, counts)
+
+
+@contextlib.contextmanager
+def under(parent: Span | None):
+    """Records made inside are children of ``parent``, which may have
+    CLOSED already: work that belongs to a region and runs after it
+    (lux_tpu/serve.py: the answers of a segment boundary, fetched
+    behind the next segment's dispatch) stays where the readers of
+    ``parent``'s children look for it.  ``None``: they are roots,
+    whatever span is open."""
+    token = _enclosing.set(0 if parent is None else parent.id)
+    try:
+        yield
+    finally:
+        _enclosing.reset(token)
 
 
 def mark(name: str, seconds: float = 0.0, **counts) -> None:

@@ -142,7 +142,9 @@ class TestAlignedDelivery:
 
 class TestBoundarySpans:
     """PR 24: every segment boundary is one ``serve.boundary`` span
-    (lux_tpu/telemetry.py) whose children split it."""
+    (lux_tpu/telemetry.py) whose children split it.  PR 41: the span
+    holds the boundary's first half; ``fetch`` / ``unpad`` /
+    ``retire`` are its children still, and run after it has closed."""
 
     @staticmethod
     def _drain(g, kind, sources, **kw):
@@ -169,31 +171,50 @@ class TestBoundarySpans:
         for b in worked:
             kids = [r for r in recs if r["parent"] == b["id"]]
             assert [k["name"][len(pre):] for k in kids] == [
-                "counts", "fetch", "unpad", "retire", "fill", "place"]
+                "counts", "take", "fill", "place",
+                "fetch", "unpad", "retire"]
             by = {k["name"][len(pre):]: k for k in kids}
             # one padded label column per retired query comes to the
             # host; a few [B] vectors go back
             assert by["fetch"]["counts"]["bytes"] \
                 == b["counts"]["retired"] * column
             assert 0 < by["place"]["counts"]["bytes"] < 64
-            assert sum(k["t1"] - k["t0"] for k in kids) \
+            first, second = kids[:4], kids[4:]
+            assert sum(k["t1"] - k["t0"] for k in first) \
                 <= b["t1"] - b["t0"]
             assert all(b["t0"] <= k["t0"] <= k["t1"] <= b["t1"]
-                       for k in kids)
+                       for k in first)
+            # the answers' half runs after the boundary has closed:
+            # inside the next turn's segment.run, behind its dispatch
+            # (hidden 1), or where no dispatch follows at the end of
+            # the boundary's own turn (hidden 0: the drain's last)
+            assert all(k["t0"] >= b["t1"] for k in second)
+            runs = [r for r in recs if r["name"] == "segment.run"
+                    and r["t0"] <= second[0]["t0"]
+                    and second[-1]["t1"] <= r["t1"]]
+            assert len(runs) == b["counts"]["hidden"]
+            turn = next(r for r in recs if r["id"] == b["parent"])
+            if not runs:
+                assert second[-1]["t1"] <= turn["t1"]
+            else:
+                assert runs[0]["parent"] != turn["id"]
             # the state is reset where it lies: no placement from the
             # host under .place
             assert not [r for r in recs
                         if r["parent"] == by["place"]["id"]]
             assert set(b["counts"]) == {"worked", "retired", "filled",
                                         "occupied", "queued",
-                                        "family"}
+                                        "family", "hidden"}
             assert b["counts"]["family"] == "push"
+        assert [b["counts"]["hidden"] for b in worked] \
+            == [1] * (len(worked) - 1) + [0]
         assert not [r for r in recs
                     if r["name"] in ("state.place", pre + "pad")]
         for b in idle:      # neither retired nor refilled
             assert [r["name"] for r in recs if r["parent"] == b["id"]] \
                 == [pre + "counts"]
             assert b["counts"]["retired"] == b["counts"]["filled"] == 0
+            assert "hidden" not in b["counts"]
         # one converge dispatch per segment leaves one mark, and the
         # driver's recount after a replaced state is spanned too
         assert sum(r["name"] == "push.converge" for r in recs) \
@@ -221,16 +242,20 @@ class TestBoundarySpans:
             by = {k["name"][len(pre):]: k for k in kids}
             retired, filled = b["counts"]["retired"], b["counts"]["filled"]
             assert list(by) == (
-                ["residual"] + ["fetch", "unpad"] * bool(retired)
-                + ["retire", "fill"] + ["place"] * bool(filled))
+                ["residual"] + ["take"] * bool(retired)
+                + ["fill"] + ["place"] * bool(filled)
+                + ["fetch", "unpad", "retire"] * bool(retired))
             if retired:
                 assert by["fetch"]["counts"]["bytes"] == retired * column
+                assert by["fetch"]["t0"] >= b["t1"]
+                assert by["place" if filled else "fill"]["t1"] <= b["t1"]
             if filled:
                 assert 0 < by["place"]["counts"]["bytes"] < 1024
                 assert not [r for r in recs
                             if r["parent"] == by["place"]["id"]]
             assert b["counts"]["worked"] == int(bool(retired or filled))
             assert b["counts"]["family"] == "pull"
+            assert ("hidden" in b["counts"]) == bool(retired or filled)
         assert not [r for r in recs
                     if r["name"] in ("state.place", pre + "pad")]
 
@@ -832,11 +857,14 @@ class TestTurns:
             srv = serve.Server(gt, batch=2, num_parts=2, seg_iters=2)
             submit_all(srv, [(kind, s) for s in sources])
             runner, coll = srv._runner(kind), srv._collector(kind)
-            got = list(runner.turn(coll))
+            runner.turn(coll)
             while runner.resident:
                 srv.submit(other, source=7)
+                # the other runner's first dispatch is what this
+                # one's last boundary answers behind (PR 41)
                 srv._runner(other).drain(srv._collector(other))
-                got += runner.turn(coll)
+                runner.turn(coll)
+            got = runner.responses
         assert [(r.source, r.iters, r.segments) for r in got] == \
             [(r.source, r.iters, r.segments) for r in want]
         for r, w in zip(got, want):

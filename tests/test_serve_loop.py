@@ -1,7 +1,8 @@
 """``serve.Server.serve``: the serving loop that answers at retirement.
 
 - an open loop (a submitter thread, the loop on this thread): every
-  query delivered exactly once, before the next turn starts, answers
+  query delivered exactly once, the moment its answer is made (no
+  turn starts between ``query_done`` and the hand-over), answers
   equal to the oracle, columns taken in submit order;
 - ``run()`` is the loop with the stop given: the responses and their
   order are those of the drain loop before it (kept here as the
@@ -110,18 +111,30 @@ def test_open_loop_delivers_each_query_once_at_its_turn(g, gap_s):
     assert serve._check_answers(g, caller.responses) == 0
     # columns go to the queries in the order they were submitted
     assert caller.started == sorted(caller.started)
-    # each batch came from ONE turn, the last that ended before it,
-    # and no later turn had started when the caller held it
+    # each batch is one boundary's answers, handed over inside the
+    # turn that made them (PR 41: the turn AFTER the one that took
+    # the columns, behind its dispatch; the boundary's own turn where
+    # no dispatch followed): no turn starts between a query_done and
+    # the instant the caller held the response
     turns = sorted((r["t0"], r["t1"]) for r in _since(tip, "serve.turn."))
     assert turns
     for t_got, batch in caller.batches:
-        ended = [i for i, (_s, e) in enumerate(turns) if e <= t_got]
-        assert ended, "delivered before any turn ended"
-        t0, t1 = turns[ended[-1]]
+        inside = [(s, e) for s, e in turns if s <= t_got <= e]
+        assert len(inside) == 1, "delivered outside any turn"
+        t0, t1 = inside[0]
         for qid in batch:
-            assert t0 <= caller.done[qid] <= t1
-        if ended[-1] + 1 < len(turns):
-            assert turns[ended[-1] + 1][0] >= t_got
+            assert t0 <= caller.done[qid] <= t_got
+    # the answers' work lies behind a dispatch wherever one followed
+    bounds = [b for b in _since(tip, "serve.boundary")
+              if b["name"] == "serve.boundary"
+              and b["counts"]["retired"]]
+    assert sum(b["counts"]["retired"] for b in bounds) == len(specs)
+    turn_of = {r["id"]: r for r in _since(tip, "serve.turn.")}
+    for b in bounds:
+        fetch = next(r for r in _since(tip, "serve.boundary.fetch")
+                     if r["parent"] == b["id"])
+        own = turn_of[b["parent"]]
+        assert b["counts"]["hidden"] == int(fetch["t0"] > own["t1"])
     # one hand-over span a delivery, counting what it carried
     spans = _since(tip, "serve.deliver")
     assert [s["counts"]["responses"] for s in spans] \
@@ -142,11 +155,16 @@ def _parent_run(srv):
                                   and runner.resident)):
                 continue
             runner = srv._runner(kind)
-            out += runner.turn(
+            runner.turn(
                 coll, srv.deadline_s,
                 switch=srv._last_turn not in (None, runner))
             srv._last_turn = runner
             served = True
+            # PR 41: a boundary's answers are made behind the NEXT
+            # dispatch, whichever runner's: take what is ready
+            for r in srv._runners.values():
+                out += r.responses
+                del r.responses[:]
     return out
 
 
