@@ -155,7 +155,7 @@ DEFAULT_SHAPE = {"pagerank": (21, 16), "cc": (20, 16),
                  # (scripts/check_bench.py rejects the contradictions:
                  # p99 < p50, achieved > offered, fraction outside
                  # [0, 1]).  The on-device run is carried as debt
-                 # serve-slo-on-device (lux_tpu/observe.py).
+                 # serve-slo-on-device (PERF.md section 7).
                  "serve-slo": (12, 8),
                  # serving-tier chaos lines (round 18,
                  # lux_tpu/fleet.py): `-config serve-chaos` runs the
@@ -190,7 +190,7 @@ DEFAULT_SHAPE = {"pagerank": (21, 16), "cc": (20, 16),
                  # mutations, hit fraction outside [0, 1], a
                  # compaction count with delta occupancy never past
                  # threshold).  The on-device run is carried as debt
-                 # live-mutation-on-device (lux_tpu/observe.py).
+                 # live-mutation-on-device (PERF.md section 7).
                  "serve-live": (12, 8)}
 
 # the batch-sweep expansion (one metric line per B per app)
@@ -890,7 +890,7 @@ def run_config(config, args):
         # paths (the modeled step-change); scripts/check_bench.py
         # validates mode-vs-name and rejects an mxu line whose
         # paired vpu baseline is missing from the artifact.  The
-        # real-TPU run is debt mxu-core-ab (lux_tpu/observe.py).
+        # real-TPU run is debt mxu-core-ab (PERF.md section 7).
         from lux_tpu import scalemodel
         from lux_tpu.apps import pagerank
         from lux_tpu.convert import community_graph

@@ -29,10 +29,8 @@ Layout:
   check.py      fixed-point correctness audits (the reference's -check)
   audit.py      compile-time program auditor (jaxpr invariant checks;
                 repo-wide: python -m lux_tpu.audit)
-  observe.py    performance observatory: session-calibration probe,
-                phase-cost attribution vs scalemodel, persistent perf
-                ledger + carried-debt registry
-                (report: python -m lux_tpu.observe)
+  observe.py    session-calibration probe, link calibration and
+                bench.py's ledger
   livegraph.py  live graphs: CRC-chained mutation WAL, snapshot-
                 isolated epochs, incremental revalidation, chaos-
                 drilled compaction (round 20, ROADMAP item 4)

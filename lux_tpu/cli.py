@@ -45,7 +45,6 @@ Flags (reference names kept):
                 observe.py): prints/emits the fingerprint (measured
                 probe ns/elem vs canonical, platform, ndev, grade) —
                 an off-canon session is labeled up front.
-                Phase-decomposition report: python -m lux_tpu.observe
 
 Timing methodology matches the reference: wall clock around the
 iteration loop only, printed as ``ELAPSED TIME = ... s`` plus GTEPS
@@ -62,7 +61,6 @@ import time
 import numpy as np
 
 
-from lux_tpu.timing import fetch as _fetch
 from lux_tpu.timing import timed_converge, timed_fused_run
 
 
@@ -225,13 +223,6 @@ def _common(ap: argparse.ArgumentParser):
                          "neither changes the timed path's shape nor "
                          "adds host syncs, and it composes with "
                          "-retries/-seg-budget segment runs")
-    ap.add_argument("-phases", type=int, default=0, metavar="N",
-                    help="after the timed run, run N instrumented "
-                         "iterations and print the per-iteration "
-                         "phase split (gather/reduce/exchange/apply; "
-                         "separate fenced programs — read relative "
-                         "weights, not GTEPS; iter 0 includes "
-                         "compilation)")
     ap.add_argument("-flight", default=None, metavar="FILE",
                     help="install the crash flight recorder "
                          "(lux_tpu/tracing.py): a bounded ring of "
@@ -302,25 +293,6 @@ def _mesh_and_parts(args):
               f"(must divide the {args.mesh}-device mesh)")
         num_parts = rounded
     return mesh, num_parts
-
-
-def _print_phases(report, tel=None):
-    """Per-iteration phase table — the analogue of the reference's
-    -verbose per-iteration loadTime/compTime/updateTime prints
-    (reference sssp_gpu.cu:513-518).  With a telemetry handle the
-    table also lands in the event log as one ``phases`` event, which
-    scripts/events_summary.py renders back into the reference-style
-    table."""
-    META = ("frontier", "bucket", "advances")   # counters, not times
-    for i, t in enumerate(report):
-        extra = "".join(f" {k}={t[k]:g}" for k in META if k in t)
-        split = "  ".join(f"{k}={v * 1e3:7.2f}ms" for k, v in t.items()
-                          if k not in META)
-        print(f"iter {i}:{extra}  {split}")
-    if tel is not None:
-        tel.emit("phases", iters=len(report),
-                 report=[{k: (v if k in META else round(v, 6))
-                          for k, v in t.items()} for t in report])
 
 
 def _batched_sources(args, nv: int):
@@ -723,9 +695,6 @@ def cmd_pagerank(argv):
                 _print_batch(sources, g.ne, ni, elapsed)
             _finish_run(tel, elapsed, total)
 
-        if args.phases:
-            _state, rep = eng.timed_phases(eng.init_state(), args.phases)
-            _print_phases(rep, tel)
         if sources is not None and args.check:
             # per-column device_check rides the batch-sweep debt
             print("note: -check does not support batched runs yet; "
@@ -826,15 +795,9 @@ def _push_app(argv, prog_name):
             _print_batch(sources, g.ne, it_exec, elapsed)
         _finish_run(tel, elapsed, iters)
 
-        if args.phases:
-            lab0, act0 = eng.init_state()
-            _l, _a, rep = eng.timed_phases(lab0, act0, args.phases)
-            _print_phases(rep, tel)
         if sources is not None and args.check:
             # per-column device_check needs the batched fixed-point
-            # audits (carried with the on-device batch sweep debt,
-            # lux_tpu/observe.py); the oracle proofs live in
-            # tests/test_batched.py
+            # audits; the oracle proofs live in tests/test_batched.py
             print("note: -check does not support batched runs yet; "
                   "skipped")
             return 0
@@ -914,9 +877,6 @@ def cmd_colfilter(argv):
         # rmse is computed over edges, so the relabeled graph is the
         # matching — and equivalent — choice
         print(f"RMSE = {colfilter.rmse(g_run, out):.6f}")
-        if args.phases:
-            _state, rep = eng.timed_phases(eng.init_state(), args.phases)
-            _print_phases(rep, tel)
         if args.check:
             from lux_tpu.device_check import check_colfilter_device
             res = check_colfilter_device(sg, out, mesh=eng.mesh)

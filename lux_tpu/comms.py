@@ -27,15 +27,10 @@ attribute / persist) in three pillars:
    disagreement raises the typed ``CommLedgerError``.
 
 2. **Measured link calibration** (lux_tpu/observe.py
-   ``calibrate_links`` + the ici/dcn bandwidth debts): ppermute-ring
-   and all_to_all payload sweeps on the trusted ``timing.loop_bench``
-   recipe feed measured link bytes/s into
-   ``scalemodel.set_measured_link``, replacing the hardcoded
-   ICI_BYTES_PER_S in the mesh projections; ``observe.decompose``
-   grades a comm-attribution verdict (measured exchange-phase time
-   vs ledger-bytes / measured-bandwidth — the wire time is a LOWER
-   bound on the phase, so a phase faster than its own bytes is a
-   contradiction).
+   ``calibrate_links``): ppermute-ring and all_to_all payload sweeps
+   on the trusted ``timing.loop_bench`` recipe feed measured link
+   bytes/s into ``scalemodel.set_measured_link``, replacing the
+   hardcoded ICI_BYTES_PER_S in the mesh projections.
 
 3. **Pod-scale forecaster** (``python -m lux_tpu.comms -project``):
    the item-3 decision table — per flagship shape, comm/compute

@@ -368,12 +368,6 @@ class PullEngine(AuditableEngine):
                    "_run_until_stats", "_run_health_fused",
                    "_run_until_health")
 
-    # timed_phases phases whose measured seconds CONTAIN the step's
-    # collectives — the comm observatory's attribution anchor
-    # (lux_tpu/comms.py; observe._comm_attribution grades the wire
-    # lower bound against exactly these phases)
-    COMM_PHASES = ("exchange", "gen_exchange")
-
     @functools.cached_property
     def _audit_state_sds(self):
         """Abstract stand-in for the iterated state (shape and dtype,
@@ -731,133 +725,6 @@ class PullEngine(AuditableEngine):
                 out = self.sg.from_padded(host)
                 unpad.count(bytes=out.nbytes)
             return out
-
-    # -- per-iteration phase observability ----------------------------
-
-    @functools.cached_property
-    def _phase_jits(self):
-        """One compiled program per phase (exchange / gather / reduce /
-        apply), each returning (output, scalar checksum) — the scalar
-        fetch is the O(1)-byte completion fence.  Separate
-        executables deliberately prevent cross-phase fusion, so the
-        split is honest at the cost of materializing phase outputs.
-
-        The phases follow the delivery's form (engine/delivery.py):
-        a fused delivery (streamed chunks, paged rows) times as ONE
-        'gather_reduce' phase and the dot path's src gather, MXU tile
-        dots and one-hot reduction as ONE 'dot_reduce', so the report
-        reflects what the compiled step runs (and stays within the
-        memory bound streaming exists for); owner mode has no
-        separable gather: generation (scan over source parts,
-        small-shard gathers) and the reduce_scatter exchange are one
-        'gen_exchange' phase, and the pair rows' all_gather rides
-        'apply'.  Dot-path programs on the FLAT layout run, and time
-        as, the generic gather / reduce pipeline."""
-        from lux_tpu.engine.phased import cksum, mesh_wrap
-
-        keys = self._graph_keys
-        sg, d, prog = self.sg, self.delivery, self.program
-        msg = self._msg
-        P = PartitionSpec
-        S, R = P(PARTS_AXIS), P()   # sharded over parts / replicated
-
-        def exchange(state, g):
-            full = state
-            if self.mesh is not None:
-                full = jax.lax.all_gather(state, PARTS_AXIS, tiled=True)
-            return full.reshape((sg.num_parts * sg.vpad,) +
-                                full.shape[2:])
-
-        def apply(state, red, g):
-            return jax.vmap(self._apply_epilogue)(state, red, g)
-
-        # name -> (fn(*inputs, g), in_specs, out_spec under the mesh)
-        if self.exchange == "owner":
-            phases = dict(
-                gen_exchange=(
-                    lambda state, g: d.owner_generate(state, msg, g),
-                    (S,), S),
-                apply=(
-                    lambda state, red, g: apply(
-                        state, d.owner_pairs(red, state, msg, g), g),
-                    (S, S), S))
-        else:
-            if d.dot_path:
-                mid = dict(dot_reduce=(
-                    lambda flat, state, g: jax.vmap(
-                        lambda old, gp: d.reduce_dot(
-                            flat, prog.edge_value_from_dot, gp, old))(
-                        state, g),
-                    (R, S), S))
-            elif d.fused:
-                mid = dict(gather_reduce=(
-                    lambda flat, state, g: jax.vmap(
-                        lambda gp: d.reduce_fused(flat, msg, gp))(g),
-                    (R, S), S))
-            else:
-                mid = dict(
-                    gather=(
-                        lambda flat, state, g: jax.vmap(
-                            lambda old, gp: self._part_msgs(
-                                flat, old, gp))(state, g),
-                        (R, S), S),
-                    reduce=(
-                        lambda flat, msgs, g: jax.vmap(
-                            lambda m, gp: d.reduce(flat, m, msg, gp))(
-                            msgs, g),
-                        (R, S), S))
-            phases = dict(exchange=(exchange, (S,), R), **mid,
-                          apply=(apply, (S, S), S))
-
-        def fenced(fn, n_in):
-            def run(*a):
-                out = fn(*a[:n_in], dict(zip(keys, a[n_in:])))
-                return out, cksum(out)
-            return run
-
-        fns = {name: fenced(fn, len(ins))
-               for name, (fn, ins, _) in phases.items()}
-        if self.mesh is not None:
-            wrap = mesh_wrap(self.mesh, len(keys), S, R)
-            fns = {name: wrap(fns[name], ins, out)
-                   for name, (_, ins, out) in phases.items()}
-        return {k: jax.jit(f) for k, f in fns.items()}
-
-    def timed_phases(self, state, iters: int = 1):
-        """Instrumented stepwise iterations -> (state, [{phase: s}]).
-
-        The analogue of the reference's per-iteration per-part
-        loadTime/compTime/updateTime -verbose prints (reference
-        sssp_gpu.cu:513-518).  Phases run as SEPARATE fenced programs
-        (engine/phased.py), so absolute times carry dispatch overhead
-        the fused run does not; read them for relative weight, not for
-        GTEPS."""
-        from lux_tpu.engine.phased import PhaseTimer
-        from lux_tpu.timing import fetch
-        jits = self._phase_jits
-        gargs = self.graph_args
-        report = []
-        for _ in range(iters):
-            pt = PhaseTimer(fetch)
-            if "gen_exchange" in jits:    # owner exchange: two phases
-                red = pt("gen_exchange", jits["gen_exchange"], state,
-                         *gargs)
-                state = pt("apply", jits["apply"], state, red, *gargs)
-                report.append(pt.t)
-                continue
-            flat = pt("exchange", jits["exchange"], state, *gargs)
-            if "dot_reduce" in jits:      # dot path: one reduce phase
-                red = pt("dot_reduce", jits["dot_reduce"], flat,
-                         state, *gargs)
-            elif "gather_reduce" in jits:  # streamed step: one phase
-                red = pt("gather_reduce", jits["gather_reduce"], flat,
-                         state, *gargs)
-            else:
-                msgs = pt("gather", jits["gather"], flat, state, *gargs)
-                red = pt("reduce", jits["reduce"], flat, msgs, *gargs)
-            state = pt("apply", jits["apply"], state, red, *gargs)
-            report.append(pt.t)
-        return state, report
 
 
 def _check_local_parts(sg, mesh, pair_threshold):

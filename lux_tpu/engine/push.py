@@ -1022,9 +1022,8 @@ class PushEngine(AuditableEngine):
                     def relax(it, lbl, act, B, *buf):
                         if stats:
                             # counters record the bucket front ENTERING
-                            # this relax — the series timed_phases'
-                            # delta schedule reports; advances relax
-                            # nothing and write no entry.  The scalar
+                            # this relax; advances relax nothing
+                            # and write no entry.  The scalar
                             # edges entry is the sum of the per-part
                             # row (bitwise, uint32 either way).
                             fsz, fed, fszp, fedp = buf[:4]
@@ -1250,14 +1249,6 @@ class PushEngine(AuditableEngine):
 
     _AUDIT_LAZY = ("_converge_stats_fn", "_converge_health_fn")
 
-    # timed_phases phases whose measured seconds CONTAIN the dense
-    # iteration's collectives (label/active all_gather rides the
-    # exchange phase, the owner routing rides gen_exchange; sparse
-    # queue exchanges are timed as one whole program and carry no
-    # phase split) — the comm observatory's attribution anchor
-    # (lux_tpu/comms.py, observe._comm_attribution)
-    COMM_PHASES = ("exchange", "gen_exchange")
-
     @functools.cached_property
     def _audit_state_sds(self):
         """Abstract (label, active) stand-ins — init runs ONCE per
@@ -1400,93 +1391,6 @@ class PushEngine(AuditableEngine):
                 unpad.count(bytes=out.nbytes)
             return out
 
-    # -- per-iteration phase observability ----------------------------
-
-    @functools.cached_property
-    def _phase_jits(self):
-        """Per-phase compiled programs for DENSE iterations (exchange /
-        relax / reduce / update), each returning (output, scalar fence)
-        — see PullEngine._phase_jits.  Sparse iterations are timed as
-        one program (their latency is queue-sized, not phase-bound)."""
-        from lux_tpu.engine.phased import cksum, mesh_wrap
-
-        keys = sorted(self.arrays)
-
-        def gdict(gargs):
-            return self._dense_g(dict(zip(keys, gargs)))
-
-        def count(improved):
-            # the fence doubles as the NEW global frontier count (psum'd
-            # under the mesh wrap's pmin — identical on every device).
-            # int32 keeps it exact past 2^24 active vertices (float32
-            # would round, misreporting 'frontier' and possibly the
-            # next iteration's sparse/dense classification)
-            cnt = jnp.sum(improved.astype(jnp.int32))
-            if self.mesh is not None:
-                cnt = jax.lax.psum(cnt, PARTS_AXIS)
-            return cnt
-
-        def gen_exchange(label, active, *gargs):
-            # owner mode has no separable gather phase: generation
-            # (scan over source parts) + reduce_scatter + update are
-            # one fused phase
-            new, improved = self._dense_parts_owner(
-                label, active, dict(zip(keys, gargs)))
-            return (new, improved), count(improved)
-
-        def exchange(label, active, *gargs):
-            full_l, full_a = label, active
-            if self.mesh is not None:
-                full_l = jax.lax.all_gather(label, PARTS_AXIS, tiled=True)
-                full_a = jax.lax.all_gather(active, PARTS_AXIS,
-                                            tiled=True)
-            flat_l = self._dense_flat(full_l, full_a)
-            return flat_l, cksum(flat_l)
-
-        def relax(flat_l, *gargs):
-            cand = jax.vmap(
-                lambda gp: self._dense_cand(flat_l, gp))(gdict(gargs))
-            return cand, cksum(cand)
-
-        def reduce(flat_l, cand, *gargs):
-            red = jax.vmap(
-                lambda c, gp: self._dense_red(flat_l, c, gp))(
-                cand, gdict(gargs))
-            return red, cksum(red)
-
-        def relax_reduce(flat_l, *gargs):
-            # a fused delivery (streamed chunk blocks, paged rows) is
-            # ONE phase, so the report matches the compiled step (and
-            # keeps its memory bound)
-            red = jax.vmap(
-                lambda gp: self._dense_red(flat_l, None, gp))(
-                gdict(gargs))
-            return red, cksum(red)
-
-        def update(label, red, *gargs):
-            new, improved = jax.vmap(self._dense_update)(
-                label, red, gdict(gargs))
-            return (new, improved), count(improved)
-
-        P = PartitionSpec
-        S, R = P(PARTS_AXIS), P()
-        # name -> (fn, in_specs, out_spec) under the mesh wrap
-        if self.exchange == "owner":
-            phases = dict(gen_exchange=(gen_exchange, (S, S), (S, S)))
-        else:
-            mid = (dict(relax_reduce=(relax_reduce, (R,), S))
-                   if self.delivery.fused else
-                   dict(relax=(relax, (R,), S),
-                        reduce=(reduce, (R, S), S)))
-            phases = dict(exchange=(exchange, (S, S), R), **mid,
-                          update=(update, (S, S), (S, S)))
-        fns = {name: fn for name, (fn, _, _) in phases.items()}
-        if self.mesh is not None:
-            wrap = mesh_wrap(self.mesh, len(keys), S, R)
-            fns = {name: wrap(*phase)
-                   for name, phase in phases.items()}
-        return {k: jax.jit(f) for k, f in fns.items()}
-
     def _sparse_mode(self):
         """Single source of truth for the per-iteration choice (traced
         inside the compiled step by _choose): returns (usable,
@@ -1501,161 +1405,3 @@ class PushEngine(AuditableEngine):
                     max(1, self.sg.nv // self.sparse_threshold)) \
             if self.enable_sparse else 0
         return usable, limit, self.pull
-
-    @functools.cached_property
-    def _runs_sparse(self):
-        """(label, active, *graph arrays) -> _choose's ``sparse`` as a
-        program of its own, for the stepwise twin of the fused loop
-        (_relax_once)."""
-        keys = sorted(self.arrays)
-
-        def runs_sparse(label, active, *gargs):
-            count = jnp.sum(active.astype(jnp.int32))
-            if self.mesh is not None:
-                count = jax.lax.psum(count, PARTS_AXIS)
-            return self._choose(label, active, count,
-                                dict(zip(keys, gargs)))[0]
-
-        if self.mesh is not None:
-            S = PartitionSpec(PARTS_AXIS)
-            runs_sparse = jax.shard_map(
-                runs_sparse, mesh=self.mesh,
-                in_specs=(S,) * (2 + len(keys)),
-                out_specs=PartitionSpec())
-        return jax.jit(runs_sparse)
-
-    def _relax_once(self, label, active, cnt, t, jits, gargs):
-        """One instrumented relaxation of ``active``, recording phase
-        seconds into ``t``.  Returns (label, na, new_count) where
-        ``na`` is the raw improvement/queue-residue mask — the plain
-        schedule uses it as the next frontier directly; the delta
-        schedule merges it into its own active set."""
-        import time as _time
-
-        from lux_tpu.engine.phased import PhaseTimer
-        from lux_tpu.timing import fetch
-
-        use_sparse, sparse_limit, pull = self._sparse_mode()
-        if use_sparse and (cnt <= sparse_limit or (pull and bool(
-                fetch(self._runs_sparse(label, active, *gargs))))):
-            t0 = _time.perf_counter()
-            label, na, c = self.step(label, active)
-            cnt = int(fetch(c))
-            t["sparse"] = _time.perf_counter() - t0
-            return label, na, cnt
-        pt = PhaseTimer(fetch)
-        pt.t = t
-        if "gen_exchange" in jits:            # owner dense: one phase
-            label, na = pt("gen_exchange", jits["gen_exchange"],
-                           label, active, *gargs)
-            return label, na, int(pt.last_fence)
-        flat_l = pt("exchange", jits["exchange"], label, active, *gargs)
-        if "relax_reduce" in jits:            # streamed: one phase
-            red = pt("relax_reduce", jits["relax_reduce"], flat_l,
-                     *gargs)
-        else:
-            cand = pt("relax", jits["relax"], flat_l, *gargs)
-            red = pt("reduce", jits["reduce"], flat_l, cand, *gargs)
-        label, na = pt("update", jits["update"], label, red, *gargs)
-        return label, na, int(pt.last_fence)  # update fence = count
-
-    def timed_phases(self, label, active, iters: int = 1):
-        """Instrumented stepwise iterations -> (label, active,
-        [{phase: seconds, 'frontier': count}]) — the analogue of the
-        reference's per-iteration per-part loadTime/compTime/updateTime
-        prints (reference sssp_gpu.cu:513-518).  Dense iterations split
-        into exchange/relax/reduce/update (owner exchange:
-        gen_exchange); iterations the engine would run sparse are timed
-        as one 'sparse' entry.  Delta engines instrument the ACTUAL
-        delta-stepping bucket schedule (each entry also records the
-        bucket bound and how many relax-free bucket advances preceded
-        it).  Separate fenced programs: use for relative weight, not
-        GTEPS."""
-        from lux_tpu.timing import fetch
-        jits = self._phase_jits
-        gargs = tuple(self.arrays[k] for k in sorted(self.arrays))
-        if self.delta is not None:
-            return self._timed_phases_delta(label, active, iters, jits,
-                                            gargs)
-        count = jax.jit(lambda a: jnp.sum(a.astype(jnp.int32)))
-        report = []
-        cnt = int(fetch(count(active)))
-        for _ in range(iters):
-            t = {"frontier": cnt}
-            label, active, cnt = self._relax_once(label, active, cnt,
-                                                  t, jits, gargs)
-            report.append(t)
-        return label, active, report
-
-    def _timed_phases_delta(self, label, active, iters, jits, gargs):
-        """Instrumented DELTA-STEPPING iterations: replicates the
-        compiled converge's bucket schedule (relax the current bucket
-        [*, B); advance B past the active minimum when the bucket
-        frontier empties) with host-orchestrated fenced phases —
-        closing the round-2 observability hole where -phases timed a
-        different algorithm than the delta bench ran."""
-        from lux_tpu.timing import fetch
-
-        prog = self.program
-        ident = prog.identity
-        ldt = np.asarray(ident).dtype
-        delta_v = np.asarray(self.delta, ldt)
-
-        @jax.jit
-        def act_stats(lbl, act):
-            am = jnp.min(jnp.where(act, lbl, jnp.asarray(ident,
-                                                         lbl.dtype)))
-            return am, jnp.sum(act.astype(jnp.int32))
-
-        @jax.jit
-        def front_of(lbl, act, B):
-            front = act & (lbl < B)
-            return front, jnp.sum(front.astype(jnp.int32))
-
-        # split the merge around the relax: the sparse step DONATES
-        # its active (= front) buffer, so compute act & ~front before
-        # relaxing and OR the improvements in after
-        @jax.jit
-        def without_front(act, front):
-            return act & ~front
-
-        @jax.jit
-        def with_improved(act_wo, na):
-            return act_wo | na
-
-        def advance(am):
-            # strict progress, exactly like the compiled path
-            nb = am + delta_v
-            if np.issubdtype(ldt, np.inexact):
-                nb = max(nb, np.nextafter(am, np.asarray(np.inf, ldt)))
-            return np.asarray(nb, ldt)
-
-        report = []
-        am, tot = (np.asarray(fetch(x)) for x in act_stats(label,
-                                                           active))
-        B = advance(am)
-        n_adv = 0
-        it = 0
-        while it < iters and int(tot) > 0:
-            front, cnt = front_of(label, active, jnp.asarray(B, ldt))
-            cnt = int(fetch(cnt))
-            if cnt == 0:
-                am, tot = (np.asarray(fetch(x))
-                           for x in act_stats(label, active))
-                if int(tot) == 0:
-                    break
-                B = advance(am)
-                n_adv += 1
-                continue
-            t = {"frontier": cnt, "bucket": float(B),
-                 "advances": n_adv}
-            n_adv = 0
-            act_wo = without_front(active, front)
-            label, na, _c = self._relax_once(label, front, cnt, t,
-                                             jits, gargs)
-            active = with_improved(act_wo, na)
-            _am, tot = (np.asarray(fetch(x))
-                        for x in act_stats(label, active))
-            report.append(t)
-            it += 1
-        return label, active, report

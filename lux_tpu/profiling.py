@@ -10,13 +10,18 @@ pagerank.cc:108-118).  The TPU-native equivalents:
   ``jax.profiler.TraceAnnotation`` wrappers — the only place in the
   package that touches that class.  ``telemetry.span`` opens
   ``annotation("lux:" + name)`` round every host region the program
-  times; the run paths (timing.py, segmented.py, checkpoint.py,
-  engine/phased.py) put them around their iterate / segment /
-  checkpoint regions, so a captured trace shows named regions instead
-  of anonymous XLA ops; the engines' traced
-  code additionally carries ``jax.named_scope`` labels (lux_exchange /
-  lux_gather / lux_reduce / lux_apply, push: lux_relax / lux_update /
-  lux_sparse) that name the device-side ops themselves.
+  times; the run paths (timing.py, segmented.py, checkpoint.py) put
+  them around their iterate / segment / checkpoint regions, so a
+  captured trace shows named regions instead of anonymous XLA ops.
+
+Where an iteration's time goes is read from the device side of such a
+trace: the engines' traced code carries ``jax.named_scope`` labels
+(lux_exchange / lux_gather / lux_reduce / lux_apply, push: lux_relax /
+lux_update / lux_dense / lux_sparse, the deliveries' lux_gather_reduce
+/ lux_combine / lux_dot_* / lux_gen_exchange) that name the ops of the
+program that runs.  ``benchmarks/trace_reduce.py`` sums a trace's
+milliseconds by scope, and ``tests/test_scopes.py`` holds every scope
+a ``scope_ms`` metric names to the lowered programs.
 """
 
 from __future__ import annotations

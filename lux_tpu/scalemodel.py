@@ -103,7 +103,7 @@ LANE_SHUFFLE_NS = 0.38         # per element, 128-wide lane shuffle
 # 150 ns fetch + compare-reduce machinery (same row shape, same
 # combine) PLUS the 128-lane shuffle the paged row adds.  MODELED
 # from measured primitive costs, not yet measured end-to-end on
-# device — the owed A/B is observe.DEBTS "paged-gather-ab".
+# device (PERF.md section 7, "paged-gather-ab").
 PAGED_ROW_NS = PAIR_ROW_NS + 128 * LANE_SHUFFLE_NS     # = 198.64
 # K-dim (SDDMM) paged rows run THREE 128x128xK MXU contractions
 # (one-hot lane shuffle + D = S @ T^T + the gradient matmul) where
@@ -186,8 +186,8 @@ def page_break_even_ratio(fill: float, table_bytes: float = 0.0,
 # page-major layout pays fetch+shuffle per FULL gather row and the
 # remainder per (low-fill) virtual row, plus one extra 24 ns take
 # binding each virtual row to its gather row's delivered values.
-# MODELED from the measured primitive costs like PAGED_ROW_NS —
-# the owed on-device split is observe.DEBTS "pagemajor-route-ab".
+# MODELED from the measured primitive costs like PAGED_ROW_NS
+# (PERF.md section 7, "pagemajor-route-ab").
 VROW_REDUCE_NS = PAIR_ROW_NS - PAGE_ROW_FETCH_NS       # = 126.0
 
 
@@ -263,8 +263,8 @@ def pagemajor_break_even_vfill(page_ratio: float = 1.0,
 # per-row toll to MATERIALIZE the [E, W] int8 one-hot (the pair row's
 # fetch-shaped cost — modeled at the measured 150 ns pair-row
 # machinery + its 0.19 ns/B int8 store, NOT yet measured on device:
-# observe.DEBTS "mxu-core-ab"), after which each payload slice is one
-# 128x128 int8 systolic pass (~2 ns at the MXU int8 rate).  min/max
+# PERF.md section 7, "mxu-core-ab"), after which each payload slice
+# is one 128x128 int8 systolic pass (~2 ns at the MXU int8 rate).  min/max
 # replay that contraction 2x per ORDER BIT (vote + candidacy
 # route-back, tiled._mxu_compare_reduce), which is why compare kinds
 # essentially never auto-engage — the resolver is deliberately
@@ -336,7 +336,7 @@ def resolve_use_mxu(kind: str, wide: int = 1, nbits: int = 32) -> bool:
 # state row per edge instead of one element — the fetch is
 # latency-bound, so the extra lanes ride at roughly the wide-row rate
 # (modeled from the measured 150 ns / 128-lane pair-row fetch, NOT
-# yet swept on-device: observe.DEBTS "batch-sweep-on-device").
+# yet swept on-device: PERF.md section 7, "batch-sweep-on-device").
 BATCH_LANE_NS = PAIR_ROW_NS / 128.0      # ~1.17 ns per extra lane
 
 
@@ -383,8 +383,7 @@ STATE_NS_PER_VERTEX = 6.0  # apply + epilogues, per padded vertex
 ICI_BYTES_PER_S = 4.5e10
 # DCN: inter-slice links are 10-100x thinner than ICI (ROADMAP item
 # 3); no canonical figure exists yet, so the model carries the
-# midpoint thinness until a multi-slice session collects the
-# dcn-bandwidth-probe debt (lux_tpu/observe.py DEBTS).
+# midpoint thinness: no multi-slice session has measured it.
 DCN_THINNESS_MODEL = 30.0
 
 # Quantized-exchange wire factors (EQuARX-style in-collective block
@@ -539,13 +538,13 @@ def phase_model(*, engine: str, exchange: str, ne: int, nv: int,
                 mxu_wide: int = 1,
                 reduce_kind: str = "sum",
                 state_nbits: int = 32) -> dict:
-    """Per-PHASE predicted nanoseconds for ONE engine iteration — the
-    model side of the observatory's measured-vs-model drift check
-    (lux_tpu/observe.py).  Keys match the engines' ``timed_phases``
-    phase names; a value of None means the phase has no measured
-    constant to price it (verdict "unmodeled" downstream) — honesty
-    over coverage, per the round-3 rule that un-measured figures are
-    flagged models.
+    """Per-PHASE predicted nanoseconds for ONE engine iteration
+    (``bench.py``'s ``model_ns`` through ``observe._engine_model``).
+    Keys are the step's phases (exchange / gather / reduce / apply,
+    owner ``gen_exchange``, push relax / update, ``dot_reduce``); a
+    value of None means the phase has no measured constant to price
+    it — honesty over coverage, per the round-3 rule that un-measured
+    figures are flagged models.
 
     ``scale`` rescales every priced constant by the session
     calibration factor (observe.session_scale: this session's measured
@@ -570,8 +569,6 @@ def phase_model(*, engine: str, exchange: str, ne: int, nv: int,
                          with ``use_mxu`` the one-hot contraction IS
                          modeled (mxu_reduce_row_ns over the chunk
                          rows at ``mxu_wide`` = K x B payload slices)
-                         — the per-phase A/B the round-23 port owes
-                         observe.decompose
     """
     if engine not in ("pull", "push"):
         raise ValueError(f"unknown engine {engine!r}")

@@ -94,8 +94,8 @@ programs of fixed shape, and so are the pull runner's per-column
 residual (``_column_residuals``; 4 B a column come to the host) and
 its live-graph correction, so the ``[nv, B]`` state never crosses
 (push: PR 25; pull: PR 27).  Only a query that brings its own reset
-vector uploads that one column.  The on-device batch sweep is a
-carried debt (lux_tpu/observe.py DEBTS "batch-sweep-on-device").
+vector uploads that one column.  The on-device batch sweep is owed
+(PERF.md section 7, "batch-sweep-on-device").
 
 Smoke: ``python -m lux_tpu.serve`` builds a small random graph,
 enqueues 2B mixed queries (sssp + components + pagerank), drains them

@@ -81,9 +81,8 @@ Counter semantics (what the buffers mean, engine by engine):
   that iteration, full-graph out-degrees even when pair-lane delivery
   splits the dense arrays).
 - push delta-stepping: ``frontier[i]`` is the bucket-front size
-  entering relax step i (the series ``timed_phases`` reports; bucket
-  advances relax nothing and are not iterations), ``edges[i]`` the
-  front's out-edges.
+  entering relax step i (bucket advances relax nothing and are not
+  iterations), ``edges[i]`` the front's out-edges.
 - pull (``PullEngine.run_stats`` / ``run_until_stats``):
   ``residual[i]`` is the max-abs state change of iteration i (the
   same scalar ``run_until`` converges on), ``changed[i]`` the number

@@ -10,7 +10,7 @@ run left no postmortem artifact.  This module is the attribution
 layer on top of that substrate, three pillars:
 
 1. **Span model + Perfetto export** (``trace_export``): reconstruct
-   the run -> attempt -> segment/timed-run -> phase hierarchy from an
+   the run -> attempt -> segment/timed-run hierarchy from an
    event stream and emit Chrome-trace/Perfetto JSON
    (``chrome://tracing`` / ui.perfetto.dev loadable).  One trace
    process per (session, pid) stream — heartbeat drills appending
@@ -19,7 +19,6 @@ layer on top of that substrate, three pillars:
    process, the median ``t - tm`` offset aligns across processes).
    Events carrying fenced ``seconds`` (segment, timed_run,
    checkpoint_save) become duration spans ending at their emit time;
-   ``phases`` reports unroll into per-iteration phase spans;
    heartbeat/topology/retry/health/budget events become instant
    markers; and an elastic ``mesh_shrink`` moves subsequent execution
    spans onto a NEW track (a visible track transition at the moment
@@ -83,12 +82,6 @@ RUN_BOUNDARIES = ("run_start", "config_start")
 # instant markers promoted to PROCESS scope (big visual arrows)
 PROCESS_INSTANTS = {"mesh_shrink", "topology_fault", "replace",
                     "failure", "health_trip", "flight_dump"}
-# timed_phases report keys that are counters, not phase seconds
-META_KEYS = ("frontier", "bucket", "advances")
-# round 19 (lux_tpu/comms.py): phases whose span subdivides into
-# per-collective child spans when the run carries a comm_ledger event
-# with a priced wire time (the engines' COMM_PHASES anchor)
-COMM_PHASE_NAMES = ("exchange", "gen_exchange")
 
 # per-query serving spans (round 17): query tracks start here, one
 # LANE per set of non-overlapping queries (greedy interval packing —
@@ -249,7 +242,7 @@ def _span_name(ev) -> str:
 
 def _run_spans(run, us, trk: _Track, te: list):
     """Emit one run's spans into ``te``: the run span + attempt spans
-    on tid 0, execution/phase spans on tid 1+epoch, everything else
+    on tid 0, execution spans on tid 1+epoch, everything else
     as instant markers.  Child spans are clamped into the run extent
     so the nesting invariant holds by construction."""
     times = [us(ev) for ev in run]
@@ -269,15 +262,6 @@ def _run_spans(run, us, trk: _Track, te: list):
                          "changed_sum")}
     te.append(_span(name, "run", rstart, rend - rstart, trk.pid, 0,
                     args=args or None))
-    # round 19: the run's comm ledgers, keyed by app (a decompose run
-    # holds several apps in one stream) — each phases event below
-    # subdivides with ITS app's ledger; a lone ledger also serves
-    # phases events that carry no app tag (the CLI -phases shape)
-    comm_by_app = {}
-    for ev in run:
-        if ev["kind"] == "comm_ledger":
-            comm_by_app[ev.get("app", ev.get("config"))] = ev
-
     # attempt spans: boundaries at retry / handled-topology events
     # (supervise() retries immediately after a handled topology fault
     # and after the retry backoff otherwise)
@@ -304,28 +288,6 @@ def _run_spans(run, us, trk: _Track, te: list):
                                                 "total", "active",
                                                 "repeat", "iter",
                                                 "path", "engine")}))
-        elif kind == "phases":
-            report = [r for r in ev.get("report", [])
-                      if isinstance(r, dict)]
-            total = sum(v for r in report for k, v in r.items()
-                        if k not in META_KEYS and _num(v)) * 1e6
-            cur = max(rstart, ts - total)
-            comm = comm_by_app.get(ev.get("app"))
-            if comm is None and "app" not in ev \
-                    and len(comm_by_app) == 1:
-                comm = next(iter(comm_by_app.values()))
-            for i, r in enumerate(report):
-                for ph, v in r.items():
-                    if ph in META_KEYS or not _num(v):
-                        continue
-                    d = v * 1e6
-                    s, d = _clamp(cur, d, rstart, rend)
-                    te.append(_span(f"i{i}:{ph}", "phase", s, d,
-                                    trk.pid, tid))
-                    if ph in COMM_PHASE_NAMES:
-                        te.extend(_collective_spans(
-                            comm, i, ph, s, d, trk.pid, tid))
-                    cur += d
         elif kind == "mem_sample":
             # round-22 memory observatory: the occupancy trail draws
             # as a Chrome COUNTER track ("C" phase) — live bytes +
@@ -399,55 +361,6 @@ def _program_spans(run, times, trk: _Track, te: list, rstart, rend):
         else:
             te.append(_instant(ev.get("name", "?"), s, trk.pid, tid,
                                args=args))
-
-
-def _collective_spans(comm, i, ph, s, d, pid, tid) -> list:
-    """Per-collective child spans inside one exchange-phase span
-    (round 19, lux_tpu/comms.py): the ledger's priced wire window —
-    min(predicted wire seconds, the measured phase) — sits at the
-    START of the phase (the collective launches before the epilogue
-    consumes it), subdivided proportionally to each collective's
-    shipped bytes.  Emitted only when the ledger carries a priced
-    wire time (a measured link rate existed): an unpriced guess must
-    not render as measurement.  Children lie strictly inside the
-    phase span, so the nesting validator holds by construction."""
-    if not isinstance(comm, dict) or d <= 0:
-        return []
-    pred = comm.get("predicted_s")
-    groups = comm.get("per_collective")
-    if not _num(pred) or pred <= 0 or not isinstance(groups, list):
-        return []
-    ents = [g for g in groups if isinstance(g, dict)
-            and _num(g.get("shipped_bytes")) and g["shipped_bytes"] > 0]
-    # cond branches are ALTERNATIVES: predicted_s prices the steady
-    # path (unconditional + heaviest branch, the ledger convention),
-    # so the subdivision keeps exactly that path — rendering a branch
-    # that did not run would show collectives the iteration never
-    # launched
-    by_branch: dict = {}
-    for g in ents:
-        by_branch.setdefault(g.get("branch") or "", []).append(g)
-    keep = by_branch.pop("", [])
-    if by_branch:
-        keep += max(by_branch.values(),
-                    key=lambda gs: sum(g["shipped_bytes"]
-                                       for g in gs))
-    ents = keep
-    total = sum(g["shipped_bytes"] for g in ents)
-    if total <= 0:
-        return []
-    win = min(pred * 1e6, d)
-    out, cur = [], s
-    for g in ents:
-        cd = win * g["shipped_bytes"] / total
-        cur2, cd = _clamp(cur, cd, s, s + d)
-        out.append(_span(f"i{i}:{ph}:{g.get('prim')}", "collective",
-                         cur2, cd, pid, tid,
-                         args={"shipped_bytes": g["shipped_bytes"],
-                               "count": g.get("count"),
-                               "tier": g.get("tier")}))
-        cur = cur2 + cd
-    return out
 
 
 def _merge_windows(windows):

@@ -35,7 +35,7 @@ SATURATION KNEE: the first ramp step whose achieved rate falls under
 serve-slo`` wraps ``run_step`` into calibrated metric lines
 (offered/achieved/p50/p99/SLO fields, validated by
 scripts/check_bench.py); the on-device run is carried as debt
-``serve-slo-on-device`` (lux_tpu/observe.py).
+``serve-slo-on-device`` (PERF.md section 7).
 
 Usage:
     PYTHONPATH=. python scripts/loadgen.py -scale 9 -rates 5,15,40 \

@@ -7,7 +7,7 @@ re-convergence beats recomputing from scratch when the touched
 fraction is small — the whole point of keeping converged state warm
 under a mutation stream.  This sweep MEASURES that claim on CPU
 (PERF_NOTES round 20; the on-device crossover is carried as debt
-``live-mutation-on-device``, lux_tpu/observe.py):
+``live-mutation-on-device``, PERF.md section 7):
 
 - per touched-fraction point f: append ``max(1, f * ne)`` random
   edges to a converged push engine's graph, then time
@@ -34,7 +34,7 @@ destinations, then the compiled converge) against the full recompute
 it must bitwise-equal, reporting the measured cone fraction and
 whether the cone cap forced the full-recompute fallback.  The
 on-device deletion path is carried as debt
-``live-deletion-on-device`` (lux_tpu/observe.py).
+``live-deletion-on-device`` (PERF.md section 7).
 
 Usage:
     PYTHONPATH=. python scripts/sweep_live.py [-scale N] [-ef E]

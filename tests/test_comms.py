@@ -359,65 +359,6 @@ def test_calibrate_links_cpu_mesh_records_but_never_feeds():
     observe._LINKS.clear()
 
 
-def test_dcn_probe_gated_on_single_slice():
-    import dataclasses as dc
-    fp = dc.replace(observe.calibrate(), platform="tpu", ndev=8)
-    collected, skipped = observe.collect_debts(
-        fp, None, only={"dcn-bandwidth-probe"})
-    assert collected == []
-    assert len(skipped) == 1
-    did, reason = skipped[0]
-    assert did == "dcn-bandwidth-probe"
-    assert "gated" in reason and "slice" in reason
-
-
-# ---------------------------------------------------------------------
-# decompose comm verdict + events_summary round-trip
-
-def test_decompose_comm_verdict_and_events(tmp_path):
-    from lux_tpu.apps import pagerank
-
-    evp = tmp_path / "ev.jsonl"
-    ev = telemetry.EventLog(str(evp))
-    fp = observe.calibrate()
-    g = mk_graph()
-    with telemetry.use(events=ev):
-        # off-mesh: honestly no-comm
-        d1 = observe.decompose(
-            pagerank.build_engine(g, num_parts=2), "pagerank",
-            iters=2, fingerprint=fp)
-        # mesh owner engine with a measured session link rate: the
-        # wire lower bound grades the gen_exchange phase
-        observe.calibrate_links(payload_elems=(1 << 10,), repeats=2)
-        d2 = observe.decompose(
-            pagerank.build_engine(g, num_parts=2, mesh=mesh_of(2),
-                                  exchange="owner"),
-            "pagerank_mesh", iters=2, fingerprint=fp)
-    ev.close()
-    assert d1.comm["verdict"] == "no-comm"
-    assert d1.comm["bytes_per_iter"] == 0
-    assert d2.comm["bytes_per_iter"] > 0
-    assert d2.comm["verdict"] in ("ok", "drift_fast")
-    assert d2.comm["predicted_s"] is not None
-    assert d2.comm["audit_eqns"] == {"reduce_scatter": 1}
-    assert d1.as_dict()["comm"]["verdict"] == "no-comm"
-    # the comm line renders in the human report
-    rep = observe.render_report([d1, d2], fp)
-    assert "comm: 0 B/iter" in rep
-    assert "comm:" in rep and "[ici]" in rep
-    # ... and the comm_ledger events render + audit clean through
-    # events_summary (the acceptance criterion)
-    r = subprocess.run(
-        [sys.executable,
-         str(REPO / "scripts" / "events_summary.py"), str(evp)],
-        capture_output=True, text=True)
-    assert r.returncode == 0, r.stderr
-    assert "comm ledger [pagerank_mesh]" in r.stdout
-    assert "reduce_scatter" in r.stdout
-    assert "link calibration [ici]" in r.stdout
-    observe._LINKS.clear()
-
-
 def test_tampered_comm_ledger_event_fails_summary(tmp_path):
     """events_summary FAILS a comm_ledger whose breakdown contradicts
     the audit eqn set it carries (the established contradiction-check
@@ -457,77 +398,6 @@ def test_tampered_comm_ledger_event_fails_summary(tmp_path):
         capture_output=True, text=True)
     assert r.returncode == 1
     assert "unknown collective" in r.stderr
-
-
-# ---------------------------------------------------------------------
-# tracing: per-collective spans inside exchange phases
-
-def test_collective_spans_in_trace():
-    from lux_tpu import tracing
-
-    events = [
-        {"t": 1.0, "tm": 1.0, "kind": "config_start",
-         "config": "pagerank_mesh", "session": "s", "pid": 1},
-        # a SECOND app's ledger in the same run: per-app matching
-        # must keep its (huge) bytes out of pagerank_mesh's phases
-        {"t": 1.2, "tm": 1.2, "kind": "comm_ledger",
-         "app": "other_app", "ndev": 2, "tier": "ici",
-         "bytes_per_iter": 1 << 30, "messages": 1, "session": "s",
-         "pid": 1, "predicted_s": 9.0, "verdict": "ok",
-         "per_collective": [
-             {"prim": "all_to_all", "count": 1, "eqns": 1,
-              "shipped_bytes": 1 << 30, "tier": "ici",
-              "branch": ""}]},
-        {"t": 1.5, "tm": 1.5, "kind": "comm_ledger",
-         "app": "pagerank_mesh", "ndev": 2, "tier": "ici",
-         "bytes_per_iter": 1024, "messages": 2, "session": "s",
-         "pid": 1, "predicted_s": 0.004, "verdict": "ok",
-         "per_collective": [
-             {"prim": "reduce_scatter", "count": 1, "eqns": 1,
-              "shipped_bytes": 768, "tier": "ici", "branch": ""},
-             {"prim": "psum", "count": 1, "eqns": 1,
-              "shipped_bytes": 256, "tier": "ici", "branch": ""},
-             # two cond ALTERNATIVES: only the heavier branch is the
-             # steady path predicted_s prices, so the lighter one
-             # must not render as a span
-             {"prim": "all_gather", "count": 1, "eqns": 1,
-              "shipped_bytes": 512, "tier": "ici",
-              "branch": "cond[5]#0"},
-             {"prim": "pmin", "count": 1, "eqns": 1,
-              "shipped_bytes": 4, "tier": "ici",
-              "branch": "cond[5]#1"}]},
-        {"t": 2.0, "tm": 2.0, "kind": "phases", "session": "s",
-         "pid": 1, "app": "pagerank_mesh",
-         "report": [{"gen_exchange": 0.01, "apply": 0.005}]},
-    ]
-    doc = tracing.trace_export(events)
-    spans = [e for e in doc["traceEvents"] if e.get("ph") == "X"]
-    names = {s["name"] for s in spans}
-    assert "i0:gen_exchange" in names
-    assert "i0:gen_exchange:reduce_scatter" in names
-    assert "i0:gen_exchange:psum" in names
-    # the other app's ledger and the lighter branch never render;
-    # the heavier branch (the steady path) does
-    assert "i0:gen_exchange:all_to_all" not in names
-    assert "i0:gen_exchange:all_gather" in names
-    assert "i0:gen_exchange:pmin" not in names
-    # children lie inside the phase span, proportional to bytes
-    ph = next(s for s in spans if s["name"] == "i0:gen_exchange")
-    rs = next(s for s in spans
-              if s["name"] == "i0:gen_exchange:reduce_scatter")
-    ps = next(s for s in spans
-              if s["name"] == "i0:gen_exchange:psum")
-    assert ph["ts"] <= rs["ts"]
-    assert rs["ts"] + rs["dur"] <= ph["ts"] + ph["dur"] + 2
-    assert rs["dur"] == pytest.approx(3 * ps["dur"], rel=0.01)
-    assert tracing.validate_trace(doc) == []
-    # no priced wire time -> no collective spans (a guess must not
-    # render as measurement)
-    events[2] = dict(events[2], predicted_s=None)
-    doc2 = tracing.trace_export(events)
-    names2 = {e["name"] for e in doc2["traceEvents"]
-              if e.get("ph") == "X"}
-    assert "i0:gen_exchange:reduce_scatter" not in names2
 
 
 # ---------------------------------------------------------------------

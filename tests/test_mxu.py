@@ -431,12 +431,3 @@ def test_memory_report_mxu_temp(g):
     assert rep_m["terms_per_part"]["mxu_temp"] == want
     assert (rep_m["total_bytes"] - rep["total_bytes"]
             == sg.num_parts * want)
-
-
-def test_mxu_core_debt_carried():
-    from lux_tpu import observe
-
-    (d,) = [d for d in observe.DEBTS if d.id == "mxu-core-ab"]
-    assert d.platform == "tpu"
-    assert d.auto == "_debt_mxu_core_ab"
-    assert callable(getattr(observe, d.auto))

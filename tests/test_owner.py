@@ -144,19 +144,6 @@ def test_owner_weighted():
     np.testing.assert_allclose(out, acc, rtol=1e-6)
 
 
-def test_owner_phases(graph):
-    eng = PullEngine(ShardedGraph.build(graph, 4),
-                     pagerank.make_program(), exchange="owner")
-    state, report = eng.timed_phases(eng.init_state(), 2)
-    assert len(report) == 2
-    assert set(report[0]) == {"gen_exchange", "apply"}
-    # the instrumented path computes the same state as the fused step
-    fused = eng.run(eng.init_state(), 2)
-    np.testing.assert_allclose(np.asarray(jax.device_get(state)),
-                               np.asarray(jax.device_get(fused)),
-                               rtol=1e-6)
-
-
 def _hub_start(graph):
     src, _dst = graph.edge_arrays()
     return int(np.bincount(src, minlength=graph.nv).argmax())
@@ -242,19 +229,6 @@ def test_push_owner_weighted(graph):
                             weighted=True, exchange="owner")
     dist, _iters = eng.run()
     np.testing.assert_allclose(dist, want)
-
-
-def test_push_owner_phases(graph):
-    from lux_tpu.apps import sssp
-    from lux_tpu.engine.push import PushEngine
-
-    start = _hub_start(graph)
-    eng = PushEngine(ShardedGraph.build(graph, 4),
-                     sssp.make_program(start), enable_sparse=False,
-                     exchange="owner")
-    label, active = eng.init_state()
-    _l, _a, rep = eng.timed_phases(label, active, 2)
-    assert all("gen_exchange" in r for r in rep)
 
 
 def test_owner_rejects_needs_dst(graph):

@@ -463,26 +463,6 @@ def test_engine_ledger_check_paged():
     assert [f for f in findings if f.severity == "error"] == []
 
 
-def test_observe_decompose_paged():
-    """The acceptance command path: a paged pull run decomposes with
-    a phase-model PRICE for the paged delivery phase (not unmodeled)
-    and a non-degraded session on CPU."""
-    from lux_tpu import observe
-    from lux_tpu.apps import pagerank
-
-    g = _skewed_graph(37, 4 * W, 6000)
-    eng = pagerank.build_engine(g, num_parts=2, gather="paged")
-    fp = observe.calibrate()
-    assert fp.grade != "degraded"
-    assert "page_gather_row_ns" in fp.probe
-    d = observe.decompose(eng, "pagerank", iters=2, fingerprint=fp)
-    by = {p.phase: p for p in d.phases}
-    assert "gather_reduce" in by
-    pc = by["gather_reduce"]
-    assert pc.predicted_s is not None and pc.predicted_s > 0
-    assert pc.verdict != "unmodeled"
-
-
 def test_check_bench_gather_fields(tmp_path):
     import subprocess
     import sys

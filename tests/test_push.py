@@ -732,30 +732,3 @@ def test_pull_step_on_weighted_levels_where_its_guard_holds():
     assert _last_mark()["pull_iters"] == 1
     np.testing.assert_array_equal(
         got, sssp.reference_sssp(g, 0, weighted=True))
-
-
-@pytest.mark.parametrize("layout", ["np1", "mesh4-owner"])
-def test_timed_phases_choose_as_the_fused_loop(layout):
-    """(g) the stepwise twin: ``timed_phases`` times an iteration as
-    'sparse' exactly where the fused loop takes the sparse branch, the
-    bottom-up step included (its choice is a program of its own,
-    collectives and all), and ends on the same labels."""
-    g, eng, _off = _kron_engines()
-    root = _sweep_roots(g)[2]
-    if layout != "np1":
-        eng = sssp.build_engine(g, root, num_parts=4, mesh=make_mesh(4),
-                                exchange="owner")
-        assert eng.pull
-    label, active = eng.place(*_root_state(eng, root))
-    fused = []
-    while np.asarray(active).any():
-        label, active, _ = eng.converge(label, active, 1)
-        mark = _last_mark()
-        fused.append((mark["sparse_iters"], mark["pull_iters"]))
-    assert (1, 1) in fused and (0, 0) in fused
-    lab2, act2, report = eng.timed_phases(
-        *eng.place(*_root_state(eng, root)), iters=len(fused))
-    assert [int("sparse" in t) for t in report] == \
-        [s for s, _p in fused]
-    np.testing.assert_array_equal(eng.unpad(lab2), eng.unpad(label))
-    assert not np.asarray(act2).any()

@@ -389,33 +389,3 @@ def test_bench_gather_ab_reorder_lines(tmp_path):
     r = subprocess.run([sys.executable, chk, "-legacy-ok", str(bad)],
                        capture_output=True, text=True)
     assert r.returncode == 1 and "DECREASED" in r.stderr
-
-
-def test_observe_debts_registered():
-    """The round-16 carried debts are machine-encoded; the
-    reorder-fill-ab probe is implemented (platform-any: the fill
-    objective is host-measured) and pagemajor-route-ab waits on a
-    real mesh."""
-    from lux_tpu import observe
-
-    ids = {d.id: d for d in observe.DEBTS}
-    assert ids["reorder-fill-ab"].auto == "_debt_reorder_fill_ab"
-    assert ids["reorder-fill-ab"].platform == "any"
-    assert ids["pagemajor-route-ab"].auto is None
-    assert ids["pagemajor-route-ab"].min_ndev >= 2
-
-
-@pytest.mark.slow
-def test_reorder_fill_debt_probe():
-    """The probe itself: fills for all three orders, hillclimb >=
-    native >= ... and the payload is ledger-shaped."""
-    from lux_tpu import observe
-
-    fp = observe.calibrate()
-    rec = observe._debt_reorder_fill_ab(fp)
-    assert rec["debt"] == "reorder-fill-ab"
-    orders = rec["orders"]
-    assert set(orders) == {"none", "native", "hillclimb"}
-    assert orders["hillclimb"]["page_fill"] >= \
-        orders["none"]["page_fill"]
-    assert orders["hillclimb"]["auto_resolves"] != "flat"

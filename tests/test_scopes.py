@@ -1,0 +1,217 @@
+"""The named scopes the benchmark reads exist in the programs its
+cells run.
+
+Where an iteration's time goes has one account: the ``jax.named_scope``
+names on the ops of the program that runs, summed from a device trace
+by ``benchmarks/trace_reduce.py``.  Every ``scope_ms.*`` per-layer
+metric, every roofline share and the ledger's ``breakdown`` read those
+names, so a renamed scope zeroes a metric without any result changing.
+Here each cell's engine FORM is built small from the cell's own
+configuration file (app, ``engine`` options, parts, mesh, batch), its
+step and its fused loop are lowered on the CPU backend, and the scope
+names are read from the lowered text's debug locations: what the
+metric files name must be there.  The files are read, not copied.
+"""
+
+import functools
+import glob
+import json
+import os
+import re
+
+import numpy as np
+import pytest
+
+# a scope name as the trace reduction itself finds one
+from benchmarks.trace_reduce import SCOPE_RE
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+# collectives as StableHLO spells them (``collective: true`` metrics)
+COLLECTIVE_RE = re.compile(
+    r"stablehlo\.(all_gather|all_reduce|reduce_scatter|all_to_all|"
+    r"collective_permute)\b")
+
+
+def _load(path):
+    with open(path) as f:
+        return json.load(f)
+
+
+BENCHMARK = _load(os.path.join(ROOT, "BENCHMARK.json"))
+CELLS = {w["name"]: w for w in BENCHMARK["workloads"]}
+SCOPE_FILES = {
+    os.path.basename(p)[:-len(".json")]: _load(p)
+    for p in sorted(glob.glob(
+        os.path.join(ROOT, "benchmarks", "layer_metrics",
+                     "scope_ms.*.json")))}
+# (metric, cell) for every scope metric and every cell that reports it
+METRIC_CELLS = [(m["name"], cell) for m in BENCHMARK["per_layer"]
+                if m["name"] in SCOPE_FILES for cell in m["workloads"]]
+NAMED_SCOPES = sorted({s for spec in SCOPE_FILES.values()
+                       for s in spec.get("scopes", ())})
+# ``lux_gather_reduce`` is the FUSED delivery's scope (paged rows, or
+# chunks streamed once a part's messages pass 1 GB): no cell is that
+# large or paged yet, so its form is ``pr.kron21``'s configuration
+# with the ``engine`` options of the cell PERF.md section 7 names for
+# that side of the choice (``pr.kron21.paged``)
+VARIANT_FORMS = {"pr.kron21": ({"gather": "paged"},)}
+# what the ledger's ``breakdown`` lists for the serving cells
+SERVING_SCOPES = {"push": ("lux_relax", "lux_reduce", "lux_aligned"),
+                  "pull": ("lux_gather",)}
+
+
+@functools.lru_cache(maxsize=None)
+def _config(cell: str) -> dict:
+    entry = {c["name"]: c for c in BENCHMARK["configs"]}[
+        CELLS[cell]["config"]]
+    return _load(os.path.join(ROOT, entry["file"]))
+
+
+def _kinds(c: dict) -> list:
+    """The query kinds of a serving configuration (none: a batch
+    cell)."""
+    return c.get("kinds", [c["kind"]] if "kind" in c else [])
+
+
+# (cell, kind) of every serving cell
+SERVING = [(cell, kind) for cell in CELLS
+           for kind in _kinds(_config(cell))]
+
+
+def _small_graph(app: str, symmetrized: bool):
+    """A graph of the cell's KIND at a size that lowers in a second:
+    R-MAT scale 10 x 16, symmetrized where the cell's is (the push
+    engine builds its bottom-up step on symmetric graphs only),
+    integer ratings 1..5 as weights for colfilter."""
+    from lux_tpu.apps import components
+    from lux_tpu.convert import rmat_graph
+    from lux_tpu.graph import Graph
+
+    g = rmat_graph(scale=10, edge_factor=16, seed=1)
+    if symmetrized:
+        g = Graph.from_edges(*components.symmetrize(*g.edge_arrays()),
+                             g.nv)
+    if app == "colfilter":
+        g.weights = np.random.default_rng(1).integers(
+            1, 6, size=g.ne).astype(np.int32)
+    return g
+
+
+def _batch_engine(c: dict, engine=None):
+    """The engine of a batch cell, built as ``benchmarks/runners/
+    common.load_and_layout`` and the runners' ``prepare`` build it
+    (``engine``: options in place of the configuration's)."""
+    import importlib
+
+    from lux_tpu.graph import ShardedGraph, pair_relabel
+    from lux_tpu.parallel.mesh import make_mesh
+
+    app = importlib.import_module("lux_tpu.apps." + c["app"])
+    opts = c.get("engine", {}) if engine is None else engine
+    num_parts, pair = int(c["num_parts"]), opts.get("pair_threshold")
+    g = _small_graph(c["app"], bool(c.get("symmetrized")))
+    starts = None
+    if pair is not None:
+        g, _perm, starts = pair_relabel(g, num_parts,
+                                        pair_threshold=pair)
+    sg = ShardedGraph.build(g, num_parts, starts=starts,
+                            pair_threshold=pair)
+    mesh = make_mesh(int(c["mesh"])) if int(c.get("mesh", 1)) > 1 \
+        else None
+    kw = dict(num_parts=num_parts, mesh=mesh, sg=sg, **opts)
+    if c["app"] == "sssp":
+        kw.update(start_vertex=0, weighted=False)
+    return app.build_engine(g, **kw)
+
+
+def _serving_engines(c: dict) -> dict:
+    """kind -> engine of a serving cell: ``serve.Server``'s own runners
+    at a small ``batch``."""
+    from lux_tpu import serve
+
+    server = serve.Server(_small_graph("sssp", False), batch=4,
+                          num_parts=int(c["num_parts"]))
+    return {k: server._runner(k).eng for k in _kinds(c)}
+
+
+def _lowered(eng) -> str:
+    """The step and the fused loop the cells drive, lowered with debug
+    locations (the scope names are in them)."""
+    loop = "converge" if hasattr(eng, "converge") else "run"
+    programs = eng.audit_programs()
+    text = []
+    for name in ("step", loop):
+        jitted, args = programs[name]
+        text.append(jitted.lower(*args()).as_text(debug_info=True))
+    return "\n".join(text)
+
+
+@functools.lru_cache(maxsize=None)
+def _form(cell: str) -> dict:
+    """engine name -> lowered text of the cell's engine form(s)."""
+    c = _config(cell)
+    if "app" in c:
+        return {c["app"]: _lowered(_batch_engine(c))}
+    return {k: _lowered(e) for k, e in _serving_engines(c).items()}
+
+
+def _text(cell: str) -> str:
+    return "\n".join(_form(cell).values())
+
+
+@functools.lru_cache(maxsize=None)
+def _scopes(cell: str) -> frozenset:
+    return frozenset(SCOPE_RE.findall(_text(cell)))
+
+
+@functools.lru_cache(maxsize=None)
+def _variant_scopes(cell: str) -> set:
+    return {s for engine in VARIANT_FORMS.get(cell, ())
+            for s in SCOPE_RE.findall(
+                _lowered(_batch_engine(_config(cell), engine)))}
+
+
+def test_every_cell_has_a_form():
+    """The table above covers the benchmark: a new cell needs a form
+    here (its configuration names an app or serving kinds)."""
+    for cell in CELLS:
+        c = _config(cell)
+        assert "app" in c or _kinds(c), cell
+    assert METRIC_CELLS and NAMED_SCOPES and SERVING
+
+
+@pytest.mark.parametrize("metric,cell", METRIC_CELLS)
+def test_scope_metric_finds_its_scope_in_the_cell(metric, cell):
+    """(a) a ``scope_ms`` metric a cell reports reads something there:
+    one of the file's scopes (or, for ``collective: true``, a
+    collective) is in the cell's lowered program."""
+    spec = SCOPE_FILES[metric]
+    if spec.get("collective"):
+        assert COLLECTIVE_RE.search(_text(cell)), (metric, cell)
+        return
+    assert set(spec["scopes"]) & _scopes(cell), (
+        f"{metric} reads {spec['scopes']} but {cell}'s program has "
+        f"only {sorted(_scopes(cell))}")
+
+
+@pytest.mark.parametrize("scope", NAMED_SCOPES)
+def test_named_scope_is_emitted_by_some_cell(scope):
+    """(b) no metric file names a scope the programs dropped: a cell
+    that reports the metric emits it, or that cell's variant form."""
+    metrics = [m for m, spec in SCOPE_FILES.items()
+               if scope in spec.get("scopes", ())]
+    cells = sorted({cell for m, cell in METRIC_CELLS if m in metrics})
+    assert any(scope in _scopes(cell) for cell in cells) or any(
+        scope in _variant_scopes(cell) for cell in cells), (
+        f"{scope} (named by {metrics}) is in none of {cells}")
+
+
+@pytest.mark.parametrize("cell,kind", SERVING)
+def test_serving_forms_emit_the_breakdown_scopes(cell, kind):
+    """(c) the query-batched push form (aligned placement) and the
+    query-batched pull form carry the names the ledger's ``breakdown``
+    lists for the serving cells."""
+    found = set(SCOPE_RE.findall(_form(cell)[kind]))
+    family = "pull" if kind == "pagerank" else "push"
+    missing = set(SERVING_SCOPES[family]) - found
+    assert not missing, (cell, kind, sorted(missing))

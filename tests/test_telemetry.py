@@ -85,20 +85,56 @@ def test_components_counters_match_stepwise(np_parts, mesh_n):
     assert np.asarray(fed)[:it].tolist() == edges
 
 
-def test_push_delta_counters_match_timed_phases():
-    """Delta engines record each relax step's bucket-front size — the
-    exact schedule the instrumented stepwise path
-    (timed_phases/_timed_phases_delta) replays."""
+def delta_bucket_fronts(g, start, delta, ident):
+    """NumPy delta-stepping, written from the schedule's definition:
+    relax the active vertices under the bucket bound ``B``; when none
+    is left, move ``B`` to the least active label + ``delta`` (strictly
+    past it).  Returns the front size entering each relax, and the
+    labels."""
+    src, dst = g.edge_arrays()
+    w = np.asarray(g.weights)
+    ldt = np.asarray(ident).dtype
+
+    def advance(am):
+        nb = am + np.asarray(delta, ldt)
+        if np.issubdtype(ldt, np.inexact):
+            nb = max(nb, np.nextafter(am, np.asarray(np.inf, ldt)))
+        return np.asarray(nb, ldt)
+
+    label = np.full(g.nv, ident, ldt)
+    label[start] = 0
+    active = np.zeros(g.nv, bool)
+    active[start] = True
+    bound = advance(label[active].min())
+    fronts = []
+    while active.any():
+        front = active & (label < bound)
+        if not front.any():
+            bound = advance(label[active].min())
+            continue
+        fronts.append(int(front.sum()))
+        e = front[src]
+        new = label.copy()
+        np.minimum.at(new, dst[e], label[src[e]] + w[e].astype(ldt))
+        active = (active & ~front) | (new < label)
+        label = new
+    return fronts, label
+
+
+def test_push_delta_counters_match_bucket_schedule():
+    """Delta engines record each relax step's bucket-front size:
+    ``converge_stats``' series against the NumPy bucket schedule."""
     g = small_graph(weighted=True)
     eng = sssp.build_engine(g, start_vertex=0, num_parts=1,
                             weighted=True, delta="auto")
-    label, active = eng.init_state()
-    _l, _a, it, fsz, _fed, _fp, _ep = eng.converge_stats(label, active)
+    label, _a, it, fsz, _fed, _fp, _ep = eng.converge_stats(
+        *eng.init_state())
     it = int(jax.device_get(it))
-    lab0, act0 = eng.init_state()
-    _l2, _a2, report = eng.timed_phases(lab0, act0, iters=it)
-    assert [t["frontier"] for t in report] == \
-        np.asarray(fsz)[:it].tolist()
+    fronts, want = delta_bucket_fronts(g, 0, eng.delta,
+                                       eng.program.identity)
+    assert len(fronts) > 3
+    assert np.asarray(fsz)[:it].tolist() == fronts
+    np.testing.assert_array_equal(eng.unpad(label), want)
 
 
 @pytest.mark.parametrize("np_parts,mesh_n", [(1, 0), (8, 8)])
