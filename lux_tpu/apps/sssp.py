@@ -102,21 +102,40 @@ def make_batched_program(sources, weighted: bool = False) -> PushProgram:
 
 
 def default_delta(g: Graph) -> float:
-    """Bucket width heuristic: the smallest positive edge weight,
-    floored at mean/16.
+    """Bucket width of ``delta="auto"``: the LARGEST edge weight (1.0
+    where no weight is positive).
 
-    Measured sweep at the bench shape (RMAT21 ef16, weights 1..5,
-    PERF_NOTES round 4): width=min (1.0) -> 0.1498 GTEPS beats the
-    old mean-width (3.0 -> 0.1455) and plain weighted frontiers
-    (0.1297).  Near-settled narrow buckets maximize the fraction of
-    USEFUL relaxations when every engine iteration is fixed-shape;
-    the mean/16 floor stops degenerate widths (near-zero float
-    weights) from turning the run into relax-free bucket advances."""
-    w = np.asarray(g.weights, np.float64)
-    pos = w[w > 0]
-    if not pos.size:
-        return 1.0
-    return float(max(pos.min(), np.mean(w) / 16.0))
+    Every iteration of the push engine costs by its static shape, not
+    by its front (a dense one all edges, a sparse one its ladder
+    rungs), so a narrow bucket saves no time by relaxing fewer edges;
+    it only adds loop trips.  MEASURED on float32 weights uniform in
+    [0, 1) only (Graph500 kernel 3 on the Kronecker graph of scale 21
+    x 16, 8 roots, TPU v5e; ``scripts/sweep_delta.py``, PERF.md
+    section 6, PR 43), seconds for the 8 searches with the loop's
+    trips (relax iterations + relax-free advances) a search beside
+    them: width 0.01 157.6 s (455 trips), 0.031 (the rule this
+    replaces, ``max(min positive weight, mean / 16)``) 112.8 s (187),
+    0.1 64.9 s (79), 0.25 57.4 s (48), 0.5 54.8 s (35), 1.0 57.3 s
+    (33), 2.0 51.1 s (27), plain frontiers 50.8 s (25): seconds
+    follow trips all the way.  The maximum is the widest width a
+    graph's own weights name: every edge out of a bucket lands in
+    that bucket or the next.  On that graph the schedule then all
+    but degenerates (2.1 advances a search; twice the width is plain
+    frontiers with one advance), and it is NOT the fastest choice:
+    plain frontiers are 12.6% faster on the 8 searches.  One root of
+    the 8 takes 48 relax iterations (11.9 s) at this width and 31 at
+    0.5 (a host replay): its only edge weighs 0.99, so the bound
+    ``0 + delta`` cuts every front to a few hubs just behind it,
+    under the vertex count that sends a front down the sparse branch,
+    with five times the edge budget in out-edges (PERF.md section 6).
+    INTEGER weights 1..5 (``bench.py``'s ``sssp-delta`` shape, RMAT21 x
+    16 directed, 8 roots, one chip run, PR 43): plain frontiers 7.59 s
+    for the 8 (8-9 relax iterations a search), 1.0 (the old rule
+    there) 19.26 s (19-28 and 17 advances), this rule's 5.0 8.74 s
+    (11-15 and 3): the same order.  No other weight distribution and
+    no high-diameter graph has been measured."""
+    top = float(np.max(g.weights, initial=0))
+    return top if top > 0 else 1.0
 
 
 def build_engine(g: Graph, start_vertex: int | None = 0,

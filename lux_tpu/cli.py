@@ -14,6 +14,8 @@ Flags (reference names kept):
                 default: the -mesh size, i.e. one partition per device)
   -mesh N       shard over an N-device mesh (default: 1 device)
   -weighted     treat the graph/run as weighted (colfilter implies it)
+  -weight-type T  sssp -weighted: the file's weights are int32
+                (default) or float32; a .lux does not say which
   -retries N    supervised run: classify + retry transient failures,
                 auto-resuming from the last segment checkpoint
   -seg-budget S duration-budgeted segments (each XLA execution < S s;
@@ -270,6 +272,8 @@ def _load(args, weighted: bool):
     t0 = time.perf_counter()
     try:
         g = Graph.from_file(args.file, weighted=weighted or None,
+                            weight_dtype=np.dtype(
+                                getattr(args, "weight_type", "int32")),
                             validate=getattr(args, "validate", False))
     except GraphFormatError as e:
         # a malformed graph is a typed, named refusal — never a run
@@ -722,6 +726,12 @@ def _push_app(argv, prog_name):
     ap.add_argument("-start", type=int, default=0)
     ap.add_argument("-weighted", action="store_true")
     if prog_name == "sssp":
+        ap.add_argument("-weight-type", dest="weight_type",
+                        choices=("int32", "float32"), default="int32",
+                        help="what the file's 4-byte weights are (a "
+                             ".lux does not say): int32 (default) or "
+                             "float32, e.g. Graph500 kernel 3's "
+                             "uniform [0, 1) weights")
         ap.add_argument("-delta", default=None,
                         help="delta-stepping bucket width (a number or "
                              "'auto'; default: off)")

@@ -75,6 +75,19 @@ The TPU-native design:
   into ONE number when the ring is read (``frontier.Folded``).  Engines
   without a ladder (batched, ``enable_sparse=False``) carry none and
   mark zeros.
+- Under ``delta`` the loop is the bucket schedule (delta-stepping): a
+  trip relaxes the current bucket's front, or, where that is empty,
+  only raises the bucket bound.  The front mask, its count, the
+  active minimum and the advance lie under the scope ``lux_bucket``,
+  and the mark carries ``advances`` (trips that relaxed nothing:
+  ``iters + advances`` = trips), ``front_edges`` (the edges the relax
+  trips relaxed, summed: a dense trip its front's out-edges, a sparse
+  one what its budget stage expanded, so a front that overflows the
+  top rung counts a prefix a trip, not whole every trip; two words,
+  as the fills) and ``graph_edges`` (the stored edges, once a call):
+  how often the schedule re-relaxes an edge, against the trips it
+  pays.
+  An engine without ``delta`` carries none of them and marks zeros.
 - Sparse overflow safety: when a frontier's out-edges exceed the
   static edge budget, the un-expanded queue suffix simply STAYS
   ACTIVE (the globally-agreed processed prefix is cleared via a
@@ -937,6 +950,8 @@ class PushEngine(AuditableEngine):
             return nl, na, jnp.stack(took), fill
 
         use_delta = converge and self.delta is not None
+        # the delta loop's two outputs behind the shared counts
+        n_counts += 2 * int(use_delta)
 
         def inner(label, active, max_iters, *gargs):
             if health:
@@ -1013,13 +1028,20 @@ class PushEngine(AuditableEngine):
                         ok = ok & (c[9][0] == 0)
                     return ok
 
+                # the schedule's own counters ride with the shared
+                # ones in the LAST carry element, (tally, bucket):
+                # bucket = (advances int32: trips that relaxed nothing,
+                # front_edges: the edges each relax trip relaxed,
+                # summed, in two uint32 words as the fills)
                 def wbody(c):
                     it, lbl, act, B, cnt = c[:5]
                     buf = c[5:]
-                    front = act & (lbl < B)
-                    nf = global_sum(front)
+                    with jax.named_scope("lux_bucket"):
+                        front = act & (lbl < B)
+                        nf = global_sum(front)
 
                     def relax(it, lbl, act, B, *buf):
+                        ctr, (adv, fe) = buf[-1]
                         if stats:
                             # counters record the bucket front ENTERING
                             # this relax; advances relax nothing
@@ -1036,6 +1058,18 @@ class PushEngine(AuditableEngine):
                                    fedp.at[it].set(ep, mode="drop")) \
                                 + buf[4:]
                         nl, na, took, fill = body(lbl, front, nf, g)
+                        with jax.named_scope("lux_bucket"):
+                            # the edges this trip relaxed: the front's
+                            # out-edges, or, on a sparse trip, what
+                            # the budget stage expanded (a front past
+                            # the top rung is relaxed a prefix a trip)
+                            edges = global_sum(
+                                jnp.where(front, g["deg"], 0)
+                                .astype(jnp.uint32))
+                            if fill is not None:
+                                edges = jnp.where(took[0] > 0, fill[2],
+                                                  edges)
+                            fe = fr.wide_add(*fe, edges)
                         merged = (act & ~front) | na
                         if health:
                             # the watchdog watches relax steps only:
@@ -1046,8 +1080,9 @@ class PushEngine(AuditableEngine):
                                 global_sum(merged))
                             buf = buf[:4] + (h, stall) + buf[6:]
                         return (it + 1, nl, merged, B, *buf[:-1],
-                                tally(buf[-1], took, fill))
+                                (tally(ctr, took, fill), (adv, fe)))
 
+                    @jax.named_scope("lux_bucket")
                     def advance(it, lbl, act, B, *buf):
                         # Strict progress: with float labels a delta
                         # below one ulp at the current magnitude makes
@@ -1062,14 +1097,17 @@ class PushEngine(AuditableEngine):
                             nb = jnp.maximum(
                                 nb, jnp.nextafter(
                                     am, jnp.asarray(jnp.inf, am.dtype)))
-                        return it, lbl, act, nb, *buf
+                        ctr, (adv, fe) = buf[-1]
+                        return (it, lbl, act, nb, *buf[:-1],
+                                (ctr, (adv + 1, fe)))
 
                     out = jax.lax.cond(
                         nf > 0, relax, advance, it, lbl, act, B, *buf)
                     it, lbl, act, B = out[:4]
                     return (it, lbl, act, B, global_sum(act), *out[4:])
 
-                B0 = active_min(label, active) + delta
+                with jax.named_scope("lux_bucket"):
+                    B0 = active_min(label, active) + delta
                 init = (jnp.int32(0), label, active, B0,
                         global_sum(active))
                 if stats:
@@ -1080,10 +1118,15 @@ class PushEngine(AuditableEngine):
                         jnp.zeros((cap_n, sg.num_parts), jnp.uint32))
                 if health:
                     init = init + (h0, stall0)
-                out = jax.lax.while_loop(cond, wbody, init + (tally0(),))
-                # (lbl, act, it, [stats], [health], *counts_out)
+                zero = jnp.uint32(0)
+                out = jax.lax.while_loop(
+                    cond, wbody,
+                    init + ((tally0(), (jnp.int32(0), (zero, zero))),))
+                # (lbl, act, it, [stats], [health], *counts_out,
+                # advances, front_edges' words [1, 2])
+                ctr, (adv, fe) = out[-1]
                 return (out[1], out[2], out[0], *out[5:-1],
-                        *counts_out(out[-1]))
+                        *counts_out(ctr), adv, jnp.stack(fe)[None])
 
             # carry: (it, lbl, act, cnt, [4 stats buffers], [health
             # word, stall], counters) — the counters ride LAST
@@ -1193,6 +1236,18 @@ class PushEngine(AuditableEngine):
         self._register_variant(vname, jitted, _args_thunk)
 
         def mark(it, counts):
+            # the bucket schedule's counts (0 on an engine without
+            # delta): advances = loop trips that relaxed nothing (so
+            # iters + advances = trips), front_edges = the edges the
+            # relax trips relaxed (a truncated sparse front: the
+            # prefix it expanded), folded as the fills, graph_edges =
+            # the stored edges, once a call
+            bucket = {"advances": 0, "front_edges": 0, "graph_edges": 0}
+            if use_delta:
+                counts, (adv, words) = counts[:-2], counts[-2:]
+                bucket = {"advances": adv,
+                          "front_edges": fr.Folded(words, 0),
+                          "graph_edges": int(sg.ne)}
             # pull_iters is 0 where the step is not built; the four
             # fill counts are 0 on an engine without a ladder, else
             # each ONE number folded from the carry's two words when
@@ -1205,7 +1260,8 @@ class PushEngine(AuditableEngine):
                 **dict(zip(("sparse_iters", "low_rung_iters",
                             "pull_iters"), (*took, 0))),
                 **{n: fr.Folded(fill[0], i) if fill else 0
-                   for i, n in enumerate(names)})
+                   for i, n in enumerate(names)},
+                **bucket)
 
         if health:
             from lux_tpu import health as _hw
@@ -1234,7 +1290,9 @@ class PushEngine(AuditableEngine):
             ``queue_items`` / ``queue_slots`` / ``budget_edges`` /
             ``budget_slots``: over the call's sparse iterations the
             vertices compacted and the edges expanded, summed over
-            the parts, beside the rungs they ran on x parts."""
+            the parts, beside the rungs they ran on x parts.  A
+            delta engine's ``advances`` and ``front_edges`` likewise;
+            its ``graph_edges`` is the host's."""
             out = jitted(label, active, jnp.int32(max_iters), *extra,
                          *graph_args)
             if not converge:

@@ -1,70 +1,143 @@
-"""Delta-stepping bucket-width sweep at the bench shape (VERDICT r3
-next #7): BENCH_r03 measured sssp-delta (delta=mean weight) BELOW
-plain frontier relaxation.  Structural context: every iteration of
-the push engine is fixed-shape (dense = all edges; sparse = static
-queue_cap/edge_budget), so delta-stepping cannot shrink per-iteration
-cost — it can only (a) flip iterations from dense to the much cheaper
-sparse path by keeping frontiers under nv/16, or (b) waste time on
-relax-free bucket advances.  This sweep measures where that trade
-lands.
+"""Bucket-width sweep of weighted SSSP on the benchmark's Graph500
+kernel 3 graph (cell ``ssspw.kron21.delta``): SECONDS a search to the
+converged answer, with the relax iterations, the relax-free advances
+and the dense / sparse split beside them.
 
-Usage:
-  PYTHONPATH=/root/repo python scripts/sweep_delta.py \
-      [scale=21] [ef=16] [repeats=3]
+Every iteration of the push engine is fixed-shape (dense = all edges;
+sparse = the ladder's rungs), so a narrower bucket cannot shrink an
+iteration: it trades re-relaxed edges (fewer) against loop trips
+(more), and it moves iterations from the dense to the sparse branch.
+Where that trade lands is read here in seconds, never in
+``ne x iterations`` (which rewards wasted iterations).
 
-Prints one JSON line per width: the timed converge (median of
-repeats), iterations, and GTEPS alongside the plain (delta=None) run.
+    python3 scripts/sweep_delta.py
+        [--widths none,0.01,auto,0.1,0.25,1.0] [--out FILE.json]
+
+The graph, the roots and the engine's options are the cell's own
+(``benchmarks/configs/kron21-sssp.json``, ``traffic/sssp-roots.json``;
+the cache entry of ``benchmarks/kron_weighted_cache.py``, generated on
+the first use), loaded and laid out as the cell's runner does.  Every
+width's answers are compared with the first width's, bit for bit.
+One JSON object a width on standard output, then a table.
 """
 
 from __future__ import annotations
 
+import argparse
 import json
+import os
+import statistics
 import sys
 import time
+import types
+
+sys.path.insert(0, os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))))
 
 
-def main():
-    scale = int(sys.argv[1]) if len(sys.argv) > 1 else 21
-    ef = int(sys.argv[2]) if len(sys.argv) > 2 else 16
-    repeats = int(sys.argv[3]) if len(sys.argv) > 3 else 3
+def _width(text: str):
+    return None if text == "none" else text if text == "auto" \
+        else float(text)
 
-    import numpy as np
 
+def main(argv=None) -> int:
+    ap = argparse.ArgumentParser(prog="scripts/sweep_delta.py")
+    ap.add_argument("--widths", default="none,0.01,auto,0.1,0.25,1.0")
+    ap.add_argument("--out", default=None)
+    args = ap.parse_args(argv)
+
+    import jax
+
+    from benchmarks import harness
+    from benchmarks.reference import sssp as ref
+    from benchmarks.runners import batch_sssp
+    from lux_tpu import runtime, telemetry
     from lux_tpu.apps import sssp
-    from lux_tpu.convert import rmat_graph
-    from lux_tpu.graph import pair_relabel
-    from lux_tpu.timing import timed_converge
 
-    t0 = time.time()
-    g = rmat_graph(scale=scale, edge_factor=ef, seed=0)
-    rng = np.random.default_rng(1)
-    g.weights = rng.integers(1, 6, size=g.ne).astype(np.int32)
-    g2, perm, starts = pair_relabel(g, 1, pair_threshold=16)
-    rank = np.empty(g.nv, np.int64)
-    rank[perm] = np.arange(g.nv)
-    start = int(rank[0])
-    print(f"# graph ready nv={g.nv} ne={g.ne} ({time.time()-t0:.0f}s)",
-          flush=True)
+    runtime.use_compile_cache()
+    t0 = time.perf_counter()
+    config = harness.load_json(
+        harness.HERE + "/configs/kron21-sssp.json")
+    options = {k: v for k, v in config["engine"].items()
+               if k != "delta"}
+    run = types.SimpleNamespace(
+        config=config, graph={}, seed=0, traffic=harness.load_json(
+            harness.HERE + "/traffic/sssp-roots.json"))
+    paths = batch_sssp.cached_graph(run)
+    roots = batch_sssp.fixed_roots(run, paths)
+    g_run, perm, sg = batch_sssp.load_and_layout(run, paths)
+    st = types.SimpleNamespace(perm=perm, sg=sg, nv=run.graph["nv"],
+                               rank=batch_sssp.rank_of(perm))
+    print(f"# graph ready nv={g_run.nv} ne={g_run.ne} "
+          f"({time.perf_counter() - t0:.0f} s); roots "
+          f"{[int(r) for r in roots]}; platform "
+          f"{jax.devices()[0].platform}", flush=True)
 
-    want = None
-    for delta in [None, 1.0, 2.0, "auto", 5.0, 8.0, 16.0, 64.0]:
-        eng = sssp.build_engine(g2, start_vertex=start, num_parts=1,
-                                weighted=True, delta=delta,
-                                pair_threshold=16, starts=starts)
-        labels, iters, elapsed = timed_converge(eng, repeats=repeats)
-        if want is None:
-            want = labels
-        else:
-            np.testing.assert_allclose(labels, want, rtol=1e-6)
-        med = sorted(elapsed)[len(elapsed) // 2]
-        print(json.dumps({
-            "delta": ("none" if delta is None else
-                      round(eng.delta or 0, 3) if delta == "auto"
-                      else delta),
-            "iters": int(iters),
-            "elapsed": [round(e, 3) for e in elapsed],
-            "gteps": round(g.ne * iters / med / 1e9, 4)}), flush=True)
+    def mark():
+        return [r for r in telemetry.spans()
+                if r["name"] == "push.converge"][-1]["counts"]
+
+    rows, first = [], None
+    for text in args.widths.split(","):
+        t1 = time.perf_counter()
+        st.eng = sssp.build_engine(
+            g_run, start_vertex=0, num_parts=1, weighted=True,
+            delta=_width(text), sg=sg, **options)
+        batch_sssp.search(None, st, roots[0])        # compile, warm
+        build_s = time.perf_counter() - t1
+        seconds, sums, answers = [], {}, []
+        for root in roots:
+            s, _iters, answer = batch_sssp.search(None, st, root)
+            seconds.append(s)
+            for k, v in mark().items():
+                sums[k] = sums.get(k, 0) + int(v)
+            answers.append(answer)
+        if first is None:
+            first = answers
+        differ = sum(ref.mismatched(a, b)
+                     for a, b in zip(answers, first))
+        n = len(roots)
+        row = {"delta": text,
+               "resolved": None if st.eng.delta is None
+               else float(st.eng.delta),
+               "median_s": statistics.median(seconds),
+               "total_s": sum(seconds),
+               "iters": sums["iters"] / n,
+               "advances": sums["advances"] / n,
+               "dense_iters": (sums["iters"] - sums["sparse_iters"]) / n,
+               "sparse_iters": sums["sparse_iters"] / n,
+               "low_rung_iters": sums["low_rung_iters"] / n,
+               "relaxed_edge_ratio": (
+                   sums["front_edges"] / sums["graph_edges"]
+                   if sums["graph_edges"] else None),
+               "differ_from_first": differ,
+               "build_s": build_s,
+               "seconds": seconds}
+        rows.append(row)
+        print(json.dumps(row), flush=True)
+        st.eng = None
+
+    print("| delta | resolved | median s a search | sum of the "
+          f"{len(roots)} | relax iterations | advances | dense | "
+          "sparse (low rung) | edges relaxed / stored | differ |")
+    print("|---|---|---|---|---|---|---|---|---|---|")
+    for r in rows:
+        ratio = "-" if r["relaxed_edge_ratio"] is None \
+            else f"{r['relaxed_edge_ratio']:.3f}"
+        res = "-" if r["resolved"] is None else f"{r['resolved']:.5g}"
+        print(f"| {r['delta']} | {res} | {r['median_s']:.4f} | "
+              f"{r['total_s']:.3f} | {r['iters']:.1f} | "
+              f"{r['advances']:.1f} | {r['dense_iters']:.1f} | "
+              f"{r['sparse_iters']:.1f} ({r['low_rung_iters']:.1f}) | "
+              f"{ratio} | {r['differ_from_first']} |")
+    if args.out:
+        os.makedirs(os.path.dirname(os.path.abspath(args.out)),
+                    exist_ok=True)
+        with open(args.out, "w") as f:
+            json.dump({"platform": jax.devices()[0].platform,
+                       "scale": config["scale"], "rows": rows}, f, indent=1)
+    return 1 if any(r["differ_from_first"] for r in rows) else 0
 
 
 if __name__ == "__main__":
-    main()
+    sys.exit(main())

@@ -78,11 +78,12 @@ SERVING = [(cell, kind) for cell in CELLS
            for kind in _kinds(_config(cell))]
 
 
-def _small_graph(app: str, symmetrized: bool):
+def _small_graph(app: str, symmetrized: bool, weighted: bool = False):
     """A graph of the cell's KIND at a size that lowers in a second:
     R-MAT scale 10 x 16, symmetrized where the cell's is (the push
     engine builds its bottom-up step on symmetric graphs only),
-    integer ratings 1..5 as weights for colfilter."""
+    integer ratings 1..5 as weights for colfilter, float32 weights
+    uniform in [0, 1) where the configuration says ``weighted``."""
     from lux_tpu.apps import components
     from lux_tpu.convert import rmat_graph
     from lux_tpu.graph import Graph
@@ -94,6 +95,9 @@ def _small_graph(app: str, symmetrized: bool):
     if app == "colfilter":
         g.weights = np.random.default_rng(1).integers(
             1, 6, size=g.ne).astype(np.int32)
+    elif weighted:
+        g.weights = np.random.default_rng(1).random(
+            g.ne, dtype=np.float32)
     return g
 
 
@@ -109,7 +113,8 @@ def _batch_engine(c: dict, engine=None):
     app = importlib.import_module("lux_tpu.apps." + c["app"])
     opts = c.get("engine", {}) if engine is None else engine
     num_parts, pair = int(c["num_parts"]), opts.get("pair_threshold")
-    g = _small_graph(c["app"], bool(c.get("symmetrized")))
+    weighted = bool(c.get("weighted"))
+    g = _small_graph(c["app"], bool(c.get("symmetrized")), weighted)
     starts = None
     if pair is not None:
         g, _perm, starts = pair_relabel(g, num_parts,
@@ -120,7 +125,7 @@ def _batch_engine(c: dict, engine=None):
         else None
     kw = dict(num_parts=num_parts, mesh=mesh, sg=sg, **opts)
     if c["app"] == "sssp":
-        kw.update(start_vertex=0, weighted=False)
+        kw.update(start_vertex=0, weighted=weighted)
     return app.build_engine(g, **kw)
 
 
@@ -178,6 +183,16 @@ def test_every_cell_has_a_form():
         c = _config(cell)
         assert "app" in c or _kinds(c), cell
     assert METRIC_CELLS and NAMED_SCOPES and SERVING
+
+
+@pytest.mark.parametrize("cell", [
+    cell for cell in CELLS if "app" in _config(cell)
+    and _config(cell).get("engine", {}).get("delta") is None])
+def test_cells_without_delta_carry_no_bucket_scope(cell):
+    """``lux_bucket`` is the bucket (delta-stepping) loop's alone: the
+    batch cells whose configuration sets no ``engine.delta`` keep the
+    programs they had."""
+    assert "lux_bucket" not in _scopes(cell), cell
 
 
 @pytest.mark.parametrize("metric,cell", METRIC_CELLS)

@@ -1,0 +1,297 @@
+"""Weighted SSSP with REAL-valued weights (Graph500 kernel 3's
+float32 uniform [0, 1)) under every schedule the push engine offers.
+
+The integer-weighted tests compare float32 sums of small integers,
+which are exact whatever the order; here every addition rounds.  The
+contract is still exact: ``fl32(a + w)`` is monotone in ``a``, so the
+fixed point of the monotone min is unique whatever the schedule, and
+the engine must reach the plain reference's
+(``benchmarks/reference/sssp.py``) bit for bit.
+
+R-MAT scale 10 x 16, symmetrized with one weight a generated tuple
+(both stored directions carry it), 4 roots of non-zero degree.
+"""
+
+import functools
+import types
+
+import numpy as np
+import pytest
+
+from benchmarks.reference import edge_weights
+from benchmarks.reference import sssp as ref
+from lux_tpu import cli, telemetry
+from lux_tpu import format as luxfmt
+from lux_tpu.apps import sssp
+from lux_tpu.convert import rmat_edges
+from lux_tpu.graph import Graph, ShardedGraph, pair_relabel
+
+SCALE, EF, SEED = 10, 16, 5
+NV = 1 << SCALE
+DELTAS = (None, 0.1, "auto")
+SCHEDULES = [(d, s) for d in DELTAS for s in (True, False)]
+
+
+@functools.lru_cache(maxsize=None)
+def _arcs():
+    """(src, dst, w) as stored, and the reference's destination-sorted
+    (offsets, src, w)."""
+    s, d = rmat_edges(SCALE, EF, seed=SEED)[:2]
+    w = edge_weights.tuple_weights(len(s), SEED)
+    src, dst, w = edge_weights.both_directions(
+        s.astype(np.uint32), d.astype(np.uint32), w)
+    return (src, dst, w), edge_weights.by_destination(src, dst, w, NV)
+
+
+@functools.lru_cache(maxsize=None)
+def _roots():
+    (src, _dst, _w), _ = _arcs()
+    has_edge = np.flatnonzero(np.bincount(src, minlength=NV))
+    return tuple(int(v) for v in np.random.default_rng(SEED).choice(
+        has_edge, size=4, replace=False))
+
+
+@functools.lru_cache(maxsize=None)
+def _want(root: int):
+    _, (offsets, src, w) = _arcs()
+    return ref.fixed_point_f32(offsets, src, w, root)[0]
+
+
+@functools.lru_cache(maxsize=None)
+def _laid_out(weights_as=None):
+    """(graph as the engine runs it, rank[file id] = engine id, sharded
+    layout), relabelled for pair rows as the cell's runner does."""
+    (src, dst, w), _ = _arcs()
+    if weights_as is not None:
+        w = weights_as(w)
+    g = Graph.from_edges(src, dst, NV, weights=w)
+    g_run, perm, starts = pair_relabel(g, 1, pair_threshold=16)
+    sg = ShardedGraph.build(g_run, 1, starts=starts, pair_threshold=16)
+    rank = np.empty(NV, np.int64)
+    rank[perm] = np.arange(NV)
+    return g_run, perm, rank, sg
+
+
+def _engine(delta, sparse, weights_as=None):
+    g_run, _perm, _rank, sg = _laid_out(weights_as)
+    return sssp.build_engine(g_run, start_vertex=0, weighted=True,
+                             delta=delta, sg=sg, pair_threshold=16,
+                             enable_sparse=sparse)
+
+
+def _start(eng, sg, r):
+    label = np.full(NV, np.inf, dtype=np.float32)
+    active = np.zeros(NV, dtype=bool)
+    label[r], active[r] = 0, True
+    return eng.place(sg.to_padded(label), sg.to_padded(active))
+
+
+@functools.lru_cache(maxsize=None)
+def _answers(delta, sparse, weights_as=None):
+    """root -> distances in the FILE's vertex ids, one engine and one
+    executable for the four roots."""
+    _g, perm, rank, sg = _laid_out(weights_as)
+    eng = _engine(delta, sparse, weights_as)
+    out = {}
+    for root in _roots():
+        label, _active, _it = eng.converge(
+            *_start(eng, sg, int(rank[root])))
+        got = np.empty(NV, np.float32)
+        got[perm] = eng.unpad(label)
+        out[root] = got
+    return out
+
+
+def test_auto_resolves_to_a_finite_width_the_largest_weight():
+    (_src, _dst, w), _ = _arcs()
+    eng = _engine("auto", True)
+    assert eng.delta == float(w.max()) and 0 < eng.delta < np.inf
+    assert sssp.default_delta(Graph.from_edges(
+        [0, 1], [1, 0], 2, weights=np.zeros(2, np.float32))) == 1.0
+
+
+@pytest.mark.parametrize("root", range(4))
+@pytest.mark.parametrize("delta,sparse", SCHEDULES)
+def test_engine_reaches_the_float32_fixed_point_bit_for_bit(
+        delta, sparse, root):
+    root = _roots()[root]
+    assert ref.mismatched(_answers(delta, sparse)[root],
+                          _want(root)) == 0
+
+
+@pytest.mark.parametrize("root", range(4))
+@pytest.mark.parametrize("delta,sparse", SCHEDULES)
+def test_answer_is_the_shortest_path_to_rounding(delta, sparse, root):
+    """Within 1e-6 relative of a float64 Dijkstra: a path of a dozen
+    or two float32 additions, each off by at most 6e-8."""
+    root = _roots()[root]
+    true = _dijkstra(root)
+    got = _answers(delta, sparse)[root].astype(np.float64)
+    assert np.array_equal(np.isfinite(got), np.isfinite(true))
+    reached = np.isfinite(true) & (true > 0)
+    assert reached.sum() > NV // 2
+    gap = np.abs(got[reached] - true[reached]) / true[reached]
+    assert gap.max() <= 1e-6
+    assert got[root] == 0
+
+
+@functools.lru_cache(maxsize=None)
+def _dijkstra(root: int):
+    _, (offsets, src, w) = _arcs()
+    return ref.dijkstra_f64(offsets, src, w, root)
+
+
+@pytest.mark.parametrize("root", range(4))
+@pytest.mark.parametrize("delta,sparse", SCHEDULES)
+def test_answer_violates_no_edge(delta, sparse, root):
+    root = _roots()[root]
+    (src, dst, w), _ = _arcs()
+    got = _answers(delta, sparse)[root]
+    assert ref.edges_violated(got, src, dst, w) == 0
+    assert ref.roots_nonzero(got, root) == 0
+
+
+@pytest.mark.parametrize("root", range(4))
+def test_control_bfloat16_weights_fail_the_exact_comparison(root):
+    """The limit 0 separates sound from unsound: an engine that sees
+    the weights rounded to bfloat16 misses the fixed point on
+    hundreds of vertices and breaks the edge rule."""
+    root = _roots()[root]
+    (src, dst, w), _ = _arcs()
+    got = _answers("auto", True, ref.to_bfloat16)[root]
+    assert ref.mismatched(got, _want(root)) > NV // 4
+    assert ref.edges_violated(got, src, dst, w) > 0
+
+
+def _replay(eng, sg, r):
+    """The bucket schedule on the host, trip by trip, each relax trip
+    one ``converge`` of the engine capped at ONE iteration on the
+    bucket's front (every front label lies under ``min + delta``, so
+    that call's own first bucket is the whole front): -> (relax trips,
+    advance trips, out-edges of the fronts the relax trips entered
+    with, edges those trips relaxed: a sparse trip's are what its
+    budget stage expanded, by that call's own mark)."""
+    delta = np.float32(eng.delta)
+    deg = np.asarray(sg.deg_padded).astype(np.int64)
+    label, active = (np.asarray(x) for x in _start(eng, sg, r))
+    bound = np.float32(label[active].min() + delta)
+    relaxes = advances = offered = relaxed = 0
+    while active.any():
+        front = active & (label < bound)
+        if front.any():
+            new_label, new_active, it = eng.converge(
+                *eng.place(label.copy(), front), max_iters=1)
+            trip = _last_mark()
+            assert int(it) == 1 and trip["advances"] == 0
+            edges = int(deg[front].sum())
+            did = trip["budget_edges"] if trip["sparse_iters"] \
+                else edges
+            assert trip["front_edges"] == did <= edges
+            offered += edges
+            relaxed += did
+            label = np.asarray(new_label)
+            active = (active & ~front) | np.asarray(new_active)
+            relaxes += 1
+        else:
+            low = np.float32(label[active].min())
+            bound = max(np.float32(low + delta),
+                        np.nextafter(low, np.float32(np.inf)))
+            advances += 1
+    return relaxes, advances, offered, relaxed
+
+
+def _last_mark():
+    return [r for r in telemetry.spans()
+            if r["name"] == "push.converge"][-1]["counts"]
+
+
+@pytest.mark.parametrize("root", range(2))
+@pytest.mark.parametrize("sparse", [True, False])
+@pytest.mark.parametrize("delta", [0.1, "auto"])
+def test_mark_counts_the_bucket_loops_trips(delta, sparse, root):
+    """``iters`` + ``advances`` = the loop's trips, ``front_edges`` =
+    the edges its relax trips relaxed, ``graph_edges`` = the stored
+    edges.  ``converge_stats``' per-iteration edges are the fronts'
+    out-edges, OFFERED: the same number on an engine without the
+    ladder, more where a sparse trip's front overflowed the edge
+    budget and only a prefix was relaxed."""
+    _g, _perm, rank, sg = _laid_out()
+    r = int(rank[_roots()[root]])
+    eng = _engine(delta, sparse)
+    relaxes, advances, offered, relaxed = _replay(eng, sg, r)
+    assert advances > 0 and relaxes > 0
+    _l, _a, it = eng.converge(*_start(eng, sg, r))
+    got = _last_mark()
+    assert int(it) == got["iters"] == relaxes
+    assert got["advances"] == advances
+    assert got["iters"] + got["advances"] == relaxes + advances
+    assert got["front_edges"] == relaxed
+    assert got["graph_edges"] == sg.ne
+    out = eng.converge_stats(*_start(eng, sg, r))
+    per_iter = np.asarray(out[4]).astype(np.int64)
+    assert int(per_iter.sum()) == offered
+    assert _last_mark()["front_edges"] == relaxed
+    if not sparse:
+        assert relaxed == offered
+
+
+def test_a_truncated_front_counts_the_prefix_it_relaxed():
+    """Scale 10's top edge budget is 2,048 edges; a hub in a sparse
+    front overflows it, the trip relaxes a prefix and the rest stays
+    active: ``front_edges`` counts what was relaxed, not the front
+    whole on every trip."""
+    _g, _perm, rank, sg = _laid_out()
+    eng = _engine("auto", True)
+    tops = [_replay(eng, sg, int(rank[v]))[2:] for v in _roots()]
+    assert any(relaxed < offered for offered, relaxed in tops)
+    assert all(relaxed <= offered for offered, relaxed in tops)
+
+
+def test_mark_of_an_engine_without_delta_counts_zeros():
+    _g, _perm, rank, sg = _laid_out()
+    eng = _engine(None, True)
+    eng.converge(*_start(eng, sg, int(rank[_roots()[0]])))
+    got = _last_mark()
+    assert (got["advances"], got["front_edges"],
+            got["graph_edges"]) == (0, 0, 0)
+    assert got["iters"] > 0
+
+
+@pytest.mark.parametrize("weight_type", ["float32", "int32"])
+def test_cli_sssp_weighted_round_trips_the_files_weight_type(
+        weight_type, tmp_path, capsys):
+    """``cli sssp -weighted -weight-type float32`` reads a file of
+    float32 weights as what they are: the run passes ``-check`` and
+    the graph the entry point loaded gives the reference's distances;
+    a file of int32 weights loads as it always did (no flag)."""
+    (src, dst, w), (offsets, by_src, by_w) = _arcs()
+    root = _roots()[0]
+    if weight_type == "int32":
+        w = (1 + np.floor(w * 5)).astype(np.int32)
+        by_w = (1 + np.floor(by_w * 5)).astype(np.float32)
+    g = Graph.from_edges(src, dst, NV, weights=w)
+    path = str(tmp_path / "g.lux")
+    luxfmt.write_lux(path, g.row_ptrs, g.col_idx, weights=g.weights,
+                     degrees=g.out_degrees)
+    flags = ["-weight-type", "float32"] if weight_type == "float32" \
+        else []
+    rc = cli.main(["sssp", "-file", path, "-weighted", *flags,
+                   "-start", str(root), "-delta", "auto", "-pair",
+                   "16", "-check"])
+    assert rc == 0 and "[PASS]" in capsys.readouterr().out
+
+    def loaded(**kw):           # the entry point's own loader
+        return cli._load(types.SimpleNamespace(
+            file=path, verbose=False, **kw), True)
+    want = ref.fixed_point_f32(offsets, by_src, by_w, root)[0]
+    got, _iters = sssp.run(
+        loaded(**({"weight_type": "float32"} if flags else {})),
+        start_vertex=root, weighted=True, delta="auto")
+    assert ref.mismatched(np.asarray(got, np.float32), want) == 0
+    if weight_type == "float32":
+        # read as the default int32 the same file's bits are other
+        # numbers: nothing like the reference's distances comes out
+        got, _iters = sssp.run(loaded(), start_vertex=root,
+                               weighted=True)
+        assert ref.mismatched(np.asarray(got, np.float32),
+                              want) > NV // 2
