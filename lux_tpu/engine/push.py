@@ -77,16 +77,26 @@ The TPU-native design:
   mark zeros.
 - Under ``delta`` the loop is the bucket schedule (delta-stepping): a
   trip relaxes the current bucket's front, or, where that is empty,
-  only raises the bucket bound.  The front mask, its count, the
-  active minimum and the advance lie under the scope ``lux_bucket``,
-  and the mark carries ``advances`` (trips that relaxed nothing:
-  ``iters + advances`` = trips), ``front_edges`` (the edges the relax
-  trips relaxed, summed: a dense trip its front's out-edges, a sparse
-  one what its budget stage expanded, so a front that overflows the
-  top rung counts a prefix a trip, not whole every trip; two words,
-  as the fills) and ``graph_edges`` (the stored edges, once a call):
-  how often the schedule re-relaxes an edge, against the trips it
-  pays.
+  only raises the bucket bound.  A relax trip chooses its branch by
+  the front's OUT-EDGES as well as its vertex count: the bound keeps
+  the front's count under the queue's limit while the few vertices
+  under it may be hubs, and a front whose out-edges pass the top
+  budget rung would be relaxed a truncated prefix a trip, trip after
+  trip, each at a dense trip's price; it takes the dense branch,
+  whole, once (the plain loop keeps ``_choose``'s count alone: there
+  a truncated level is a cheap head start and the next front runs
+  dense anyway; PERF.md section 6, PR 44).  The front mask, its
+  count, its out-edge total, the active minimum and the advance lie
+  under the scope ``lux_bucket``, and the mark carries ``advances``
+  (trips that relaxed nothing: ``iters + advances`` = trips),
+  ``front_edges`` (the edges the relax trips relaxed, summed: a
+  dense trip its front's out-edges, a sparse one what its budget
+  stage expanded; two words, as the fills), ``graph_edges`` (the
+  stored edges, once a call): how often the schedule re-relaxes an
+  edge, against the trips it pays; and ``edge_dense_iters`` (relax
+  trips whose front fit the queue by vertex count and ran dense for
+  its out-edges: ``iters`` = ``sparse_iters`` + dense by count +
+  these).
   An engine without ``delta`` carries none of them and marks zeros.
 - Sparse overflow safety: when a frontier's out-edges exceed the
   static edge budget, the un-expanded queue suffix simply STAYS
@@ -754,6 +764,33 @@ class PushEngine(AuditableEngine):
         pull = ~q_fits & fits & guard
         return (q_fits | pull, pull, jnp.where(pull, t_most, count), T)
 
+    def _spills(self, edges):
+        """The bucket loop's half of the choice, traced -> replicated
+        bool: a front of ``edges`` out-edges (uint32, summed over the
+        mesh) passes the top budget rung, the size at which
+        _sparse_parts starts to truncate, and runs DENSE whatever its
+        vertex count.  The bucket bound keeps a front's count under
+        the queue's limit while the few vertices under it are hubs;
+        relaxed a truncated prefix a trip, at a dense trip's price,
+        the rest meets the same bound on the next trip and truncates
+        again (25 trips in a row from one root of Graph500 kernel 3:
+        PERF.md section 6, PR 44), where one dense trip relaxes the
+        front whole.  The plain loop does not ask: there a truncated
+        level is a cheap head start and the next front runs dense
+        anyway (PERF.md section 7, "BFS after PR 33").
+
+        On one part the total is what frontier_extents sums, so the
+        test is exactly "this trip would truncate".  On a mesh a part
+        expands only the front's edges that land in it: a global total
+        of at most the top rung means no part truncates, a larger one
+        may send a front dense that no part would have truncated, so
+        the estimate errs towards DENSE.  Under it no relax trip of
+        the bucket loop truncates (short of a total that wraps uint32,
+        on a graph of 2^32 edges: then a trip may truncate after all).
+        The answer is the same either way: the fixed point of the
+        monotone reduce is unique whatever the schedule."""
+        return edges > jnp.uint32(self.budget_rungs[-1])
+
     def _part_index(self):
         """Global part index of this device's parts [P_local] int32."""
         P_local = self.sg.num_parts if self.mesh is None else \
@@ -795,7 +832,7 @@ class PushEngine(AuditableEngine):
         graph_args = tuple(self.arrays[k] for k in keys)
         on_mesh = self.mesh is not None
         sg, prog = self.sg, self.program
-        use_sparse, _limit, pull_built = self._sparse_mode()
+        use_sparse, limit, pull_built = self._sparse_mode()
         # the loops' counter carry, last: (took, fill).  took: int32
         # sparse_iters, low_rung_iters and, where the bottom-up step
         # is built, pull_iters.  fill (engines with a ladder, else
@@ -910,7 +947,7 @@ class PushEngine(AuditableEngine):
                 full_l, full_a = label, active
             return self._dense_parts(label, active, full_l, full_a, g)
 
-        def body(label, active, count, g):
+        def body(label, active, count, g, spills=None):
             """-> (label, active, took, fill): took = int32 [n_took],
             1 if the SPARSE branch ran, 1 if it ran below the top edge
             budget and (engines with the bottom-up step) 1 if it ran
@@ -918,7 +955,10 @@ class PushEngine(AuditableEngine):
             / ``low_rung_iters`` / ``pull_iters`` carry (the
             device-side counters telemetry reads); fill = the sparse
             branch's four uint32 scalars (_sparse_parts; zeros from
-            the dense one), None on an engine without a ladder."""
+            the dense one), None on an engine without a ladder.
+            spills (the bucket loop's alone, else None: no op) is its
+            replicated flag that the front's out-edges pass the top
+            budget rung: such a front runs dense."""
             if not use_sparse:
                 return (*dense_body(label, active, g),
                         jnp.zeros((2,), jnp.int32), None)
@@ -929,6 +969,8 @@ class PushEngine(AuditableEngine):
             # where the UNREACHED vertices fit instead (_choose).
             sparse, pull, need, unreached = self._choose(
                 label, active, count, g)
+            if spills is not None:
+                sparse = sparse & ~spills
 
             def sparse_branch():
                 with jax.named_scope("lux_sparse"):
@@ -950,8 +992,8 @@ class PushEngine(AuditableEngine):
             return nl, na, jnp.stack(took), fill
 
         use_delta = converge and self.delta is not None
-        # the delta loop's two outputs behind the shared counts
-        n_counts += 2 * int(use_delta)
+        # the delta loop's three outputs behind the shared counts
+        n_counts += 3 * int(use_delta)
 
         def inner(label, active, max_iters, *gargs):
             if health:
@@ -1032,7 +1074,10 @@ class PushEngine(AuditableEngine):
                 # ones in the LAST carry element, (tally, bucket):
                 # bucket = (advances int32: trips that relaxed nothing,
                 # front_edges: the edges each relax trip relaxed,
-                # summed, in two uint32 words as the fills)
+                # summed, in two uint32 words as the fills,
+                # edge_dense int32: relax trips whose front fit the
+                # queue by vertex count and ran dense for its
+                # out-edges: `relax` below)
                 def wbody(c):
                     it, lbl, act, B, cnt = c[:5]
                     buf = c[5:]
@@ -1041,7 +1086,20 @@ class PushEngine(AuditableEngine):
                         nf = global_sum(front)
 
                     def relax(it, lbl, act, B, *buf):
-                        ctr, (adv, fe) = buf[-1]
+                        # the front's out-edge total, once: the
+                        # choice of the branch reads it (_spills) and
+                        # so does the front_edges count
+                        ctr, (adv, fe, edge_dense) = buf[-1]
+                        with jax.named_scope("lux_bucket"):
+                            edges = global_sum(
+                                jnp.where(front, g["deg"], 0)
+                                .astype(jnp.uint32))
+                            spills = None
+                            if use_sparse:
+                                spills = self._spills(edges)
+                                edge_dense = edge_dense + (
+                                    spills & (nf <= jnp.int32(limit))
+                                ).astype(jnp.int32)
                         if stats:
                             # counters record the bucket front ENTERING
                             # this relax; advances relax nothing
@@ -1057,19 +1115,12 @@ class PushEngine(AuditableEngine):
                                                    mode="drop"),
                                    fedp.at[it].set(ep, mode="drop")) \
                                 + buf[4:]
-                        nl, na, took, fill = body(lbl, front, nf, g)
-                        with jax.named_scope("lux_bucket"):
-                            # the edges this trip relaxed: the front's
-                            # out-edges, or, on a sparse trip, what
-                            # the budget stage expanded (a front past
-                            # the top rung is relaxed a prefix a trip)
-                            edges = global_sum(
-                                jnp.where(front, g["deg"], 0)
-                                .astype(jnp.uint32))
-                            if fill is not None:
-                                edges = jnp.where(took[0] > 0, fill[2],
-                                                  edges)
-                            fe = fr.wide_add(*fe, edges)
+                        nl, na, took, fill = body(lbl, front, nf, g,
+                                                  spills)
+                        # the edges this trip relaxed are the front's
+                        # out-edges: a sparse trip's budget stage
+                        # expands them all (_spills: none truncates)
+                        fe = fr.wide_add(*fe, edges)
                         merged = (act & ~front) | na
                         if health:
                             # the watchdog watches relax steps only:
@@ -1080,7 +1131,8 @@ class PushEngine(AuditableEngine):
                                 global_sum(merged))
                             buf = buf[:4] + (h, stall) + buf[6:]
                         return (it + 1, nl, merged, B, *buf[:-1],
-                                (tally(ctr, took, fill), (adv, fe)))
+                                (tally(ctr, took, fill),
+                                 (adv, fe, edge_dense)))
 
                     @jax.named_scope("lux_bucket")
                     def advance(it, lbl, act, B, *buf):
@@ -1097,9 +1149,9 @@ class PushEngine(AuditableEngine):
                             nb = jnp.maximum(
                                 nb, jnp.nextafter(
                                     am, jnp.asarray(jnp.inf, am.dtype)))
-                        ctr, (adv, fe) = buf[-1]
+                        ctr, (adv, *rest) = buf[-1]
                         return (it, lbl, act, nb, *buf[:-1],
-                                (ctr, (adv + 1, fe)))
+                                (ctr, (adv + 1, *rest)))
 
                     out = jax.lax.cond(
                         nf > 0, relax, advance, it, lbl, act, B, *buf)
@@ -1121,12 +1173,14 @@ class PushEngine(AuditableEngine):
                 zero = jnp.uint32(0)
                 out = jax.lax.while_loop(
                     cond, wbody,
-                    init + ((tally0(), (jnp.int32(0), (zero, zero))),))
+                    init + ((tally0(), (jnp.int32(0), (zero, zero),
+                                        jnp.int32(0))),))
                 # (lbl, act, it, [stats], [health], *counts_out,
-                # advances, front_edges' words [1, 2])
-                ctr, (adv, fe) = out[-1]
+                # advances, front_edges' words [1, 2], edge_dense)
+                ctr, (adv, fe, edge_dense) = out[-1]
                 return (out[1], out[2], out[0], *out[5:-1],
-                        *counts_out(ctr), adv, jnp.stack(fe)[None])
+                        *counts_out(ctr), adv, jnp.stack(fe)[None],
+                        edge_dense)
 
             # carry: (it, lbl, act, cnt, [4 stats buffers], [health
             # word, stall], counters) — the counters ride LAST
@@ -1239,15 +1293,20 @@ class PushEngine(AuditableEngine):
             # the bucket schedule's counts (0 on an engine without
             # delta): advances = loop trips that relaxed nothing (so
             # iters + advances = trips), front_edges = the edges the
-            # relax trips relaxed (a truncated sparse front: the
-            # prefix it expanded), folded as the fills, graph_edges =
-            # the stored edges, once a call
-            bucket = {"advances": 0, "front_edges": 0, "graph_edges": 0}
+            # relax trips relaxed, folded as the fills, graph_edges =
+            # the stored edges, once a call, edge_dense_iters = the
+            # relax trips whose front fit the queue by vertex count
+            # and ran dense because its out-edges pass the top budget
+            # rung (iters = sparse_iters + dense by count + these)
+            bucket = {"advances": 0, "front_edges": 0, "graph_edges": 0,
+                      "edge_dense_iters": 0}
             if use_delta:
-                counts, (adv, words) = counts[:-2], counts[-2:]
+                counts, (adv, words, edge_dense) = \
+                    counts[:-3], counts[-3:]
                 bucket = {"advances": adv,
                           "front_edges": fr.Folded(words, 0),
-                          "graph_edges": int(sg.ne)}
+                          "graph_edges": int(sg.ne),
+                          "edge_dense_iters": edge_dense}
             # pull_iters is 0 where the step is not built; the four
             # fill counts are 0 on an engine without a ladder, else
             # each ONE number folded from the carry's two words when
@@ -1291,8 +1350,9 @@ class PushEngine(AuditableEngine):
             ``budget_slots``: over the call's sparse iterations the
             vertices compacted and the edges expanded, summed over
             the parts, beside the rungs they ran on x parts.  A
-            delta engine's ``advances`` and ``front_edges`` likewise;
-            its ``graph_edges`` is the host's."""
+            delta engine's ``advances``, ``front_edges`` and
+            ``edge_dense_iters`` likewise; its ``graph_edges`` is the
+            host's."""
             out = jitted(label, active, jnp.int32(max_iters), *extra,
                          *graph_args)
             if not converge:

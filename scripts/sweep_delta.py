@@ -1,7 +1,8 @@
 """Bucket-width sweep of weighted SSSP on the benchmark's Graph500
 kernel 3 graph (cell ``ssspw.kron21.delta``): SECONDS a search to the
 converged answer, with the relax iterations, the relax-free advances
-and the dense / sparse split beside them.
+and the dense / sparse split beside them (of the dense trips, those
+whose front fit the queue and ran dense for its out-edges).
 
 Every iteration of the push engine is fixed-shape (dense = all edges;
 sparse = the ladder's rungs), so a narrower bucket cannot shrink an
@@ -105,6 +106,7 @@ def main(argv=None) -> int:
                "iters": sums["iters"] / n,
                "advances": sums["advances"] / n,
                "dense_iters": (sums["iters"] - sums["sparse_iters"]) / n,
+               "edge_dense_iters": sums["edge_dense_iters"] / n,
                "sparse_iters": sums["sparse_iters"] / n,
                "low_rung_iters": sums["low_rung_iters"] / n,
                "relaxed_edge_ratio": (
@@ -118,8 +120,9 @@ def main(argv=None) -> int:
         st.eng = None
 
     print("| delta | resolved | median s a search | sum of the "
-          f"{len(roots)} | relax iterations | advances | dense | "
-          "sparse (low rung) | edges relaxed / stored | differ |")
+          f"{len(roots)} | relax iterations | advances | dense (for "
+          "the front's out-edges) | sparse (low rung) | edges relaxed "
+          "/ stored | differ |")
     print("|---|---|---|---|---|---|---|---|---|---|")
     for r in rows:
         ratio = "-" if r["relaxed_edge_ratio"] is None \
@@ -127,7 +130,8 @@ def main(argv=None) -> int:
         res = "-" if r["resolved"] is None else f"{r['resolved']:.5g}"
         print(f"| {r['delta']} | {res} | {r['median_s']:.4f} | "
               f"{r['total_s']:.3f} | {r['iters']:.1f} | "
-              f"{r['advances']:.1f} | {r['dense_iters']:.1f} | "
+              f"{r['advances']:.1f} | {r['dense_iters']:.1f} "
+              f"({r['edge_dense_iters']:.1f}) | "
               f"{r['sparse_iters']:.1f} ({r['low_rung_iters']:.1f}) | "
               f"{ratio} | {r['differ_from_first']} |")
     if args.out:

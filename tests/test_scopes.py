@@ -152,12 +152,38 @@ def _lowered(eng) -> str:
 
 
 @functools.lru_cache(maxsize=None)
-def _form(cell: str) -> dict:
-    """engine name -> lowered text of the cell's engine form(s)."""
+def _engines(cell: str) -> dict:
+    """engine name -> engine of the cell's form(s)."""
     c = _config(cell)
     if "app" in c:
-        return {c["app"]: _lowered(_batch_engine(c))}
-    return {k: _lowered(e) for k, e in _serving_engines(c).items()}
+        return {c["app"]: _batch_engine(c)}
+    return _serving_engines(c)
+
+
+@functools.lru_cache(maxsize=None)
+def _form(cell: str) -> dict:
+    """engine name -> lowered text of the cell's engine form(s)."""
+    return {k: _lowered(e) for k, e in _engines(cell).items()}
+
+
+def _loop_carry(eng) -> int:
+    """Leaves of the carry of the fused loop's outermost
+    ``while_loop``."""
+    import jax
+
+    jitted, args = eng.audit_programs()["converge"]
+
+    def outermost(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "while":
+                return len(eqn.outvars)
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                found = outermost(sub)
+                if found:
+                    return found
+        return 0
+
+    return outermost(jitted.trace(*args()).jaxpr.jaxpr)
 
 
 def _text(cell: str) -> str:
@@ -193,6 +219,39 @@ def test_cells_without_delta_carry_no_bucket_scope(cell):
     batch cells whose configuration sets no ``engine.delta`` keep the
     programs they had."""
     assert "lux_bucket" not in _scopes(cell), cell
+
+
+# the bucket loop's choice: the front's out-edge total (uint32)
+# GREATER than the top budget rung (``PushEngine._spills``)
+SPILLS_RE = re.compile(
+    r"stablehlo\.compare\s+GT\b[^\n]*tensor<ui32>")
+# (cell, engine) of every push engine a cell runs
+PUSH_FORMS = [(cell, name) for cell, name in
+              [(cell, _config(cell)["app"]) for cell in CELLS
+               if "app" in _config(cell)] + SERVING
+              if name in ("sssp", "components")]
+
+
+@pytest.mark.parametrize("cell,name", PUSH_FORMS)
+def test_only_the_bucket_loop_compares_a_fronts_out_edges(cell, name):
+    """The choice by out-edge total is the bucket (delta-stepping)
+    loop's alone.  Every push engine whose configuration sets no
+    ``engine.delta`` keeps the plain loop: its carry is the four loop
+    words, the ``took`` counts and, on an engine with the ladder, the
+    four fills' eight words, and its text holds neither the compare
+    nor ``lux_bucket``; the bucket loop carries its bound and its
+    four own words more (``advances``, ``front_edges``' two,
+    ``edge_dense``)."""
+    eng, text = _engines(cell)[name], _form(cell)[name]
+    plain = 4 + 1 + 8 * int(eng._sparse_mode()[0])
+    if eng.delta is None:
+        assert _loop_carry(eng) == plain
+        assert not SPILLS_RE.search(text)
+        assert "lux_bucket" not in text
+    else:
+        assert _loop_carry(eng) == plain + 1 + 4
+        assert SPILLS_RE.search(text)
+        assert "lux_bucket" in text
 
 
 @pytest.mark.parametrize("metric,cell", METRIC_CELLS)

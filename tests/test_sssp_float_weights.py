@@ -24,7 +24,9 @@ from lux_tpu import cli, telemetry
 from lux_tpu import format as luxfmt
 from lux_tpu.apps import sssp
 from lux_tpu.convert import rmat_edges
+from lux_tpu.engine.push import PushEngine
 from lux_tpu.graph import Graph, ShardedGraph, pair_relabel
+from lux_tpu.parallel.mesh import make_mesh
 
 SCALE, EF, SEED = 10, 16, 5
 NV = 1 << SCALE
@@ -58,15 +60,16 @@ def _want(root: int):
 
 
 @functools.lru_cache(maxsize=None)
-def _laid_out(weights_as=None):
+def _laid_out(weights_as=None, num_parts=1):
     """(graph as the engine runs it, rank[file id] = engine id, sharded
     layout), relabelled for pair rows as the cell's runner does."""
     (src, dst, w), _ = _arcs()
     if weights_as is not None:
         w = weights_as(w)
     g = Graph.from_edges(src, dst, NV, weights=w)
-    g_run, perm, starts = pair_relabel(g, 1, pair_threshold=16)
-    sg = ShardedGraph.build(g_run, 1, starts=starts, pair_threshold=16)
+    g_run, perm, starts = pair_relabel(g, num_parts, pair_threshold=16)
+    sg = ShardedGraph.build(g_run, num_parts, starts=starts,
+                            pair_threshold=16)
     rank = np.empty(NV, np.int64)
     rank[perm] = np.arange(NV)
     return g_run, perm, rank, sg
@@ -86,6 +89,13 @@ def _start(eng, sg, r):
     return eng.place(sg.to_padded(label), sg.to_padded(active))
 
 
+def _in_file_ids(eng, perm, label):
+    """The engine's padded labels as distances by the FILE's ids."""
+    got = np.empty(NV, np.float32)
+    got[perm] = eng.unpad(label)
+    return got
+
+
 @functools.lru_cache(maxsize=None)
 def _answers(delta, sparse, weights_as=None):
     """root -> distances in the FILE's vertex ids, one engine and one
@@ -96,9 +106,7 @@ def _answers(delta, sparse, weights_as=None):
     for root in _roots():
         label, _active, _it = eng.converge(
             *_start(eng, sg, int(rank[root])))
-        got = np.empty(NV, np.float32)
-        got[perm] = eng.unpad(label)
-        out[root] = got
+        out[root] = _in_file_ids(eng, perm, label)
     return out
 
 
@@ -125,8 +133,12 @@ def test_answer_is_the_shortest_path_to_rounding(delta, sparse, root):
     """Within 1e-6 relative of a float64 Dijkstra: a path of a dozen
     or two float32 additions, each off by at most 6e-8."""
     root = _roots()[root]
+    _is_shortest_to_rounding(_answers(delta, sparse)[root], root)
+
+
+def _is_shortest_to_rounding(got, root):
     true = _dijkstra(root)
-    got = _answers(delta, sparse)[root].astype(np.float64)
+    got = got.astype(np.float64)
     assert np.array_equal(np.isfinite(got), np.isfinite(true))
     reached = np.isfinite(true) & (true > 0)
     assert reached.sum() > NV // 2
@@ -235,16 +247,146 @@ def test_mark_counts_the_bucket_loops_trips(delta, sparse, root):
         assert relaxed == offered
 
 
-def test_a_truncated_front_counts_the_prefix_it_relaxed():
-    """Scale 10's top edge budget is 2,048 edges; a hub in a sparse
-    front overflows it, the trip relaxes a prefix and the rest stays
-    active: ``front_edges`` counts what was relaxed, not the front
-    whole on every trip."""
+def _rule_off(monkeypatch):
+    """The bucket loop's choice as it was before it saw the front's
+    out-edges: the compare never holds, the vertex count decides."""
+    monkeypatch.setattr(PushEngine, "_spills",
+                        lambda self, edges: edges != edges)
+
+
+def _offered(eng, sg, r) -> int:
+    """Out-edges of the fronts a search's relax trips entered with."""
+    out = eng.converge_stats(*_start(eng, sg, r))
+    return int(np.asarray(out[4]).astype(np.int64).sum())
+
+
+def test_no_relax_trip_of_the_bucket_loop_truncates(monkeypatch):
+    """Scale 10's top edge budget is 2,209 edges and a hub in a sparse
+    front overflows it.  Such a front runs dense
+    (``edge_dense_iters``), so every relax trip relaxes its front
+    whole; with the out-edge test off the trip relaxes a prefix, the
+    rest stays active and is offered again: more trips, more edges
+    offered."""
     _g, _perm, rank, sg = _laid_out()
+    starts = [int(rank[v]) for v in _roots()]
+
+    def searches(eng):
+        """-> [(edges offered, the search's mark)] a root."""
+        return [(_offered(eng, sg, r), _last_mark()) for r in starts]
+
     eng = _engine("auto", True)
-    tops = [_replay(eng, sg, int(rank[v]))[2:] for v in _roots()]
-    assert any(relaxed < offered for offered, relaxed in tops)
-    assert all(relaxed <= offered for offered, relaxed in tops)
+    for r in starts:
+        _relaxes, _advances, offered, relaxed = _replay(eng, sg, r)
+        assert relaxed == offered
+    new = searches(eng)
+    _rule_off(monkeypatch)
+    old = searches(_engine("auto", True))
+    assert all(m["edge_dense_iters"] > 0 for _e, m in new)
+    assert all(m["edge_dense_iters"] == 0 for _e, m in old)
+    assert all(a["iters"] <= b["iters"]
+               for (_e, a), (_f, b) in zip(new, old))
+    assert sum(m["iters"] for _e, m in new) \
+        < sum(m["iters"] for _e, m in old)
+    assert sum(e for e, _m in new) < sum(e for e, _m in old)
+
+
+@pytest.mark.parametrize("root", range(3))
+@pytest.mark.parametrize("delta", [0.1, "auto", 2.0])
+def test_relax_trips_add_up_by_kind_and_the_answer_stands(delta, root):
+    """``iters`` = ``sparse_iters`` + the trips whose front passed the
+    queue's limit by vertex count + ``edge_dense_iters`` (those that
+    fit it and ran dense for their out-edges), counted against the
+    per-iteration record of ``converge_stats``; and the choice moves
+    no distance: plain frontiers' answer, the reference's fixed point
+    bit for bit, the float64 Dijkstra to rounding."""
+    _g, perm, rank, sg = _laid_out()
+    v = _roots()[root]
+    eng = _engine(delta, True)
+    label, _a, it, sizes, edges = eng.converge_stats(
+        *_start(eng, sg, int(rank[v])))[:5]
+    got = _last_mark()
+    n = int(it)
+    sizes = np.asarray(sizes)[:n].astype(np.int64)
+    edges = np.asarray(edges)[:n].astype(np.int64)
+    fits = sizes <= eng._sparse_mode()[1]
+    spills = edges > eng.budget_rungs[-1]
+    assert got["iters"] == n
+    assert got["sparse_iters"] == int((fits & ~spills).sum())
+    assert got["edge_dense_iters"] == int((fits & spills).sum())
+    assert n == (got["sparse_iters"] + int((~fits).sum())
+                 + got["edge_dense_iters"])
+    dist = _in_file_ids(eng, perm, label)
+    assert ref.mismatched(dist, _answers(None, True)[v]) == 0
+    assert ref.mismatched(dist, _want(v)) == 0
+    _is_shortest_to_rounding(dist, v)
+
+
+HUBS, LEAVES, BUDGET = 12, 60, 128
+
+
+def _hubs_behind_the_bound():
+    """The fault ISSUE 44 names, constructed: vertex 0's ONE edge
+    weighs 0.99 of the bucket width (1.0) and leads to a hub whose
+    neighbours are hubs a whisker further, so the first bound falls
+    just behind them all: a front of 11 vertices, under the queue's
+    limit (673 // 16), whose 660 out-edges (0.5 each, so their ends
+    lie beyond the bound) pass a top budget rung of 128 five times
+    over.  -> (graph, the reference's destination-sorted arcs)."""
+    hub = 1 + np.arange(HUBS)
+    nv = 1 + HUBS + (HUBS - 1) * LEAVES
+    src = np.concatenate([[0], np.full(HUBS - 1, hub[0]),
+                          np.repeat(hub[1:], LEAVES)])
+    dst = np.concatenate([hub, np.arange(1 + HUBS, nv)])
+    w = np.concatenate([[0.99], np.full(HUBS - 1, 0.001),
+                        np.full((HUBS - 1) * LEAVES, 0.5)]
+                       ).astype(np.float32)
+    return (Graph.from_edges(src, dst, nv, weights=w),
+            edge_weights.by_destination(
+                src.astype(np.uint32), dst.astype(np.uint32), w, nv))
+
+
+def test_hubs_just_behind_the_bound_run_one_dense_trip(monkeypatch):
+    """With the out-edge test the sliver of hubs is relaxed in ONE
+    dense trip; with it off, a 128-edge prefix a trip."""
+    g, (offsets, src, w) = _hubs_behind_the_bound()
+    sg = ShardedGraph.build(g, 1)
+
+    def search():
+        eng = PushEngine(sg, sssp.make_program(0, True), delta=1.0,
+                         edge_budget=BUDGET)
+        assert eng.budget_rungs[-1] == BUDGET
+        label, _a, it = eng.converge(*eng.init_state())
+        return eng.unpad(label), int(it), _last_mark()
+
+    new, new_iters, new_mark = search()
+    _rule_off(monkeypatch)
+    old, old_iters, old_mark = search()
+    assert new_mark["edge_dense_iters"] == 1
+    assert old_mark["edge_dense_iters"] == 0
+    assert old_iters - new_iters >= (HUBS - 1) * LEAVES // BUDGET - 1
+    assert old_mark["sparse_iters"] > new_mark["sparse_iters"]
+    want = ref.fixed_point_f32(offsets, src, w, 0)[0]
+    assert ref.mismatched(new, want) == ref.mismatched(old, want) == 0
+    assert np.isfinite(want).all()
+
+
+@pytest.mark.parametrize("delta", [0.1, "auto"])
+def test_bucket_loop_on_a_mesh_of_four_gives_the_one_part_answer(delta):
+    """np = 4 on the CPU's virtual devices: the choice reads the
+    GLOBAL out-edge total (an estimate that errs towards dense: a
+    part expands only the edges that land in it), and the distances
+    are the one-part engine's bit for bit."""
+    g_run, perm, rank, sg = _laid_out(num_parts=4)
+    eng = sssp.build_engine(g_run, start_vertex=0, num_parts=4,
+                            mesh=make_mesh(4), weighted=True,
+                            delta=delta, sg=sg, pair_threshold=16)
+    spilled = 0
+    for v in _roots():
+        label, _a, _it = eng.converge(*_start(eng, sg, int(rank[v])))
+        assert ref.mismatched(_in_file_ids(eng, perm, label),
+                              _answers(delta, True)[v]) == 0
+        spilled += _last_mark()["edge_dense_iters"]
+    assert spilled > 0
 
 
 def test_mark_of_an_engine_without_delta_counts_zeros():
