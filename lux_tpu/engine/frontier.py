@@ -40,6 +40,12 @@ shapes, so the design is:
 - Labels ride along with vertex ids in the queue (the reference
   gathers them from the all-parts dist region instead), so multi-chip
   sparse iterations exchange O(queue) bytes over ICI, not O(nv).
+- On the budget stage a slot does not go back to the queue for its
+  item's data (the CSR-expand trick, ``expand_extents``): each item
+  drops, at its first slot, the step from its predecessor's value,
+  and a running sum along the slots telescopes to the owning item's
+  value.  The chip fetches a row in 6-7 ns whatever the order of the
+  indices, and a slot of a running sum in a fraction of that.
 
 Everything here is per-part, static-shape, and built from sorted
 cumsum/gather primitives — no data-dependent shapes anywhere.
@@ -52,10 +58,15 @@ import jax.numpy as jnp
 
 from lux_tpu.parallel.mesh import vary_like
 
-# Block length for the MXU cumsum-as-matmul in expand_frontier: one
+# Block length for the MXU cumsum-as-matmul in expand_extents: one
 # int8 lower-triangular [B, B] matrix (64 KB) contracted per block,
 # same sizing rationale as ops/tiled.MXU_SCAN_BLOCK.
 FRONTIER_MXU_BLOCK = 256
+
+# Stride of a channel in ``_along_slots``' vector: every channel
+# starts on a whole (8, 128) int32 tile, so taking one out is a slice
+# and not a shift of lanes.
+SLOT_ALIGN = 1024
 
 # jnp.searchsorted's method for the queue-sized binary searches: the
 # rolled loop.  Unrolled ("scan_unrolled") it runs in the same time
@@ -218,67 +229,90 @@ def frontier_extents(ids, src_ids, src_off, nv: int):
         return begin, off, off[-1]
 
 
+def _along_slots(channels, start, edge_budget: int,
+                 running_sum=jnp.cumsum):
+    """Queue channels (int32 [Q] each) laid along the budget's slots
+    -> one int32 [EB] each: at slot s, the channel's value of the LAST
+    item whose first slot ``start`` is <= s, which is the slot's
+    owner.  Every item drops its step ``x_i - x_(i-1)`` (``x_(-1) =
+    0``) at its start and a running sum telescopes them: items are in
+    queue order and ``start`` never decreases, a zero-degree item
+    shares its start with the next and cancels, and the items past
+    the budget collide in slot EB, which no slot reads.  Integer
+    addition wraps, so the sum is exact for any 32-bit pattern, and
+    zeros under a scatter-ADD are the identity init.
+
+    The channels lie end to end in ONE vector (``SLOT_ALIGN``-aligned
+    strides): one scatter and one running sum whatever their number,
+    since each is one more compiled copy per rung and compiled code is
+    device memory; a channel's sum is the vector's less what ran up
+    before its first slot.  The indices ascend, and saying so spares
+    the scatter its sort of the queue."""
+    stride = -(-(edge_budget + 1) // SLOT_ALIGN) * SLOT_ALIGN
+    at = jnp.minimum(start, edge_budget)
+    zero = jnp.zeros((1,), jnp.int32)
+    marks = jnp.zeros((len(channels) * stride,), jnp.int32).at[
+        jnp.concatenate([at + k * stride
+                         for k in range(len(channels))])].add(
+        jnp.concatenate([jnp.diff(x, prepend=zero) for x in channels]),
+        indices_are_sorted=True)
+    run = running_sum(marks)
+    return [run[k * stride:k * stride + edge_budget]
+            - (run[k * stride - 1] if k else 0)
+            for k in range(len(channels))]
+
+
 def expand_extents(vals, begin, off, edge_budget: int,
                    use_mxu: bool = False):
     """The budget-sized half: one slot per frontier out-edge, up to
     ``edge_budget`` of them.
 
-    vals [Q] are the queue items' labels; begin, off are
-    ``frontier_extents``'.  Returns (edge_idx int32 [EB], src_val
-    [EB], in_range bool [EB], owner int32 [EB]): edge_idx indexes the
-    part's src-sorted edge arrays, owner is the queue index of the
-    item a slot belongs to and src_val that item's label, and slots
-    past ``min(off[-1], EB)`` are masked by in_range (with more
-    out-edges than slots the expansion is a prefix: the caller keeps
-    the un-expanded queue suffix active).
+    vals [Q] are the queue items' labels (None where the caller reads
+    no label off the slots: src_val is then None, and nothing is
+    computed for it); begin, off are ``frontier_extents``'.  Returns
+    (edge_idx int32 [EB], src_val [EB], in_range bool [EB], owner
+    int32 [EB]): edge_idx indexes the part's src-sorted edge arrays,
+    owner is the queue index of the item a slot belongs to and
+    src_val that item's label, and slots past ``min(off[-1], EB)``
+    are masked by in_range (with more out-edges than slots the
+    expansion is a prefix: the caller keeps the un-expanded queue
+    suffix active).
+
+    No slot fetches from the queue (a fetched row is 6-7 ns on the
+    chip, sorted or not; PERF.md, PR 46): what a slot needs of its
+    item runs along the slots as prefix sums (``_along_slots``), one
+    channel each for the item's queue index, for ``begin - start``
+    (the slot number plus it is the edge's index) and for the bits of
+    a 32-bit label; a label of another width is fetched by the owner.
+    ``use_mxu`` takes the owner's running sum, a count of starts, as
+    blocked triangular matmuls; the other channels' steps are full
+    32-bit patterns and run as ``jnp.cumsum`` either way.
     """
     with jax.named_scope("lux_sparse_expand"):
-        Q = begin.shape[0]
         total = off[-1]
         deg = jnp.diff(off, prepend=jnp.zeros((1,), off.dtype))
-        start = off - deg                       # begin offset per item
-        # Owner of each edge slot via the CSR-expand trick: drop each
-        # item's 1-based queue index at its first slot, then a running
-        # max spreads it across the item's extent.  (Items with
-        # deg > 0 have distinct starts, so the scatter-max never
-        # collides.)
-        marks = jnp.zeros((edge_budget + 1,), jnp.int32)
-        qidx = jnp.arange(Q, dtype=jnp.int32) + 1
+        start = off - deg                       # first slot per item
+        rides = vals is not None and vals.dtype.itemsize == 4
+        channels = [jnp.arange(1, begin.shape[0] + 1, dtype=jnp.int32),
+                    (begin - start).astype(jnp.int32)]
+        if rides:
+            channels.append(
+                jax.lax.bitcast_convert_type(vals, jnp.int32))
         if use_mxu:
-            # MXU form: because deg > 0 items have strictly increasing
-            # starts AND increasing qidx, the running max of scattered
-            # qidx equals the running SUM of scattered qidx-DELTAS
-            # (delta = qidx - previous deg>0 item's qidx telescopes,
-            # so every prefix sum lands exactly on the most recent
-            # item's qidx — including the clamped edge_budget slot,
-            # where colliding overflow deltas telescope to the last
-            # overflow qidx).  Scatter-ADD into a zero-filled buffer
-            # IS the identity init (0 = sum identity), so the
-            # identity-init audit passes this path without a pragma;
-            # the cumsum then runs as blocked triangular matmuls.
-            qm = jnp.where(deg > 0, qidx, 0)
-            run = jax.lax.cummax(qm)                 # cheap [Q] op
-            prev = jnp.concatenate(
-                [jnp.zeros((1,), jnp.int32), run[:-1]], axis=0)
-            delta = jnp.where(deg > 0, qidx - prev, 0)
-            marks = marks.at[jnp.minimum(start, edge_budget)].add(delta)
-            owner = _cumsum_matmul(marks[:edge_budget]) - 1  # [EB]
+            sums = _along_slots(channels[:1], start, edge_budget,
+                                _cumsum_matmul) \
+                + _along_slots(channels[1:], start, edge_budget)
         else:
-            # audit: allow(identity-init) — 0 deliberately marks "no
-            # item starts here": values are 1-based queue indices
-            # >= 1, and the cummax - 1 below maps an untouched 0 back
-            # to no-owner (an int32-min init would overflow that - 1).
-            marks = marks.at[jnp.minimum(start, edge_budget)].max(
-                jnp.where(deg > 0, qidx, 0))
-            owner = jax.lax.cummax(marks[:edge_budget]) - 1  # [EB]
-        owner = jnp.maximum(owner, 0)
+            sums = _along_slots(channels, start, edge_budget)
+        owner = sums[0] - 1
         slot = jnp.arange(edge_budget, dtype=off.dtype)
         in_range = slot < jnp.minimum(total, edge_budget)
-        within = slot - jnp.take(start, owner, axis=0)
-        edge_idx = (jnp.take(begin, owner, axis=0)
-                    + within).astype(jnp.int32)
-        edge_idx = jnp.where(in_range, edge_idx, 0)
-        src_val = jnp.take(vals, owner, axis=0)
+        edge_idx = jnp.where(in_range, slot + sums[1], 0)
+        if rides:
+            src_val = jax.lax.bitcast_convert_type(sums[2], vals.dtype)
+        else:
+            src_val = None if vals is None \
+                else jnp.take(vals, owner, axis=0)
         return edge_idx, src_val, in_range, owner
 
 

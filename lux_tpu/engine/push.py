@@ -571,9 +571,12 @@ class PushEngine(AuditableEngine):
 
             def on_budget(EB):
                 def relax_part(lab, begin, off, ssd, ssw):
+                    # (the two-way slot reads its label off the wide
+                    # array, whichever way it runs: none rides along)
                     edge_idx, src_val, in_range, owner = \
-                        fr.expand_extents(all_vals, begin, off, EB,
-                                          use_mxu=self.use_mxu)
+                        fr.expand_extents(
+                            all_vals if pull is None else None,
+                            begin, off, EB, use_mxu=self.use_mxu)
                     dst = jnp.take(ssd, edge_idx, axis=0)
                     w = jnp.take(ssw, edge_idx, axis=0) \
                         if ssw is not None else None

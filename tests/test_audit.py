@@ -279,12 +279,17 @@ def test_ledger_drift_violation():
 # allow= / pragma mechanics
 
 
-def test_frontier_pragma_is_honored():
-    """The push sparse path's CSR-expand scatter-max deliberately
-    inits with 0 (1-based marks; see engine/frontier.py) — its
-    ``# audit: allow(identity-init)`` pragma must suppress the
-    finding, which the clean repo-wide audit depends on."""
+def test_frontier_marks_need_no_pragma():
+    """The push sparse path's CSR-expand marks are dropped by
+    scatter-ADD into zeros, which IS the identity init (PR 46; the
+    scatter-max into zeros they replaced lived on a pragma):
+    engine/frontier.py carries no ``# audit: allow`` at all, and the
+    audit is clean all the same."""
+    import inspect
+
     from lux_tpu.apps import sssp
+    from lux_tpu.engine import frontier
+    assert "audit: allow" not in inspect.getsource(frontier)
     eng = sssp.build_engine(_graph(), 0, num_parts=2)
     findings = audit.audit_engine(eng, mode=None)
     assert [f for f in findings if f.check == "identity-init"] == []
@@ -294,38 +299,55 @@ def test_frontier_pragma_is_honored():
 def test_ladder_budgets_restated_per_rung(use_mxu, monkeypatch):
     """PR 29: the sparse branch is instantiated once per (queue rung,
     budget rung) of the ladder, and the static budgets hold for EACH
-    instantiation rather than as a loosened total: every program
-    variant carries exactly one CSR-expand marks scatter per pair
-    (operand ``[P_local, EB_r + 1]``, ``len(queue_rungs)`` of each
-    size); on the VPU form each is the one zero-initialized
-    scatter-max the frontier pragma allows (nothing else surfaces
-    with the pragma ignored), on the MXU form none needs it; and the
-    state-table gather budget of the fused loop stays what the dense
-    branch alone spends."""
+    instantiation rather than as a loosened total.  Since PR 46 every
+    program variant carries, per pair, ONE CSR-expand marks
+    scatter-add whose operand holds the three channels of a budget
+    end to end (``[P_local, 3 x stride]``: owner, edge offset, the
+    int32 label's bits), ``len(queue_rungs)`` of each size; the MXU
+    form sums the owner's channel apart, so it has two (``[stride]``
+    and ``[2 x stride]``).  No variant makes a budget-sized gather
+    from a queue-sized table: nothing a slot needs of its item is
+    fetched.  Nothing surfaces under frontier.py with pragmas
+    ignored (zeros under a scatter-add are the identity init), and
+    the state-table gather budget of the fused loop stays what the
+    dense branch alone spends."""
     from lux_tpu.apps import sssp
+    from lux_tpu.engine import frontier
     eng = sssp.build_engine(_graph(), 0, num_parts=2, use_mxu=use_mxu)
     q_rungs, eb_rungs = eng.queue_rungs, eng.budget_rungs
     assert len(q_rungs) >= 2 and len(eb_rungs) >= 2
     assert q_rungs[-1] == eng.queue_cap
     assert eb_rungs[-1] == eng.edge_budget
-    marks_prim = "scatter-add" if use_mxu else "scatter-max"
+    stride = [-(-(eb + 1) // frontier.SLOT_ALIGN) * frontier.SLOT_ALIGN
+              for eb in eb_rungs]
+    channels = (1, 2) if use_mxu else (3,)
+    want = sorted(c * st for st in stride for c in channels
+                  for _ in q_rungs)
+    queues = {eng.sg.num_parts * q for q in q_rungs}
     variants = eng.audit_programs()
     for name, (jitted, thunk) in variants.items():
         closed = audit.trace_variant(jitted, thunk())
-        sizes = [eqn.invars[0].aval.shape[-1] - 1
-                 for eqn, _, _ in audit._iter_eqns(closed.jaxpr)
-                 if eqn.primitive.name == marks_prim
-                 and eqn.invars[0].aval.dtype == np.int32
-                 and eqn.invars[0].aval.shape[-1] - 1 in eb_rungs]
-        assert sorted(sizes) == sorted(eb_rungs * len(q_rungs)), name
+        marks, fetched = [], []
+        for eqn, _, _ in audit._iter_eqns(closed.jaxpr):
+            if eqn.primitive.name not in ("scatter-add", "gather"):
+                continue
+            aval = eqn.invars[0].aval
+            if eqn.primitive.name == "scatter-add" \
+                    and aval.dtype == np.int32 \
+                    and aval.shape[-1] in want:
+                marks.append(aval.shape[-1])
+            if eqn.primitive.name == "gather" \
+                    and aval.shape[-1] in queues \
+                    and eqn.outvars[0].aval.shape[-1] in eb_rungs:
+                fetched.append(eqn.outvars[0].aval.shape)
+        assert sorted(marks) == want, name
+        assert fetched == [], name
     assert audit.audit_engine(eng, mode=None) == []
     monkeypatch.setattr(audit, "_pragma_allows",
                         lambda eqn, check, stack=(): False)
     bare = audit.audit_engine(eng, mode=None)
+    assert [f for f in bare if "frontier.py" in f.where] == []
     assert {f.check for f in bare} <= {"identity-init"}
-    want = 0 if use_mxu else len(variants) * len(q_rungs) * len(eb_rungs)
-    assert len(bare) == want
-    assert all("frontier.py" in f.where for f in bare)
 
 
 # ---------------------------------------------------------------------

@@ -255,3 +255,196 @@ def test_expand_extents_owner_is_the_repeated_queue_index(degs, budget,
                                   np.asarray(vals)[want[:k]])
     assert (np.asarray(owner) >= 0).all() and \
         (np.asarray(owner) <= len(degs)).all()
+
+
+# ---------------------------------------------------------------------
+# the budget stage without a fetch from the queue: what a slot needs of
+# its item runs along the slots as prefix sums (PR 46)
+
+_PQ = 16        # queue length of the property test: one compiled shape
+
+
+def _label_cases(dtype, n, rng):
+    """Labels whose bit patterns would show any rounding or lost
+    carry: both ends of int32; inf, -0.0, subnormals, nan in float32."""
+    if dtype == np.int32:
+        edge = np.array([-2**31, 2**31 - 1, -1, 0, 1, -2**31 + 1],
+                        np.int32)
+        rand = rng.integers(-2**31, 2**31, n).astype(np.int32)
+    else:
+        edge = np.array([np.inf, -np.inf, -0.0, 0.0, 1e-45, -1e-45,
+                         1.1754942e-38, np.nan, 3.4028235e38],
+                        np.float32)
+        rand = rng.integers(0, 2**32, n, dtype=np.uint64) \
+            .astype(np.uint32).view(np.float32)
+    mix = np.where(rng.random(n) < 0.5, rng.choice(edge, n), rand)
+    return mix.astype(dtype)
+
+
+# degrees of the queue's items (0 = a source with no out-edge here or
+# an absent one), _PQ of them: zero-degree items leading, trailing and
+# in runs, one item alone, no edge at all
+_DEG_CASES = {
+    "mixed": [2, 0, 3, 1, 0, 0, 4, 1, 0, 5, 0, 0, 0, 2, 1, 0],
+    "leading-zeros": [0, 0, 0, 3, 1, 2, 0, 1, 1, 1, 0, 2, 0, 0, 4, 1],
+    "trailing-zeros": [1, 2, 3, 4, 0, 0, 0, 0, 0, 0, 0, 0, 0, 0, 0, 0],
+    "one-hub": [0, 0, 0, 0, 0, 0, 0, 19, 0, 0, 0, 0, 0, 0, 0, 0],
+    "all-ones": [1] * 16,
+    "empty": [0] * 16,
+}
+
+
+@pytest.mark.parametrize("use_mxu", [False, True])
+@pytest.mark.parametrize("dtype", [np.int32, np.float32])
+@pytest.mark.parametrize("case", list(_DEG_CASES))
+def test_expand_extents_is_np_repeat_of_the_queue(case, dtype, use_mxu):
+    """``expand_extents`` against ``np.repeat`` of the queue positions
+    by their degrees, on budgets under, at and over the total (several
+    items past the budget among them): ``in_range`` on every slot, and
+    ``edge_idx``, ``owner`` and the label's BITS on the in-range
+    ones."""
+    deg = np.asarray(_DEG_CASES[case])
+    assert deg.size == _PQ
+    rng = np.random.default_rng(sum(map(ord, case)))
+    begin = np.where(deg > 0, rng.integers(0, 2**31 - 64, _PQ), 0) \
+        .astype(np.int32)
+    off = np.cumsum(deg).astype(np.int32)
+    total = int(off[-1])
+    vals = _label_cases(dtype, _PQ, rng)
+    owner_want = np.repeat(np.arange(_PQ), deg)
+    edge_want = np.concatenate(
+        [b + np.arange(d) for b, d in zip(begin, deg)] + [[]]) \
+        .astype(np.int32)
+    for budget in sorted({1, max(1, total - 9), max(1, total - 1),
+                          max(1, total), total + 1, total + 40}):
+        edge_idx, src_val, in_range, owner = fr.expand_extents(
+            jnp.asarray(vals), jnp.asarray(begin), jnp.asarray(off),
+            budget, use_mxu=use_mxu)
+        k = min(budget, total)
+        assert src_val.dtype == vals.dtype and owner.dtype == jnp.int32
+        assert np.asarray(in_range).tolist() == \
+            [True] * k + [False] * (budget - k)
+        np.testing.assert_array_equal(np.asarray(owner)[:k],
+                                      owner_want[:k])
+        np.testing.assert_array_equal(np.asarray(edge_idx)[:k],
+                                      edge_want[:k])
+        assert not np.asarray(edge_idx)[k:].any()
+        np.testing.assert_array_equal(
+            np.asarray(src_val)[:k].view(np.uint32),
+            vals[owner_want[:k]].view(np.uint32))
+        # a slot past the total still names a queue item: the two-way
+        # slot indexes the labels by it whatever in_range says
+        assert (np.asarray(owner) >= 0).all() and \
+            (np.asarray(owner) < _PQ).all()
+
+
+@pytest.mark.parametrize("dtype", [np.int8, np.uint16, np.float16])
+def test_expand_extents_fetches_a_label_that_is_not_32_bits(dtype):
+    """Only a 32-bit label can ride a prefix sum of int32 words; any
+    other width is fetched by the owner, as before."""
+    deg = np.asarray(_DEG_CASES["mixed"])
+    vals = (np.arange(_PQ) * 7 + 1).astype(dtype)
+    _e, src_val, in_range, _o = fr.expand_extents(
+        jnp.asarray(vals), jnp.zeros((_PQ,), jnp.int32),
+        jnp.asarray(np.cumsum(deg).astype(np.int32)), 24)
+    assert src_val.dtype == vals.dtype
+    k = int(np.asarray(in_range).sum())
+    np.testing.assert_array_equal(np.asarray(src_val)[:k],
+                                  np.repeat(vals, deg)[:k])
+
+
+def _expand_by_fetch(vals, begin, off, edge_budget, use_mxu=False):
+    """The plain form the engine is held to: a slot's owner by binary
+    search in the END offsets, everything else fetched by the owner."""
+    slot = jnp.arange(edge_budget, dtype=jnp.int32)
+    owner = jnp.minimum(
+        jnp.searchsorted(off, slot, side="right"),
+        off.shape[0] - 1).astype(jnp.int32)
+    deg = jnp.diff(off, prepend=jnp.zeros((1,), off.dtype))
+    in_range = slot < jnp.minimum(off[-1], edge_budget)
+    edge_idx = slot - (off - deg)[owner] + begin[owner]
+    return (jnp.where(in_range, edge_idx, 0).astype(jnp.int32),
+            None if vals is None else vals[owner], in_range, owner)
+
+
+_COUNTS = ("iters", "sparse_iters", "low_rung_iters", "queue_items",
+           "queue_slots", "budget_edges", "budget_slots")
+
+
+@pytest.mark.parametrize("kind", ["sssp-float32", "bfs", "components"])
+def test_engine_answers_and_counters_are_the_fetching_forms(
+        kind, monkeypatch):
+    """The same small graph through the engine as it is and through
+    one whose budget stage fetches from the queue (``_expand_by_fetch``
+    patched in before the build): labels bit for bit, and the same
+    ``iters``, rung counts and slot fills, with sparse iterations on
+    BOTH budget rungs; the labels are the oracle's too."""
+    from lux_tpu import telemetry
+    from lux_tpu.engine.push import PushEngine
+    from lux_tpu.graph import ShardedGraph
+    rng = np.random.default_rng(46)
+    # (symmetric and thin for components: the engine builds its
+    # two-way slot, and the late frontiers shrink through both rungs)
+    nv, ne = 3000, 3000 if kind == "components" else 9000
+    src, dst = rng.integers(0, nv, ne), rng.integers(0, nv, ne)
+    if kind == "components":
+        src, dst = np.concatenate([src, dst]), np.concatenate([dst, src])
+    w = rng.random(src.size).astype(np.float32) \
+        if kind == "sssp-float32" else None
+    g = Graph.from_edges(src, dst, nv, weights=w)
+    prog = components.make_program() if kind == "components" \
+        else sssp.make_program(0, w is not None)
+    sg = ShardedGraph.build(g, 1)
+
+    def solve():
+        eng = PushEngine(sg, prog, edge_budget=1600)
+        assert eng.budget_rungs == (100, 1600)
+        label, _active, _it = eng.converge(*eng.init_state())
+        mark = [r for r in telemetry.spans()
+                if r["name"] == "push.converge"][-1]["counts"]
+        return eng.unpad(label), {k: mark[k] for k in _COUNTS}
+
+    got, counts = solve()
+    monkeypatch.setattr(fr, "expand_extents", _expand_by_fetch)
+    want, want_counts = solve()
+    assert got.dtype == want.dtype
+    np.testing.assert_array_equal(got.view(np.uint32),
+                                  want.view(np.uint32))
+    assert counts == want_counts
+    assert 0 < counts["low_rung_iters"] < counts["sparse_iters"]
+    assert 0 < counts["budget_edges"] <= counts["budget_slots"]
+    if kind == "components":
+        np.testing.assert_array_equal(
+            got, components.reference_components(g))
+    elif kind == "bfs":
+        np.testing.assert_array_equal(got.astype(np.int64),
+                                      sssp.reference_sssp(g, 0))
+
+
+@pytest.mark.parametrize("use_mxu", [False, True])
+def test_expand_extents_without_labels_lays_no_label_channel(use_mxu):
+    """The two-way slot reads its label off the labels' array, so the
+    engine hands ``expand_extents`` no labels: the other outputs are
+    what they are with labels, and the marks vector is one channel
+    shorter (the channel is not computed and dropped, it is not
+    there)."""
+    import jax
+    deg = np.asarray(_DEG_CASES["mixed"])
+    begin = jnp.asarray(np.where(deg > 0, 100 * np.arange(_PQ), 0)
+                        .astype(np.int32))
+    off = jnp.asarray(np.cumsum(deg).astype(np.int32))
+    vals = jnp.arange(_PQ, dtype=jnp.int32)
+    with_labels = fr.expand_extents(vals, begin, off, 24,
+                                    use_mxu=use_mxu)
+    bare = fr.expand_extents(None, begin, off, 24, use_mxu=use_mxu)
+    assert bare[1] is None
+    for got, want in zip(bare[::2] + bare[3:], with_labels[::2]
+                         + with_labels[3:]):
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+    def slots(v):
+        jaxpr = jax.make_jaxpr(lambda b, o: fr.expand_extents(
+            v, b, o, 24, use_mxu=use_mxu)[::2])(begin, off)
+        return sum(e.invars[0].aval.shape[-1] for e in jaxpr.eqns
+                   if e.primitive.name == "scatter-add")
+    assert slots(vals) - slots(None) == fr.SLOT_ALIGN
