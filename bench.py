@@ -583,6 +583,9 @@ def run_serve_live(config, args):
     # algebra leg (round 21) the unweighted line structurally
     # couldn't (reweights=0 forever)
     g = build_graph(scale, ef, args.verbose, weighted=True)
+    # f32 as said: integer weights would give int32 distances
+    # (apps/sssp.py), and the appended / reweighted edges are f32
+    g.weights = g.weights.astype(np.float32)
     capacity = args.delta_capacity
 
     def build_tier():

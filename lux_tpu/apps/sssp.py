@@ -7,13 +7,31 @@ Two modes:
   loads edge weights and computes BFS levels (reference
   sssp_gpu.cu:122,208,225; weights unread in PushLoadTask,
   push_model.inl:60-75; SURVEY.md §7 quirks).
-- ``weighted``: true shortest paths with float edge weights, candidate
+- ``weighted``: true shortest paths with edge weights, candidate
   = dist[src] + w — the superset BASELINE.md's config list asks for.
+  The distances take their type from the weights (``distance_dtype``):
+  float weights give float32 distances (``+inf`` unreached), INTEGER
+  weights give int32 distances, summed in int32 and exact (the GAP
+  benchmark's SSSP contract), with ``HOP_INF`` for unreached.
 
 Distances of unreachable vertices stay at INF (the reference seeds
 dist = nv as its infinity, sssp_gpu.cu:733-744; we use a large sentinel
 and expose ``unreachable`` masks instead of leaking graph-size-dependent
 magic values).
+
+What the int32 distances hold.  The edge layouts keep every weight as
+float32 (graph.py, ops/pairs.py, ops/owner.py: one storage type for
+every program), which holds every integer up to 2^24 exactly, and the
+integer relax reads a weight back with an exact cast: the largest
+weight is ``INT_WEIGHT_MAX`` = 2^24 = 16,777,216 (``build_engine``
+refuses a graph with a larger or a negative one: ``WeightRangeError``).
+The largest distance is ``INT_DIST_MAX`` = ``HOP_INF`` - 2 =
+1,073,741,821.  A relax adds in int32 and cannot wrap (``HOP_INF`` +
+2^24 < 2^31); a sum past ``INT_DIST_MAX`` becomes ``INT_DIST_OVER`` =
+``HOP_INF`` - 1, which stays what it is under every further relax, so
+an answer that holds it says so: ``ensure_in_range`` (called by ``run``
+and the CLI) raises ``DistanceRangeError`` instead of handing out a
+wrong distance.
 """
 
 from __future__ import annotations
@@ -27,27 +45,86 @@ from lux_tpu.graph import Graph, ShardedGraph
 
 HOP_INF = np.int32(np.iinfo(np.int32).max // 2)   # +1 cannot overflow
 DIST_INF = np.float32(np.inf)
+# int32 distances of integer weights (module docstring)
+INT_WEIGHT_MAX = 1 << 24
+INT_DIST_OVER = np.int32(HOP_INF - 1)
+INT_DIST_MAX = int(HOP_INF) - 2
 
 
-def make_program(start_vertex: int, weighted: bool = False) -> PushProgram:
-    if weighted:
+class WeightRangeError(ValueError):
+    """An integer weight the int32 relax cannot take exactly: negative,
+    or past ``INT_WEIGHT_MAX``."""
+
+
+class DistanceRangeError(ValueError):
+    """An int32 answer in which a distance passed ``INT_DIST_MAX``."""
+
+
+def distance_dtype(weights):
+    """The distance type of a weighted search on ``weights``: int32
+    for integer weights, float32 for every other.  Integer weights
+    are held to the range the int32 relax is exact on
+    (``WeightRangeError``)."""
+    weights = np.asarray(weights)
+    if not np.issubdtype(weights.dtype, np.integer):
+        return np.dtype(np.float32)
+    low = int(weights.min(initial=0))
+    top = int(weights.max(initial=0))
+    if low < 0 or top > INT_WEIGHT_MAX:
+        raise WeightRangeError(
+            f"integer weights in [{low}, {top}]: int32 distances take "
+            f"weights in [0, {INT_WEIGHT_MAX}] (2^24, what the float32 "
+            f"edge layouts hold exactly); load them as float32 for "
+            f"float32 distances")
+    return np.dtype(np.int32)
+
+
+def ensure_in_range(dist):
+    """``dist`` as it is, unless it is an int32 answer in which a
+    distance passed ``INT_DIST_MAX`` (``DistanceRangeError``).  Hop
+    counts never hold the marker: a path has under ``HOP_INF`` - 1
+    edges."""
+    if dist.dtype == np.int32:
+        over = int(np.count_nonzero(dist == INT_DIST_OVER))
+        if over:
+            raise DistanceRangeError(
+                f"{over} int32 distances passed {INT_DIST_MAX}; load "
+                f"the weights as float32 for float32 distances")
+    return dist
+
+
+def _relax_of(weighted: bool, dtype, batched: bool = False):
+    """(relax, identity) of the distance type ``dtype``."""
+    if not weighted:
+        return (lambda src_label, w: src_label + np.int32(1)), HOP_INF
+    if dtype == np.int32:
         def relax(src_label, w):
-            return src_label + w
-        identity = np.float32(np.inf)
-        dtype = np.float32
-        inf = DIST_INF
-    else:
-        def relax(src_label, w):
-            return src_label + np.int32(1)
-        identity = HOP_INF
-        dtype = np.int32
-        inf = HOP_INF
+            # the layouts' float32 holds the integer exactly; the sum
+            # is int32 and cannot wrap (HOP_INF + 2^24 < 2^31); past
+            # INT_DIST_MAX it becomes the marker, and stays it
+            w = w.astype(jnp.int32)
+            return jnp.minimum(src_label + (w[..., None] if batched
+                                            else w), INT_DIST_OVER)
+        return relax, HOP_INF
+    if batched:
+        # weight [.., E] broadcasts over the trailing query axis
+        return (lambda src_label, w: src_label + w[..., None]), DIST_INF
+    return (lambda src_label, w: src_label + w), DIST_INF
+
+
+def make_program(start_vertex: int, weighted: bool = False,
+                 dtype=np.float32) -> PushProgram:
+    """``dtype``: the weighted program's distance type
+    (``distance_dtype`` of the graph's weights: ``build_engine``
+    passes it); hop counts are int32 whatever it says."""
+    dtype = np.dtype(dtype if weighted else np.int32)
+    relax, identity = _relax_of(weighted, dtype)
 
     def init(sg: ShardedGraph):
         if not 0 <= start_vertex < sg.nv:
             raise ValueError(
                 f"start vertex {start_vertex} out of range [0, {sg.nv})")
-        dist = np.full(sg.nv, inf, dtype=dtype)
+        dist = np.full(sg.nv, identity, dtype=dtype)
         dist[start_vertex] = 0
         active = np.zeros(sg.nv, dtype=bool)
         active[start_vertex] = True
@@ -57,7 +134,8 @@ def make_program(start_vertex: int, weighted: bool = False) -> PushProgram:
                        init=init, name="sssp")
 
 
-def make_batched_program(sources, weighted: bool = False) -> PushProgram:
+def make_batched_program(sources, weighted: bool = False,
+                         dtype=np.float32) -> PushProgram:
     """k-source SSSP: labels carry a query-batch axis ``[vpad, B]``
     with column q the independent single-source run from
     ``sources[q]`` (ROADMAP item 2: ONE label gather per dense
@@ -71,26 +149,15 @@ def make_batched_program(sources, weighted: bool = False) -> PushProgram:
     if not sources:
         raise ValueError("sources must name at least one query")
     B = len(sources)
-    if weighted:
-        def relax(src_label, w):
-            # weight [.., E] broadcasts over the trailing query axis
-            return src_label + w[..., None]
-        identity = np.float32(np.inf)
-        dtype = np.float32
-        inf = DIST_INF
-    else:
-        def relax(src_label, w):
-            return src_label + np.int32(1)
-        identity = HOP_INF
-        dtype = np.int32
-        inf = HOP_INF
+    dtype = np.dtype(dtype if weighted else np.int32)
+    relax, identity = _relax_of(weighted, dtype, batched=True)
 
     def init(sg: ShardedGraph):
         for s in sources:
             if not 0 <= s < sg.nv:
                 raise ValueError(
                     f"source vertex {s} out of range [0, {sg.nv})")
-        dist = np.full((sg.nv, B), inf, dtype=dtype)
+        dist = np.full((sg.nv, B), identity, dtype=dtype)
         active = np.zeros((sg.nv, B), dtype=bool)
         for q, s in enumerate(sources):
             dist[s, q] = 0
@@ -101,9 +168,10 @@ def make_batched_program(sources, weighted: bool = False) -> PushProgram:
                        init=init, name="ksssp", batch=B)
 
 
-def default_delta(g: Graph) -> float:
-    """Bucket width of ``delta="auto"``: the LARGEST edge weight (1.0
-    where no weight is positive).
+def default_delta(g: Graph):
+    """Bucket width of ``delta="auto"``: the LARGEST edge weight (1
+    where no weight is positive); a Python int on integer weights, so
+    it is a whole width > 0 on int32 distances too.
 
     Every iteration of the push engine costs by its static shape, not
     by its front (a dense one all edges, a sparse one its ladder
@@ -132,10 +200,24 @@ def default_delta(g: Graph) -> float:
     16 directed, 8 roots, one chip run, PR 43): plain frontiers 7.59 s
     for the 8 (8-9 relax iterations a search), 1.0 (the old rule
     there) 19.26 s (19-28 and 17 advances), this rule's 5.0 8.74 s
-    (11-15 and 3): the same order.  No other weight distribution and
-    no high-diameter graph has been measured."""
-    top = float(np.max(g.weights, initial=0))
-    return top if top > 0 else 1.0
+    (11-15 and 3): the same order.
+    A HIGH-DIAMETER graph is the other way round (the GAP suite's
+    road network at ``USA-road-d.CAL``'s counts, 1.89 M vertices,
+    degree 2.5, int32 lengths of mean 8,862 and a heavy tail, 4 roots,
+    TPU v5e, PERF.md section 6, PR 47): plain frontiers re-relax the
+    graph over fronts of a tenth of the vertices, 810 of their 2,038
+    trips a search dense, 417.2 s for the 4 searches; this rule's
+    808,900 (91 means) 135.7 s (4,568 trips of 2,700 vertices, each
+    on the ladder's low rungs); GAP's own width scaled to these
+    weights, 192,652 (22 means), 173.3 s (6,769 trips); ten times that
+    255.7 s, a tenth of it 338.2 s (13,988 trips).  There the bucket
+    bound is what keeps a trip small, but a trip on the lowest rungs
+    costs 6 ms at 1.89 M padded vertices whatever it holds (3.6 ms at
+    1.07 M: ``USA-road-d.FLA``'s counts, where GAP's width was 2.6%
+    ahead of this rule's), so more and emptier trips lose: the largest
+    weight is the best width measured there."""
+    top = np.max(g.weights, initial=0).item()
+    return top if top > 0 else type(top)(1)
 
 
 def build_engine(g: Graph, start_vertex: int | None = 0,
@@ -170,18 +252,19 @@ def build_engine(g: Graph, start_vertex: int | None = 0,
     delta/pair_threshold must be off (single-query machinery)."""
     if weighted and g.weights is None:
         raise ValueError("weighted SSSP needs a weighted graph")
+    dtype = distance_dtype(g.weights) if weighted else None
     if sources is not None:
         if delta is not None:
             raise ValueError("delta-stepping is single-query; "
                              "sources=[...] requires delta=None")
-        program = make_batched_program(sources, weighted)
+        program = make_batched_program(sources, weighted, dtype)
     else:
         if start_vertex is None:
             raise ValueError("single-query SSSP needs start_vertex "
                              "(or pass sources=[...] for a batch)")
         if delta == "auto":
             delta = default_delta(g) if weighted else 1.0
-        program = make_program(start_vertex, weighted)
+        program = make_program(start_vertex, weighted, dtype)
     if sg is None:
         vpad_align, _ = sharding_demands(gather)
         sg = ShardedGraph.build(
@@ -203,7 +286,8 @@ def run(g: Graph, start_vertex: int = 0, num_parts: int = 1, mesh=None,
     """Returns (dist [nv], iterations)."""
     eng = build_engine(g, start_vertex, num_parts, mesh, weighted,
                        delta=delta)
-    return eng.run(max_iters=max_iters, verbose=verbose)
+    dist, iters = eng.run(max_iters=max_iters, verbose=verbose)
+    return ensure_in_range(dist), iters
 
 
 def unreachable(dist: np.ndarray) -> np.ndarray:
@@ -212,16 +296,24 @@ def unreachable(dist: np.ndarray) -> np.ndarray:
     return ~np.isfinite(dist)
 
 
+def _oracle_weights(g: Graph, weighted: bool):
+    """(weights, infinity) the oracles compute in, by the rule of
+    ``distance_dtype``: hops and integer weights in int64 under
+    ``HOP_INF``, every other weight in float64 under ``inf``."""
+    if weighted and distance_dtype(g.weights) != np.int32:
+        return np.asarray(g.weights, dtype=np.float64), np.inf
+    w = g.weights if weighted else np.ones(g.ne, dtype=np.int64)
+    return np.asarray(w, dtype=np.int64), np.int64(int(HOP_INF))
+
+
 def reference_sssp(g: Graph, start_vertex: int = 0,
                    weighted: bool = False) -> np.ndarray:
-    """NumPy Bellman-Ford oracle (exact fixed point)."""
+    """NumPy Bellman-Ford oracle (exact fixed point): float64
+    distances (``inf`` unreached) of float weights, int64 ones
+    (``HOP_INF`` unreached) of hops and of integer weights."""
     src, dst = g.edge_arrays()
-    if weighted:
-        w = np.asarray(g.weights, dtype=np.float64)
-        dist = np.full(g.nv, np.inf)
-    else:
-        w = np.ones(g.ne, dtype=np.int64)
-        dist = np.full(g.nv, int(HOP_INF), dtype=np.int64)
+    w, inf = _oracle_weights(g, weighted)
+    dist = np.full(g.nv, inf, dtype=w.dtype)
     dist[start_vertex] = 0
     while True:
         cand = dist[src] + w
@@ -260,13 +352,12 @@ def reference_sssp_incremental(g_new: Graph, dist_old: np.ndarray,
             # and monotone propagation can never repair it
             raise ValueError("weighted incremental oracle needs "
                              "new_w for every appended edge")
-        w = np.asarray(g_new.weights, dtype=np.float64)
-        dist = np.asarray(dist_old, dtype=np.float64).copy()
-        nw = np.asarray(new_w, np.float64)
+        w, _inf = _oracle_weights(g_new, True)
+        nw = np.asarray(new_w, w.dtype)
     else:
         w = np.ones(g_new.ne, dtype=np.int64)
-        dist = np.asarray(dist_old, dtype=np.int64).copy()
         nw = np.ones(len(new_src), dtype=np.int64)
+    dist = np.asarray(dist_old, dtype=w.dtype).copy()
     # seed: relax the appended edges against the old fixed point
     frontier = np.zeros(g_new.nv, dtype=bool)
     cand = dist[np.asarray(new_src, np.int64)] + nw
@@ -310,14 +401,8 @@ def reference_sssp_decremental(g_new: Graph, dist_old: np.ndarray,
     weight DECREASES are covered too — the improved paths route
     through a touched destination, hence through the cone)."""
     src, dst = g_new.edge_arrays()
-    if weighted:
-        w = np.asarray(g_new.weights, dtype=np.float64)
-        dist = np.asarray(dist_old, dtype=np.float64).copy()
-        inf = np.inf
-    else:
-        w = np.ones(g_new.ne, dtype=np.int64)
-        dist = np.asarray(dist_old, dtype=np.int64).copy()
-        inf = np.int64(int(HOP_INF))
+    w, inf = _oracle_weights(g_new, weighted)
+    dist = np.asarray(dist_old, dtype=w.dtype).copy()
     cone = np.zeros(g_new.nv, dtype=bool)
     cone[np.asarray(touched_dst, np.int64)] = True
     while True:
@@ -351,12 +436,9 @@ def reference_sssp_batched(g: Graph, sources,
     ROADMAP item 2)."""
     src, dst = g.edge_arrays()
     B = len(sources)
-    if weighted:
-        w = np.asarray(g.weights, dtype=np.float64)[:, None]
-        dist = np.full((g.nv, B), np.inf)
-    else:
-        w = np.ones((g.ne, 1), dtype=np.int64)
-        dist = np.full((g.nv, B), int(HOP_INF), dtype=np.int64)
+    w, inf = _oracle_weights(g, weighted)
+    w = w[:, None]
+    dist = np.full((g.nv, B), inf, dtype=w.dtype)
     for q, s in enumerate(sources):
         dist[int(s), q] = 0
     while True:

@@ -15,7 +15,9 @@ Flags (reference names kept):
   -mesh N       shard over an N-device mesh (default: 1 device)
   -weighted     treat the graph/run as weighted (colfilter implies it)
   -weight-type T  sssp -weighted: the file's weights are int32
-                (default) or float32; a .lux does not say which
+                (default) or float32; a .lux does not say which.
+                int32 weights give exact int32 distances, float32
+                weights float32 ones (apps/sssp.py)
   -retries N    supervised run: classify + retry transient failures,
                 auto-resuming from the last segment checkpoint
   -seg-budget S duration-budgeted segments (each XLA execution < S s;
@@ -801,6 +803,10 @@ def _push_app(argv, prog_name):
             print(f"GTEPS = {g.ne * it_exec / elapsed / 1e9:.4f}{mark}")
         else:
             print("GTEPS = n/a (run already complete in checkpoint)")
+        if weighted:
+            # int32 distances of integer weights: a sum past the type
+            # is refused by name, never handed out
+            sssp.ensure_in_range(np.asarray(labels))
         if sources is not None:
             _print_batch(sources, g.ne, it_exec, elapsed)
         _finish_run(tel, elapsed, iters)
@@ -935,11 +941,14 @@ def main(argv=None) -> int:
     try:
         return _APPS[app](argv[1:])
     except Exception as e:
+        from lux_tpu.apps.sssp import DistanceRangeError, WeightRangeError
         from lux_tpu.audit import AuditError
-        if isinstance(e, AuditError):
+        if isinstance(e, (AuditError, WeightRangeError,
+                          DistanceRangeError)):
             # -audit error: a violating build is a typed, named
             # refusal (like GraphFormatError), never a run whose
-            # numbers silently embed the violation
+            # numbers silently embed the violation; so are integer
+            # weights or distances past what int32 distances hold
             print(f"error: {e}", file=sys.stderr)
             return 2
         raise

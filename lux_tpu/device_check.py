@@ -134,8 +134,11 @@ class DeviceChecker:
             raise ValueError("weighted check needs a weighted graph")
 
         def pred(src_v, dst_v, w):
-            if not weighted:
-                w = jnp.asarray(1, src_v.dtype)
+            # int32 distances (hops, integer weights: apps/sssp.py)
+            # add in int32: promoted to float32 a sum past 2^24 would
+            # round, and HOP_INF + 2^24 does not wrap
+            w = jnp.asarray(1, src_v.dtype) if not weighted \
+                else w.astype(src_v.dtype)
             return dst_v > src_v + w
 
         counts = self._edge_pred_counts(state, pred)

@@ -91,7 +91,9 @@ The TPU-native design:
   (trips that relaxed nothing: ``iters + advances`` = trips),
   ``front_edges`` (the edges the relax trips relaxed, summed: a
   dense trip its front's out-edges, a sparse one what its budget
-  stage expanded; two words, as the fills), ``graph_edges`` (the
+  stage expanded; two words, as the fills), ``front_vertices`` (the
+  vertices of those fronts, summed the same way: what a relax trip
+  holds, against the static shapes it pays), ``graph_edges`` (the
   stored edges, once a call): how often the schedule re-relaxes an
   edge, against the trips it pays; and ``edge_dense_iters`` (relax
   trips whose front fit the queue by vertex count and ran dense for
@@ -1076,11 +1078,11 @@ class PushEngine(AuditableEngine):
                 # the schedule's own counters ride with the shared
                 # ones in the LAST carry element, (tally, bucket):
                 # bucket = (advances int32: trips that relaxed nothing,
-                # front_edges: the edges each relax trip relaxed,
-                # summed, in two uint32 words as the fills,
-                # edge_dense int32: relax trips whose front fit the
-                # queue by vertex count and ran dense for its
-                # out-edges: `relax` below)
+                # front: the edges each relax trip relaxed and the
+                # vertices of its front, each summed in two uint32
+                # words as the fills, edge_dense int32: relax trips
+                # whose front fit the queue by vertex count and ran
+                # dense for its out-edges: `relax` below)
                 def wbody(c):
                     it, lbl, act, B, cnt = c[:5]
                     buf = c[5:]
@@ -1092,7 +1094,7 @@ class PushEngine(AuditableEngine):
                         # the front's out-edge total, once: the
                         # choice of the branch reads it (_spills) and
                         # so does the front_edges count
-                        ctr, (adv, fe, edge_dense) = buf[-1]
+                        ctr, (adv, (fe, fv), edge_dense) = buf[-1]
                         with jax.named_scope("lux_bucket"):
                             edges = global_sum(
                                 jnp.where(front, g["deg"], 0)
@@ -1124,6 +1126,7 @@ class PushEngine(AuditableEngine):
                         # out-edges: a sparse trip's budget stage
                         # expands them all (_spills: none truncates)
                         fe = fr.wide_add(*fe, edges)
+                        fv = fr.wide_add(*fv, nf.astype(jnp.uint32))
                         merged = (act & ~front) | na
                         if health:
                             # the watchdog watches relax steps only:
@@ -1135,7 +1138,7 @@ class PushEngine(AuditableEngine):
                             buf = buf[:4] + (h, stall) + buf[6:]
                         return (it + 1, nl, merged, B, *buf[:-1],
                                 (tally(ctr, took, fill),
-                                 (adv, fe, edge_dense)))
+                                 (adv, (fe, fv), edge_dense)))
 
                     @jax.named_scope("lux_bucket")
                     def advance(it, lbl, act, B, *buf):
@@ -1176,13 +1179,16 @@ class PushEngine(AuditableEngine):
                 zero = jnp.uint32(0)
                 out = jax.lax.while_loop(
                     cond, wbody,
-                    init + ((tally0(), (jnp.int32(0), (zero, zero),
+                    init + ((tally0(), (jnp.int32(0),
+                                        ((zero, zero),) * 2,
                                         jnp.int32(0))),))
                 # (lbl, act, it, [stats], [health], *counts_out,
-                # advances, front_edges' words [1, 2], edge_dense)
-                ctr, (adv, fe, edge_dense) = out[-1]
+                # advances, the words of front_edges and
+                # front_vertices [2, 2], edge_dense)
+                ctr, (adv, front, edge_dense) = out[-1]
                 return (out[1], out[2], out[0], *out[5:-1],
-                        *counts_out(ctr), adv, jnp.stack(fe)[None],
+                        *counts_out(ctr), adv,
+                        jnp.stack([jnp.stack(w) for w in front]),
                         edge_dense)
 
             # carry: (it, lbl, act, cnt, [4 stats buffers], [health
@@ -1296,18 +1302,21 @@ class PushEngine(AuditableEngine):
             # the bucket schedule's counts (0 on an engine without
             # delta): advances = loop trips that relaxed nothing (so
             # iters + advances = trips), front_edges = the edges the
-            # relax trips relaxed, folded as the fills, graph_edges =
-            # the stored edges, once a call, edge_dense_iters = the
+            # relax trips relaxed, folded as the fills, front_vertices
+            # = the vertices of their fronts, graph_edges = the
+            # stored edges, once a call, edge_dense_iters = the
             # relax trips whose front fit the queue by vertex count
             # and ran dense because its out-edges pass the top budget
             # rung (iters = sparse_iters + dense by count + these)
-            bucket = {"advances": 0, "front_edges": 0, "graph_edges": 0,
+            bucket = {"advances": 0, "front_edges": 0,
+                      "front_vertices": 0, "graph_edges": 0,
                       "edge_dense_iters": 0}
             if use_delta:
                 counts, (adv, words, edge_dense) = \
                     counts[:-3], counts[-3:]
                 bucket = {"advances": adv,
                           "front_edges": fr.Folded(words, 0),
+                          "front_vertices": fr.Folded(words, 1),
                           "graph_edges": int(sg.ne),
                           "edge_dense_iters": edge_dense}
             # pull_iters is 0 where the step is not built; the four
@@ -1353,9 +1362,9 @@ class PushEngine(AuditableEngine):
             ``budget_slots``: over the call's sparse iterations the
             vertices compacted and the edges expanded, summed over
             the parts, beside the rungs they ran on x parts.  A
-            delta engine's ``advances``, ``front_edges`` and
-            ``edge_dense_iters`` likewise; its ``graph_edges`` is the
-            host's."""
+            delta engine's ``advances``, ``front_edges``,
+            ``front_vertices`` and ``edge_dense_iters`` likewise; its
+            ``graph_edges`` is the host's."""
             out = jitted(label, active, jnp.int32(max_iters), *extra,
                          *graph_args)
             if not converge:

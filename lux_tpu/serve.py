@@ -1232,9 +1232,10 @@ class PushBatchRunner(_RunnerBase):
                 g, sources=placeholder, num_parts=num_parts,
                 mesh=mesh, weighted=self.weighted,
                 exchange=exchange, health=health)
-            self._inf = (app.DIST_INF if self.weighted
-                         else app.HOP_INF)
-            self._dtype = np.float32 if self.weighted else np.int32
+            # hops are int32 under HOP_INF; weighted distances take
+            # their type from the weights (apps/sssp.py)
+            self._inf = self.eng.program.identity
+            self._dtype = np.asarray(self._inf).dtype
         elif kind == "components":
             from lux_tpu.apps import components as app
             self.eng = app.build_engine(

@@ -39,7 +39,8 @@ def test_converge_guarded_matches_plain():
 def test_converge_guarded_weighted_inf_ok():
     """+inf sentinel distances must NOT trip the divergence guard."""
     src, dst, w = uniform_random_edges(100, 600, seed=73, weighted=True)
-    g = Graph.from_edges(src, dst, 100, weights=w)
+    # float weights: float32 distances, +inf the sentinel
+    g = Graph.from_edges(src, dst, 100, weights=w.astype(np.float32))
     eng = sssp.build_engine(g, start_vertex=0, num_parts=2,
                             weighted=True)
     got, _ = debug.converge_guarded(eng, segment=2)

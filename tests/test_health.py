@@ -183,7 +183,9 @@ def test_converge_health_matches_converge(np_parts, mesh_n):
 
 def test_push_nan_labels_trip():
     src, dst, w = uniform_random_edges(100, 800, seed=5, weighted=True)
-    g = Graph.from_edges(src, dst, 100, weights=w)
+    # float weights: float32 labels can hold a NaN (integer weights
+    # give int32 distances, apps/sssp.py)
+    g = Graph.from_edges(src, dst, 100, weights=w.astype(np.float32))
     eng = sssp.build_engine(g, start_vertex=0, num_parts=2,
                             weighted=True, health=True)
     label, active = eng.init_state()
@@ -202,7 +204,8 @@ def test_push_inf_sentinel_never_trips():
     a converged run full of them must stay clean."""
     src, dst, w = uniform_random_edges(100, 400, seed=9, weighted=True)
     # vertices 100..119 have no edges at all: provably unreachable
-    g = Graph.from_edges(src, dst, 120, weights=w)
+    # (float weights: float32 labels, +inf the sentinel)
+    g = Graph.from_edges(src, dst, 120, weights=w.astype(np.float32))
     eng = sssp.build_engine(g, start_vertex=0, num_parts=2,
                             weighted=True, health=True)
     label, _a, _it, _f, _e, _fp, _ep, h = eng.converge_health(*eng.init_state())

@@ -78,12 +78,15 @@ SERVING = [(cell, kind) for cell in CELLS
            for kind in _kinds(_config(cell))]
 
 
-def _small_graph(app: str, symmetrized: bool, weighted: bool = False):
+def _small_graph(app: str, symmetrized: bool, weighted: bool = False,
+                 weight_type: str = "float32"):
     """A graph of the cell's KIND at a size that lowers in a second:
     R-MAT scale 10 x 16, symmetrized where the cell's is (the push
     engine builds its bottom-up step on symmetric graphs only),
-    integer ratings 1..5 as weights for colfilter, float32 weights
-    uniform in [0, 1) where the configuration says ``weighted``."""
+    integer ratings 1..5 as weights for colfilter; where the
+    configuration says ``weighted``, weights of its ``weight_type``:
+    float32 uniform in [0, 1), or int32 lengths 1..9999 (the program
+    then runs int32 distances, ``apps/sssp.py``)."""
     from lux_tpu.apps import components
     from lux_tpu.convert import rmat_graph
     from lux_tpu.graph import Graph
@@ -95,6 +98,9 @@ def _small_graph(app: str, symmetrized: bool, weighted: bool = False):
     if app == "colfilter":
         g.weights = np.random.default_rng(1).integers(
             1, 6, size=g.ne).astype(np.int32)
+    elif weighted and weight_type == "int32":
+        g.weights = np.random.default_rng(1).integers(
+            1, 10_000, size=g.ne).astype(np.int32)
     elif weighted:
         g.weights = np.random.default_rng(1).random(
             g.ne, dtype=np.float32)
@@ -114,7 +120,8 @@ def _batch_engine(c: dict, engine=None):
     opts = c.get("engine", {}) if engine is None else engine
     num_parts, pair = int(c["num_parts"]), opts.get("pair_threshold")
     weighted = bool(c.get("weighted"))
-    g = _small_graph(c["app"], bool(c.get("symmetrized")), weighted)
+    g = _small_graph(c["app"], bool(c.get("symmetrized")), weighted,
+                     c.get("weight_type", "float32"))
     starts = None
     if pair is not None:
         g, _perm, starts = pair_relabel(g, num_parts,
@@ -240,8 +247,8 @@ def test_only_the_bucket_loop_compares_a_fronts_out_edges(cell, name):
     words, the ``took`` counts and, on an engine with the ladder, the
     four fills' eight words, and its text holds neither the compare
     nor ``lux_bucket``; the bucket loop carries its bound and its
-    four own words more (``advances``, ``front_edges``' two,
-    ``edge_dense``)."""
+    six own words more (``advances``, ``front_edges``' two,
+    ``front_vertices``' two, ``edge_dense``)."""
     eng, text = _engines(cell)[name], _form(cell)[name]
     plain = 4 + 1 + 8 * int(eng._sparse_mode()[0])
     if eng.delta is None:
@@ -249,9 +256,28 @@ def test_only_the_bucket_loop_compares_a_fronts_out_edges(cell, name):
         assert not SPILLS_RE.search(text)
         assert "lux_bucket" not in text
     else:
-        assert _loop_carry(eng) == plain + 1 + 4
+        assert _loop_carry(eng) == plain + 1 + 6
         assert SPILLS_RE.search(text)
         assert "lux_bucket" in text
+
+
+def test_the_road_cell_runs_int32_labels_under_kernel_3s_scopes():
+    """``ssspw.road.delta``'s form is built from its configuration's
+    ``weight_type``: the int32 program, whose guarded scopes are those
+    of ``ssspw.kron21.delta`` (the same loop on another label type)."""
+    road, kron = "ssspw.road.delta", "ssspw.kron21.delta"
+    eng = _engines(road)["sssp"]
+    assert np.asarray(eng.program.identity).dtype == np.int32
+    assert isinstance(eng.delta, int) and eng.delta > 0
+    assert np.asarray(_engines(kron)["sssp"].program.identity
+                      ).dtype == np.float32
+    assert _scopes(road) == _scopes(kron)
+    # ... less the two that read the dense branch: no trip of a road
+    # search is dense, so on the chip their readers find nothing there
+    mine = {m for m, cell in METRIC_CELLS if cell == road}
+    assert mine <= {m for m, cell in METRIC_CELLS if cell == kron}
+    assert not mine & {"scope_ms.dense", "scope_ms.combine"}
+    assert {"scope_ms.sparse", "scope_ms.bucket"} <= mine
 
 
 @pytest.mark.parametrize("metric,cell", METRIC_CELLS)

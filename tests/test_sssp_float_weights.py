@@ -405,7 +405,9 @@ def test_cli_sssp_weighted_round_trips_the_files_weight_type(
     """``cli sssp -weighted -weight-type float32`` reads a file of
     float32 weights as what they are: the run passes ``-check`` and
     the graph the entry point loaded gives the reference's distances;
-    a file of int32 weights loads as it always did (no flag)."""
+    a file of int32 weights loads as it always did (no flag) and
+    gives the same distances as int32 (sums of small integers are
+    exact in float32 too), ``HOP_INF`` where float32 says ``+inf``."""
     (src, dst, w), (offsets, by_src, by_w) = _arcs()
     root = _roots()[0]
     if weight_type == "int32":
@@ -429,11 +431,13 @@ def test_cli_sssp_weighted_round_trips_the_files_weight_type(
     got, _iters = sssp.run(
         loaded(**({"weight_type": "float32"} if flags else {})),
         start_vertex=root, weighted=True, delta="auto")
+    assert got.dtype == np.dtype(weight_type)
+    got = np.where(sssp.unreachable(got), np.inf, got)
     assert ref.mismatched(np.asarray(got, np.float32), want) == 0
     if weight_type == "float32":
         # read as the default int32 the same file's bits are other
-        # numbers: nothing like the reference's distances comes out
-        got, _iters = sssp.run(loaded(), start_vertex=root,
-                               weighted=True)
-        assert ref.mismatched(np.asarray(got, np.float32),
-                              want) > NV // 2
+        # numbers, far past what int32 distances take: refused by name
+        with pytest.raises(sssp.WeightRangeError):
+            sssp.run(loaded(), start_vertex=root, weighted=True)
+        assert cli.main(["sssp", "-file", path, "-weighted",
+                         "-start", str(root)]) == 2

@@ -1213,18 +1213,20 @@ def test_sparse_iters_counts_the_branch_the_loop_took(variant):
     assert set(counts) == {"iters", "sparse_iters", "low_rung_iters",
                            "pull_iters", "queue_items", "queue_slots",
                            "budget_edges", "budget_slots", "advances",
-                           "front_edges", "graph_edges",
-                           "edge_dense_iters"}
+                           "front_edges", "front_vertices",
+                           "graph_edges", "edge_dense_iters"}
     # the bucket schedule's counts: the delta engine's alone
     if variant == "delta":
         assert type(counts["front_edges"]) is int
+        assert type(counts["front_vertices"]) is int
         assert counts["front_edges"] > 0 and counts["advances"] >= 0
+        assert counts["front_vertices"] == sum(entering)
         assert counts["graph_edges"] == g.ne
         assert 0 <= counts["edge_dense_iters"] <= it - want
     else:
         assert (counts["advances"], counts["front_edges"],
-                counts["graph_edges"],
-                counts["edge_dense_iters"]) == (0, 0, 0, 0)
+                counts["front_vertices"], counts["graph_edges"],
+                counts["edge_dense_iters"]) == (0, 0, 0, 0, 0)
     # the four fill counts settle to plain ints (fr.Folded)
     assert all(type(counts[k]) is int for k in (
         "queue_items", "queue_slots", "budget_edges", "budget_slots"))
