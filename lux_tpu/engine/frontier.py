@@ -27,9 +27,10 @@ shapes, so the design is:
   gather, scatter and scan below costs per SLOT, real or not, so a
   root with five out-edges on a 4 M-slot budget pays for 4 M.  The
   work is therefore split where the sizes split: ``mask_ranks`` is
-  [vpad]-sized and the same on every rung; ``pick_queue`` and
-  ``frontier_extents`` are queue-sized and end in the frontier's real
-  out-edge ``total``; ``expand_extents`` is budget-sized.  The caller
+  [vpad]-sized (the ranks and their search tree) and the same on
+  every rung; ``pick_queue`` and ``frontier_extents`` are queue-sized
+  and end in the frontier's real out-edge ``total``;
+  ``expand_extents`` is budget-sized.  The caller
   picks the smallest queue rung that holds the frontier's count, then
   the smallest budget rung that holds ``total`` (``rung_index``,
   scalars chosen outside the per-part vmap so each branch stays a
@@ -40,6 +41,17 @@ shapes, so the design is:
 - Labels ride along with vertex ids in the queue (the reference
   gathers them from the all-parts dist region instead), so multi-chip
   sparse iterations exchange O(queue) bytes over ICI, not O(nv).
+- On the queue stage a slot's two searches (its vertex among the
+  mask's ranks, ``pick_queue``; its id in the part's compressed
+  source index, ``frontier_extents``) are ROW searches
+  (``table_search``): every level of a 128-ary tree over the table is
+  rows of 128 sorted splitters, the table itself the leaves, all
+  stacked in one array (``row_table``: the ranks' built once a trip,
+  the source index's once a graph), and a step fetches one ROW and
+  counts the splitters under the query.  Three fetched rows a slot on
+  a 2 M-entry table where a binary search fetched 21 scalars; with
+  the label and the three scalars of the extent, 4 + 6 fetches a slot
+  where there were 22 + 24.
 - On the budget stage a slot does not go back to the queue for its
   item's data (the CSR-expand trick, ``expand_extents``): each item
   drops, at its first slot, the step from its predecessor's value,
@@ -55,6 +67,7 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from lux_tpu.parallel.mesh import vary_like
 
@@ -68,12 +81,88 @@ FRONTIER_MXU_BLOCK = 256
 # and not a shift of lanes.
 SLOT_ALIGN = 1024
 
-# jnp.searchsorted's method for the queue-sized binary searches: the
-# rolled loop.  Unrolled ("scan_unrolled") it runs in the same time
-# and compiles to 25 MB more code on a 2 M-vertex part, a third of the
-# whole push program, and compiled code is device memory (PERF.md,
-# PR 29).
+# jnp.searchsorted's method where a binary search is left: the ONE
+# query a part of the budget stage makes (how many queue items its
+# budget held, push.py ``relax_part``); the queue stage's, one a slot,
+# are row searches (``table_search``).  The rolled loop: unrolled
+# ("scan_unrolled") the queue stage's ran in the same time and
+# compiled to 25 MB more code on a 2 M-vertex part, and compiled code
+# is device memory (PERF.md, PR 29).
 SEARCH = "scan"
+
+# The row search's fan-out (``table_search``): how many sorted
+# splitters one fetched row holds.  128 is the lane count of the
+# chip's (8, 128) int32 tile: a [n, 128] view of a table IS the table,
+# where a narrower row is padded to 128 lanes in device memory (a
+# [n, 16] level of 1.9 M entries is 60 MB, the entries 7.6) and
+# fetched no faster (PERF.md, PR 48: the probe's table).
+ROW_FANOUT = 128
+
+
+def row_plan(length: int):
+    """The static shape of ``row_table``'s tree over ``length``
+    entries: ((first row, rows) of every level, the one-row top level
+    first and the leaves last)."""
+    sizes, n = [], int(length)
+    while True:
+        n = -(-n // ROW_FANOUT)
+        sizes.append(n)
+        if n == 1:
+            break
+    firsts = np.cumsum([0] + sizes[:-1])
+    return tuple(zip(firsts.tolist()[::-1], sizes[::-1]))
+
+
+def row_table(table):
+    """A non-decreasing int32 ``table`` [N] (NumPy's or JAX's, the
+    result is the same kind) -> its search tree int32 [T, ROW_FANOUT],
+    all levels stacked in ONE array of rows: the table itself first (so
+    ``rows.reshape(-1)[:N]`` is the table), then level on level the
+    LAST element of every row below (the row's greatest), up to a
+    level of one row.  Short rows are filled with the dtype's greatest
+    value, which no query is above."""
+    xp = np if isinstance(table, np.ndarray) else jnp
+    levels, flat = [], table
+    while True:
+        n = -(-flat.shape[0] // ROW_FANOUT)
+        if n * ROW_FANOUT != flat.shape[0]:
+            flat = xp.concatenate([flat, xp.full(
+                (n * ROW_FANOUT - flat.shape[0],),
+                np.iinfo(flat.dtype).max, flat.dtype)])
+        levels.append(flat.reshape(n, ROW_FANOUT))
+        if n == 1:
+            return xp.concatenate(levels, axis=0)
+        flat = levels[-1][:, -1]
+
+
+def table_search(rows, length: int, queries):
+    """Positions int32 [Q] of ``queries`` in the table of ``length``
+    entries behind ``rows`` (its ``row_table``): what
+    ``jnp.searchsorted(table, queries, side="left")`` returns, bit for
+    bit, by a descent that fetches one ROW of splitters a level where
+    the binary search fetches one scalar a step (7 steps a level at
+    128 lanes; the chip fetches a row for about the price of a
+    scalar).  A strict ``<`` counts the splitters under the query, so
+    a node is the first row whose greatest element reaches it and the
+    leaf the first such position: equal runs resolve as
+    ``side="left"`` does.  The levels are one rolled loop over the
+    stacked rows, ONE gather and one count in the program however
+    deep the tree: compiled code is device memory."""
+    plan = np.asarray(row_plan(length), np.int32)
+    first, count = jnp.asarray(plan[:, 0]), jnp.asarray(plan[:, 1])
+    q = queries.astype(rows.dtype)[:, None]
+
+    def level(k, node):
+        # (a query over every splitter counts one row too many)
+        node = jnp.minimum(node, count[k] - 1)
+        row = rows.at[first[k] + node].get(mode="promise_in_bounds")
+        return node * ROW_FANOUT + jnp.sum(row < q, axis=1,
+                                           dtype=jnp.int32)
+
+    node = jax.lax.fori_loop(
+        0, len(plan), level,
+        vary_like(jnp.zeros(queries.shape, jnp.int32), rows, queries))
+    return jnp.minimum(node, length)
 
 
 def _cumsum_matmul(x, block: int = FRONTIER_MXU_BLOCK):
@@ -105,27 +194,27 @@ def _cumsum_matmul(x, block: int = FRONTIER_MXU_BLOCK):
 
 
 def mask_ranks(mask):
-    """Dense bool mask [vpad] -> (ranks int32 [vpad], count int32):
-    the 1-based running count of set bits and its total — the
-    [vpad]-sized half of ``compact_mask``, the same on every queue
-    rung."""
+    """Dense bool mask [vpad] -> (rows int32 [T, ROW_FANOUT], count
+    int32): the 1-based running count of set bits as its search tree
+    (``row_table``: the ranks themselves are its first vpad entries)
+    and the count's total: the [vpad]-sized half of ``compact_mask``,
+    the same on every queue rung."""
     with jax.named_scope("lux_sparse_compact"):
         ranks = jnp.cumsum(mask.astype(jnp.int32))      # 1-based
-        return ranks, ranks[-1]
+        return row_table(ranks), ranks[-1]
 
 
-def pick_queue(ranks, labels, capacity: int):
+def pick_queue(rows, labels, capacity: int):
     """The queue-sized half: (ids int32 [capacity], vals [capacity])
-    of the first ``capacity`` set bits behind ``mask_ranks``' ranks.
+    of the first ``capacity`` set bits behind ``mask_ranks``' rows.
     ids[i] for i >= count is vpad (an invalid slot)."""
     with jax.named_scope("lux_sparse_compact"):
-        vpad = ranks.shape[0]
+        vpad = labels.shape[0]
         # i-th set bit = first position whose running count reaches
-        # i+1; vectorized binary search over the monotone ranks array.
+        # i+1; a row search over the monotone ranks.
         want = jnp.arange(capacity, dtype=jnp.int32) + 1
-        ids = jnp.searchsorted(ranks, want, side="left",
-                               method=SEARCH).astype(jnp.int32)
-        ids = jnp.where(want <= ranks[-1], ids, vpad)
+        ids = table_search(rows, vpad, want)
+        ids = jnp.where(want <= rows.reshape(-1)[vpad - 1], ids, vpad)
         vals = jnp.take(labels, jnp.minimum(ids, vpad - 1), axis=0)
         return ids, vals
 
@@ -138,8 +227,8 @@ def compact_mask(mask, labels, capacity: int):
     position < count.  If count > capacity the queue is truncated —
     callers must branch to the dense path in that case.
     """
-    ranks, count = mask_ranks(mask)
-    ids, vals = pick_queue(ranks, labels, capacity)
+    rows, count = mask_ranks(mask)
+    ids, vals = pick_queue(rows, labels, capacity)
     return ids, vals, count
 
 
@@ -204,7 +293,9 @@ def frontier_extents(ids, src_ids, src_off, nv: int):
     out-edges lie in this part.
 
     ids     int32 [Q]   vertex GLOBAL ids (graph numbering), nv=invalid
-    src_ids int32 [S]   this part's present-source ids, sorted, pad=nv
+    src_ids int32 [T, ROW_FANOUT]  the ``row_table`` of this part's
+                        present-source ids [S] (sorted, pad=nv), built
+                        ahead (the engine's, once a graph)
     src_off int32 [S+1] END offsets into the part's src-sorted edge
                         arrays (ShardedGraph.src_sorted — the
                         compressed replacement for the reference's
@@ -217,12 +308,11 @@ def frontier_extents(ids, src_ids, src_off, nv: int):
     caller sizes the budget stage by.
     """
     with jax.named_scope("lux_sparse_expand"):
-        S = src_ids.shape[0]
-        # binary-search each queue id in the compressed source index
-        pos = jnp.searchsorted(src_ids, ids, side="left",
-                               method=SEARCH)
-        posc = jnp.minimum(pos, S - 1).astype(jnp.int32)
-        present = (jnp.take(src_ids, posc, axis=0) == ids) & (ids < nv)
+        S = src_off.shape[0] - 1
+        # row-search each queue id in the compressed source index
+        posc = jnp.minimum(table_search(src_ids, S, ids), S - 1)
+        present = (jnp.take(src_ids.reshape(-1), posc, axis=0) == ids) \
+            & (ids < nv)
         begin = jnp.where(present, jnp.take(src_off, posc, axis=0), 0)
         end = jnp.where(present, jnp.take(src_off, posc + 1, axis=0), 0)
         off = jnp.cumsum((end - begin).astype(jnp.int32))
@@ -319,12 +409,14 @@ def expand_extents(vals, begin, off, edge_budget: int,
 def expand_frontier(ids, vals, src_ids, src_off, nv: int,
                     edge_budget: int, use_mxu: bool = False):
     """Map a gathered queue to its out-edge slots in this part:
-    ``frontier_extents`` then ``expand_extents`` on one budget.
-    Returns (edge_idx int32 [EB], src_val [EB], in_range bool [EB],
+    ``frontier_extents`` (``src_ids`` int32 [S] are the sorted ids
+    themselves: their tree is built here) then ``expand_extents`` on
+    one budget.  Returns (edge_idx int32 [EB], src_val [EB], in_range bool [EB],
     total int32, off int32 [Q]); ``total`` may exceed EB (the
     expansion is then a prefix, see ``expand_extents``).
     """
-    begin, off, total = frontier_extents(ids, src_ids, src_off, nv)
+    begin, off, total = frontier_extents(ids, row_table(src_ids),
+                                         src_off, nv)
     edge_idx, src_val, in_range, _owner = expand_extents(
         vals, begin, off, edge_budget, use_mxu=use_mxu)
     return edge_idx, src_val, in_range, total, off

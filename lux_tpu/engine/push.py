@@ -306,7 +306,10 @@ class PushEngine(AuditableEngine):
             self.budget_rungs = fr.rungs(self.edge_budget,
                                          BUDGET_RUNG_DIVISORS)
             arrays = dict(arrays,
-                          src_ids=dev(ss["src_ids"]),
+                          # (as the row search's tree, built once)
+                          src_ids=dev(np.stack(
+                              [fr.row_table(ids)
+                               for ids in ss["src_ids"]])),
                           src_off=dev(ss["src_off"]),
                           ss_dst=dev(ss["ss_dst"]),
                           part_start=dev(
@@ -507,7 +510,7 @@ class PushEngine(AuditableEngine):
         pidx = self._part_index()
         queued = active if pull is None else \
             jnp.where(pull, unreached, active)
-        ranks, cnts = jax.vmap(fr.mask_ranks)(queued)
+        rows, cnts = jax.vmap(fr.mask_ranks)(queued)
 
         def exchanged(fn, x):
             # the sparse branch's collectives under one scope of a
@@ -519,12 +522,12 @@ class PushEngine(AuditableEngine):
         def on_queue(Q):
             # 1. compact each local part's mask into a (global id,
             #    label) queue.
-            def compact(ranks, lab, start):
-                ids, vals = fr.pick_queue(ranks, lab, Q)
+            def compact(rows, lab, start):
+                ids, vals = fr.pick_queue(rows, lab, Q)
                 gids = jnp.where(ids < sg.vpad, start[0] + ids, nv)
                 return gids.astype(jnp.int32), vals
 
-            gids, vals = jax.vmap(compact)(ranks, label,
+            gids, vals = jax.vmap(compact)(rows, label,
                                            g["part_start"])
 
             # 2. exchange queues: [P_total * Q] flat, part-major order

@@ -870,7 +870,7 @@ class ShardedGraph:
         view the reference's push init builds on device with atomic
         degree counting (reference sssp_gpu.cu:550-607) — with a
         COMPRESSED source index: only sources with >=1 edge in the
-        part are stored (sorted ids + END offsets), binary-searched at
+        part are stored (sorted ids + END offsets), searched at
         frontier-expansion time (engine/frontier.expand_frontier).
         This replaces the reference's nv-wide per-part row pointers
         (reference push_model.inl:321-324) — O(nv) rows per part,

@@ -198,7 +198,8 @@ def test_expand_extents_on_a_lower_rung_is_the_top_rungs_prefix(
     vals = jnp.concatenate([vals, jnp.asarray([0], jnp.int32)])
     top = fr.expand_frontier(ids, vals, sids, soff, nv=nv,
                              edge_budget=25, use_mxu=use_mxu)
-    begin, off, total = fr.frontier_extents(ids, sids, soff, nv)
+    begin, off, total = fr.frontier_extents(ids, fr.row_table(sids),
+                                            soff, nv)
     assert int(total) == int(top[3]) == 6
     np.testing.assert_array_equal(np.asarray(off), np.asarray(top[4]))
     edge_idx, src_val, in_range, _owner = fr.expand_extents(
@@ -244,7 +245,8 @@ def test_expand_extents_owner_is_the_repeated_queue_index(degs, budget,
     ids, vals, sids, soff, nv = _star_queue(degs)
     ids = jnp.concatenate([jnp.asarray([nv], jnp.int32), ids])  # absent
     vals = jnp.concatenate([jnp.asarray([0], jnp.int32), vals])
-    begin, off, total = fr.frontier_extents(ids, sids, soff, nv)
+    begin, off, total = fr.frontier_extents(ids, fr.row_table(sids),
+                                            soff, nv)
     _edge_idx, src_val, in_range, owner = fr.expand_extents(
         vals, begin, off, budget, use_mxu=use_mxu)
     want = np.repeat(np.arange(len(degs) + 1), [0, *degs])
@@ -448,3 +450,236 @@ def test_expand_extents_without_labels_lays_no_label_channel(use_mxu):
         return sum(e.invars[0].aval.shape[-1] for e in jaxpr.eqns
                    if e.primitive.name == "scatter-add")
     assert slots(vals) - slots(None) == fr.SLOT_ALIGN
+
+
+# ---------------------------------------------------------------------
+# the queue stage's searches fetch a row of splitters a step (PR 48)
+
+_F = fr.ROW_FANOUT
+_ROW_LENGTHS = (1, _F - 1, _F, _F + 1, _F * _F - 1, _F * _F + 1, 4097,
+                70001)
+
+
+def row_search(table, queries):
+    """``jnp.searchsorted(table, queries, side="left")`` as int32 on a
+    non-decreasing int32 ``table`` [N]: ``table_search`` on the tree
+    built on the spot."""
+    return fr.table_search(fr.row_table(table), table.shape[0], queries)
+
+
+def _row_tables(kind, n, rng):
+    """Non-decreasing int32 tables of length ``n`` as the queue stage
+    meets them: the running count of a mask (long plateaus: sparse,
+    empty and full masks) and sorted ids under an ``nv``-padded tail
+    (``src_ids``); and tables that reach both ends of int32."""
+    if kind == "ranks-sparse":
+        return np.cumsum(rng.random(n) < 0.01).astype(np.int32)
+    if kind == "ranks-empty":
+        return np.zeros(n, np.int32)
+    if kind == "ranks-full":
+        return np.arange(1, n + 1, dtype=np.int32)
+    if kind == "ids-padded":
+        real = max(1, n - n // 3)
+        ids = np.sort(rng.choice(4 * n, size=real, replace=False))
+        return np.concatenate(
+            [ids, np.full(n - real, 4 * n)]).astype(np.int32)
+    assert kind == "int32-ends"
+    t = np.sort(rng.integers(-2**31, 2**31, n)).astype(np.int32)
+    t[:1 + n // 7] = -2**31
+    t[n - 1 - n // 7:] = 2**31 - 1
+    return t
+
+
+def _row_queries(table, rng):
+    """Queries under the least entry, equal to entries, between them
+    and over the greatest, unsorted, and both ends of int32."""
+    lo, hi = int(table[0]), int(table[-1])
+    q = np.concatenate([
+        rng.choice(table, 200), rng.choice(table, 50).astype(np.int64) + 1,
+        rng.choice(table, 50).astype(np.int64) - 1,
+        rng.integers(lo - 3, hi + 4, 200),
+        [lo - 1, lo, hi, hi + 1, -2**31, 2**31 - 1]])
+    return np.clip(q, -2**31, 2**31 - 1).astype(np.int32)
+
+
+@pytest.mark.parametrize("kind", ["ranks-sparse", "ranks-empty",
+                                  "ranks-full", "ids-padded",
+                                  "int32-ends"])
+@pytest.mark.parametrize("n", _ROW_LENGTHS)
+def test_row_search_is_searchsorted_left(n, kind):
+    """``row_search`` against ``np.searchsorted(side="left")``, bit
+    for bit: table lengths around the fan-out's powers, plateaus,
+    padded tails, queries outside and inside the table; and under a
+    ``vmap`` over two parts' tables with one shared vector of
+    queries, as the engine runs it."""
+    import jax
+    rng = np.random.default_rng(n * 31 + len(kind))
+    table = _row_tables(kind, n, rng)
+    queries = _row_queries(table, rng)
+    want = np.searchsorted(table, queries, side="left")
+    got = row_search(jnp.asarray(table), jnp.asarray(queries))
+    assert got.dtype == jnp.int32
+    np.testing.assert_array_equal(np.asarray(got), want)
+    rows = fr.row_table(jnp.asarray(table))
+    # the host's tree is the device's, and holds the table first
+    np.testing.assert_array_equal(fr.row_table(table), np.asarray(rows))
+    np.testing.assert_array_equal(
+        np.asarray(rows).reshape(-1)[:n], table)
+    assert rows.shape == (sum(c for _f, c in fr.row_plan(n)), _F)
+    other = np.sort(np.roll(table, n // 2) // 2 + 1).astype(np.int32)
+    both = jax.vmap(row_search, in_axes=(0, None))(
+        jnp.asarray(np.stack([table, other])), jnp.asarray(queries))
+    np.testing.assert_array_equal(np.asarray(both[0]), want)
+    np.testing.assert_array_equal(
+        np.asarray(both[1]),
+        np.searchsorted(other, queries, side="left"))
+
+
+def _ranks_by_cumsum(mask):
+    """``mask_ranks`` as it was: the ranks themselves."""
+    ranks = jnp.cumsum(mask.astype(jnp.int32))
+    return ranks, ranks[-1]
+
+
+def _pick_by_binary(ranks, labels, capacity):
+    """``pick_queue`` as it was: a binary search a queue slot."""
+    vpad = ranks.shape[0]
+    want = jnp.arange(capacity, dtype=jnp.int32) + 1
+    ids = jnp.searchsorted(ranks, want, side="left").astype(jnp.int32)
+    ids = jnp.where(want <= ranks[-1], ids, vpad)
+    return ids, jnp.take(labels, jnp.minimum(ids, vpad - 1), axis=0)
+
+
+def _extents_by_binary(ids, src_ids, src_off, nv):
+    """``frontier_extents`` as it was, over the sorted ids [S]."""
+    S = src_off.shape[0] - 1
+    pos = jnp.searchsorted(src_ids, ids, side="left")
+    posc = jnp.minimum(pos, S - 1).astype(jnp.int32)
+    present = (jnp.take(src_ids, posc, axis=0) == ids) & (ids < nv)
+    begin = jnp.where(present, jnp.take(src_off, posc, axis=0), 0)
+    end = jnp.where(present, jnp.take(src_off, posc + 1, axis=0), 0)
+    off = jnp.cumsum((end - begin).astype(jnp.int32))
+    return begin, off, off[-1]
+
+
+def _extents_by_binary_in_tree(ids, rows, src_off, nv):
+    """The same over the engine's ``row_table``, whose first entries
+    are the sorted ids."""
+    return _extents_by_binary(
+        ids, rows.reshape(-1)[:src_off.shape[0] - 1], src_off, nv)
+
+
+@pytest.mark.parametrize("density", [0.0, 0.002, 0.05, 1.0])
+@pytest.mark.parametrize("capacity", [37, 300])   # both queue rungs
+def test_queue_stage_is_its_binary_search_forms(capacity, density):
+    """``pick_queue`` and ``frontier_extents`` against the
+    ``jnp.searchsorted`` forms they replaced, bit for bit, on a lower
+    and a top queue rung: masks from empty to full (a count under and
+    over the queue), labels at both ends of int32, ids absent from
+    the source index, invalid (``nv``) and unsorted."""
+    rng = np.random.default_rng(capacity + int(1000 * density))
+    vpad, nv = 5000, 4990
+    mask = rng.random(vpad) < density
+    labels = _label_cases(np.int32, vpad, rng)
+    ranks, count = _ranks_by_cumsum(jnp.asarray(mask))
+    rows, got_count = fr.mask_ranks(jnp.asarray(mask))
+    assert int(got_count) == int(count) == int(mask.sum())
+    want = _pick_by_binary(ranks, jnp.asarray(labels), capacity)
+    got = fr.pick_queue(rows, jnp.asarray(labels), capacity)
+    for a, b in zip(got, want):
+        assert a.dtype == b.dtype
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    # a compressed source index: two thirds of the vertices have
+    # out-edges here; the queue holds sources, strangers and pads
+    deg = np.where(rng.random(nv) < 0.66, rng.integers(1, 9, nv), 0)
+    sids, soff = _compress(np.concatenate([[0], np.cumsum(deg)]))
+    S = sids.shape[0] + 7                          # a padded tail
+    sids = jnp.concatenate([sids, jnp.full((7,), nv, jnp.int32)])
+    soff = jnp.concatenate([soff, jnp.full((7,), soff[-1], jnp.int32)])
+    gids = np.where(np.asarray(got[0]) < vpad,
+                    np.minimum(np.asarray(got[0]), nv), nv)
+    gids = jnp.asarray(rng.permutation(gids).astype(np.int32))
+    want = _extents_by_binary(gids, sids, soff, nv)
+    assert soff.shape[0] == S + 1
+    for a, b in zip(fr.frontier_extents(gids, fr.row_table(sids), soff,
+                                        nv), want):
+        assert a.dtype == b.dtype
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+_BUCKET_COUNTS = ("advances", "front_edges", "front_vertices",
+                  "edge_dense_iters")
+
+
+@pytest.mark.parametrize("parts", [1, 2, 4])
+@pytest.mark.parametrize("kind", ["delta-int32", "delta-float32",
+                                  "bfs-two-way", "components"])
+def test_engine_answers_and_counters_are_the_binary_search_forms(
+        kind, parts, monkeypatch):
+    """The same small graph through the engine as it is and through
+    one whose queue stage runs the binary searches it ran (patched in
+    before the build), on 1, 2 and 4 parts of the CPU mesh: labels bit
+    for bit, the same ``iters``, rung counts and slot fills (the queue
+    a trip builds is the same queue), the bucket loop's counts on the
+    delta engines and ``pull_iters`` on the BFS's bottom-up step,
+    with sparse iterations on BOTH queue rungs."""
+    from lux_tpu import telemetry
+    from lux_tpu.engine.push import PushEngine
+    from lux_tpu.graph import ShardedGraph
+    from lux_tpu.parallel.mesh import make_mesh
+    rng = np.random.default_rng(48)
+    nv, ne = 6000, 9000
+    src, dst = rng.integers(0, nv, ne), rng.integers(0, nv, ne)
+    src, dst = np.concatenate([src, dst]), np.concatenate([dst, src])
+    half = {"delta-int32": rng.integers(1, 1000, ne).astype(np.int32),
+            "delta-float32": rng.random(ne).astype(np.float32)}.get(kind)
+    w = None if half is None else np.concatenate([half, half])
+    g = Graph.from_edges(src, dst, nv, weights=w)
+    prog = components.make_program() if kind == "components" \
+        else sssp.make_program(0, w is not None) if w is None \
+        else sssp.make_program(0, True, sssp.distance_dtype(w))
+    delta = None if w is None else sssp.default_delta(g) / 4
+    if kind == "delta-int32":
+        delta = int(delta)
+    sg = ShardedGraph.build(g, parts)
+    mesh = make_mesh(parts) if parts > 1 else None
+    keys = _COUNTS + ("pull_iters",) + \
+        (_BUCKET_COUNTS if delta is not None else ())
+
+    def solve():
+        eng = PushEngine(sg, prog, mesh=mesh, delta=delta)
+        assert len(eng.queue_rungs) == 2
+        label, _active, _it = eng.converge(*eng.init_state())
+        mark = [r for r in telemetry.spans()
+                if r["name"] == "push.converge"][-1]["counts"]
+        return eng, eng.unpad(label), {k: mark[k] for k in keys}
+
+    eng, got, counts = solve()
+    assert eng.arrays["src_ids"].ndim == 3          # [P, T, F] trees
+    monkeypatch.setattr(fr, "mask_ranks", _ranks_by_cumsum)
+    monkeypatch.setattr(fr, "pick_queue", _pick_by_binary)
+    monkeypatch.setattr(fr, "frontier_extents",
+                        _extents_by_binary_in_tree)
+    _eng, want, want_counts = solve()
+    assert got.dtype == want.dtype
+    np.testing.assert_array_equal(got.view(np.uint32),
+                                  want.view(np.uint32))
+    assert counts == want_counts
+    assert 0 < counts["sparse_iters"]
+    assert 0 < counts["queue_items"] <= counts["queue_slots"]
+    # both queue rungs ran: slots of the low rung alone would be a
+    # multiple of it
+    low, top = eng.queue_rungs
+    assert counts["queue_slots"] % (low * parts) or \
+        counts["queue_slots"] // (low * parts) > counts["sparse_iters"]
+    if kind == "bfs-two-way":
+        assert eng.pull and counts["pull_iters"] > 0
+    if kind == "components":
+        np.testing.assert_array_equal(
+            got, components.reference_components(g))
+    else:
+        ref = sssp.reference_sssp(g, 0, weighted=w is not None)
+        if kind == "delta-float32":
+            np.testing.assert_allclose(got, ref, rtol=1e-5)
+        else:
+            np.testing.assert_array_equal(got.astype(np.int64), ref)

@@ -315,3 +315,67 @@ def test_serving_forms_emit_the_breakdown_scopes(cell, kind):
     family = "pull" if kind == "pagerank" else "push"
     missing = set(SERVING_SCOPES[family]) - found
     assert not missing, (cell, kind, sorted(missing))
+
+
+# the unbatched push cells, whose loop runs the sparse queue stage
+QUEUE_CELLS = [cell for cell in CELLS
+               if _config(cell).get("app") in ("sssp", "components")]
+
+
+def _gathers(jaxpr, mult=1, whiles=0, above="", out=None):
+    """Every ``gather`` of a traced program, through its calls, loops
+    and branches -> [(scope path, times a pass runs it, index vectors,
+    whiles around it)]: a ``scan``'s length multiplies what it holds
+    (a loop of static length traces as one), a ``while`` is counted."""
+    import math
+
+    import jax
+
+    out = [] if out is None else out
+    for eqn in jaxpr.eqns:
+        path = f"{above}/{eqn.source_info.name_stack}"
+        name = eqn.primitive.name
+        if name == "gather":
+            out.append((path, mult,
+                        math.prod(eqn.invars[1].aval.shape[:-1]), whiles))
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            _gathers(sub, mult * eqn.params["length"]
+                     if name == "scan" else mult,
+                     whiles + (name == "while"), path, out)
+    return out
+
+
+@pytest.mark.parametrize("cell", QUEUE_CELLS)
+def test_a_queue_slot_fetches_a_row_a_level(cell):
+    """What a queue slot fetches, counted in the traced loop of each
+    push cell's form, on BOTH queue rungs: under
+    ``lux_sparse_compact`` one row of splitters a level of the ranks'
+    tree and the label (levels + 1 fetches a slot), under
+    ``lux_sparse_expand`` (outside the budget stage's ``lux_eb``) one
+    row a level of the source index's tree and three scalars (levels
+    + 3); and no ``while`` of its own around any of them.  The binary
+    searches these replaced fetched log2(vpad) + 1 and log2(S) + 3 a
+    slot (22 and 24 on the road cell): a change that brings a step a
+    bit back shows here, not only on the chip."""
+    from lux_tpu.engine import frontier as fr
+
+    eng = _engines(cell)[_config(cell)["app"]]
+    jitted, args = eng.audit_programs()["converge"]
+    found = _gathers(jitted.trace(*args()).jaxpr.jaxpr)
+    S = eng.arrays["src_off"].shape[-1] - 1
+    assert eng.arrays["src_ids"].shape[1:] == (
+        sum(c for _f, c in fr.row_plan(S)), fr.ROW_FANOUT)
+    allowed = {"lux_sparse_compact": len(fr.row_plan(eng.sg.vpad)) + 1,
+               "lux_sparse_expand": len(fr.row_plan(S)) + 3}
+    assert len(eng.queue_rungs) == 2
+    for rung in range(2):
+        for scope, most in allowed.items():
+            mine = [(mult, n, whiles) for path, mult, n, whiles in found
+                    if f"lux_q{rung}/" in path and scope in path
+                    and "lux_eb" not in path and n > 1]
+            slots = max(n for _mult, n, _whiles in mine)
+            fetched = sum(mult * n for mult, n, _whiles in mine)
+            assert fetched == most * slots, (
+                cell, rung, scope, mine)
+            assert len({whiles for _m, _n, whiles in mine}) == 1, (
+                cell, rung, scope, mine)
